@@ -6,7 +6,6 @@ pipeline, expert, and sequence parallelism plus a ZeRO-1 distributed
 optimizer — expressed as one compiled SPMD program over a
 ``jax.sharding.Mesh`` instead of process groups, RPC, and threads.
 """
-from pipegoose_tpu.distributed import compat as _compat  # noqa: F401 — installs jax<0.6 shims
 from pipegoose_tpu.distributed import ParallelContext, ParallelMode
 
 __version__ = "0.1.0"

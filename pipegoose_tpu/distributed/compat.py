@@ -1,79 +1,12 @@
-"""JAX version compatibility shims (imported by ``pipegoose_tpu``'s
-package init, so they are installed before any framework code runs).
-
-APIs this codebase targets that moved under us on older jax:
-
-- ``shard_map``: ``jax.experimental.shard_map`` (< 0.6) takes
-  ``check_rep``; the promoted ``jax.shard_map`` renamed it
-  ``check_vma``. Every sharded entry point here disables that check
-  (pytree-of-arrays params defeat the replication inference), so the
-  kwarg mismatch was a runtime ``TypeError`` on every shard_map call
-  under jax 0.4.x. Import :func:`shard_map` from here — it speaks
-  ``check_vma`` and translates — instead of repeating the try/except
-  import dance at each call site.
-- ``jax.lax.axis_size`` (missing < 0.6): installed via the
-  ``psum(1, axis)`` const-fold.
-- ``pallas.tpu.CompilerParams`` (named ``TPUCompilerParams`` < 0.6):
-  aliased.
-- ``jax.distributed.is_initialized`` (missing < 0.6): read from the
-  coordination-service client handle.
-"""
+"""The codebase's ``shard_map`` call shape over ``jax.shard_map``."""
 from __future__ import annotations
-
-import inspect
 
 import jax
 
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.6
-except ImportError:  # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_HAS_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions; ``check_vma`` maps to the
-    old ``check_rep`` when running under jax < 0.6."""
-    if _HAS_VMA:
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
-try:
-    import jax.experimental.pallas.tpu as _pltpu
-
-    if not hasattr(_pltpu, "CompilerParams"):
-        # jax < 0.6 calls it TPUCompilerParams; the Pallas kernels
-        # (ops/flash_attention.py, ops/fused_ce.py) use the current name
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except ImportError:  # pallas not available on this build
-    pass
-
-if not hasattr(jax.distributed, "is_initialized"):
-    # jax < 0.6: the coordination client handle is the initialized flag
-    # (parallel_context.init_multihost's idempotency check)
-    def _is_initialized():
-        from jax._src import distributed as _dist
-
-        return _dist.global_state.client is not None
-
-    jax.distributed.is_initialized = _is_initialized
-
-if not hasattr(jax.lax, "axis_size"):
-    # jax < 0.6 has no ``lax.axis_size``; ``psum`` of a literal int
-    # const-folds to a STATIC python int at trace time (no collective
-    # emitted), which is exactly the newer API's contract — call sites
-    # here use it for static shape math (``n_head // tp``). Installed on
-    # jax.lax (not re-exported) so the ~40 existing call sites across
-    # the model/nn stack keep reading as the current-jax idiom.
-    def _axis_size(axis_name):
-        return jax.lax.psum(1, axis_name)
-
-    jax.lax.axis_size = _axis_size
+    """``jax.shard_map`` with positional mesh/specs and the replication
+    check off by default: every sharded entry point here passes
+    pytree-of-arrays params, which defeat the inference."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -39,8 +39,8 @@ NEG_INF = -1e9
 
 
 def _resolve_interpret(interpret):
-    # same convention as ops/flash_attention.py:728-732 — None = auto
-    # (compiled on TPU, interpreter elsewhere e.g. the CPU test mesh)
+    # None = choose by platform, as in ops/flash_attention.py (guarded
+    # by chip_smoke.py's tpu_custom_call assertions)
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret

@@ -31,7 +31,9 @@ serves speculative verify bundles and chunked prefill. Pad queries
 (beyond a row's ``n_valid``) produce garbage rows the CALLER zeroes
 via its qmask, matching ``_attn_core``'s contract.
 
-Tiles are (page_size, head_dim) per grid step — the page IS the block.
+Tiles are (page_size, n_head*head_dim) per grid step — the page IS the
+block, all local heads of it, flattened into the lane axis so that the
+chip's compiler accepts the block (tests/ops/test_chip_compile.py).
 ``check_paged_tile`` is the fused_ce-style feasibility guard: compiled
 runs raise loudly when the tile cannot fit VMEM (never a silent
 fallback to the gather path); the interpreter is exempt (no VMEM).
@@ -55,8 +57,8 @@ _SUBLANE = {4: 8, 2: 16, 1: 32}   # itemsize -> second-to-last tile height
 
 
 def _resolve_interpret(interpret):
-    # same convention as ops/flash_attention.py / ops/fused_ce.py —
-    # None = auto (compiled on TPU, interpreter elsewhere)
+    # None = choose by platform, as in ops/flash_attention.py (guarded
+    # by chip_smoke.py's tpu_custom_call assertions)
     if interpret is None:
         return jax.default_backend() != "tpu"
     return interpret
@@ -70,31 +72,50 @@ def _pad_up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
-def paged_tile_geometry(page_size: int, head_dim: int, n_queries: int,
-                        *, quantized: bool) -> dict:
-    """Host-side tile picker report for one kernel instantiation: the
-    (page_size, head_dim) KV tile the page-table walk DMAs per grid
-    step, with the VMEM working-set estimate the feasibility guard
-    checks. All inputs are trace-time constants (array shapes), so this
-    runs once per compiled program shape, never per step. The estimate
-    pads every buffer to Mosaic's physical tiles ((8|16|32) x 128 by
-    itemsize) and doubles the streamed operands for double buffering."""
-    kv_itemsize = 1 if quantized else 4      # int8 wire vs f32 in VMEM
-    ps_pad = _pad_up(page_size, _SUBLANE[kv_itemsize])
-    hd_pad = _pad_up(head_dim, _LANE)
+def _head_group(n_head: int, head_dim: int) -> int:
+    """Heads the kernel handles per lane-aligned column slab of the
+    flattened (nh*hd) axis: as many whole heads as fit 128 lanes (2 at
+    head_dim 64), one when head_dim is a multiple of 128. A slab that
+    is no multiple of 128 lanes (an odd head count, head_dim 96) is a
+    static column slice Mosaic still takes; test_chip_compile.py keeps
+    such cases."""
+    g = max(1, min(n_head, _LANE // head_dim))
+    while n_head % g:
+        g -= 1
+    return g
+
+
+def paged_tile_geometry(page_size: int, n_head: int, head_dim: int,
+                        n_queries: int, *, quantized: bool) -> dict:
+    """Host-side tile report for one kernel instantiation: the
+    (page_size, n_head*head_dim) KV tile the page-table walk DMAs per
+    grid step — one whole page, every local head — with the VMEM
+    working-set estimate the feasibility guard checks. All inputs are
+    trace-time constants (array shapes), so this runs once per compiled
+    program shape, never per step. The estimate pads every buffer to
+    Mosaic's physical tiles ((8|16|32) x 128 by itemsize), counts fp
+    pools at f32 (the widest the pool holds) and doubles every operand
+    that crosses HBM for double buffering."""
+    kv_itemsize = 1 if quantized else 4
+    row = _pad_up(n_head * head_dim, _LANE)
     c_pad = _pad_up(n_queries, _SUBLANE[4])
-    kv_tile = ps_pad * hd_pad * kv_itemsize
-    scale_tile = _pad_up(page_size, _SUBLANE[4]) * _LANE * 4
-    streamed = 2 * kv_tile + (2 * scale_tile if quantized else 0)
-    resident = (
-        c_pad * hd_pad * 4            # q tile (f32 in-register)
-        + c_pad * hd_pad * 4          # acc scratch
-        + 2 * c_pad * _LANE * 4       # m/l scratch ((C,1) padded)
-        + c_pad * hd_pad * 4          # output tile
+    kv_tile = _pad_up(page_size, _SUBLANE[kv_itemsize]) * row * kv_itemsize
+    scale_tile = (_pad_up(page_size, _SUBLANE[4]) * _pad_up(n_head, _LANE) * 4
+                  if quantized else 0)
+    q_tile = c_pad * row * 4
+    piped = 2 * (kv_tile + scale_tile) + 2 * q_tile      # k, v, q, out
+    scratch = (
+        q_tile                                 # acc
+        + 2 * n_head * c_pad * _LANE * 4       # m/l ((nh, C, 1) padded)
     )
-    vmem_bytes = 2 * streamed + resident   # x2: double-buffered stream
+    # in-register working set of one head group: dequantized f32 k/v
+    # slabs and the (C, slab) q/acc rows
+    slab = _pad_up(_head_group(n_head, head_dim) * head_dim, _LANE)
+    live = 2 * _pad_up(page_size, _SUBLANE[4]) * slab * 4 + 2 * c_pad * slab * 4
+    vmem_bytes = 2 * piped + scratch + live
     return {
         "block_kv": page_size,
+        "n_head": n_head,
         "head_dim": head_dim,
         "n_queries": n_queries,
         "quantized": quantized,
@@ -104,22 +125,22 @@ def paged_tile_geometry(page_size: int, head_dim: int, n_queries: int,
     }
 
 
-def check_paged_tile(page_size: int, head_dim: int, n_queries: int, *,
-                     quantized: bool,
+def check_paged_tile(page_size: int, n_head: int, head_dim: int,
+                     n_queries: int, *, quantized: bool,
                      interpret: Optional[bool] = None) -> dict:
     """The fused_ce-style loud guard: returns the geometry dict when the
-    (page_size, head_dim) tile fits the VMEM budget, raises ValueError
-    for COMPILED runs when it cannot — never a silent fallback to the
-    gather path (a half-switched fleet would silently lose the perf the
-    config claims). Interpret-mode runs are exempt: the interpreter has
-    no VMEM limit, and the CPU test mesh must keep covering oversized
-    geometries."""
-    geom = paged_tile_geometry(page_size, head_dim, n_queries,
+    (page_size, n_head*head_dim) tile fits the VMEM budget, raises
+    ValueError for COMPILED runs when it cannot — never a silent
+    fallback to the gather path (a half-switched fleet would silently
+    lose the perf the config claims). Interpret-mode runs are exempt:
+    the interpreter has no VMEM limit, and the CPU test mesh must keep
+    covering oversized geometries."""
+    geom = paged_tile_geometry(page_size, n_head, head_dim, n_queries,
                                quantized=quantized)
     if not geom["fits"] and not _resolve_interpret(interpret):
         raise ValueError(
-            f"paged attention: a (page_size={page_size} x "
-            f"head_dim={head_dim}) KV tile with C={n_queries} queries "
+            f"paged attention: a (page_size={page_size} x n_head={n_head} "
+            f"x head_dim={head_dim}) KV tile with C={n_queries} queries "
             f"needs ~{geom['vmem_bytes']} bytes of VMEM "
             f"(budget {VMEM_BUDGET_BYTES}) on hardware. Shrink "
             f"page_size (the page IS the kernel block) or keep "
@@ -241,17 +262,29 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
     _, w_pages = page_table.shape
     quantized = _is_quantized(k_pages)
     ps = (k_pages["q"] if quantized else k_pages).shape[1]
-    check_paged_tile(ps, hd, c, quantized=quantized, interpret=interpret)
+    check_paged_tile(ps, nh, hd, c, quantized=quantized, interpret=interpret)
     if interpret is None and jax.default_backend() != "tpu":
-        # auto mode off-TPU takes the compiled one-pass lane — same
-        # algorithm, XLA-jitted. interpret=True still forces the Pallas
-        # interpreter (the kernel-logic tests pin that path).
+        # auto mode picks by platform: off the TPU it is this XLA lane
+        # (same algorithm), on it the compiled kernel. chip_smoke.py's
+        # tpu_custom_call assertion guards the choice.
         return _xla_one_pass(q, k_pages, v_pages, page_table,
                              start.astype(jnp.int32), slopes)
     interpret = _resolve_interpret(interpret)
     scale = hd ** -0.5
     page_table = page_table.astype(jnp.int32)
     start = start.astype(jnp.int32)
+    # Heads are flattened into the lane axis — a free reshape of the
+    # (.., nh, hd) pool layout — so every block's last two dims equal
+    # the array's (Mosaic's tiling rule) and one DMA fetches one whole
+    # contiguous page. The kernel walks the heads in lane-aligned slabs
+    # of `group` heads; within a slab a head is picked by masking q's
+    # lanes (the other heads' lanes contribute zero to the contraction).
+    group = _head_group(nh, hd)
+    slab = group * hd
+    row = nh * hd
+
+    def flat(x):
+        return x.reshape(x.shape[:2] + (row,))
 
     def kernel(pt_ref, start_ref, slopes_ref, q_ref, *rest):
         if quantized:
@@ -260,36 +293,26 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
         else:
             k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc = rest
         bi = pl.program_id(0)
-        hi = pl.program_id(1)
-        wi = pl.program_id(2)
-        slope = slopes_ref[hi]
+        wi = pl.program_id(1)
         row_start = start_ref[bi]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, slab), 1)
+
+        def lanes_of(j):
+            """The slab lanes that hold the slab's j-th head."""
+            return (lane >= j * hd) & (lane < (j + 1) * hd)
 
         @pl.when(wi == 0)
         def _init():
-            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-            l_sc[:] = jnp.zeros_like(l_sc)
-            acc_sc[:] = jnp.zeros_like(acc_sc)
+            m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
+            acc_sc[...] = jnp.zeros_like(acc_sc)
 
         # pages whose FIRST key position exceeds the row's last query
         # position are fully masked: skip the whole tile. Their table
         # entries are NULL, so consecutive skipped steps revisit block
-        # (0, 0, hi, 0) and Pallas elides the redundant DMAs too.
+        # 0 and Pallas elides the redundant DMAs too.
         @pl.when(wi * ps <= row_start + (c - 1))
         def _compute():
-            qb = q_ref[0, :, 0, :].astype(jnp.float32)       # (C, hd)
-            if quantized:
-                kb = (kq_ref[0, :, 0, :].astype(jnp.float32)
-                      * ks_ref[0])                           # (ps, hd)
-                vb = (vq_ref[0, :, 0, :].astype(jnp.float32)
-                      * vs_ref[0])
-            else:
-                kb = k_ref[0, :, 0, :].astype(jnp.float32)
-                vb = v_ref[0, :, 0, :].astype(jnp.float32)
-            s_blk = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                        # (C, ps)
             # logical key position = w*ps + offset: the grid's w IS the
             # logical page index — physical indirection lives only in
             # the index maps, so the mask math matches _paged_bias
@@ -299,74 +322,93 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
             q_pos = row_start + jax.lax.broadcasted_iota(
                 jnp.int32, (c, ps), 0
             )
-            bias = slope * key_pos.astype(jnp.float32)
-            s_blk = s_blk + bias + jnp.where(
-                key_pos <= q_pos, 0.0, NEG_INF
-            )
-            m_prev = m_sc[:, 0]
-            m_new = jnp.maximum(m_prev, s_blk.max(axis=1))
-            p = jnp.exp(s_blk - m_new[:, None])
-            alpha = jnp.exp(m_prev - m_new)
-            l_sc[:, 0] = l_sc[:, 0] * alpha + p.sum(axis=1)
-            acc_sc[:] = acc_sc[:] * alpha[:, None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_sc[:, 0] = m_new
+            causal = jnp.where(key_pos <= q_pos, 0.0, NEG_INF)
+            key_posf = key_pos.astype(jnp.float32)
+            for gi in range(nh // group):
+                cols = slice(gi * slab, (gi + 1) * slab)
+                qb = q_ref[0, :, cols].astype(jnp.float32)   # (C, slab)
+                if quantized:
+                    kb = kq_ref[0, :, cols].astype(jnp.float32)
+                    vb = vq_ref[0, :, cols].astype(jnp.float32)
+                    ksb, vsb = ks_ref[0], vs_ref[0]          # (ps, nh)
+                else:
+                    kb = k_ref[0, :, cols].astype(jnp.float32)
+                    vb = v_ref[0, :, cols].astype(jnp.float32)
+                acc = acc_sc[:, cols]                        # (C, slab)
+                for j in range(group):
+                    h = gi * group + j
+                    mine = lanes_of(j)
+                    if quantized:
+                        # per-(position, head) scales: one column of the
+                        # (ps, nh) plane, spread over this head's lanes
+                        kb = jnp.where(mine, kb * ksb[:, h:h + 1], kb)
+                        vb = jnp.where(mine, vb * vsb[:, h:h + 1], vb)
+                    s_blk = jax.lax.dot_general(
+                        jnp.where(mine, qb, 0.0), kb,
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ) * scale                                # (C, ps)
+                    s_blk = s_blk + slopes_ref[h] * key_posf + causal
+                    m_prev = m_sc[h, :, 0]
+                    m_new = jnp.maximum(m_prev, s_blk.max(axis=1))
+                    p = jnp.exp(s_blk - m_new[:, None])
+                    alpha = jnp.exp(m_prev - m_new)
+                    l_sc[h, :, 0] = l_sc[h, :, 0] * alpha + p.sum(axis=1)
+                    m_sc[h, :, 0] = m_new
+                    pv = jax.lax.dot_general(
+                        p, vb, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    )                                        # (C, slab)
+                    acc = jnp.where(mine, acc * alpha[:, None] + pv, acc)
+                acc_sc[:, cols] = acc
 
         @pl.when(wi == w_pages - 1)
         def _finish():
-            l = jnp.maximum(l_sc[:, 0], 1e-30)
-            o_ref[0, :, 0, :] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
+            for gi in range(nh // group):
+                cols = slice(gi * slab, (gi + 1) * slab)
+                inv = jnp.zeros((c, slab), jnp.float32)
+                for j in range(group):
+                    l = jnp.maximum(l_sc[gi * group + j, :, 0], 1e-30)
+                    inv = jnp.where(lanes_of(j), (1.0 / l)[:, None], inv)
+                o_ref[0, :, cols] = (acc_sc[:, cols] * inv).astype(o_ref.dtype)
 
-    def qidx(bi, hi, wi, pt_ref, start_ref):
-        return (bi, 0, hi, 0)
+    def qidx(bi, wi, pt_ref, start_ref):
+        return (bi, 0, 0)
 
-    def kvidx(bi, hi, wi, pt_ref, start_ref):
-        return (pt_ref[bi, wi], 0, hi, 0)
+    def kvidx(bi, wi, pt_ref, start_ref):
+        return (pt_ref[bi, wi], 0, 0)
 
-    def scidx(bi, hi, wi, pt_ref, start_ref):
-        return (pt_ref[bi, wi], 0, hi)
-
-    pl_ = pl  # keep the closure explicit for the spec builders below
-    q_spec = pl_.BlockSpec((1, c, 1, hd), qidx)
-    slope_spec = pl_.BlockSpec((nh,), lambda bi, hi, wi, pt, st: (0,),
-                               memory_space=pltpu.SMEM)
+    q_spec = pl.BlockSpec((1, c, row), qidx)
+    page_spec = pl.BlockSpec((1, ps, row), kvidx)
+    slope_spec = pl.BlockSpec((nh,), lambda bi, wi, pt, st: (0,),
+                              memory_space=pltpu.SMEM)
     if quantized:
-        in_specs = [
-            slope_spec, q_spec,
-            pl_.BlockSpec((1, ps, 1, hd), kvidx),   # k int8 plane
-            pl_.BlockSpec((1, ps, 1), scidx),       # k scale plane
-            pl_.BlockSpec((1, ps, 1, hd), kvidx),   # v int8 plane
-            pl_.BlockSpec((1, ps, 1), scidx),       # v scale plane
-        ]
-        operands = (slopes.astype(jnp.float32), q,
-                    k_pages["q"], k_pages["scale"],
-                    v_pages["q"], v_pages["scale"])
+        scale_spec = pl.BlockSpec((1, ps, nh), kvidx)
+        in_specs = [slope_spec, q_spec,
+                    page_spec, scale_spec, page_spec, scale_spec]
+        operands = (flat(k_pages["q"]), k_pages["scale"],
+                    flat(v_pages["q"]), v_pages["scale"])
     else:
-        in_specs = [
-            slope_spec, q_spec,
-            pl_.BlockSpec((1, ps, 1, hd), kvidx),
-            pl_.BlockSpec((1, ps, 1, hd), kvidx),
-        ]
-        operands = (slopes.astype(jnp.float32), q, k_pages, v_pages)
+        in_specs = [slope_spec, q_spec, page_spec, page_spec]
+        operands = (flat(k_pages), flat(v_pages))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, nh, w_pages),
+            grid=(b, w_pages),
             in_specs=in_specs,
-            out_specs=pl_.BlockSpec((1, c, 1, hd), qidx),
+            out_specs=pl.BlockSpec((1, c, row), qidx),
             scratch_shapes=[
-                pltpu.VMEM((c, 1), jnp.float32),
-                pltpu.VMEM((c, 1), jnp.float32),
-                pltpu.VMEM((c, hd), jnp.float32),
+                pltpu.VMEM((nh, c, 1), jnp.float32),
+                pltpu.VMEM((nh, c, 1), jnp.float32),
+                pltpu.VMEM((c, row), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, c, nh, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, c, row), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(page_table, start, *operands)
+    )(page_table, start, slopes.astype(jnp.float32), flat(q), *operands)
+    return out.reshape(b, c, nh, hd)

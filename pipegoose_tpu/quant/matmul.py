@@ -45,13 +45,15 @@ def _resolve_impl(impl: Optional[str]) -> str:
 def unpack_int4(packed: jax.Array) -> jax.Array:
     """(..., K//2, N) int8 -> (..., K, N) int8 values in [-8, 7]: the
     low nibble is row 2i, the high nibble row 2i+1 (arithmetic shifts
-    sign-extend, matching pack_int4's two's-complement nibbles)."""
-    low = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    high = jnp.right_shift(packed, 4)
+    sign-extend, matching pack_int4's two's-complement nibbles). The
+    shifts run on int32: Mosaic has no shift on int8 vectors."""
+    wide = packed.astype(jnp.int32)
+    low = jnp.right_shift(jnp.left_shift(wide, 28), 28)
+    high = jnp.right_shift(wide, 4)
     inter = jnp.stack([low, high], axis=-2)  # (..., K//2, 2, N)
     return inter.reshape(
         packed.shape[:-2] + (packed.shape[-2] * 2, packed.shape[-1])
-    )
+    ).astype(jnp.int8)
 
 
 def dequantize_weight(q: jax.Array, scale: jax.Array) -> jax.Array:

@@ -657,8 +657,9 @@ class ServingEngine:
         from pipegoose_tpu.ops.paged_attention import paged_tile_geometry
 
         head_dim = self.config.hidden_size // self.config.n_head
+        tp = self.mesh.shape[self.tp_axis] if self.mesh is not None else 1
         return paged_tile_geometry(
-            self.page_size, head_dim, n_queries,
+            self.page_size, self.config.n_head // tp, head_dim, n_queries,
             quantized=self.kv_dtype == "int8",
         )
 

@@ -21,7 +21,6 @@ copy.
 from __future__ import annotations
 
 import re
-import warnings
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -102,21 +101,12 @@ HBM_BW_BYTES: Dict[str, float] = {
 DCI_AXES: tuple = ("diloco",)
 
 
-# documented fallbacks for device kinds absent from the spec tables —
-# finite, clearly-not-real numbers (the "cpu" placeholder philosophy)
-# so an unknown chip plans/meters with the same code path instead of
-# dividing by zero. The lookup WARNS when it falls back: a silent
-# default would let a typo'd --device-kind quietly score every layout
-# against the wrong machine.
-DEFAULT_PEAK_FLOPS = 1e12
-DEFAULT_ICI_BYTES = 10e9
-DEFAULT_DCI_BYTES = 1e9
-DEFAULT_HBM_BYTES = 16 * 1024**3
-DEFAULT_HBM_BW_BYTES = 100e9
-
-
 def _kind_lookup(table: Dict[str, float], device_kind: Optional[str],
-                 default: float, table_name: str = "") -> float:
+                 table_name: str) -> float:
+    """Substring match of a device-kind string against a spec table. A
+    kind no row matches is an error: a default would score a typo'd
+    --device-kind, or a chip nobody has entered, against another
+    machine's numbers."""
     if device_kind is None:
         dev = jax.devices()[0]
         device_kind = getattr(dev, "device_kind", dev.platform)
@@ -124,54 +114,42 @@ def _kind_lookup(table: Dict[str, float], device_kind: Optional[str],
     for k, v in table.items():
         if k in kind:
             return v
-    warnings.warn(
-        f"unknown device kind {device_kind!r}: no {table_name or 'spec-table'}"
-        f" entry matches — falling back to the documented default "
-        f"{default:g} (plans/meters against this kind are placeholders, "
-        f"not hardware numbers)",
-        stacklevel=3,
+    raise ValueError(
+        f"unknown device kind {device_kind!r}: no row of "
+        f"telemetry.derived.{table_name} matches (rows: {sorted(table)}) "
+        f"— add the chip's published number to the table"
     )
-    return default
 
 
 def peak_flops_for(device_kind: Optional[str] = None) -> float:
     """Peak FLOP/s for a device-kind string (substring match, like
-    bench.py always did); defaults to the first visible device. Unknown
-    kinds fall back LOUDLY (UserWarning) to ``DEFAULT_PEAK_FLOPS``."""
-    return _kind_lookup(PEAK_FLOPS, device_kind, DEFAULT_PEAK_FLOPS,
-                        "PEAK_FLOPS")
+    bench.py always did); defaults to the first visible device. An
+    unknown kind raises, here and in the peer lookups below."""
+    return _kind_lookup(PEAK_FLOPS, device_kind, "PEAK_FLOPS")
 
 
 def ici_bytes_per_s_for(device_kind: Optional[str] = None) -> float:
     """Per-chip intra-slice interconnect bandwidth (B/s) for a
-    device-kind string; defaults to the first visible device. Unknown
-    kinds fall back LOUDLY to ``DEFAULT_ICI_BYTES``."""
-    return _kind_lookup(PEAK_ICI_BYTES, device_kind, DEFAULT_ICI_BYTES,
-                        "PEAK_ICI_BYTES")
+    device-kind string; defaults to the first visible device."""
+    return _kind_lookup(PEAK_ICI_BYTES, device_kind, "PEAK_ICI_BYTES")
 
 
 def dci_bytes_per_s_for(device_kind: Optional[str] = None) -> float:
-    """Per-chip cross-slice (data-center network) bandwidth (B/s).
-    Unknown kinds fall back LOUDLY to ``DEFAULT_DCI_BYTES``."""
-    return _kind_lookup(PEAK_DCI_BYTES, device_kind, DEFAULT_DCI_BYTES,
-                        "PEAK_DCI_BYTES")
+    """Per-chip cross-slice (data-center network) bandwidth (B/s)."""
+    return _kind_lookup(PEAK_DCI_BYTES, device_kind, "PEAK_DCI_BYTES")
 
 
 def hbm_bytes_for(device_kind: Optional[str] = None) -> float:
     """Per-chip HBM capacity (bytes) from the spec table — the planner's
     feasibility budget where the backend reports no live ``bytes_limit``
-    (fake CPU devices report none). Unknown kinds fall back LOUDLY to
-    ``DEFAULT_HBM_BYTES``."""
-    return _kind_lookup(HBM_BYTES, device_kind, DEFAULT_HBM_BYTES,
-                        "HBM_BYTES")
+    (fake CPU devices report none)."""
+    return _kind_lookup(HBM_BYTES, device_kind, "HBM_BYTES")
 
 
 def hbm_bw_bytes_per_s_for(device_kind: Optional[str] = None) -> float:
     """Per-chip HBM bandwidth (B/s) — the memory-bound decode cost
-    model's denominator (planner/serving.py). Unknown kinds fall back
-    LOUDLY to ``DEFAULT_HBM_BW_BYTES``."""
-    return _kind_lookup(HBM_BW_BYTES, device_kind, DEFAULT_HBM_BW_BYTES,
-                        "HBM_BW_BYTES")
+    model's denominator (planner/serving.py)."""
+    return _kind_lookup(HBM_BW_BYTES, device_kind, "HBM_BW_BYTES")
 
 
 def mfu(flops_per_step: float, step_seconds: float,

@@ -34,6 +34,7 @@ import zlib
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
+from jax._src.core import trace_state_clean
 
 
 def _tracing(value: Any = None) -> bool:
@@ -42,10 +43,7 @@ def _tracing(value: Any = None) -> bool:
     compile, not per execution)."""
     if isinstance(value, jax.core.Tracer):
         return True
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # noqa: BLE001 - exotic jax builds: fail open
-        return False
+    return not trace_state_clean()
 
 
 class _AlwaysEnabled:

@@ -350,11 +350,15 @@ def attribute_op_times(
     collectives: List[Dict[str, Any]] = []
     top_ops: List[Dict[str, Any]] = []
     for name, secs in per_op.items():
-        if not _is_collective_name(name):
+        # XLA names an instruction after the op that made it
+        # (``psum.14`` is an all-reduce), so the schedule — parsed from
+        # the HLO by op kind — says what is a collective; the name
+        # prefix only catches those the schedule does not list
+        info = by_name.get(name) or by_name.get(_base_collective_name(name))
+        if info is None and not _is_collective_name(name):
             compute_s += secs
             top_ops.append({"name": name, "seconds": secs})
             continue
-        info = by_name.get(name) or by_name.get(_base_collective_name(name))
         axes = tuple(info.mesh_axes) if info is not None and info.mesh_axes \
             else None
         key = "+".join(axes) if axes else "?"
@@ -394,7 +398,7 @@ def _trace_session(logdir: str, create_perfetto_trace: bool = False):
     being profiled. The XLA op events this module consumes come from
     the host/device tracers, so the Python tracer is pure observer
     effect here. Falls back to plain ``jax.profiler.trace`` when the
-    session API is unavailable (it is on the container's jax 0.4.37).
+    (private) session API is unavailable.
     """
     try:
         from jax._src.lib import xla_client

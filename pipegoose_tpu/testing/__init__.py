@@ -37,28 +37,10 @@ __all__ = [
     "fake_cluster",
     "set_fake_device_flags",
     "force_cpu_devices",
-    "old_jax_cpu_reason",
     "parameter_similarity",
     "assert_trees_allclose",
     "random_input_ids",
 ]
-
-
-def old_jax_cpu_reason(feature: str = "this check") -> Any:
-    """Non-None (a human-readable reason) when the running environment
-    is jax < 0.5 on the CPU backend — the combination several tests can
-    NEVER pass under (multiprocess collectives unimplemented, Pallas
-    interpret-mode f32 reduction-order drift). The single shared
-    predicate the test suite's environment-detection skips use."""
-    import jax
-
-    version = tuple(int(x) for x in jax.__version__.split(".")[:2])
-    if version < (0, 5) and jax.default_backend() == "cpu":
-        return (
-            f"jax {jax.__version__} on the CPU backend cannot run "
-            f"{feature} (needs jax >= 0.5 or a real TPU/GPU backend)"
-        )
-    return None
 
 
 def force_cpu_devices(n: int = 8) -> None:

@@ -215,7 +215,7 @@ class Trainer:
         reports should quote. Default: equal batch weights (exact when
         every batch carries the same token count)."""
         if self._eval_fn is None:
-            from pipegoose_tpu.parallel.hybrid import shard_map  # jax<0.6-safe
+            from pipegoose_tpu.parallel.hybrid import shard_map
 
             in_specs = (self.param_specs, self._batch_spec) + (
                 (P(),) if self.with_rng else ()

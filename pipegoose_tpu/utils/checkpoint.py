@@ -33,6 +33,7 @@ the retry path without monkeypatching orbax.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 import shutil
 import time
@@ -69,6 +70,18 @@ def _checkpointer():
     return ocp.StandardCheckpointer()
 
 
+def _retry_io(fn: Callable[[], None], retries: int, backoff_s: float) -> None:
+    """Run ``fn``; a transient ``OSError`` retries it with exponential
+    backoff, ``retries`` attempts beyond the first, then surfaces."""
+    for attempt in range(retries + 1):
+        try:
+            return fn()
+        except OSError:
+            if attempt >= retries:
+                raise
+            time.sleep(backoff_s * (2 ** attempt))
+
+
 def save_pretrained(
     params: Any,
     path: str,
@@ -94,27 +107,31 @@ def save_pretrained(
         # a doomed save doesn't burn I/O (and the rename can't clobber)
         raise ValueError(f"checkpoint already exists: {path}")
     tmp = path + TMP_SUFFIX
-    last_err: Optional[BaseException] = None
-    for attempt in range(retries + 1):
-        try:
-            if _IO_FAULT_HOOK is not None:
-                _IO_FAULT_HOOK()
-            if os.path.isdir(tmp):
-                # stale sibling from a crashed/failed earlier attempt
-                shutil.rmtree(tmp)
-            ckpt = _checkpointer()
-            ckpt.save(tmp, params)
-            ckpt.wait_until_finished()
-            os.rename(tmp, path)  # the commit point: atomic on one fs
-            return path
-        except OSError as e:  # transient I/O: retry with backoff
-            last_err = e
-            if attempt >= retries:
-                raise
-            time.sleep(backoff_s * (2 ** attempt))
-    raise RuntimeError(  # pragma: no cover - loop always returns/raises
-        f"checkpoint save failed after {retries + 1} attempts: {last_err}"
-    )
+
+    def write():
+        if _IO_FAULT_HOOK is not None:
+            _IO_FAULT_HOOK()
+        if os.path.isdir(tmp):
+            # stale sibling from a crashed/failed earlier attempt
+            shutil.rmtree(tmp)
+        ckpt = _checkpointer()
+        ckpt.save(tmp, params)
+        ckpt.wait_until_finished()
+
+    _retry_io(write, retries, backoff_s)
+    # the commit point: atomic on one fs. One process renames, and a
+    # failed rename retries the rename alone: the save above is
+    # collective, and a process that repeated it by itself would leave
+    # the others waiting at the barrier below
+    if jax.process_index() == 0:
+        _retry_io(lambda: os.rename(tmp, path), retries, backoff_s)
+    if jax.process_count() > 1:
+        from jax.experimental import multihost_utils
+
+        multihost_utils.sync_global_devices(
+            f"pipegoose_checkpoint_commit:{path}"
+        )
+    return path
 
 
 def from_pretrained(
@@ -127,7 +144,16 @@ def from_pretrained(
     from_pretrained, nn/utils.py:31-50, could only reload the exact
     (tp, pp) layout that saved). ``like`` is a pytree of arrays or
     ShapeDtypeStructs giving structure/shape/dtype; ``specs`` (optional)
-    a matching PartitionSpec tree for the target sharding."""
+    a matching PartitionSpec tree for the target sharding. Under a
+    ``ParallelContext``, a stored array whose shape differs from
+    ``like``'s raises."""
+    return _restore(path, like, specs, parallel_context)
+
+
+def _restore(path, like, specs, parallel_context, recut=()):
+    """``from_pretrained``; the top-level subtrees named in ``recut``
+    may change shape on the way in (orbax truncates or zero-pads them),
+    every other leaf must match its stored shape."""
     import orbax.checkpoint as ocp
 
     path = os.path.abspath(path)
@@ -149,7 +175,19 @@ def from_pretrained(
     target = jax.tree_util.tree_map(
         to_struct, like, specs, is_leaf=lambda x: hasattr(x, "shape")
     )
-    return _checkpointer().restore(path, target)
+    restore_args = ocp.checkpoint_utils.construct_restore_args(target)
+
+    def relax(key_path, arg):
+        if key_path[0].key in recut and hasattr(arg, "strict"):
+            return dataclasses.replace(arg, strict=False)
+        return arg
+
+    if recut:
+        restore_args = jax.tree_util.tree_map_with_path(relax, restore_args)
+    return ocp.PyTreeCheckpointer().restore(
+        path,
+        args=ocp.args.PyTreeRestore(item=target, restore_args=restore_args),
+    )
 
 
 def _complete_step(path: str, name: str) -> Optional[int]:
@@ -226,6 +264,11 @@ def restore_train_state(
         step = latest_step(path)
         if step is None:
             raise FileNotFoundError(f"no step_N checkpoints under {path}")
-    return from_pretrained(
-        os.path.join(path, f"step_{step}"), like, specs, parallel_context
+    # only the optimizer state may be re-cut: a ZeRO moment's dim 0 is
+    # zero-padded to a multiple of dp, so a restore onto a mesh with
+    # another dp (the elastic 8 -> 4 path) drops or adds padding rows.
+    # A parameter of another shape is another model, and raises
+    return _restore(
+        os.path.join(path, f"step_{step}"), like, specs, parallel_context,
+        recut=("opt_state",),
     )

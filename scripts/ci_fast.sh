@@ -17,11 +17,9 @@ cd "$(dirname "$0")/.."
 export JAX_PLATFORMS=cpu
 
 # Persistent XLA compilation cache for the SERVING smokes (same dir as
-# tests/conftest.py — see there for why it is serving-only: this
-# jaxlib segfaults deserializing hybrid train-step executables, while
-# jit-pure serving programs round-trip cleanly). Prefix a smoke's
-# python invocation with $JAX_SERVING_CACHE_ENV to opt it in.
-JAX_SERVING_CACHE_ENV="JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-/tmp/pipegoose_jax_cache} JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0 JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=0"
+# tests/conftest.py — see there for why it is serving-only). Prefix a
+# smoke's python invocation with $JAX_SERVING_CACHE_ENV to opt it in.
+JAX_SERVING_CACHE_ENV="JAX_COMPILATION_CACHE_DIR=${JAX_COMPILATION_CACHE_DIR:-$PWD/.jax_cache} JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0 JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=0"
 
 # Static jit-safety lint FIRST (scripts/lint_jit_safety.py): pure AST,
 # no jax import — host-sync calls (.item(), np.asarray, time.*,

@@ -125,9 +125,8 @@ def main() -> int:
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--heads", type=int, default=4)
     ap.add_argument("--fake-devices", type=int, default=None,
-                    help="force N fake CPU devices (XLA_FLAGS host "
-                         "platform count; works under a sitecustomize "
-                         "that pins an accelerator platform)")
+                    help="run on N fake CPU devices (XLA_FLAGS host "
+                         "platform count)")
     ap.add_argument("--serving", action="store_true",
                     help="also doctor the paged decode step and the "
                          "chunked-prefill mixed-step program")

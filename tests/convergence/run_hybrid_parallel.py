@@ -42,8 +42,7 @@ def main():
     ap.add_argument("--out", default=None, help="write a JSON run record")
     ap.add_argument(
         "--platform", choices=("auto", "cpu"), default="auto",
-        help="'cpu' pins the fake-CPU-device backend before first use "
-        "(needed where a sitecustomize pins an accelerator plugin)",
+        help="'cpu' pins the fake-CPU-device backend before first use",
     )
     args = ap.parse_args()
 

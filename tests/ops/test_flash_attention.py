@@ -86,19 +86,6 @@ def test_bf16():
 def test_padded_batch_matches_reference():
     """Right-padded batch: flash with attention_mask == XLA reference with
     the same kv_pos/kv_neg biases (forward AND backward)."""
-    from pipegoose_tpu.testing import old_jax_cpu_reason
-
-    # environment detection, not a blanket skip: interpret-mode Pallas
-    # on jax 0.4.x CPU accumulates the backward's delta subtraction
-    # with different f32 reductions than newer builds — ~1/65536 grad
-    # elements land at 1.3e-5 vs the 1e-5 atol. Real TPUs (and
-    # jax >= 0.5 interpret mode) pass at these tolerances.
-    reason = old_jax_cpu_reason(
-        "this interpret-mode grad-tolerance check (f32 reduction-order "
-        "drift misses the atol by ~1.3x on isolated elements)"
-    )
-    if reason is not None:
-        pytest.skip(reason)
     q, k, v = _qkv(5)
     slopes = jnp.asarray(alibi_slopes(NH))
     mask = np.ones((B, S), np.int32)

@@ -172,12 +172,12 @@ def test_tp2_head_sharded_matches_single_device(case, devices):
 
 
 def test_tile_geometry_reports_footprint():
-    g = paged_tile_geometry(PS, HD, 1, quantized=False)
+    g = paged_tile_geometry(PS, NH, HD, 1, quantized=False)
     assert g["fits"] is True and g["vmem_bytes"] <= g["vmem_budget_bytes"]
-    gq = paged_tile_geometry(PS, HD, 1, quantized=True)
+    gq = paged_tile_geometry(PS, NH, HD, 1, quantized=True)
     # the quantized tile streams an extra scale plane per operand
     assert gq["vmem_bytes"] > g["vmem_bytes"]
-    assert paged_tile_geometry(4096, 4096, 1, quantized=True)["fits"] is False
+    assert paged_tile_geometry(4096, 1, 4096, 1, quantized=True)["fits"] is False
 
 
 def test_guard_raises_compiled_exempt_interpret():
@@ -185,8 +185,8 @@ def test_guard_raises_compiled_exempt_interpret():
     head_dim tile refuses to compile, loudly, naming the footprint.
     The interpreter has no VMEM limit, so interpret runs are exempt."""
     with pytest.raises(ValueError, match="VMEM"):
-        check_paged_tile(4096, 4096, 1, quantized=True, interpret=False)
-    g = check_paged_tile(4096, 4096, 1, quantized=True, interpret=True)
+        check_paged_tile(4096, 1, 4096, 1, quantized=True, interpret=False)
+    g = check_paged_tile(4096, 1, 4096, 1, quantized=True, interpret=True)
     assert g["fits"] is False          # reported honestly even when exempt
-    ok = check_paged_tile(PS, HD, 1, quantized=True, interpret=False)
+    ok = check_paged_tile(PS, NH, HD, 1, quantized=True, interpret=False)
     assert ok["fits"] is True

@@ -15,8 +15,9 @@ def test_peak_flops_table_substring_match():
     assert derived.peak_flops_for("TPU v5e") == 197e12
     assert derived.peak_flops_for("TPU v5 lite") == 197e12
     assert derived.peak_flops_for("v5p slice") == 459e12
-    assert derived.peak_flops_for("cpu-fallback") == 1e12
-    assert derived.peak_flops_for("martian accelerator") == 1e12  # default
+    assert derived.peak_flops_for("cpu") == 1e12  # fake-device planning row
+    with pytest.raises(ValueError, match="PEAK_FLOPS"):
+        derived.peak_flops_for("TPU v9")
 
 
 def test_mfu_arithmetic():
@@ -169,32 +170,25 @@ def test_iter_collectives_line_level():
     ]
 
 
-def test_unknown_device_kind_falls_back_loudly():
-    """ISSUE 14 satellite: every `*_for` peer-table lookup must fall
-    back to its DOCUMENTED default on an unknown device kind — and WARN
-    naming the table, never return a silent zero (a typo'd
-    --device-kind would otherwise score every layout against garbage).
-    Pinned for PEAK_FLOPS / ICI / DCI / HBM (+ HBM bandwidth)."""
-    cases = [
-        (derived.peak_flops_for, derived.DEFAULT_PEAK_FLOPS, "PEAK_FLOPS"),
-        (derived.ici_bytes_per_s_for, derived.DEFAULT_ICI_BYTES,
-         "PEAK_ICI_BYTES"),
-        (derived.dci_bytes_per_s_for, derived.DEFAULT_DCI_BYTES,
-         "PEAK_DCI_BYTES"),
-        (derived.hbm_bytes_for, derived.DEFAULT_HBM_BYTES, "HBM_BYTES"),
-        (derived.hbm_bw_bytes_per_s_for, derived.DEFAULT_HBM_BW_BYTES,
-         "HBM_BW_BYTES"),
-    ]
-    for fn, default, table in cases:
-        with pytest.warns(UserWarning, match=table):
-            got = fn("martian accelerator v9")
-        assert got == default and got > 0
+@pytest.mark.parametrize("fn_name,table", [
+    ("peak_flops_for", "PEAK_FLOPS"),
+    ("ici_bytes_per_s_for", "PEAK_ICI_BYTES"),
+    ("dci_bytes_per_s_for", "PEAK_DCI_BYTES"),
+    ("hbm_bytes_for", "HBM_BYTES"),
+    ("hbm_bw_bytes_per_s_for", "HBM_BW_BYTES"),
+])
+def test_unknown_device_kind_raises_naming_the_table(fn_name, table):
+    """A device kind no row matches is an error that names the table —
+    never a default (a typo'd --device-kind, or a chip nobody entered,
+    would otherwise be scored against another machine's numbers)."""
+    with pytest.raises(ValueError, match=table):
+        getattr(derived, fn_name)("martian accelerator v9")
 
 
 def test_known_device_kinds_never_warn():
     import warnings as _w
 
-    for kind in ("TPU v5e", "TPU v5 lite", "v5p slice", "cpu-fallback",
+    for kind in ("TPU v5e", "TPU v5 lite", "v5p slice", "cpu",
                  "TPU v4"):
         with _w.catch_warnings():
             _w.simplefilter("error")

@@ -10,7 +10,7 @@ import pytest
 
 REPO = Path(__file__).resolve().parent.parent
 # the examples import pipegoose_tpu from the repo; keep any existing
-# PYTHONPATH (e.g. the machine's sitecustomize dir) behind it
+# PYTHONPATH behind it
 ENV = {
     **os.environ,
     "PYTHONPATH": os.pathsep.join(
@@ -21,16 +21,18 @@ ENV = {
 # SERVING demos share the session's persistent XLA compilation cache
 # (tests/conftest.py): they jit the same tiny-config engine programs
 # the serving suite already compiled, so each subprocess starts warm.
-# Training-step demos stay uncached — this jaxlib segfaults
-# deserializing hybrid train-step executables (see conftest.py).
+# Training-step demos stay uncached, like the trainer tests (see
+# conftest.py).
 SERVING_DEMOS = {
     "serve_bloom.py", "request_trace_demo.py", "disagg_serving_demo.py",
     "quantized_serving_demo.py", "control_plane_demo.py",
     "kv_tier_demo.py", "goodput_demo.py",
 }
 CACHE_ENV = {
+    # JAX's own variable where set; else the checkout's fixed path,
+    # the same one chip_smoke.py and bench.py use
     "JAX_COMPILATION_CACHE_DIR": os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR", "/tmp/pipegoose_jax_cache"),
+        "JAX_COMPILATION_CACHE_DIR", str(REPO / ".jax_cache")),
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
     "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "0",
 }

@@ -82,6 +82,58 @@ def test_missing_checkpoint_raises(tmp_path, cfg_params, devices):
         ckpt.restore_train_state(str(tmp_path / "nope"), None, {"params": params})
 
 
+def _zero_like_state(tmp_path):
+    """A train state whose moment was cut for dp=4: a (2, 3) parameter,
+    its (4, 3) ZeRO moment with two rows of padding."""
+    w = jnp.arange(6, dtype=jnp.float32).reshape(2, 3)
+    mu = jnp.concatenate([w + 10, jnp.zeros_like(w)])
+    ckpt.save_train_state(str(tmp_path / "run"), 1, {"w": w}, {"mu": mu})
+    return w, mu
+
+
+def _sds(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+@pytest.mark.parametrize("rows", [2, 6], ids=["dp4_to_dp2", "dp4_to_dp6"])
+def test_restore_recuts_the_zero_padding_of_opt_state_only(
+    tmp_path, devices, rows
+):
+    """A ZeRO moment's dim 0 is padded to a multiple of dp, so a
+    restore onto another dp drops or adds padding rows (the elastic
+    8 -> 4 path) — and touches nothing else."""
+    w, mu = _zero_like_state(tmp_path)
+    ctx = ParallelContext(data_parallel_size=2)
+    try:
+        like = {"params": {"w": w}, "opt_state": {"mu": _sds(rows, 3)}}
+        restored = ckpt.restore_train_state(str(tmp_path / "run"), 1, like)
+    finally:
+        ctx.destroy()
+    np.testing.assert_array_equal(np.asarray(restored["params"]["w"]), w)
+    want = np.zeros((rows, 3), np.float32)
+    want[:2] = np.asarray(w) + 10
+    np.testing.assert_array_equal(np.asarray(restored["opt_state"]["mu"]), want)
+
+
+@pytest.mark.parametrize("via", ["restore_train_state", "from_pretrained"])
+def test_restore_of_a_parameter_of_another_shape_raises(
+    tmp_path, devices, via
+):
+    """Another vocab, width or TP padding is another model: it must not
+    load truncated or zero-padded, whatever the optimizer state may."""
+    _zero_like_state(tmp_path)
+    like = {"params": {"w": _sds(1, 3)}, "opt_state": {"mu": _sds(4, 3)}}
+    ctx = ParallelContext(data_parallel_size=2)
+    try:
+        with pytest.raises(ValueError, match="not compatible with the stored"):
+            if via == "restore_train_state":
+                ckpt.restore_train_state(str(tmp_path / "run"), 1, like)
+            else:
+                ckpt.from_pretrained(str(tmp_path / "run" / "step_1"), like)
+    finally:
+        ctx.destroy()
+
+
 # -- crash-atomicity contract (ISSUE 9) ------------------------------------
 
 
@@ -134,6 +186,34 @@ def test_save_retries_transient_io_errors(tmp_path):
         str(tmp_path / "run"), 1, {"params": _tiny()})
     np.testing.assert_array_equal(
         np.asarray(restored["params"]["w"]), np.arange(4))
+
+
+def test_failed_rename_retries_the_rename_not_the_save(tmp_path, monkeypatch):
+    """The save is collective on a multi-process run; a process that
+    repeated it alone after a failed commit would hang the others. So
+    a transient rename failure repeats only the rename."""
+    import os
+
+    writes, renames = [], []
+    real_rename = os.rename
+
+    def flaky_rename(src, dst):
+        # orbax renames too, inside the save; only the commit is flaky
+        if str(dst) == str(tmp_path / "m"):
+            renames.append(dst)
+            if len(renames) == 1:
+                raise OSError("transient rename failure")
+        real_rename(src, dst)
+
+    monkeypatch.setattr(ckpt.os, "rename", flaky_rename)
+    prev = ckpt.set_io_fault_hook(lambda: writes.append(1))
+    try:
+        path = ckpt.save_pretrained(_tiny(), str(tmp_path / "m"),
+                                    backoff_s=0.0)
+    finally:
+        ckpt.set_io_fault_hook(prev)
+    assert len(writes) == 1 and len(renames) == 2
+    assert os.path.isdir(path) and not os.path.exists(path + ckpt.TMP_SUFFIX)
 
 
 def test_save_surfaces_persistent_io_errors(tmp_path):
