@@ -181,6 +181,7 @@ def _flash_fwd_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(slopes, q, k, v, kpos[:, None, :], kneg[:, None, :])
     return out, lse[:, 0, :]
 
@@ -264,6 +265,7 @@ def _flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_dq",
     )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
       kpos[:, None, :], kneg[:, None, :])
 
@@ -365,6 +367,7 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_dkv",
     )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
       kpos[:, None, :], kneg[:, None, :])
 
@@ -471,6 +474,7 @@ def _flash_chunk_pallas(q, k, v, slopes, qpos, kpos, kneg, m0, l0, acc0,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_ring_fwd",
     )(slopes, q, k, v, qpos[:, None, :], kpos[:, None, :], kneg[:, None, :],
       m0[:, None, :], l0[:, None, :], acc0)
     return m[:, 0, :], l[:, 0, :], acc
@@ -590,6 +594,7 @@ def _chunk_dq_pallas(q, k, v, do, lse, delta, slopes, qpos, kpos, kneg,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_ring_dq",
     )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
       qpos[:, None, :], kpos[:, None, :], kneg[:, None, :])
 
@@ -685,6 +690,7 @@ def _chunk_dkv_pallas(q, k, v, do, lse, delta, slopes, qpos, kpos, kneg,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_ring_dkv",
     )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
       qpos[:, None, :], kpos[:, None, :], kneg[:, None, :])
 

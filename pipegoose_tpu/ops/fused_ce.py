@@ -137,6 +137,7 @@ def _fwd_pallas(h, w, targets, offset, valid, block_t, block_v, interpret,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_ce_fwd",
     )(offset, h, w, targets[None, :])
     return lse[0], tl[0]
 
@@ -215,6 +216,7 @@ def _dh_pallas(h, w, targets, lse, g, offset, valid, block_t, block_v,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_ce_dh",
     )(offset, h, w, targets[None, :], lse[None, :], g[None, :])
 
 
@@ -283,6 +285,7 @@ def _dw_pallas(h, w, targets, lse, g, offset, valid, block_t, block_v,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="fused_ce_dw",
     )(offset, h, w, targets[None, :], lse[None, :], g[None, :])
 
 

@@ -410,5 +410,6 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_attention",
     )(page_table, start, slopes.astype(jnp.float32), flat(q), *operands)
     return out.reshape(b, c, nh, hd)

@@ -128,6 +128,7 @@ def _matmul_int8_pallas(x, q, scale, block_t, block_o, interpret):
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="int8_matmul",
     )(x, q, scale[None, :])
 
 
@@ -170,6 +171,7 @@ def _matmul_int4_pallas(x, q, scale, block_t, block_o, interpret):
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="int4_matmul",
     )(x, q, scale)
 
 

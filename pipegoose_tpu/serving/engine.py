@@ -54,14 +54,28 @@ mesh the whole step runs in shard_map with head-sharded pages and
 models/_decode.py's sharded driver.
 
 Metrics follow utils/profiler.py's convention of returning plain dicts
-the caller can JSON-dump, and the engine is instrumented against the
-telemetry registry: on top of the PR 2 gauges/histograms/spans it
-counts prefix-cache ``hit_tokens``/``miss_tokens``/``shared_pages``/
-``cow_copies``, prefill chunks and forwarded prefill tokens (the
-prefill-FLOP meter the cache shrinks), pool fragmentation, decode-step
-gaps, and speculative draft/accept tallies. The legacy aggregate dict
-keeps its exact keys — ``serving_ab_benchmark`` and existing callers
-parse it; new information lands under NEW keys only.
+the caller can JSON-dump. Three clocks are always on, each a handful of
+``now()`` calls: ``tick_phase_s`` splits the host wall inside
+``tick_once`` into admit / prefill / prepare / dispatch / fetch /
+record (``TICK_PHASES``), and ``setup`` keeps the wall of ``__init__``
+and of the first call of every jitted program, which is the call that
+compiled it or loaded it from the cache. The same regions are spans
+(telemetry/spans.py): ``serving.admit``, ``serving.prefill``,
+``serving.prepare``, ``serving.decode_step`` with its children
+``.dispatch`` and ``.fetch``, ``serving.record``. A span is always a
+``jax.profiler.TraceAnnotation``, so a profiler session shows what the
+host was doing in every gap of the device's line, and is recorded as
+``span.<path>.seconds`` only when the registry is enabled. There is no
+span around the whole tick: it would prefix the paths of the others.
+
+The engine is also instrumented against the telemetry registry: on top
+of the PR 2 gauges/histograms it counts prefix-cache ``hit_tokens``/
+``miss_tokens``/``shared_pages``/``cow_copies``, prefill chunks and
+forwarded prefill tokens (the prefill-FLOP meter the cache shrinks),
+pool fragmentation, decode-step gaps, and speculative draft/accept
+tallies. The legacy aggregate dict keeps its exact keys —
+``serving_ab_benchmark`` and existing callers parse it; new information
+lands under NEW keys only.
 """
 from __future__ import annotations
 
@@ -131,6 +145,15 @@ class RequestOutput:
                                np.asarray(self.generated, np.int64)])
 
 
+# host-wall phases of one ``tick_once``, in the order they run; their
+# accumulators (``finish_run()["tick_phase_s"]``) sum to the wall inside
+# ``tick_once``. ``admit`` holds the tick hook, ``prefill`` every prefill
+# or chunk up to its token fetch (0.0 exactly when none ran), ``record``
+# the tail of a tick that decoded nothing; ``dispatch`` + ``fetch`` is
+# ``decode_step_time_s``.
+TICK_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch", "record")
+
+
 class _RunState:
     """Accumulators for one serving run — the state ``run()`` kept in
     locals before the steppable extraction (``start_run`` /
@@ -143,7 +166,7 @@ class _RunState:
         "per_request", "generated_total", "shed_count", "steps",
         "prefills", "chunks", "spec_drafted", "spec_accepted",
         "occ_slots", "occ_pages", "stalled", "tick", "t_last_decode",
-        "max_gap", "step_time", "table", "seq_lens", "tokens",
+        "max_gap", "step_time", "phase_s", "table", "seq_lens", "tokens",
     )
 
     def __init__(self, engine: "ServingEngine", now, tick_hook):
@@ -164,6 +187,7 @@ class _RunState:
         self.t_last_decode: Optional[float] = None
         self.max_gap = 0.0
         self.step_time = 0.0            # summed decode-step wall time
+        self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.table = np.zeros((engine.num_slots, engine.table_width),
                               np.int32)
         self.seq_lens = np.zeros((engine.num_slots,), np.int32)
@@ -260,6 +284,7 @@ class ServingEngine:
         (ops/paged_attention.py) — one HBM pass over raw pages at wire
         precision, no contiguous KV materialization. "gather" is the
         two-pass XLA reference the kernel is parity-pinned against."""
+        t_build = time.perf_counter()
         if max_context % page_size:
             raise ValueError("max_context must be a multiple of page_size")
         if prefill_only and prefill_chunk is None:
@@ -423,8 +448,11 @@ class ServingEngine:
         # entry per jitted program family x width actually executed —
         # the control plane reads the counter delta around a tick to
         # book first-run (compile + warmup) wall separately from
-        # steady-state productive wall
-        self._progs_seen: set = set()
+        # steady-state productive wall. The entry keeps the host wall of
+        # that first call, the one that compiled the program or loaded
+        # it from the cache: (family, width) -> seconds, None while the
+        # call is in flight
+        self._first_call_s: dict = {}
         self.programs_run = 0
         # every cached engine gets a RestoreManager (cheap — nothing
         # compiles until the first spill/pull), so it can serve as a
@@ -616,6 +644,7 @@ class ServingEngine:
             self.attach_memledger(
                 memledger if isinstance(memledger, MemoryLedger)
                 else MemoryLedger())
+        self._build_s = time.perf_counter() - t_build
 
     def doctor(self, large_bytes: int = 1 << 20, registry=None):
         """Mesh-doctor report (telemetry/doctor.py) for the compiled
@@ -843,14 +872,20 @@ class ServingEngine:
         )
         self.memledger = ledger
 
-    def _note_program(self, family: str, width: int) -> None:
+    def _note_program(self, family: str, width: int) -> bool:
         """Record one jitted-program execution for the goodput
         ledger's compile/warmup detection: the first (family, width)
-        pair is the tick that paid the XLA compile."""
+        pair is the tick that paid the XLA compile. Returns True for
+        that first execution; the call site then keeps its host wall in
+        ``_first_call_s`` (``finish_run()["setup"]``): dispatch to
+        completed fetch where it fetches, the call alone (in which the
+        compile or the cache load runs) where it does not."""
         key = (family, width)
-        if key not in self._progs_seen:
-            self._progs_seen.add(key)
-            self.programs_run += 1
+        if key in self._first_call_s:
+            return False
+        self._first_call_s[key] = None
+        self.programs_run += 1
+        return True
 
     def _ledger_tick(self, rs) -> None:
         """Per-tick ledger hook (conservation check + forecast +
@@ -952,25 +987,38 @@ class ServingEngine:
         with span("serving.prefill", registry=self.registry):
             s = req.prompt_len
             bucket = self.pool.pages_for(s) * self.page_size
-            self._note_program("prefill", bucket)
+            first = self._note_program("prefill", bucket)
+            first_write = self._note_program("write", bucket)
             pad = bucket - s
             ids = np.zeros((1, bucket), np.int32)
             ids[0, pad:] = np.asarray(req.prompt, np.int32)
             mask = np.zeros((1, bucket), np.int32)
             mask[0, pad:] = 1
+            t_call = now()
             tok, cache = self._prefill(
                 self.params, jnp.asarray(ids), jnp.asarray(mask)
             )
+            t_write = now()
             phys = np.zeros((self.table_width,), np.int32)
             phys[:len(req.pages)] = req.pages
+            # dispatched and never fetched: on the device the write runs
+            # after this span has closed, in the next fetch's wait
             self.k_pages, self.v_pages = self._write(
                 self.k_pages, self.v_pages, cache, jnp.asarray(phys),
                 jnp.asarray(pad, jnp.int32),
             )
+            t_fetch = now()
             # the token fetch syncs the device, so the span's wall time
             # covers the prefill's actual device work
             tok = int(np.asarray(tok)[0])  # host fetch syncs the device:
             t1 = now()                     # span + chunk dur = device work
+            if first:
+                # its own call and the wait for its token, not the
+                # write's call that runs between the two
+                self._first_call_s["prefill", bucket] = (
+                    (t_write - t_call) + (t1 - t_fetch))
+            if first_write:
+                self._first_call_s["write", bucket] = t_fetch - t_write
             if tr is not None:
                 tr.on_prefill_chunk(req, t1, dur_s=t1 - t0, tokens=s)
             self.sched.record_token(req, tok, t1)
@@ -1022,14 +1070,14 @@ class ServingEngine:
         # monolithic path's prompt buckets
         prog = (self.prefill_chunk if self.prefill_chunk is not None
                 else self.pool.pages_for(n) * self.page_size)
-        self._note_program("chunk", prog)
+        first = self._note_program("chunk", prog)
         self.sched.ensure_pages(req, end)
         ids = np.zeros((1, prog), np.int32)
         ids[0, :n] = req.tokens[begin:end]
         table = np.zeros((1, self.table_width), np.int32)
         table[0, :len(req.pages)] = req.pages
         tr = self.tracer
-        t0 = now() if tr is not None else 0.0
+        t0 = now()
         with span("serving.prefill", registry=self.registry):
             tok, self.k_pages, self.v_pages = self._chunk(
                 self.params, jnp.asarray(ids), self.k_pages, self.v_pages,
@@ -1037,8 +1085,10 @@ class ServingEngine:
                 jnp.asarray([n], jnp.int32),
             )
             tok = int(np.asarray(tok)[0])  # sync: span = device work
+        t1 = now()
+        if first:
+            self._first_call_s["chunk", prog] = t1 - t0
         if tr is not None:
-            t1 = now()
             tr.on_prefill_chunk(req, t1, dur_s=t1 - t0, tokens=n)
         req.prefilled_len = end
         self._m_chunks.inc()
@@ -1100,7 +1150,7 @@ class ServingEngine:
         ``done``. Returns (emitted, drafted, accepted, surviving rows
         — lazy growth may retract a neighbor mid-batch)."""
         spec_k, n_spec = self.speculative
-        self._note_program("spec", n_spec)
+        first = self._note_program("spec", n_spec)
         table = np.zeros((self.num_slots, self.table_width), np.int32)
         seq = np.zeros((self.num_slots,), np.int32)
         tok0 = np.zeros((self.num_slots,), np.int32)
@@ -1123,7 +1173,7 @@ class ServingEngine:
         cur = jnp.asarray(tok0)
         jtable = jnp.asarray(table)
         tr = self.tracer
-        t_c0 = now() if tr is not None else 0.0
+        t_c0 = now()
         # same span as the plain path: speculative mode must not make
         # the decode-step stream vanish from dashboards/Perfetto
         with span("serving.decode_step", registry=self.registry):
@@ -1146,6 +1196,9 @@ class ServingEngine:
             )
             toks = np.asarray(toks)  # host fetch syncs: span = device work
         t = now()
+        if first:
+            # the draft and the verify program together
+            self._first_call_s["spec", n_spec] = t - t_c0
         emitted = accepted = 0
         for r in rows:
             i = r.slot
@@ -1337,23 +1390,31 @@ class ServingEngine:
             return False
         reg = self.registry
         now = rs.now
+        # host wall by phase (TICK_PHASES): every boundary closes the
+        # phase before it, so the six sum to the wall inside this call
+        phase = rs.phase_s
+        t_mark = now()
         rs.tick += 1
-        if rs.tick_hook is not None:
-            rs.tick_hook(self, rs.tick)
-        if self.kv_tier is not None:
-            # KV-tier pre-admission intercept: give the queue head one
-            # shot at a cross-replica pull and/or a host-tier restore,
-            # so the admission below sees the pages as ordinary cache
-            # hits (restore) or resumes chunked prefill (pull)
-            self.kv_tier.tick_intercept(now)
-        admitted = self.sched.admit(now())
-        shed_now = self.sched.drain_shed()
-        if shed_now:
-            # shedding IS the degraded-but-healthy mode: a counter
-            # and terminal outputs, never a watchdog trigger — the
-            # SLO shed-fraction target decides when it's too much
-            self._m_shed.inc(len(shed_now))
-            rs.done.extend(shed_now)
+        with span("serving.admit", registry=reg):
+            if rs.tick_hook is not None:
+                rs.tick_hook(self, rs.tick)
+            if self.kv_tier is not None:
+                # KV-tier pre-admission intercept: give the queue head one
+                # shot at a cross-replica pull and/or a host-tier restore,
+                # so the admission below sees the pages as ordinary cache
+                # hits (restore) or resumes chunked prefill (pull)
+                self.kv_tier.tick_intercept(now)
+            admitted = self.sched.admit(now())
+            shed_now = self.sched.drain_shed()
+            if shed_now:
+                # shedding IS the degraded-but-healthy mode: a counter
+                # and terminal outputs, never a watchdog trigger — the
+                # SLO shed-fraction target decides when it's too much
+                self._m_shed.inc(len(shed_now))
+                rs.done.extend(shed_now)
+        t = now()
+        phase["admit"] += t - t_mark
+        t_mark = t
         chunked_this_tick = 0
         if self._paged_prefill:
             for req in admitted:
@@ -1367,6 +1428,9 @@ class ServingEngine:
                     continue  # retracted by an earlier neighbor's
                     # lazy growth this very loop: back in the queue
                 self._prefill_chunk_tick(req, now)
+                t = now()
+                phase["prefill"] += t - t_mark
+                t_mark = t
                 rs.chunks += 1
                 chunked_this_tick += 1
                 if req.status is Status.DONE:
@@ -1376,6 +1440,9 @@ class ServingEngine:
         else:
             for req in admitted:
                 self._prefill_request(req, now)
+                t = now()
+                phase["prefill"] += t - t_mark
+                t_mark = t
                 rs.prefills += 1
                 if req.status is Status.DONE:
                     rs.done.append(req)
@@ -1398,6 +1465,7 @@ class ServingEngine:
                     self._stall(rs.steps, now() - rs.t0)
             rs.t_last_decode = None
             self._ledger_tick(rs)
+            phase["record"] += now() - t_mark
             # everything admitted finished at prefill
             return bool(admitted or chunked_this_tick or shed_now)
         rs.stalled = 0
@@ -1407,88 +1475,106 @@ class ServingEngine:
                     for r in active)
         )
         if use_spec:
-            t_step = now()
+            # a speculative cycle builds its own tables and interleaves
+            # its draft and verify dispatches with their fetches: its
+            # whole wall is booked under ``fetch``
+            t_step = t_disp = now()
             emitted, drafted, accepted, active = self._spec_cycle(
                 active, now, rs.done)
             rs.spec_drafted += drafted
             rs.spec_accepted += accepted
             t = now()
         else:
-            for req in active:
-                if req.status is Status.DECODE:
-                    self.sched.ensure_page(req)
-            # lazy growth may have RETRACTED a neighbor (temporal
-            # cache-ledger interference — see Scheduler.ensure_pages);
-            # only still-decoding survivors join the step
-            active = [r for r in active if r.status is Status.DECODE]
-            rs.table.fill(0)
-            rs.seq_lens.fill(0)
-            rs.tokens.fill(0)
-            for req in active:
-                rs.table[req.slot, :len(req.pages)] = req.pages
-                rs.seq_lens[req.slot] = req.cached_len
-                rs.tokens[req.slot] = req.generated[-1]
-            self._note_program("step", 0)
+            with span("serving.prepare", registry=reg):
+                for req in active:
+                    if req.status is Status.DECODE:
+                        self.sched.ensure_page(req)
+                # lazy growth may have RETRACTED a neighbor (temporal
+                # cache-ledger interference — see Scheduler.ensure_pages);
+                # only still-decoding survivors join the step
+                active = [r for r in active if r.status is Status.DECODE]
+                rs.table.fill(0)
+                rs.seq_lens.fill(0)
+                rs.tokens.fill(0)
+                for req in active:
+                    rs.table[req.slot, :len(req.pages)] = req.pages
+                    rs.seq_lens[req.slot] = req.cached_len
+                    rs.tokens[req.slot] = req.generated[-1]
+            first = self._note_program("step", 0)
             t_step = now()
             with span("serving.decode_step", registry=reg):
-                nxt, self.k_pages, self.v_pages = self._step(
-                    self.params, jnp.asarray(rs.tokens), self.k_pages,
-                    self.v_pages, jnp.asarray(rs.table),
-                    jnp.asarray(rs.seq_lens),
-                )
-                nxt = np.asarray(nxt)  # host fetch syncs: span = work
+                with span("dispatch", registry=reg):
+                    nxt, self.k_pages, self.v_pages = self._step(
+                        self.params, jnp.asarray(rs.tokens), self.k_pages,
+                        self.v_pages, jnp.asarray(rs.table),
+                        jnp.asarray(rs.seq_lens),
+                    )
+                t_disp = now()
+                # the host waiting on the device: what it waits for is
+                # this step and whatever was queued before it (a page
+                # write dispatched by this tick's prefill)
+                with span("fetch", registry=reg):
+                    nxt = np.asarray(nxt)  # host fetch syncs: span = work
             t = now()
+            if first:
+                self._first_call_s["step", 0] = t - t_step
             emitted = len(active)
-            self._trace_tick(active, t_step, t)
-        if rs.t_last_decode is not None:
-            gap = t_step - rs.t_last_decode
-            self._m_gap.observe(gap)
-            rs.max_gap = max(rs.max_gap, gap)
-        rs.t_last_decode = t
-        rs.steps += 1
-        rs.step_time += t - t_step
-        slot_occ = len(active) / self.num_slots
-        page_occ = self.pool.used_count / self.pool.capacity
-        rs.occ_slots += slot_occ
-        rs.occ_pages += page_occ
-        # per-token decode latency: a plain step emits one token per
-        # active slot; a speculative cycle may emit several — both
-        # normalize to seconds per token per slot
-        self._m_tok_lat.observe(
-            (t - t_step) * len(active) / max(emitted, 1))
-        self._m_steps.inc()
-        self._m_tokens.inc(emitted)
-        self._m_active.set(len(active))
-        self._m_slot_occ.set(slot_occ)
-        self._m_page_occ.set(page_occ)
-        if reg.enabled:
-            # fragmentation() sorts the free list — too heavy for
-            # the disabled path's one-branch cost contract
-            self._m_frag.set(self.pool.fragmentation())
-            if self.prefix_cache is not None:
-                # refresh per step, not just on insert: pressure
-                # eviction happens exactly when dashboards look
-                self._m_cached.set(self.prefix_cache.cached_pages)
-                self._m_evictable.set(
-                    self.prefix_cache.evictable_count()
+        phase["prepare"] += t_step - t_mark
+        phase["dispatch"] += t_disp - t_step
+        phase["fetch"] += t - t_disp
+        with span("serving.record", registry=reg):
+            if not use_spec:
+                self._trace_tick(active, t_step, t)
+            if rs.t_last_decode is not None:
+                gap = t_step - rs.t_last_decode
+                self._m_gap.observe(gap)
+                rs.max_gap = max(rs.max_gap, gap)
+            rs.t_last_decode = t
+            rs.steps += 1
+            rs.step_time += t - t_step
+            slot_occ = len(active) / self.num_slots
+            page_occ = self.pool.used_count / self.pool.capacity
+            rs.occ_slots += slot_occ
+            rs.occ_pages += page_occ
+            # per-token decode latency: a plain step emits one token per
+            # active slot; a speculative cycle may emit several — both
+            # normalize to seconds per token per slot
+            self._m_tok_lat.observe(
+                (t - t_step) * len(active) / max(emitted, 1))
+            self._m_steps.inc()
+            self._m_tokens.inc(emitted)
+            self._m_active.set(len(active))
+            self._m_slot_occ.set(slot_occ)
+            self._m_page_occ.set(page_occ)
+            if reg.enabled:
+                # fragmentation() sorts the free list — too heavy for
+                # the disabled path's one-branch cost contract
+                self._m_frag.set(self.pool.fragmentation())
+                if self.prefix_cache is not None:
+                    # refresh per step, not just on insert: pressure
+                    # eviction happens exactly when dashboards look
+                    self._m_cached.set(self.prefix_cache.cached_pages)
+                    self._m_evictable.set(
+                        self.prefix_cache.evictable_count()
+                    )
+            # the occupancy TIME SERIES the end-of-run averages flatten
+            reg.event("serving.step", step=rs.steps, active=len(active),
+                      queue_depth=len(self.sched.queue), dur_s=t - t_step,
+                      slot_occupancy=slot_occ, page_occupancy=page_occ,
+                      tokens=emitted)
+            if self.recorder is not None:
+                self.recorder.observe_serving_step(
+                    rs.steps, active=len(active),
+                    queue_depth=len(self.sched.queue), dur_s=t - t_step,
+                    tokens=emitted,
                 )
-        # the occupancy TIME SERIES the end-of-run averages flatten
-        reg.event("serving.step", step=rs.steps, active=len(active),
-                  queue_depth=len(self.sched.queue), dur_s=t - t_step,
-                  slot_occupancy=slot_occ, page_occupancy=page_occ,
-                  tokens=emitted)
-        if self.recorder is not None:
-            self.recorder.observe_serving_step(
-                rs.steps, active=len(active),
-                queue_depth=len(self.sched.queue), dur_s=t - t_step,
-                tokens=emitted,
-            )
-        if not use_spec:
-            for req in active:
-                self.sched.record_token(req, int(nxt[req.slot]), t)
-                if req.status is Status.DONE:
-                    rs.done.append(req)
-        self._ledger_tick(rs)
+            if not use_spec:
+                for req in active:
+                    self.sched.record_token(req, int(nxt[req.slot]), t)
+                    if req.status is Status.DONE:
+                        rs.done.append(req)
+            self._ledger_tick(rs)
+        phase["record"] += now() - t
         return True
 
     def _build_output(self, r: Request) -> RequestOutput:
@@ -1611,6 +1697,19 @@ class ServingEngine:
             "prefill_tokens": self._run_prefill_tokens,
             # deadline-shed terminal count (graceful degradation)
             "shed_requests": rs.shed_count,
+            # host wall inside tick_once by phase (TICK_PHASES)
+            "ticks": rs.tick,
+            "tick_phase_s": {k: round(v, 6) for k, v in rs.phase_s.items()},
+            # engine-lifetime facts, the same in every run's metrics:
+            # the wall of __init__ and of each program's first call
+            "setup": {
+                "build_s": round(self._build_s, 6),
+                "first_call_s": {
+                    f"{family}/{width}": round(sec, 6)
+                    for (family, width), sec in self._first_call_s.items()
+                    if sec is not None
+                },
+            },
         }
         if self._paged_prefill:
             metrics["prefill_chunks"] = rs.chunks

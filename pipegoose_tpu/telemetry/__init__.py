@@ -5,7 +5,8 @@ The observability layer the reference never had (its
 ``DistributedLogger`` was an empty stub and it had no timeline tracing,
 SURVEY.md §5). Library hot paths (trainer fit loop, serving engine,
 decode driver) are instrumented against the GLOBAL registry, which
-starts disabled — un-observed runs pay one branch per site. Turn it on
+starts disabled — un-observed runs pay one branch per metric site and
+an inert profiler annotation per span. Turn it on
 with ``telemetry.enable()`` (or by adding a ``TelemetryCallback`` /
 constructing an engine with an enabled registry) and attach exporters:
 

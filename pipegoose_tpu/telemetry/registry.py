@@ -7,9 +7,11 @@ traffic" north star needs them. Design constraints, in order:
 1. **Near-zero overhead when disabled.** Library code (trainer loop,
    serving engine, decode driver) is instrumented UNCONDITIONALLY; the
    global registry starts disabled, so the un-observed cost of a
-   ``counter.inc()`` or ``span()`` entry is one attribute read and a
-   branch (< 5 µs guarded by tests/telemetry/test_registry.py). There
-   is no "if telemetry:" litter at call sites.
+   ``counter.inc()`` is one attribute read and a branch, and that of a
+   ``span()`` entry one small object and a profiler annotation that is
+   inert outside a profiler session (each < 5 µs, guarded by
+   tests/telemetry/test_registry.py). There is no "if telemetry:"
+   litter at call sites.
 2. **Safe under jit tracing.** Host-side metric mutation inside a
    traced function would record trace-time (once per COMPILE, not per
    execution) — every mutation no-ops when the value is a

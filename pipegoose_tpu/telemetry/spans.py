@@ -1,10 +1,19 @@
-"""Span tracing: named, nestable wall-time regions with device fencing.
+"""Span tracing: named, nestable regions on two clocks, with device fencing.
 
-``with span("decode_step") as sp: ...`` records the region's wall time
-into the active registry as both a histogram
-(``span.<dotted.path>.seconds``) and a ``"span"`` event for the JSONL
-stream. Spans nest through a thread-local stack — a span opened inside
-another records under the joined path (``step.forward``) — which is how
+``with span("decode_step") as sp: ...`` does two things:
+
+* it ALWAYS enters a ``jax.profiler.TraceAnnotation`` named by the
+  span's dotted path, so a profiler session (``jax.profiler.start_trace``
+  or ``.trace``) holds the region on the host plane, on the same clock as
+  the device's ``XLA Ops`` line. With no session active the annotation
+  does nothing (well under a microsecond);
+* when the registry is enabled it also records the region's wall time
+  (``time.perf_counter``) as a histogram (``span.<dotted.path>.seconds``)
+  and a ``"span"`` event for the JSONL stream. A disabled registry
+  records nothing.
+
+Spans nest through a thread-local stack — a span opened inside another
+takes the joined path (``step.forward``) on both clocks — which is how
 the per-step breakdown (data/forward/backward/optimizer/comms) is
 assembled without any global schema.
 
@@ -13,13 +22,13 @@ jitted call long before the device finishes, so a naive wall-time span
 around a dispatch measures enqueue cost, not work. ``sp.fence(x)``
 registers arrays to ``jax.block_until_ready`` at span exit so the
 device work that produced them is attributed to THIS span. Fencing only
-happens when the span is live (registry enabled) — disabled runs keep
-full async pipelining.
+happens when the span records (registry enabled) — disabled runs keep
+full async pipelining, under a profiler session too.
 
-**Jit safety.** ``span()`` returns a shared no-op when the registry is
-disabled OR a jit trace is in progress: entering a span inside a traced
-function must neither crash nor record trace-time (the fence would also
-be meaningless — you cannot block on a tracer). Guarded by
+**Jit safety.** ``span()`` returns a shared no-op while a jit trace is
+in progress: entering a span inside a traced function must neither
+crash nor record or annotate trace-time (the fence would also be
+meaningless — you cannot block on a tracer). Guarded by
 tests/telemetry/test_spans.py.
 """
 from __future__ import annotations
@@ -47,7 +56,7 @@ def _stack() -> list:
 
 
 class _NoopSpan:
-    """Shared disabled/trace-time span: every operation is a no-op."""
+    """Shared trace-time span: every operation is a no-op."""
 
     __slots__ = ()
 
@@ -65,26 +74,31 @@ _NOOP = _NoopSpan()
 
 
 class Span:
-    __slots__ = ("name", "path", "_registry", "_attrs", "_t0", "_fences")
+    __slots__ = ("name", "path", "_registry", "_attrs", "_t0", "_fences",
+                 "_annotation")
 
-    def __init__(self, name: str, registry: MetricsRegistry,
+    def __init__(self, name: str, registry: Optional[MetricsRegistry],
                  attrs: Optional[dict] = None):
         self.name = name
         self.path = name  # finalized on __enter__ (nesting)
-        self._registry = registry
+        self._registry = registry  # None: annotate only, record nothing
         self._attrs = attrs
         self._t0 = 0.0
         self._fences: list = []
+        self._annotation = None
 
     def fence(self, *arrays: Any) -> None:
         """Block on these arrays at span exit so their device work lands
-        in this span's duration."""
-        self._fences.extend(arrays)
+        in this span's duration (recording spans only)."""
+        if self._registry is not None:
+            self._fences.extend(arrays)
 
     def __enter__(self) -> "Span":
         stack = _stack()
-        self.path = ".".join([s.path for s in stack[-1:]] + [self.name])
+        self.path = f"{stack[-1].path}.{self.name}" if stack else self.name
         stack.append(self)
+        self._annotation = jax.profiler.TraceAnnotation(self.path)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -95,16 +109,18 @@ class Span:
             except Exception:  # noqa: BLE001 - non-array fence targets
                 pass
         dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = _stack()
         if stack and stack[-1] is self:
             stack.pop()
-        if exc_type is StopIteration:
-            # iterator-protocol control flow, not work: a span around
-            # `next(it)` (trainer.fit's data span) would otherwise log a
-            # phantom near-zero sample for the final exhausted pull,
-            # skewing the data-time quantiles it exists to report
-            return False
         reg = self._registry
+        if reg is None or exc_type is StopIteration:
+            # StopIteration is iterator-protocol control flow, not work:
+            # a span around `next(it)` (trainer.fit's data span) would
+            # otherwise log a phantom near-zero sample for the final
+            # exhausted pull, skewing the data-time quantiles it exists
+            # to report
+            return False
         reg.histogram(f"span.{self.path}.seconds").observe(dur)
         reg.event("span", span=self.path, dur_s=dur,
                   **(self._attrs or {}))
@@ -113,16 +129,18 @@ class Span:
 
 def span(name: str, *, registry: Optional[MetricsRegistry] = None,
          attrs: Optional[dict] = None):
-    """Context manager timing a named region (see module docstring).
+    """Context manager around a named region (see module docstring):
+    a profiler annotation always, a registry record when that is enabled.
 
-    Returns a shared no-op object when telemetry is disabled or a jit
-    trace is in progress — the disabled cost is one branch, safe to
+    Returns a shared no-op object while a jit trace is in progress. With
+    the registry disabled and no profiler session the cost is one small
+    object and an inert annotation (about a microsecond) — safe to
     leave in library hot loops.
     """
-    reg = registry if registry is not None else get_registry()
-    if not reg._enabled or _tracing():
+    if _tracing():
         return _NOOP
-    return Span(name, reg, attrs)
+    reg = registry if registry is not None else get_registry()
+    return Span(name, reg if reg._enabled else None, attrs)
 
 
 def current_span_path() -> Optional[str]:

@@ -406,9 +406,10 @@ class Trainer:
                 if max_steps is not None and self.state.step >= max_steps:
                     break
                 try:
-                    # disabled-registry spans are one branch; enabled,
-                    # they split host-side data time from step dispatch
-                    # in the JSONL stream (telemetry/spans.py)
+                    # train.data / train.step: profiler annotations always
+                    # (inert without a session); with the registry enabled
+                    # they also split host-side data time from step
+                    # dispatch in the JSONL stream (telemetry/spans.py)
                     with span("train.data"):
                         batch = next(it)
                 except StopIteration:

@@ -69,6 +69,29 @@ def devices():
     return devs
 
 
+@pytest.fixture
+def annotations(monkeypatch):
+    """``jax.profiler.TraceAnnotation`` replaced by a recorder: the list
+    of ("enter" | "exit", name) in order. (No profiler session in
+    tier-1: under six workers a real one would be unsteady.)"""
+    log = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+            return self
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return log
+
+
 # --- fast tier ------------------------------------------------------------
 #
 # `pytest -m fast` runs a subsystem-representative subset in < 5 min on

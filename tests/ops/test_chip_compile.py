@@ -8,6 +8,7 @@ The topology is described inside a fixture of THIS file only: the worker
 that runs the file loads the TPU library, every other worker never does.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -65,12 +66,20 @@ def _flash(grad):
     return jax.grad(loss, argnums=(0, 1, 2)), qkv + [slopes]
 
 
-def _ring_chunk():
+def _ring_chunk(grad=False):
+    shapes = [((B, S, NH, HD), jnp.bfloat16)] * 3 + [((NH,), jnp.float32)]
+
     def fn(q, k, v, sl):
         return ring_flash_attention(q, k, v, None, alibi_slopes=sl,
                                     interpret=False)
 
-    return fn, [((B, S, NH, HD), jnp.bfloat16)] * 3 + [((NH,), jnp.float32)]
+    if not grad:
+        return fn, shapes
+
+    def loss(q, k, v, sl):
+        return fn(q, k, v, sl).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes
 
 
 def _fused_ce(grad):
@@ -115,6 +124,7 @@ CASES = {
     "flash_fwd": lambda: _flash(False),
     "flash_fwd_bwd": lambda: _flash(True),
     "ring_chunk": _ring_chunk,
+    "ring_chunk_bwd": lambda: _ring_chunk(True),
     "fused_ce_fwd": lambda: _fused_ce(False),
     "fused_ce_bwd": lambda: _fused_ce(True),
     "matmul_int8": lambda: _quant_matmul(False),
@@ -133,12 +143,49 @@ CASES = {
 }
 
 
+# the kernels each case runs, by the ``name=`` of their ``pallas_call``:
+# the name the chip's trace prints for the kernel's ``XLA Ops`` events
+KERNELS = {
+    "flash_fwd": ["flash_fwd"],
+    "flash_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "ring_chunk": ["flash_ring_fwd"],
+    "ring_chunk_bwd": ["flash_ring_fwd", "flash_ring_dq", "flash_ring_dkv"],
+    "fused_ce_fwd": ["fused_ce_fwd"],
+    "fused_ce_bwd": ["fused_ce_fwd", "fused_ce_dh", "fused_ce_dw"],
+    "matmul_int8": ["int8_matmul"],
+    "matmul_int4": ["int4_matmul"],
+}
+
+
+def _shapes(shapes, **kw):
+    return jax.tree_util.tree_map(
+        lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], **kw),
+        shapes, is_leaf=lambda x: isinstance(x, tuple),
+    )
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(one_chip, case):
     fn, shapes = CASES[case]()
-    args = jax.tree_util.tree_map(
-        lambda sd: jax.ShapeDtypeStruct(sd[0], sd[1], sharding=one_chip),
-        shapes, is_leaf=lambda x: isinstance(x, tuple),
-    )
-    text = jax.jit(fn).lower(*args).compile().as_text()
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
     assert "tpu_custom_call" in text
+    # the compiled instruction, which is the trace's event, is named
+    # after the kernel; jax wraps the name in the transforms it went
+    # through (``%transpose_jvp_flash_dkv__.1``), so a reader searches
+    called = [ln.split(" = ")[0] for ln in text.splitlines()
+              if " custom-call(" in ln and "tpu_custom_call" in ln]
+    for name in KERNELS.get(case, ["paged_attention"]):
+        assert any(name in instruction for instruction in called), \
+            (name, called)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_lowered_kernel_holds_its_name(case):
+    """Lowered for the TPU without one (no topology, nothing compiled):
+    every kernel of the case is a ``tpu_custom_call`` under its name."""
+    fn, shapes = CASES[case]()
+    text = jax.jit(fn).trace(*_shapes(shapes)).lower(
+        lowering_platforms=("tpu",)).as_text()
+    found = set(re.findall(r'kernel_name = "([^"]*)"', text))
+    assert found == set(KERNELS.get(case, ["paged_attention"]))

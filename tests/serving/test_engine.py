@@ -422,3 +422,114 @@ def test_sentinel_skips_runs_with_no_decode_steps(setup):
     eng._sentinel_observe(
         SimpleNamespace(steps=0, step_time=0.0, generated_total=0), 2.0)
     assert sent.regressions == 0 and sent.baseline_size == 1
+
+
+# -- the tick's phase clocks, the set-up clocks and the spans (PR 26) ---------
+
+
+def _requests(prompts, rows):
+    return [Request(prompt=p, max_new_tokens=n)
+            for p, (_, n) in zip(prompts, rows)]
+
+
+def _timed_run(eng, requests):
+    """Drive the steppable run, timing every ``tick_once`` from outside."""
+    import time
+
+    eng.start_run(requests)
+    wall = 0.0
+    while not eng.sched.all_done():
+        t0 = time.perf_counter()
+        eng.tick_once()
+        wall += time.perf_counter() - t0
+    return wall, eng.finish_run()[1]
+
+
+def test_tick_phase_clocks_sum_to_the_tick_wall(setup):
+    from pipegoose_tpu.serving.engine import TICK_PHASES
+
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=3, num_pages=32,
+                        page_size=4, max_context=64)
+    eng.run(_requests(prompts, MIXED))     # compiles land in this run
+    wall, m = _timed_run(eng, _requests(prompts, MIXED))
+    phases = m["tick_phase_s"]
+    assert tuple(phases) == TICK_PHASES
+    assert all(v >= 0.0 for v in phases.values())
+    assert m["ticks"] >= m["decode_steps"] > 0
+    assert sum(phases.values()) == pytest.approx(wall, rel=0.10)
+    # an existing metric keeps its meaning: the decode step is dispatch
+    # plus fetch (each of the three is rounded to a microsecond)
+    assert phases["dispatch"] + phases["fetch"] == pytest.approx(
+        m["decode_step_time_s"], abs=3e-6)
+    assert m["prefills"] == len(MIXED) and phases["prefill"] > 0.0
+
+
+def test_prefill_phase_is_zero_exactly_when_nothing_prefilled(setup):
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64)
+    eng.start_run([])
+    assert eng.tick_once() is False        # an idle tick: admit + record
+    _, m = eng.finish_run()
+    assert m["prefills"] == 0 and m["ticks"] == 1
+    assert m["tick_phase_s"]["prefill"] == 0.0
+    assert m["tick_phase_s"]["dispatch"] == m["tick_phase_s"]["fetch"] == 0.0
+    _, m = eng.run(_requests(prompts[:1], MIXED[:1]))
+    assert m["prefills"] == 1 and m["tick_phase_s"]["prefill"] > 0.0
+
+
+@pytest.mark.parametrize("kwargs, families", [
+    ({}, {"prefill", "write", "step"}),
+    ({"prefill_chunk": 8}, {"chunk", "step"}),
+    # every cycle of these requests is speculative: no plain step runs
+    ({"speculative": (1, 2)}, {"prefill", "write", "spec"}),
+])
+def test_setup_keeps_one_first_call_per_program(setup, kwargs, families):
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64, **kwargs)
+    _, m = eng.run(_requests(prompts[:4], MIXED[:4]))
+    first = m["setup"]
+    assert first["build_s"] > 0.0
+    calls = first["first_call_s"]
+    assert len(calls) == eng.programs_run
+    assert {k.split("/")[0] for k in calls} == families
+    assert all(v > 0.0 for v in calls.values())
+    if not kwargs:
+        # one prefill and one page-write program per page count
+        buckets = {-(-len(p) // 4) * 4 for p in prompts[:4]}
+        assert set(calls) == ({f"prefill/{b}" for b in buckets}
+                              | {f"write/{b}" for b in buckets}
+                              | {"step/0"})
+    # engine-lifetime facts: a second run adds nothing, changes nothing
+    _, again = eng.run(_requests(prompts[:4], MIXED[:4]))
+    assert again["setup"] == first
+    assert again["setup"] is not first
+
+
+def test_tick_spans_in_order_under_their_documented_paths(setup):
+    """The tick is the run of its phases: seven span paths, no parent
+    that would rename ``serving.prefill`` and ``serving.decode_step``."""
+    from pipegoose_tpu.telemetry import MetricsRegistry
+
+    cfg, params, prompts = setup
+    reg = MetricsRegistry(enabled=True)
+    events = []
+    reg.attach(events.append)
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64, registry=reg)
+    eng.start_run(_requests(prompts[:1], [(3, 4)]))
+    eng.tick_once()                        # admits, prefills and decodes
+    spans = [e["span"] for e in events if e["kind"] == "span"]
+    assert spans == [
+        "serving.admit", "serving.prefill", "serving.prepare",
+        "serving.decode_step.dispatch", "serving.decode_step.fetch",
+        "serving.decode_step", "serving.record"]
+    del events[:]
+    eng.tick_once()                        # a pure decode tick
+    assert [e["span"] for e in events if e["kind"] == "span"] == [
+        s for s in spans if s != "serving.prefill"]
+    while not eng.sched.all_done():
+        eng.tick_once()
+    eng.finish_run()

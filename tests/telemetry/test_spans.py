@@ -3,6 +3,7 @@ jit-trace no-op regression (ISSUE 2: spans entered inside traced code
 must neither crash nor record)."""
 import jax
 import jax.numpy as jnp
+import pytest
 
 from pipegoose_tpu.telemetry import MetricsRegistry, span
 from pipegoose_tpu.telemetry.spans import _NOOP, current_span_path
@@ -54,13 +55,62 @@ def test_fence_blocks_on_device_work():
     assert reg.histogram("span.odd.seconds").count == 1
 
 
-def test_disabled_registry_returns_shared_noop():
+def test_disabled_registry_returns_shared_noop(annotations):
+    """The contract since PR 26. Registry off: nothing is recorded and
+    nothing fenced, but the annotation is still entered, so a profiler
+    session sees the span. Under a jit trace: the shared no-op, which
+    annotates nothing either."""
     reg = MetricsRegistry(enabled=False)
+    events = []
+    reg.attach(events.append)
     s = span("x", registry=reg)
-    assert s is _NOOP
+    assert s is not _NOOP
     with s as sp:
-        sp.fence(jnp.ones(2))  # all no-ops
-    assert reg.snapshot()["histograms"] == {}
+        sp.fence(object())  # not kept: nothing to block on at exit
+        assert current_span_path() == "x"
+    assert sp._fences == []
+    assert annotations == [("enter", "x"), ("exit", "x")]
+    assert reg.snapshot()["histograms"] == {} and events == []
+    assert current_span_path() is None
+
+    seen = []
+
+    @jax.jit
+    def f(a):
+        seen.append(span("traced", registry=reg))
+        return a + 1
+
+    f(jnp.zeros(2))
+    assert seen == [_NOOP]
+    assert annotations == [("enter", "x"), ("exit", "x")]
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_span_enters_trace_annotation_with_dotted_path(annotations, enabled):
+    """A span is a ``TraceAnnotation`` named by its dotted path, whether
+    or not the registry records it; children close before parents."""
+    reg = MetricsRegistry(enabled=enabled)
+    with span("serving.decode_step", registry=reg):
+        with span("dispatch", registry=reg):
+            pass
+        with span("fetch", registry=reg):
+            pass
+    names = ["serving.decode_step", "serving.decode_step.dispatch",
+             "serving.decode_step.fetch"]
+    assert annotations == [
+        ("enter", names[0]), ("enter", names[1]), ("exit", names[1]),
+        ("enter", names[2]), ("exit", names[2]), ("exit", names[0])]
+    recorded = set(reg.snapshot()["histograms"])
+    assert recorded == ({f"span.{n}.seconds" for n in names} if enabled
+                        else set())
+
+
+def test_annotation_closes_when_the_body_raises(annotations):
+    with pytest.raises(RuntimeError):
+        with span("boom", registry=MetricsRegistry(enabled=False)):
+            raise RuntimeError("x")
+    assert annotations == [("enter", "boom"), ("exit", "boom")]
+    assert current_span_path() is None
 
 
 def test_span_inside_jit_noops_cleanly():

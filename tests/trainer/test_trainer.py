@@ -285,3 +285,25 @@ def test_trainer_profile_measures_and_training_continues(parts):
     state = trainer.fit(_batches(cfg, 2))
     assert state.step == 2
     assert np.isfinite(float(state.last_loss))
+
+
+def test_fit_annotates_data_and_step_for_the_profiler(parts, annotations):
+    """``train.data`` / ``train.step`` are profiler annotations with the
+    registry off (telemetry/spans.py): a profiler session sees the loop
+    without any telemetry switched on."""
+    from pipegoose_tpu.telemetry import get_registry
+
+    cfg, params, ctx = parts
+    assert not get_registry().enabled
+    trainer = Trainer(
+        lambda p, ids: bloom.loss_fn(p, ids, None, ids, cfg,
+                                     tp_axis="tensor"),
+        params, bloom.tp_specs(params),
+        DistributedOptimizer(optax.adam(1e-3), axis_name="data"), ctx)
+    trainer.fit(_batches(cfg, 2))
+    # the third pull finds the batches exhausted
+    assert [n for kind, n in annotations
+            if kind == "enter" and n.startswith("train.")] == [
+        "train.data", "train.step", "train.data", "train.step", "train.data"]
+    assert not any(k.startswith("span.train.")
+                   for k in get_registry().snapshot()["histograms"])
