@@ -262,8 +262,9 @@ def phase_kernels(rep, seed):
     # paged attention at decode geometry, fp and int8 pools, vs the
     # gather-then-attend reference
     ps, width, pages = k["ps"], k["width"], k["pages"]
-    kp = jax.random.normal(next(keys), (pages, ps, nh, hd), bf)
-    vp = jax.random.normal(next(keys), (pages, ps, nh, hd), bf)
+    # banks in the pool's layout: a position's heads in one row
+    kp = jax.random.normal(next(keys), (pages, ps, nh * hd), bf)
+    vp = jax.random.normal(next(keys), (pages, ps, nh * hd), bf)
     rng = np.random.RandomState(seed)
     table = jnp.asarray(rng.permutation(np.arange(1, pages))[:b * width]
                         .reshape(b, width), jnp.int32)
@@ -274,8 +275,8 @@ def phase_kernels(rep, seed):
     q1 = jax.random.normal(next(keys), (b, 1, nh, hd), bf)
 
     def quant_bank(p):
-        qv, sc = quantize_kv(p.astype(jnp.float32))
-        return {"q": qv, "scale": sc}
+        qv, sc = quantize_kv(p.astype(jnp.float32).reshape(pages, ps, nh, hd))
+        return {"q": qv.reshape(p.shape), "scale": sc}
 
     for name, kbank, vbank in (("paged_fp", kp, vp),
                                ("paged_int8", quant_bank(kp), quant_bank(vp))):

@@ -32,8 +32,11 @@ serves speculative verify bundles and chunked prefill. Pad queries
 via its qmask, matching ``_attn_core``'s contract.
 
 Tiles are (page_size, n_head*head_dim) per grid step — the page IS the
-block, all local heads of it, flattened into the lane axis so that the
-chip's compiler accepts the block (tests/ops/test_chip_compile.py).
+block, all local heads of it in the lane axis. That is the pool's own
+layout (serving/kv_pool.py:init_pages keeps a position's heads in one
+lane-dense row, because rows of a half-filled 128-lane tile make the
+compiler put the PAGES in the lanes), so a bank goes to the kernel as
+it is stored and one DMA fetches one whole contiguous page.
 ``check_paged_tile`` is the fused_ce-style feasibility guard: compiled
 runs raise loudly when the tile cannot fit VMEM (never a silent
 fallback to the gather path); the interpreter is exempt (no VMEM).
@@ -191,10 +194,11 @@ def _xla_one_pass(q, k_pages, v_pages, page_table, start, slopes):
     q_pos = start.astype(jnp.int32)[:, None] + jnp.arange(c)[None, :]
 
     def dequant(pages, ids):
+        rows = (pages["q"] if quantized else pages)[ids].astype(jnp.float32)
+        vals = rows.reshape(b, ps, nh, hd)
         if quantized:
-            return (pages["q"][ids].astype(jnp.float32)
-                    * pages["scale"][ids][..., None])
-        return pages[ids].astype(jnp.float32)
+            return vals * pages["scale"][ids][..., None]
+        return vals
 
     def step(carry, wi):
         m, l, acc = carry
@@ -229,8 +233,8 @@ def paged_attention_reference(q, k_pages, v_pages, page_table, start, *,
     (B, C, nh, hd) like the kernel."""
     from pipegoose_tpu.serving.kv_pool import gather_pages
 
-    keys = gather_pages(k_pages, page_table)
-    vals = gather_pages(v_pages, page_table)
+    keys = gather_pages(k_pages, page_table, q.shape[-1])
+    vals = gather_pages(v_pages, page_table, q.shape[-1])
     return _ref_attention(q.astype(jnp.float32), keys.astype(jnp.float32),
                           vals.astype(jnp.float32), start, slopes)
 
@@ -243,9 +247,11 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
       q: (B, C, nh_local, hd) queries (any float dtype; upcast to f32
         in-register). C=1 is a decode step, C>1 a verify bundle or
         prefill chunk.
-      k_pages / v_pages: ONE layer's bank — fp (P, ps, nh_local, hd) or
-        the int8 pytree {"q": int8 (P, ps, nh_local, hd),
-        "scale": f32 (P, ps, nh_local)}.
+      k_pages / v_pages: a bank of pages in the pool's layout, a
+        position's heads in one row — fp (P, ps, nh_local*hd) or the
+        int8 pytree {"q": int8 (P, ps, nh_local*hd), "scale": f32
+        (P, ps, nh_local)}. The serving pool passes every layer's pages
+        as one bank, the layer folded into the page ids.
       page_table: (B, W) int32 physical page ids; entries beyond a
         row's live prefix must be NULL (0), like everywhere else.
       start: (B,) int32 global position of each row's FIRST query token
@@ -273,18 +279,15 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
     scale = hd ** -0.5
     page_table = page_table.astype(jnp.int32)
     start = start.astype(jnp.int32)
-    # Heads are flattened into the lane axis — a free reshape of the
-    # (.., nh, hd) pool layout — so every block's last two dims equal
-    # the array's (Mosaic's tiling rule) and one DMA fetches one whole
-    # contiguous page. The kernel walks the heads in lane-aligned slabs
-    # of `group` heads; within a slab a head is picked by masking q's
-    # lanes (the other heads' lanes contribute zero to the contraction).
+    # Heads share the lane axis (the pool's rows), so every block's
+    # last two dims equal the array's (Mosaic's tiling rule) and one DMA
+    # fetches one whole contiguous page. The kernel walks the heads in
+    # lane-aligned slabs of `group` heads; within a slab a head is
+    # picked by masking q's lanes (the other heads' lanes contribute
+    # zero to the contraction).
     group = _head_group(nh, hd)
     slab = group * hd
     row = nh * hd
-
-    def flat(x):
-        return x.reshape(x.shape[:2] + (row,))
 
     def kernel(pt_ref, start_ref, slopes_ref, q_ref, *rest):
         if quantized:
@@ -386,11 +389,11 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
         scale_spec = pl.BlockSpec((1, ps, nh), kvidx)
         in_specs = [slope_spec, q_spec,
                     page_spec, scale_spec, page_spec, scale_spec]
-        operands = (flat(k_pages["q"]), k_pages["scale"],
-                    flat(v_pages["q"]), v_pages["scale"])
+        operands = (k_pages["q"], k_pages["scale"],
+                    v_pages["q"], v_pages["scale"])
     else:
         in_specs = [slope_spec, q_spec, page_spec, page_spec]
-        operands = (flat(k_pages), flat(v_pages))
+        operands = (k_pages, v_pages)
 
     out = pl.pallas_call(
         kernel,
@@ -411,5 +414,6 @@ def paged_attention(q, k_pages, v_pages, page_table, start, *, slopes,
         ),
         interpret=interpret,
         name="paged_attention",
-    )(page_table, start, slopes.astype(jnp.float32), flat(q), *operands)
+    )(page_table, start, slopes.astype(jnp.float32), q.reshape(b, c, row),
+      *operands)
     return out.reshape(b, c, nh, hd)

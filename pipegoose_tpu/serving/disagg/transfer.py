@@ -262,8 +262,8 @@ class PoolTransfer:
                       else max(1, src_engine.prefill_chunk // self.page_size))
 
         def _exp(kp, vp, ids):
-            return (export_page_slab(kp, ids, wire_dtype),
-                    export_page_slab(vp, ids, wire_dtype))
+            return (export_page_slab(kp, ids, scfg.head_dim, wire_dtype),
+                    export_page_slab(vp, ids, scfg.head_dim, wire_dtype))
 
         def _imp(kp, vp, ks, vs, dst_ids):
             return (import_page_slab(kp, ks, dst_ids),

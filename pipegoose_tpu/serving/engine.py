@@ -528,15 +528,16 @@ class ServingEngine:
             self._draft = jax.jit(_draft, donate_argnums=(2, 3))
             self._verify = jax.jit(_verify, donate_argnums=(2, 3))
         else:
-            vspec = P(None, None, None, tp_axis, None)   # pages: head-sharded
-            # int8 pools are {"q", "scale"} pytrees: the scale plane has
-            # no head_dim, so its spec drops the trailing entry — the
-            # per-head scales shard WITH their heads
+            # pages: a row holds its heads major, so sharding the rows
+            # gives each shard its nh/tp heads. int8 pools are {"q",
+            # "scale"} pytrees: the per-head scales shard WITH their heads
+            vspec = P(None, None, None, tp_axis)
             pspec = (
-                {"q": vspec, "scale": P(None, None, None, tp_axis)}
+                {"q": vspec, "scale": vspec}
                 if self.kv_dtype == "int8" else vspec
             )
-            cspec = {"k": vspec, "v": vspec}             # fp prefill cache
+            hspec = P(None, None, None, tp_axis, None)   # fp prefill cache
+            cspec = {"k": hspec, "v": hspec}
 
             def _prefill_body(params, ids, mask):
                 cache = init_cache(config, 1, ids.shape[1], tp)

@@ -6,10 +6,15 @@ contiguous cache (models/generate.py:init_cache) allocates
 them; a serving engine multiplexing many requests instead draws from ONE
 preallocated pool
 
-    (n_layer, num_pages, page_size, n_head_local, head_dim)
+    (n_layer, num_pages, page_size, n_head_local * head_dim)
 
 per k and v, where a sequence owns ``ceil(len / page_size)`` pages wired
-up by an integer page table. Three pieces live here:
+up by an integer page table. A position's heads share ONE lane-dense
+row, heads major (:func:`init_pages` says why), and every program
+updates the pool IN PLACE: rows are addressed by (layer, page, offset)
+on the donated buffer, never on a slice of it. Values keep
+``(.., nh, hd)`` at the edges (:func:`gather_pages`, the wire slabs).
+Three pieces live here:
 
 - :class:`PagePool` — the HOST-side free-list allocator. Allocation is a
   LIFO stack pop, so placement is deterministic given the request/evict
@@ -23,20 +28,19 @@ up by an integer page table. Three pieces live here:
   the page tables (chunked prefill and self-speculative verification
   share this one program shape).
 - :func:`paged_decode_step` — one decode step over the ragged active
-  batch: each slot's pending token is scatter-written through its page
-  table, attention reads the gathered page view, and invalid key
-  columns (beyond ``seq_lens``, stale page tails, null-page garbage)
-  are masked to exactly zero softmax weight. Reuses the SAME qkv
-  projection and attention core as the contiguous path
+  batch: each slot's pending token is written through its page table,
+  attention reads the gathered page view, and invalid key columns
+  (beyond ``seq_lens``, stale page tails, null-page garbage) are masked
+  to exactly zero softmax weight. Both run ONE layer loop with the SAME
+  qkv projection and attention core as the contiguous path
   (models/generate.py:_qkv_proj/_attn_core) so numerics cannot drift.
 - :func:`write_prompt_pages` — scatter a prefill's contiguous cache
   into the pool, repacking a LEFT-padded prompt to logical positions
   0..len-1 (the unpadded layout the decode bias assumes).
 
 Under TP every function sees the LOCAL head subset (call inside
-shard_map with the pool's head dim sharded over the tensor axis), and
-the engine pairs the local logits with ``global_greedy_pick`` exactly
-like models/_decode.py's sharded driver.
+shard_map with the pool's rows sharded over the tensor axis), and the
+engine pairs the local logits with ``global_greedy_pick``.
 
 ``init_pages(kv_dtype="int8")`` swaps each bank for an int8 pytree with
 a per-page scale plane (one fp32 per layer/page-slot/head): writes
@@ -261,32 +265,73 @@ class PagePool:
 
 def init_pages(config, num_pages: int, page_size: int, tp: int = 1,
                kv_dtype: Optional[str] = None):
-    """The pool's device buffers; under TP each shard holds nh/tp heads
-    (create the GLOBAL array and shard dim 3 over the tensor axis).
+    """The pool's device buffers, ``(L, num_pages, page_size, nh*hd)``
+    per bank: a position's heads in one row, heads major, so under TP
+    each shard holds its nh/tp heads (create the GLOBAL array and shard
+    dim 3 over the tensor axis). Kept apart, a ``head_dim`` of 64
+    half-fills the TPU's 128 lanes and the compiler puts the PAGES in
+    the lanes instead: one page's rows lie strided across its plane and
+    every access through a page table re-lays out the plane, or the
+    pool (PERF.md, PR 27).
 
     ``kv_dtype=None`` (or "fp") keeps the fp pool: a bare array pair in
     ``config.dtype``. ``"int8"`` stores each bank as a PYTREE
-    ``{"q": int8 (L, P, ps, nh, hd), "scale": f32 (L, P, ps, nh)}`` —
+    ``{"q": int8 (L, P, ps, nh*hd), "scale": f32 (L, P, ps, nh)}`` —
     the per-page scale plane rides one fp32 scalar per (layer, page
     slot, head), ~hd x 4 bytes lighter than the values it scales. Every
     pool function below dispatches on the structure, so the engine's
     jitted programs, donation, and shard_map specs carry the pair as
     one value either way."""
-    L, nh, hd = config.n_layer, config.n_head, config.head_dim
+    L, nh, hd = config.n_layer, config.n_head // tp, config.head_dim
     kv_dtype = check_kv_dtype(kv_dtype)
-    shape = (L, num_pages, page_size, nh // tp, hd)
+    shape = (L, num_pages, page_size, nh * hd)
     if kv_dtype is None:
         return jnp.zeros(shape, config.dtype), jnp.zeros(shape, config.dtype)
 
     def bank():
         return {"q": jnp.zeros(shape, jnp.int8),
-                "scale": jnp.zeros(shape[:-1], jnp.float32)}
+                "scale": jnp.zeros(shape[:-1] + (nh,), jnp.float32)}
 
     return bank(), bank()
 
 
+def _rows(x):
+    """Values (.., nh, hd) -> the pool's rows (.., nh*hd)."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
+def _heads(x, head_dim: int):
+    """The pool's rows (.., nh*hd) -> values (.., nh, hd)."""
+    return x.reshape(x.shape[:-1] + (-1, head_dim))
+
+
+def _write_rows(pages, idx, val):
+    """Scatter fp values ``val`` (.., nh, hd) into the rows ``idx`` =
+    (layer, page, offset) of a WHOLE bank — quantizing on write when the
+    bank is int8, value and scale plane in lockstep. All three indices
+    on the donated bank keep the update in place: a slice of it
+    (``pages[l]``, ``.at[:, page]``) is copied out before it is written."""
+    if _is_quantized(pages):
+        q, s = quantize_kv(val)
+        return {"q": pages["q"].at[idx].set(_rows(q)),
+                "scale": pages["scale"].at[idx].set(s)}
+    return pages.at[idx].set(_rows(val).astype(pages.dtype))
+
+
+def _values(pages):
+    """A bank's value array (an int8 bank's ``q`` plane)."""
+    return pages["q"] if _is_quantized(pages) else pages
+
+
+def _one_bank(pages):
+    """(L, P, ..) -> (L*P, ..), free: layer l's page p is page l*P + p."""
+    return jax.tree_util.tree_map(
+        lambda a: a.reshape((-1,) + a.shape[2:]), pages)
+
+
 def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size):
-    """Scatter a prefill's contiguous cache into the pool.
+    """Scatter a prefill's contiguous cache into the pool, in place (the
+    L x S_pad rows addressed by layer, page and offset).
 
     ``cache`` is forward_cached's (L, 1, S_pad, nh, hd) pair holding a
     LEFT-padded prompt (``pad`` pad slots, then the prompt); logical
@@ -304,58 +349,42 @@ def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size):
     lclip = jnp.where(valid, logical, 0)
     dest_page = jnp.where(valid, phys_pages[lclip // page_size], NULL_PAGE)
     dest_off = jnp.where(valid, lclip % page_size, 0)
-
-    def scatter(pages, seq):
-        if _is_quantized(pages):
-            q, s = quantize_kv(seq)
-            return {"q": pages["q"].at[:, dest_page, dest_off].set(q),
-                    "scale": pages["scale"].at[:, dest_page, dest_off].set(s)}
-        return pages.at[:, dest_page, dest_off].set(seq.astype(pages.dtype))
-
-    return scatter(k_pages, k_seq), scatter(v_pages, v_seq)
+    layers = jnp.arange(_values(k_pages).shape[0])[:, None]
+    idx = (layers, dest_page[None], dest_off[None])
+    return _write_rows(k_pages, idx, k_seq), _write_rows(v_pages, idx, v_seq)
 
 
-def _gather(arr, page_table, trailing: int):
-    """Page-table gather over an array whose page dim sits ``trailing``
-    dims from the end-plus-one: take inserts the (B, W) table dims,
-    then W and the page_size dim merge into the contiguous view."""
-    b, w = page_table.shape
-    ps = arr.shape[-trailing]
-    view = jnp.take(arr, page_table, axis=-(trailing + 1))
-    return view.reshape(
-        view.shape[:-(trailing + 1)] + (w * ps,) + view.shape[-(trailing - 1):]
-    )
+def _gather(arr, page_table, layer):
+    """Read ``(.., P, ps, X)`` through a (B, W) page table: the table
+    dims replace the page dim, then W and ps merge into the contiguous
+    (.., B, W*ps, X) view. With ``layer``, one gather addressed by
+    (layer, page) reads a whole bank: no plane is sliced out first."""
+    _, w = page_table.shape
+    if layer is None:
+        view = jnp.take(arr, page_table, axis=-3)
+    else:
+        view = arr[layer, page_table]
+    return view.reshape(view.shape[:-3] + (w * arr.shape[-2], arr.shape[-1]))
 
 
-def gather_pages(pages, page_table):
+def gather_pages(pages, page_table, head_dim: int, layer=None):
     """Read the pool through a page table: (B, W) int32 -> the per-slot
-    contiguous view (B, W * page_size, nh, hd). The read path of the
-    paged attention; exposed for the reconstruction tests. An int8 bank
-    dequantizes HERE — inside the gather, per (position, head) — so the
-    attention core sees fp values and the pool keeps 1-byte pages."""
+    contiguous view (.., B, W * page_size, nh, hd), the rows split back
+    into heads of ``head_dim``. The read path of the paged attention
+    (``layer``: one layer of a whole bank, picked inside the gather);
+    exposed for the reconstruction tests. An int8 bank dequantizes HERE
+    — inside the gather, per (position, head) — so the attention core
+    sees fp values and the pool keeps 1-byte pages."""
     if _is_quantized(pages):
-        q = _gather(pages["q"], page_table, trailing=3)
-        s = _gather(pages["scale"], page_table, trailing=2)
-        return dequantize_kv(q, s)
-    return _gather(pages, page_table, trailing=3)
+        q = _heads(_gather(pages["q"], page_table, layer), head_dim)
+        return dequantize_kv(q, _gather(pages["scale"], page_table, layer))
+    return _heads(_gather(pages, page_table, layer), head_dim)
 
 
 def page_size_of(pages) -> int:
-    """Static page_size of a bank, fp or int8 (dim 2 past the layer and
-    page dims; the scale plane shares it)."""
-    leaf = pages["q"] if _is_quantized(pages) else pages
-    return leaf.shape[-3]
-
-
-def _write_kv(pages, page_idx, off_idx, val):
-    """Scatter fp values ``val`` at (page_idx, off_idx) of one LAYER's
-    bank (leading layer dim already scanned away) — quantizing on write
-    when the bank is int8, value and scale plane in lockstep."""
-    if _is_quantized(pages):
-        q, s = quantize_kv(val)
-        return {"q": pages["q"].at[page_idx, off_idx].set(q),
-                "scale": pages["scale"].at[page_idx, off_idx].set(s)}
-    return pages.at[page_idx, off_idx].set(val.astype(pages.dtype))
+    """Static page_size of a bank, fp or int8 (the dim before the rows;
+    the scale plane shares it)."""
+    return _values(pages).shape[-2]
 
 
 def _local_slopes(config, tp_axis):
@@ -370,19 +399,77 @@ def _local_slopes(config, tp_axis):
     return slopes
 
 
-def _paged_bias(config, seq_lens, n_keys, tp_axis):
-    """Additive attention bias for one paged decode step: ALiBi over the
-    GLOBAL key position + a per-ROW keep mask ``key_pos <= seq_len``
-    (causal-by-slot: masks not-yet-written offsets, stale page tails
-    from a previous owner, and null-page garbage alike). Serving slots
-    hold UNPADDED sequences, so plain global positions apply — the same
-    bias _decode_bias builds for extras=None, generalized to a per-row
-    ``start``. Returns (B, nh_local, 1, n_keys)."""
-    slopes = _local_slopes(config, tp_axis)
+def _key_bias(slopes, q_pos, n_keys):
+    """Additive attention bias for queries at GLOBAL positions ``q_pos``
+    (B, C) over ``n_keys`` logical key positions: ALiBi over the key
+    position + the keep mask ``key_pos <= q_pos`` (causal-by-slot: masks
+    not-yet-written offsets, stale page tails from a previous owner, and
+    null-page garbage alike). Serving slots hold UNPADDED sequences, so
+    plain global positions apply — _decode_bias's for extras=None, with
+    a per-row start. Returns (B, nh_local, C, K)."""
     key_pos = jnp.arange(n_keys)
-    keep = key_pos[None, :] <= seq_lens[:, None]  # (B, n_keys)
-    bias = slopes[None, :, None, None] * key_pos[None, None, None, :].astype(jnp.float32)
-    return bias + jnp.where(keep[:, None, None, :], 0.0, NEG_INF)
+    keep = key_pos[None, None, :] <= q_pos[:, :, None]            # (B, C, K)
+    bias = slopes[None, :, None, None] * key_pos.astype(jnp.float32)
+    return bias + jnp.where(keep[:, None, :, :], 0.0, NEG_INF)
+
+
+def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
+                   dest_page, dest_off, qmask, config, tp_axis, attn_impl,
+                   n_layers=None):
+    """The forward both paged programs share: ``tokens`` (B, C) at
+    global positions ``pos`` (B, C) through the first ``n_layers``
+    blocks (all by default) and the final layer norm. The pool rides
+    the layer loop's CARRY: layer ``l`` writes its B x C rows at (l,
+    dest_page, dest_off) and attention reads through a gather addressed
+    by (l, page_table), so the donated pool is updated in place —
+    scanned in and stacked out (xs/ys) it is copied once a call and
+    each layer's plane twice more. Returns (hidden, k_pages, v_pages)."""
+    check_attn_impl(attn_impl)
+    b, c = tokens.shape
+    n_keys = page_table.shape[1] * page_size_of(k_pages)
+    hd = config.head_dim
+
+    x = vocab_parallel_embedding(params["embed"], tokens, tp_axis)
+    x = x.astype(config.dtype)
+    x = layer_norm(params["embed_ln"], x, config.layer_norm_epsilon)
+    slopes = _local_slopes(config, tp_axis)
+    all_layers, num_pages = _values(k_pages).shape[:2]
+    if attn_impl == "gather":
+        bias = _key_bias(slopes, pos, n_keys)
+
+    def layer(l, carry):
+        h, kp, vp = carry
+        blk = jax.tree_util.tree_map(
+            lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
+            params["blocks"])
+        ln1 = layer_norm(blk["ln_1"], h, config.layer_norm_epsilon)
+        q, k, v = _qkv_proj({"qkv": blk["attn"]["qkv"]}, ln1, config, tp_axis)
+        kp = _write_rows(kp, (l, dest_page, dest_off), k)
+        vp = _write_rows(vp, (l, dest_page, dest_off), v)
+        if attn_impl == "paged":
+            # the kernel takes one bank of pages: every layer's, the
+            # layer folded into the page id
+            ctx = paged_attention(q, _one_bank(kp), _one_bank(vp),
+                                  page_table + l * num_pages, pos[:, 0],
+                                  slopes=slopes)
+            if qmask is not None:
+                ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
+            ctx = ctx.astype(h.dtype).reshape(b, c, -1)
+        else:
+            keys = gather_pages(kp, page_table, hd, layer=l)
+            vals = gather_pages(vp, page_table, hd, layer=l)
+            ctx = _attn_core(q, keys, vals, bias, qmask, h.dtype)
+        h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
+        ln2 = layer_norm(blk["ln_2"], h, config.layer_norm_epsilon)
+        up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
+        h = h + row_parallel_linear(blk["mlp"]["down"], bloom_gelu(up), tp_axis)
+        return h, kp, vp
+
+    x, k_pages, v_pages = lax.fori_loop(
+        0, all_layers if n_layers is None else n_layers, layer,
+        (x, k_pages, v_pages))
+    x = layer_norm(params["ln_f"], x, config.layer_norm_epsilon)
+    return x, k_pages, v_pages
 
 
 def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
@@ -393,101 +480,58 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
 
     ``tokens`` (B,) are the pending tokens (each slot's last emitted
     token), ``seq_lens`` (B,) the number of tokens already cached per
-    slot — the pending token's position. Each slot's k/v is written
-    through its ``page_table`` (B, W) row at page ``seq_len // ps``,
-    offset ``seq_len % ps``; attention reads the gathered page view.
-    Padded slots must point every table entry at the NULL page (their
-    writes and reads are garbage-in/garbage-out, masked by the bias and
-    discarded by the scheduler).
+    slot — the pending token's position. Each slot's k/v row is written
+    in place through its ``page_table`` (B, W) row at page ``seq_len //
+    ps``, offset ``seq_len % ps``; attention reads the gathered page
+    view (the loop is :func:`_paged_forward`'s). Padded slots must
+    point every table entry at the NULL page (their writes and reads
+    are garbage-in/garbage-out, masked by the bias and discarded by the
+    scheduler).
 
     ``write_ok`` (B,) bool routes a row's k/v write to the NULL page
     when False — the self-speculative draft loop uses it to cap
     per-slot draft depth inside one compiled program. ``draft_layers``
     (static) runs only the FIRST k transformer blocks before the final
     LN and lm head — the shallow-exit draft model that shares every
-    weight with the verifier; its k/v writes land in the pool's first k
-    layer planes (the verification pass later overwrites them with
+    weight with the verifier; it bounds the layer loop, so its k/v
+    writes land in the pool's first k layers and no deeper layer is
+    touched (the verification pass later overwrites them with
     byte-identical values, since layer i's k/v depend only on the token
     sequence and layers < i).
 
-    ``attn_impl`` selects the attention read: ``"gather"`` (default)
-    materializes the page view (gather_pages + _attn_core, the parity
-    reference), ``"paged"`` walks the page table in one fused Pallas
-    pass (ops/paged_attention.py) — same mask/bias semantics, no
-    contiguous KV buffer, int8 pages dequantized in-register.
+    ``attn_impl``: ``"gather"`` (default) materializes the page view
+    (gather_pages + _attn_core, the parity reference); ``"paged"`` walks
+    the page table in one fused Pallas pass (ops/paged_attention.py) —
+    same mask/bias semantics, int8 pages dequantized in-register.
 
     Returns (logits (B, V_local), k_pages, v_pages). Under ``tp_axis``
     the logits are the LOCAL vocab shard — pair with
     ``_decode.global_greedy_pick`` like the sharded generate driver.
     """
-    check_attn_impl(attn_impl)
-    b = tokens.shape[0]
     ps = page_size_of(k_pages)
-    n_keys = page_table.shape[1] * ps
-
-    x = vocab_parallel_embedding(params["embed"], tokens[:, None], tp_axis)
-    x = x.astype(config.dtype)
-    x = layer_norm(params["embed_ln"], x, config.layer_norm_epsilon)
-    if attn_impl == "paged":
-        slopes = _local_slopes(config, tp_axis)
-        bias = None
-    else:
-        bias = _paged_bias(config, seq_lens, n_keys, tp_axis)
-
     page_idx = seq_lens // ps
     off = seq_lens % ps
     phys = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
     if write_ok is not None:
         phys = jnp.where(write_ok, phys, NULL_PAGE)
         off = jnp.where(write_ok, off, 0)
-
-    blocks = params["blocks"]
-    k_all, v_all = k_pages, v_pages
-    if draft_layers is not None:
-        blocks = jax.tree_util.tree_map(lambda a: a[:draft_layers], blocks)
-        k_pages = jax.tree_util.tree_map(lambda a: a[:draft_layers], k_pages)
-        v_pages = jax.tree_util.tree_map(lambda a: a[:draft_layers], v_pages)
-
-    def scan_fn(carry, blk_and_pages):
-        h = carry
-        blk, kp, vp = blk_and_pages
-        ln1 = layer_norm(blk["ln_1"], h, config.layer_norm_epsilon)
-        q, k, v = _qkv_proj({"qkv": blk["attn"]["qkv"]}, ln1, config, tp_axis)
-        kp = _write_kv(kp, phys, off, k[:, 0])
-        vp = _write_kv(vp, phys, off, v[:, 0])
-        if attn_impl == "paged":
-            ctx = paged_attention(q, kp, vp, page_table, seq_lens,
-                                  slopes=slopes)
-            ctx = ctx.astype(h.dtype).reshape(b, 1, -1)
-        else:
-            keys = gather_pages(kp, page_table)
-            vals = gather_pages(vp, page_table)
-            ctx = _attn_core(q, keys, vals, bias, None, h.dtype)
-        h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
-        ln2 = layer_norm(blk["ln_2"], h, config.layer_norm_epsilon)
-        up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
-        h = h + row_parallel_linear(blk["mlp"]["down"], bloom_gelu(up), tp_axis)
-        return h, (kp, vp)
-
-    x, (k_pages, v_pages) = lax.scan(scan_fn, x, (blocks, k_pages, v_pages))
-    if draft_layers is not None:
-        merge = lambda full, part: full.at[:draft_layers].set(part)  # noqa: E731
-        k_pages = jax.tree_util.tree_map(merge, k_all, k_pages)
-        v_pages = jax.tree_util.tree_map(merge, v_all, v_pages)
-    x = layer_norm(params["ln_f"], x, config.layer_norm_epsilon)
+    x, k_pages, v_pages = _paged_forward(
+        params, tokens[:, None], k_pages, v_pages, page_table,
+        seq_lens[:, None], phys[:, None], off[:, None], None, config,
+        tp_axis, attn_impl, n_layers=draft_layers)
     logits = logits_fn(params, x, tp_axis)[:, 0]  # (B, V_local)
     return logits, k_pages, v_pages
 
 
-def export_page_slab(pages, page_ids, wire_dtype=None):
+def export_page_slab(pages, page_ids, head_dim: int, wire_dtype=None):
     """Page EXPORT view for cross-pool KV streaming (serving/disagg/):
     gather ``page_ids`` (W,) int32 out of one bank into a contiguous
-    slab ``(L, W, ps, nh, hd)`` at WIRE precision. An int8 bank ships
-    its ``{"q", "scale"}`` planes verbatim — quantized pages are NEVER
-    dequantized in flight (the whole point of the int8 wire format);
-    an fp bank optionally down-casts to ``wire_dtype="bf16"`` (the
-    distributed/compressed.py convention — exact when the pool dtype
-    is already bf16, lossy for an fp32 pool). Pure jax: jit it on the
+    slab ``(L, W, ps, nh, hd)`` at WIRE precision (the small slab is
+    split into heads, never the pool). An int8 bank ships its ``{"q",
+    "scale"}`` planes verbatim — quantized pages are NEVER dequantized
+    in flight; an fp bank optionally down-casts to ``wire_dtype="bf16"``
+    (the distributed/compressed.py convention — exact when the pool is
+    already bf16, lossy for an fp32 pool). Pure jax: jit it on the
     source pool's mesh and the gather resolves this shard's heads; the
     host fetch of the result is the resharding point."""
     if _is_quantized(pages):
@@ -496,9 +540,9 @@ def export_page_slab(pages, page_ids, wire_dtype=None):
                 "int8 pools define their own wire format (q + scale); "
                 f"wire_dtype={wire_dtype!r} does not apply"
             )
-        return {"q": jnp.take(pages["q"], page_ids, axis=1),
+        return {"q": _heads(jnp.take(pages["q"], page_ids, axis=1), head_dim),
                 "scale": jnp.take(pages["scale"], page_ids, axis=1)}
-    slab = jnp.take(pages, page_ids, axis=1)
+    slab = _heads(jnp.take(pages, page_ids, axis=1), head_dim)
     if wire_dtype == "bf16":
         return slab.astype(jnp.bfloat16)
     if wire_dtype is not None:
@@ -508,16 +552,17 @@ def export_page_slab(pages, page_ids, wire_dtype=None):
 
 
 def import_page_slab(pages, slab, dst_ids):
-    """Page IMPORT view: scatter a wire slab into ``dst_ids`` (W,) of
-    one bank. The quantized layout lands q and scale planes together
-    (still never dequantized — the decode pool's gather does that, per
-    read, like for locally written pages); a bf16 wire slab up-casts to
-    the pool dtype here. Padding entries route to the NULL page, the
-    same sink every other pad write uses."""
+    """Page IMPORT view: scatter a wire slab ``(L, W, ps, nh, hd)`` into
+    ``dst_ids`` (W,) of one bank, in place (pages addressed by layer and
+    page). The quantized layout lands q and scale planes together (still
+    never dequantized — the decode pool's gather does that, per read);
+    a bf16 wire slab up-casts to the pool dtype here. Padding entries
+    route to the NULL page, the same sink every other pad write uses."""
+    idx = (jnp.arange(_values(pages).shape[0])[:, None], dst_ids[None])
     if _is_quantized(pages):
-        return {"q": pages["q"].at[:, dst_ids].set(slab["q"]),
-                "scale": pages["scale"].at[:, dst_ids].set(slab["scale"])}
-    return pages.at[:, dst_ids].set(slab.astype(pages.dtype))
+        return {"q": pages["q"].at[idx].set(_rows(slab["q"])),
+                "scale": pages["scale"].at[idx].set(slab["scale"])}
+    return pages.at[idx].set(_rows(slab).astype(pages.dtype))
 
 
 def copy_page(k_pages, v_pages, src, dst):
@@ -554,7 +599,8 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     the NULL page and get zero context. Attention is causal over the
     global position — every cached position plus the chunk's own
     earlier tokens — with the same ALiBi-over-global-position bias as
-    the decode step, so chunk boundaries are invisible in the math.
+    the decode step (:func:`_paged_forward` serves both), so chunk
+    boundaries are invisible in the math.
 
     Returns (logits, k_pages, v_pages): logits at each row's LAST VALID
     position, (B, V_local) — the next-token distribution chunked
@@ -562,67 +608,22 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     ``all_logits=True`` (self-speculative verification scores the whole
     draft bundle in one pass through this same paged path).
 
-    ``attn_impl="paged"`` routes the attention read through the fused
-    Pallas page-table walk (ops/paged_attention.py) in its ragged
-    multi-token mode — the same kernel the decode step uses, with
+    ``attn_impl="paged"`` reads through the fused Pallas page-table
+    walk in its ragged multi-token mode — the decode step's kernel, with
     ``start`` as the per-row global query origin; pad queries beyond
-    ``n_valid`` are zeroed by the same qmask multiply as the gather
-    path.
+    ``n_valid`` are zeroed by the gather path's qmask multiply.
     """
-    check_attn_impl(attn_impl)
-    b, c = tokens.shape
+    c = tokens.shape[1]
     ps = page_size_of(k_pages)
-    n_keys = page_table.shape[1] * ps
-
-    x = vocab_parallel_embedding(params["embed"], tokens, tp_axis)
-    x = x.astype(config.dtype)
-    x = layer_norm(params["embed_ln"], x, config.layer_norm_epsilon)
-
     pos = start[:, None] + jnp.arange(c)[None, :]             # (B, C)
     valid = jnp.arange(c)[None, :] < n_valid[:, None]         # (B, C)
     dest_page = jnp.where(
         valid, jnp.take_along_axis(page_table, pos // ps, axis=1), NULL_PAGE
     )
     dest_off = jnp.where(valid, pos % ps, 0)
-
-    slopes = _local_slopes(config, tp_axis)
-    if attn_impl == "paged":
-        bias = None
-    else:
-        key_pos = jnp.arange(n_keys)
-        keep = key_pos[None, None, :] <= pos[:, :, None]      # (B, C, K)
-        bias = slopes[None, :, None, None] * key_pos[
-            None, None, None, :
-        ].astype(jnp.float32)
-        bias = bias + jnp.where(keep[:, None, :, :], 0.0, NEG_INF)
-    qmask = valid
-
-    def scan_fn(carry, blk_and_pages):
-        h = carry
-        blk, kp, vp = blk_and_pages
-        ln1 = layer_norm(blk["ln_1"], h, config.layer_norm_epsilon)
-        q, k, v = _qkv_proj({"qkv": blk["attn"]["qkv"]}, ln1, config, tp_axis)
-        kp = _write_kv(kp, dest_page, dest_off, k)
-        vp = _write_kv(vp, dest_page, dest_off, v)
-        if attn_impl == "paged":
-            ctx = paged_attention(q, kp, vp, page_table, start,
-                                  slopes=slopes)
-            ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
-            ctx = ctx.astype(h.dtype).reshape(b, c, -1)
-        else:
-            keys = gather_pages(kp, page_table)
-            vals = gather_pages(vp, page_table)
-            ctx = _attn_core(q, keys, vals, bias, qmask, h.dtype)
-        h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
-        ln2 = layer_norm(blk["ln_2"], h, config.layer_norm_epsilon)
-        up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
-        h = h + row_parallel_linear(blk["mlp"]["down"], bloom_gelu(up), tp_axis)
-        return h, (kp, vp)
-
-    x, (k_pages, v_pages) = lax.scan(
-        scan_fn, x, (params["blocks"], k_pages, v_pages)
-    )
-    x = layer_norm(params["ln_f"], x, config.layer_norm_epsilon)
+    x, k_pages, v_pages = _paged_forward(
+        params, tokens, k_pages, v_pages, page_table, pos, dest_page,
+        dest_off, valid, config, tp_axis, attn_impl)
     if all_logits:
         return logits_fn(params, x, tp_axis), k_pages, v_pages  # (B, C, V)
     last = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)

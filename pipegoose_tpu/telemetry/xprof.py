@@ -329,7 +329,9 @@ def attribute_op_times(
     ``sum(dur) / (steps * n_devices)``. Collective events join the
     doctor ``schedule`` by HLO instruction name (async start/done halves
     by stem) to inherit mesh axes + payload bytes; unmatched collectives
-    land in the ``"?"`` bucket with ``bytes=0``.
+    land in the ``"?"`` bucket with ``bytes=0``. A ``while``,
+    ``conditional`` or ``call`` is not counted: its event spans its
+    body's ops, which are events of their own.
     """
     totals: Dict[str, float] = {}
     for e in events:
@@ -337,6 +339,8 @@ def attribute_op_times(
         if not name:
             continue
         op = (e.get("args") or {}).get("hlo_op") or name
+        if op.split(".")[0] in ("while", "conditional", "call"):
+            continue
         totals[op] = totals.get(op, 0.0) + float(e.get("dur", 0.0)) * 1e-6
     denom = max(steps, 1) * max(n_devices, 1)
     per_op = {k: v / denom for k, v in totals.items()}

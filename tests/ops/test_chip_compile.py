@@ -2,7 +2,9 @@
 (not attached) TPU v5e at real widths. Nothing runs: the chip's compiler
 accepts or refuses each kernel, which interpret-mode tests cannot show
 (tile alignment, Mosaic legalization, VMEM). Results and times come from
-``chip_smoke.py`` on the chip.
+``chip_smoke.py`` on the chip. The serving engine's pool programs are
+compiled here too, for their STRUCTURE: each has to update the donated
+KV pool in place (PERF.md, PR 27).
 
 The topology is described inside a fixture of THIS file only: the worker
 that runs the file loads the TPU library, every other worker never does.
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from pipegoose_tpu.models import bloom
 from pipegoose_tpu.nn.sequence_parallel.ring_attention import (
     ring_flash_attention,
 )
@@ -22,6 +25,8 @@ from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.quant.matmul import quantized_matmul
+from pipegoose_tpu.serving import ServingEngine
+from pipegoose_tpu.serving.kv_pool import import_page_slab
 
 # bloom-560m: hidden 1024, 16 heads x 64, padded vocab 250880; train
 # b8 x s1024, decode 8 slots over a 16-row-page pool.
@@ -106,11 +111,12 @@ def _quant_matmul(int4):
 
 def _paged(quantized, ps=PS, c=1, nh=NH, hd=HD):
     w = W * PS // ps
+    # a bank in the pool's layout: a position's heads in one row
     if quantized:
-        bank = {"q": ((PAGES, ps, nh, hd), jnp.int8),
+        bank = {"q": ((PAGES, ps, nh * hd), jnp.int8),
                 "scale": ((PAGES, ps, nh), jnp.float32)}
     else:
-        bank = ((PAGES, ps, nh, hd), jnp.bfloat16)
+        bank = ((PAGES, ps, nh * hd), jnp.bfloat16)
 
     def fn(q, kp, vp, pt, start, sl):
         return paged_attention(q, kp, vp, pt, start, slopes=sl,
@@ -189,3 +195,85 @@ def test_lowered_kernel_holds_its_name(case):
         lowering_platforms=("tpu",)).as_text()
     found = set(re.findall(r'kernel_name = "([^"]*)"', text))
     assert found == set(KERNELS.get(case, ["paged_attention"]))
+
+
+# -- the serving engine's pool programs, for their structure ------------------
+#
+# The pool is 3.2 GB in the benchmark's serving cell and every program
+# that touches it is handed it donated. Stored (.., nh, hd) with 64-wide
+# heads the compiler kept the PAGES in the lanes: the decode step
+# re-laid out two planes a layer and copied the pool once a call, the
+# page write copied it four times (PERF.md, PR 27). The mechanism
+# engages in the compiled program or not at all, so this is its counter.
+
+POOL_HEADS = {"16x64": (16, 64), "16x128": (16, 128)}    # bloom-560m, -1b7
+# 320 pages: a plane's element count is no weight's (those are powers of
+# two and three times them), so a plane is known by its size
+POOL_L, POOL_PAGES, POOL_SLOTS, POOL_CONTEXT, POOL_BUCKET = 2, 320, 2, 256, 64
+POOL_PROGRAMS = ("step", "write", "chunk", "copy", "import")
+
+
+def _pool_program(one_chip, heads, program):
+    """(lowered program, one bank's bytes) of a default engine at small
+    depth and pool: 2 slots x 16 table entries reach 32 of 320 pages, so
+    nothing a program may legitimately gather is as large as a plane."""
+    nh, hd = POOL_HEADS[heads]
+    cfg = bloom.BloomConfig(vocab_size=512, hidden_size=nh * hd,
+                            n_layer=POOL_L, n_head=nh, dtype=jnp.bfloat16)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda k: bloom.init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, num_slots=POOL_SLOTS,
+                        num_pages=POOL_PAGES, page_size=PS,
+                        max_context=POOL_CONTEXT)
+    kp, vp = sds(eng.k_pages), sds(eng.v_pages)
+    assert kp.shape == (POOL_L, POOL_PAGES, PS, nh * hd)
+    slots, width = eng.num_slots, eng.table_width
+    if program == "step":
+        low = eng._step.lower(params, vec(slots), kp, vp, vec(slots, width),
+                              vec(slots))
+    elif program == "chunk":
+        low = eng._chunk.lower(params, vec(slots, PS), kp, vp,
+                               vec(slots, width), vec(slots), vec(slots))
+    elif program == "write":
+        cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+            eng._prefill, params, vec(1, POOL_BUCKET), vec(1, POOL_BUCKET))[1])
+        low = eng._write.lower(kp, vp, cache, vec(width), vec())
+    elif program == "copy":
+        low = eng._copy.lower(kp, vp, vec(), vec())
+    else:
+        slab = jax.ShapeDtypeStruct((POOL_L, 4, PS, nh, hd), jnp.bfloat16,
+                                    sharding=one_chip)
+        low = jax.jit(import_page_slab, donate_argnums=0).lower(
+            kp, slab, vec(4))
+    return low, kp.size * kp.dtype.itemsize
+
+
+@pytest.mark.parametrize("heads", sorted(POOL_HEADS))
+@pytest.mark.parametrize("program", POOL_PROGRAMS)
+def test_pool_program_updates_the_pool_in_place(one_chip, program, heads):
+    low, bank_bytes = _pool_program(one_chip, heads, program)
+    compiled = low.compile()
+    banks = 1 if program == "import" else 2
+    ma = compiled.memory_analysis()
+    # every bank handed in is the bank handed back ...
+    assert ma.alias_size_in_bytes == banks * bank_bytes
+    # ... no second one is built beside it ...
+    assert ma.temp_size_in_bytes < bank_bytes, ma.temp_size_in_bytes
+    # ... and none, nor one layer's plane of it, is copied or re-laid out
+    plane = bank_bytes // 2 // POOL_L            # elements of a plane
+    moved = []
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(",
+                         compiled.as_text()):
+        elements = 1
+        for d in m.group(1).split(","):
+            elements *= int(d)
+        if elements % plane == 0:
+            moved.append(m.group(0))
+    assert not moved, moved

@@ -39,13 +39,18 @@ def _make_pool(rng, quantized):
     the mask, not zeroed memory, must keep invalid keys out."""
     k = jnp.asarray(rng.randn(NPAGES, PS, NH, HD), jnp.float32)
     v = jnp.asarray(rng.randn(NPAGES, PS, NH, HD), jnp.float32)
+
+    def rows(x):   # the pool's layout: a position's heads in one row
+        return x.reshape(NPAGES, PS, NH * HD)
+
     if not quantized:
-        return k, v, k, v
+        return rows(k), rows(v), k, v
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
     kd = (kq.astype(jnp.float32) * ks[..., None])
     vd = (vq.astype(jnp.float32) * vs[..., None])
-    return {"q": kq, "scale": ks}, {"q": vq, "scale": vs}, kd, vd
+    return ({"q": rows(kq), "scale": ks}, {"q": rows(vq), "scale": vs},
+            kd, vd)
 
 
 def _dense_rows(q, kd, vd, table, start, slopes):
@@ -153,7 +158,8 @@ def test_tp2_head_sharded_matches_single_device(case, devices):
     full = paged_attention(q, kp, vp, table, start, slopes=slopes,
                            interpret=True)
     mesh = Mesh(np.array(jax.devices()[:2]), ("tensor",))
-    pspec = {"q": P(None, None, "tensor", None), "scale": P(None, None, "tensor")}
+    # heads are major inside a row: sharding the rows shards the heads
+    pspec = {"q": P(None, None, "tensor"), "scale": P(None, None, "tensor")}
 
     def body(q, kp, vp, table, start, slopes):
         return paged_attention(q, kp, vp, table, start, slopes=slopes,

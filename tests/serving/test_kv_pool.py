@@ -8,15 +8,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from pipegoose_tpu.models import bloom
+from pipegoose_tpu.distributed import ParallelContext
+from pipegoose_tpu.models import bloom, generate as gen
 from pipegoose_tpu.models.generate import forward_cached, init_cache
 from pipegoose_tpu.serving import (
     NULL_PAGE,
     PagePool,
+    Request,
+    ServingEngine,
     gather_pages,
     init_pages,
     write_prompt_pages,
 )
+from pipegoose_tpu.serving.kv_pool import paged_decode_step
+
+# n_head x head_dim: rows narrower than, equal to and wider than the 128
+# lanes the pool's layout is about (bloom-560m is 16 x 64, bloom-1b7
+# 16 x 128)
+HEADS = {"4x16": (4, 16), "2x64": (2, 64), "2x128": (2, 128)}
 
 
 # --- allocator --------------------------------------------------------------
@@ -96,11 +105,34 @@ def test_pages_for_rounding():
 # --- gather / scatter reconstruction ---------------------------------------
 
 
-@pytest.fixture(scope="module")
-def tiny():
-    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=64, n_layer=2, n_head=4)
-    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
-    return cfg, params
+def _config(heads, n_layer):
+    nh, hd = HEADS[heads]
+    return bloom.BloomConfig(vocab_size=64, hidden_size=nh * hd,
+                             n_layer=n_layer, n_head=nh)
+
+
+def _model(heads, n_layer=3):
+    cfg = _config(heads, n_layer)
+    return cfg, bloom.init_params(cfg, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module", params=sorted(HEADS))
+def tiny(request):
+    return _model(request.param)
+
+
+def _prefill(cfg, params, prompt, bucket):
+    """forward_cached over a LEFT-padded prompt: (logits, cache, pad)."""
+    pad = bucket - len(prompt)
+    ids = np.zeros((1, bucket), np.int32)
+    ids[0, pad:] = prompt
+    mask = np.zeros((1, bucket), np.int32)
+    mask[0, pad:] = 1
+    logits, cache = forward_cached(
+        params, jnp.asarray(ids), init_cache(cfg, 1, bucket), 0, cfg,
+        extras={"mask": jnp.asarray(mask)},
+    )
+    return logits, cache, pad
 
 
 def test_write_prompt_pages_reconstructs_contiguous_cache(tiny):
@@ -109,19 +141,10 @@ def test_write_prompt_pages_reconstructs_contiguous_cache(tiny):
     and the null page is untouched garbage territory."""
     cfg, params = tiny
     page_size, s, pad = 4, 9, 3  # 9 real tokens in a 12-slot bucket
-    bucket = s + pad
-    ids = np.zeros((1, bucket), np.int32)
-    ids[0, pad:] = np.arange(1, s + 1)
-    mask = np.zeros((1, bucket), np.int32)
-    mask[0, pad:] = 1
-
-    cache = init_cache(cfg, 1, bucket)
-    _, cache = forward_cached(
-        params, jnp.asarray(ids), cache, 0, cfg,
-        extras={"mask": jnp.asarray(mask)},
-    )
-
+    _, cache, _ = _prefill(cfg, params, np.arange(1, s + 1), s + pad)
     k_pages, v_pages = init_pages(cfg, num_pages=8, page_size=page_size)
+    # a position's heads share one lane-dense row
+    assert k_pages.shape == (cfg.n_layer, 8, page_size, cfg.hidden_size)
     phys = np.zeros((4,), np.int32)
     phys[:3] = [5, 2, 7]  # 3 pages cover 9 tokens, deliberately unordered
     k_pages, v_pages = write_prompt_pages(
@@ -129,8 +152,9 @@ def test_write_prompt_pages_reconstructs_contiguous_cache(tiny):
     )
 
     table = jnp.asarray(phys)[None]  # (1, W)
-    got_k = np.asarray(gather_pages(k_pages, table))  # (L, 1, W*ps, nh, hd)
-    got_v = np.asarray(gather_pages(v_pages, table))
+    # (L, 1, W*ps, nh, hd): values come back split into heads
+    got_k = np.asarray(gather_pages(k_pages, table, cfg.head_dim))
+    got_v = np.asarray(gather_pages(v_pages, table, cfg.head_dim))
     want_k = np.asarray(cache["k"])[:, :, pad:]  # unpadded layout
     want_v = np.asarray(cache["v"])[:, :, pad:]
     np.testing.assert_array_equal(got_k[:, :, :s], want_k)
@@ -146,16 +170,7 @@ def test_write_routes_padding_to_null_page(tiny):
     any REAL page never sees another request's garbage."""
     cfg, params = tiny
     page_size, s, pad = 4, 5, 3
-    bucket = s + pad
-    ids = np.zeros((1, bucket), np.int32)
-    ids[0, pad:] = np.arange(1, s + 1)
-    mask = np.zeros((1, bucket), np.int32)
-    mask[0, pad:] = 1
-    cache = init_cache(cfg, 1, bucket)
-    _, cache = forward_cached(
-        params, jnp.asarray(ids), cache, 0, cfg,
-        extras={"mask": jnp.asarray(mask)},
-    )
+    _, cache, _ = _prefill(cfg, params, np.arange(1, s + 1), s + pad)
     k_pages, v_pages = init_pages(cfg, num_pages=8, page_size=page_size)
     phys = np.zeros((2,), np.int32)
     phys[:2] = [3, 6]
@@ -165,6 +180,144 @@ def test_write_routes_padding_to_null_page(tiny):
     k_np = np.asarray(k_pages)
     untouched = [p for p in range(1, 8) if p not in (3, 6)]
     np.testing.assert_array_equal(k_np[:, untouched], 0.0)
+
+
+# --- the decode step over the in-place pool ---------------------------------
+
+
+def _paged_rows(cfg, params, prompts, page_size, width, num_pages):
+    """Prefill each prompt into its own pages: (k_pages, v_pages, table,
+    seq_lens, first tokens)."""
+    k_pages, v_pages = init_pages(cfg, num_pages, page_size)
+    table = np.zeros((len(prompts), width), np.int32)
+    nxt, first = 1, []
+    for r, prompt in enumerate(prompts):
+        n = -(-len(prompt) // page_size)
+        table[r, :n] = range(nxt, nxt + n)
+        nxt += n + 1      # leave a page for the row to grow into
+        table[r, n] = nxt - 1
+        logits, cache, pad = _prefill(cfg, params, prompt, width * page_size)
+        k_pages, v_pages = write_prompt_pages(
+            k_pages, v_pages, cache, jnp.asarray(table[r]), pad, page_size)
+        first.append(int(jnp.argmax(logits[0])))
+    seq = np.asarray([len(p) for p in prompts], np.int32)
+    return k_pages, v_pages, jnp.asarray(table), seq, first
+
+
+def test_decode_steps_match_generate(tiny):
+    """Two ragged rows decoded through the page tables: every step's
+    logits match the contiguous-cache decode of each row alone, and the
+    greedy stream is generate()'s."""
+    cfg, params = tiny
+    rng = np.random.RandomState(1)
+    prompts = [rng.randint(1, 64, (n,)) for n in (9, 5)]
+    ps, width, steps = 4, 5, 4
+    kp, vp, table, seq, first = _paged_rows(
+        cfg, params, prompts, ps, width, num_pages=12)
+    tok = jnp.asarray(first, jnp.int32)
+    streams = [[t] for t in first]
+    for i in range(steps - 1):
+        logits, kp, vp = paged_decode_step(
+            params, tok, kp, vp, table, jnp.asarray(seq + i), cfg)
+        for r, prompt in enumerate(prompts):
+            # the same sequence through forward_cached, uncached
+            ids = np.concatenate([prompt, streams[r]])[None]
+            want, _ = forward_cached(
+                params, jnp.asarray(ids), init_cache(cfg, 1, ids.shape[1]),
+                0, cfg)
+            np.testing.assert_allclose(np.asarray(logits[r]),
+                                       np.asarray(want[0]), atol=2e-4)
+        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+        for r in range(len(prompts)):
+            streams[r].append(int(tok[r]))
+    for r, prompt in enumerate(prompts):
+        out = gen.generate(params, jnp.asarray(prompt)[None], cfg,
+                           max_new_tokens=steps)
+        assert streams[r] == list(np.asarray(out)[0, len(prompt):])
+
+
+def _two_rows_mid_page(cfg, seed):
+    """A pool of random values (so an untouched row is known by its
+    bytes) and two rows about to write (page 4, offset 1) and (page 5,
+    offset 2): (k_pages, v_pages, table, seq_lens, tokens)."""
+    rng = np.random.RandomState(seed)
+    shape = init_pages(cfg, 6, 4)[0].shape
+    kp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    vp = jnp.asarray(rng.randn(*shape), jnp.float32)
+    table = jnp.asarray([[2, 4], [5, 1]], jnp.int32)
+    return (kp, vp, table, jnp.asarray([5, 2], jnp.int32),
+            jnp.asarray([7, 9], jnp.int32))
+
+
+def test_draft_layers_leave_deeper_layers_untouched(tiny):
+    """``draft_layers=1`` is the layer loop's bound: one row a slot
+    lands in layer 0, every deeper layer's plane is byte-for-byte what
+    it was, and the logits are a one-block model's."""
+    cfg, params = tiny
+    kp, vp, table, seq, tok = _two_rows_mid_page(cfg, seed=2)
+    logits, k1, v1 = paged_decode_step(params, tok, kp, vp, table, seq, cfg,
+                                       draft_layers=1)
+    for before, after in ((kp, k1), (vp, v1)):
+        before, after = np.asarray(before), np.asarray(after)
+        np.testing.assert_array_equal(after[1:], before[1:])
+        changed = np.argwhere((after[0] != before[0]).any(-1))
+        assert sorted(map(tuple, changed)) == [(4, 1), (5, 2)]
+    shallow = jax.tree_util.tree_map(lambda a: a, params)
+    shallow["blocks"] = jax.tree_util.tree_map(lambda a: a[:1],
+                                               params["blocks"])
+    want, _, _ = paged_decode_step(shallow, tok, kp[:1], vp[:1], table, seq,
+                                   cfg)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               atol=1e-5)
+
+
+def test_write_ok_false_routes_the_row_to_the_null_page(tiny):
+    """A row with ``write_ok=False`` writes the NULL page's first row
+    in every layer and nothing of its own pages."""
+    cfg, params = tiny
+    kp, vp, table, seq, tok = _two_rows_mid_page(cfg, seed=3)
+    _, k1, _ = paged_decode_step(
+        params, tok, kp, vp, table, seq, cfg,
+        write_ok=jnp.asarray([True, False]))
+    before, after = np.asarray(kp), np.asarray(k1)
+    for layer in range(cfg.n_layer):
+        changed = np.argwhere((after[layer] != before[layer]).any(-1))
+        assert sorted(map(tuple, changed)) == [(NULL_PAGE, 0), (4, 1)]
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_tp2_head_sharded_rows_match_generate(devices, heads, kv_dtype):
+    """The pool's rows sharded over the tensor axis give each shard its
+    nh/2 heads: a tp=2 engine on the CPU mesh emits generate()'s
+    tokens (int8 KV: the single-device int8 engine's)."""
+    cfg, params = _model(heads, n_layer=2)
+    rng = np.random.RandomState(5)
+    reqs = [(rng.randint(1, 64, (n,)), m) for n, m in ((9, 5), (6, 4))]
+    kw = dict(num_slots=2, num_pages=16, page_size=4, max_context=32,
+              kv_dtype=kv_dtype)
+
+    def run(eng):
+        outs, _ = eng.run([Request(prompt=p, max_new_tokens=m)
+                           for p, m in reqs])
+        return [list(o.generated) for o in outs]
+
+    if kv_dtype is None:
+        want = [list(np.asarray(gen.generate(
+            params, jnp.asarray(p)[None], cfg, max_new_tokens=m))[0, len(p):])
+            for p, m in reqs]
+    else:
+        want = run(ServingEngine(params, cfg, **kw))
+    ctx = ParallelContext(tensor_parallel_size=2, data_parallel_size=4)
+    try:
+        eng = ServingEngine(params, cfg, mesh=ctx.mesh,
+                            param_specs=bloom.tp_specs(params), **kw)
+        leaf = jax.tree_util.tree_leaves(eng.k_pages)[0]
+        assert leaf.sharding.shard_shape(leaf.shape)[-1] == \
+            cfg.hidden_size // 2
+        assert run(eng) == want
+    finally:
+        ctx.destroy()
 
 
 # -- int8 pools: transferred-in pages mixed with local writes (ISSUE 13) ----
@@ -177,9 +330,10 @@ def test_write_routes_padding_to_null_page(tiny):
 
 
 def _int8_pool(cfg, num_pages=9, ps=4):
-    from pipegoose_tpu.serving import init_pages
-
-    return init_pages(cfg, num_pages, ps, kv_dtype="int8")
+    kp, vp = init_pages(cfg, num_pages, ps, kv_dtype="int8")
+    assert kp["q"].shape == (cfg.n_layer, num_pages, ps, cfg.hidden_size)
+    assert kp["scale"].shape == (cfg.n_layer, num_pages, ps, cfg.n_head)
+    return kp, vp
 
 
 def _fake_cache(cfg, s, seed):
@@ -189,26 +343,29 @@ def _fake_cache(cfg, s, seed):
             "v": jnp.asarray(rng.randn(*shape).astype(np.float32))}
 
 
-def test_int8_export_import_roundtrip_preserves_q_and_scale():
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_int8_export_import_roundtrip_preserves_q_and_scale(heads):
     from pipegoose_tpu.serving.kv_pool import (
         export_page_slab,
         import_page_slab,
     )
 
-    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=32, n_layer=2,
-                            n_head=2)
+    cfg = _config(heads, n_layer=2)
     kp, vp = _int8_pool(cfg)
     phys = np.zeros((4,), np.int32)
     phys[:2] = [1, 2]
     kp, vp = write_prompt_pages(kp, vp, _fake_cache(cfg, 8, 0), phys,
                                 pad=0, page_size=4)
     ids = jnp.asarray([1, 2], jnp.int32)
-    k_slab = export_page_slab(kp, ids)
-    v_slab = export_page_slab(vp, ids)
+    k_slab = export_page_slab(kp, ids, cfg.head_dim)
+    v_slab = export_page_slab(vp, ids, cfg.head_dim)
     # the wire is q + scale, at wire dtypes — never fp
     assert set(k_slab) == {"q", "scale"}
     assert k_slab["q"].dtype == jnp.int8
     assert k_slab["scale"].dtype == jnp.float32
+    # ... and in its own shape, heads apart, whatever the pool's rows are
+    assert k_slab["q"].shape == (2, 2, 4, cfg.n_head, cfg.head_dim)
+    assert k_slab["scale"].shape == (2, 2, 4, cfg.n_head)
     dst = jnp.asarray([5, 6], jnp.int32)
     kp = import_page_slab(kp, k_slab, dst)
     vp = import_page_slab(vp, v_slab, dst)
@@ -222,7 +379,8 @@ def test_int8_export_import_roundtrip_preserves_q_and_scale():
         )
 
 
-def test_int8_gather_over_mixed_transferred_and_local_pages():
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_int8_gather_over_mixed_transferred_and_local_pages(heads):
     """A page table mixing transferred-in pages (5, 6) with a locally
     written one (3) dequantizes to exactly what the all-local table
     (1, 2, 3) does — transferred pages are first-class pool citizens."""
@@ -231,8 +389,7 @@ def test_int8_gather_over_mixed_transferred_and_local_pages():
         import_page_slab,
     )
 
-    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=32, n_layer=2,
-                            n_head=2)
+    cfg = _config(heads, n_layer=2)
     kp, vp = _int8_pool(cfg)
     phys = np.zeros((4,), np.int32)
     phys[:2] = [1, 2]
@@ -242,23 +399,26 @@ def test_int8_gather_over_mixed_transferred_and_local_pages():
     phys_b[0] = 3
     kp, vp = write_prompt_pages(kp, vp, _fake_cache(cfg, 4, 1), phys_b,
                                 pad=0, page_size=4)
-    k_slab = export_page_slab(kp, jnp.asarray([1, 2], jnp.int32))
-    v_slab = export_page_slab(vp, jnp.asarray([1, 2], jnp.int32))
+    k_slab = export_page_slab(kp, jnp.asarray([1, 2], jnp.int32),
+                              cfg.head_dim)
+    v_slab = export_page_slab(vp, jnp.asarray([1, 2], jnp.int32),
+                              cfg.head_dim)
     kp = import_page_slab(kp, k_slab, jnp.asarray([5, 6], jnp.int32))
     vp = import_page_slab(vp, v_slab, jnp.asarray([5, 6], jnp.int32))
     mixed = jnp.asarray([[5, 6, 3]], jnp.int32)
     local = jnp.asarray([[1, 2, 3]], jnp.int32)
     np.testing.assert_array_equal(
-        np.asarray(gather_pages(kp, mixed)),
-        np.asarray(gather_pages(kp, local)),
+        np.asarray(gather_pages(kp, mixed, cfg.head_dim)),
+        np.asarray(gather_pages(kp, local, cfg.head_dim)),
     )
     np.testing.assert_array_equal(
-        np.asarray(gather_pages(vp, mixed)),
-        np.asarray(gather_pages(vp, local)),
+        np.asarray(gather_pages(vp, mixed, cfg.head_dim)),
+        np.asarray(gather_pages(vp, local, cfg.head_dim)),
     )
 
 
-def test_int8_copy_page_of_transferred_page_carries_scale_plane():
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_int8_copy_page_of_transferred_page_carries_scale_plane(heads):
     """COW duplication of a transferred-in page copies its scale plane
     WITH the values — a reader of the copy dequantizes byte-identically
     to a reader of the source."""
@@ -268,15 +428,16 @@ def test_int8_copy_page_of_transferred_page_carries_scale_plane():
         import_page_slab,
     )
 
-    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=32, n_layer=2,
-                            n_head=2)
+    cfg = _config(heads, n_layer=2)
     kp, vp = _int8_pool(cfg)
     phys = np.zeros((4,), np.int32)
     phys[0] = 1
     kp, vp = write_prompt_pages(kp, vp, _fake_cache(cfg, 4, 2), phys,
                                 pad=0, page_size=4)
-    k_slab = export_page_slab(kp, jnp.asarray([1], jnp.int32))
-    v_slab = export_page_slab(vp, jnp.asarray([1], jnp.int32))
+    k_slab = export_page_slab(kp, jnp.asarray([1], jnp.int32),
+                              cfg.head_dim)
+    v_slab = export_page_slab(vp, jnp.asarray([1], jnp.int32),
+                              cfg.head_dim)
     kp = import_page_slab(kp, k_slab, jnp.asarray([5], jnp.int32))
     vp = import_page_slab(vp, v_slab, jnp.asarray([5], jnp.int32))
     kp, vp = copy_page(kp, vp, jnp.asarray(5, jnp.int32),
@@ -287,6 +448,6 @@ def test_int8_copy_page_of_transferred_page_carries_scale_plane():
         np.testing.assert_array_equal(np.asarray(bank["scale"][:, 7]),
                                       np.asarray(bank["scale"][:, 5]))
     np.testing.assert_array_equal(
-        np.asarray(gather_pages(kp, jnp.asarray([[7]], jnp.int32))),
-        np.asarray(gather_pages(kp, jnp.asarray([[1]], jnp.int32))),
+        np.asarray(gather_pages(kp, jnp.asarray([[7]], jnp.int32), cfg.head_dim)),
+        np.asarray(gather_pages(kp, jnp.asarray([[1]], jnp.int32), cfg.head_dim)),
     )

@@ -25,6 +25,9 @@ from pipegoose_tpu.serving import kv_pool as kvp
 from pipegoose_tpu.telemetry.doctor import DoctorReport, assert_no_resharding
 
 KV_MODES = {"fp": None, "int8": "int8"}
+# n_head x head_dim: the pool's rows narrower than, equal to and wider
+# than 128 lanes (tests/serving/test_kv_pool.py)
+HEADS = {"4x16": (4, 16), "2x64": (2, 64), "2x128": (2, 128)}
 
 
 @pytest.fixture(scope="module")
@@ -127,12 +130,16 @@ def test_tp2_greedy_parity_and_zero_resharding(setup, devices, mode):
 # --- page-table edge cases through the kernel (kv_pool level) ---------------
 
 
-@pytest.fixture(scope="module")
-def pool_state(setup):
-    """A prefilled 3-row pool per kv mode: full row, mid-page partial
-    row (partial LAST page), near-empty row."""
-    cfg, params, _, _ = setup
-    out = {}
+@pytest.fixture(scope="module", params=sorted(HEADS))
+def pool_state(request):
+    """(cfg, params) at one head shape and a prefilled 3-row pool per
+    kv mode: full row, mid-page partial row (partial LAST page),
+    near-empty row."""
+    nh, hd = HEADS[request.param]
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=nh * hd, n_layer=2,
+                            n_head=nh)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    out = {"model": (cfg, params)}
     for mode, kv in KV_MODES.items():
         rng = np.random.RandomState(3)
         kp, vp = kvp.init_pages(cfg, 32, 4, kv_dtype=kv)
@@ -152,11 +159,11 @@ def _leaves(pages):
 
 
 @pytest.mark.parametrize("mode", sorted(KV_MODES))
-def test_partial_last_page_decode_parity(setup, pool_state, mode):
+def test_partial_last_page_decode_parity(pool_state, mode):
     """Rows whose cursor sits mid-page: the kernel masks the unwritten
     offsets of the last page exactly like the gather bias does —
     logits allclose, greedy token identical."""
-    cfg, params, _, _ = setup
+    cfg, params = pool_state["model"]
     kp, vp, table, seq = pool_state[mode]
     tok = jnp.asarray([5, 9, 11], jnp.int32)
     ref, rk, rv = kvp.paged_decode_step(params, tok, kp, vp, table, seq, cfg)
@@ -171,12 +178,12 @@ def test_partial_last_page_decode_parity(setup, pool_state, mode):
 
 
 @pytest.mark.parametrize("mode", sorted(KV_MODES))
-def test_write_ok_null_page_routing_parity(setup, pool_state, mode):
+def test_write_ok_null_page_routing_parity(pool_state, mode):
     """Draft-mode rows with write_ok=False route their writes to the
     NULL page; the kernel's mask never reads them back. Parity on
     logits AND the resulting pools (the PR 6 contract, now through the
     kernel)."""
-    cfg, params, _, _ = setup
+    cfg, params = pool_state["model"]
     kp, vp, table, seq = pool_state[mode]
     tok = jnp.asarray([5, 9, 11], jnp.int32)
     ok = jnp.asarray([True, False, True])
@@ -192,20 +199,20 @@ def test_write_ok_null_page_routing_parity(setup, pool_state, mode):
 
 
 @pytest.mark.parametrize("mode", sorted(KV_MODES))
-def test_mixed_imported_and_local_pages_parity(setup, pool_state, mode):
+def test_mixed_imported_and_local_pages_parity(pool_state, mode):
     """A PR 12-shaped table: pages transferred in from another pool
     (slab export/import at DIFFERENT physical indices) mixed with pages
     the local pool then writes — decode + a follow-up chunk through the
     kernel match the gather reference token-for-token."""
-    cfg, params, _, _ = setup
+    cfg, params = pool_state["model"]
     kp, vp, table, seq = pool_state[mode]
     src_ids = table[1, :2]               # row 1's first two pages
     dst_ids = jnp.asarray([29, 30], jnp.int32)
     fresh_k, fresh_v = kvp.init_pages(cfg, 32, 4, kv_dtype=KV_MODES[mode])
     fresh_k = kvp.import_page_slab(
-        fresh_k, kvp.export_page_slab(kp, src_ids), dst_ids)
+        fresh_k, kvp.export_page_slab(kp, src_ids, cfg.head_dim), dst_ids)
     fresh_v = kvp.import_page_slab(
-        fresh_v, kvp.export_page_slab(vp, src_ids), dst_ids)
+        fresh_v, kvp.export_page_slab(vp, src_ids, cfg.head_dim), dst_ids)
     # imported pages at new physical slots + a locally-written third
     # page, in one row's table
     mixed = jnp.zeros((1, 8), jnp.int32).at[0, 0].set(29).at[0, 1].set(30)

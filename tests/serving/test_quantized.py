@@ -17,9 +17,19 @@ from pipegoose_tpu.distributed import ParallelContext
 from pipegoose_tpu.models import bloom, generate as gen
 from pipegoose_tpu.quant import QuantSpec, quantize_params
 from pipegoose_tpu.serving import Request, ServingEngine, Status
-from pipegoose_tpu.serving.kv_pool import dequantize_kv, quantize_kv
+from pipegoose_tpu.serving.kv_pool import (
+    dequantize_kv,
+    gather_pages,
+    init_pages,
+    quantize_kv,
+    write_prompt_pages,
+)
 from pipegoose_tpu.telemetry import MetricsRegistry
 from pipegoose_tpu.telemetry.doctor import assert_no_resharding
+
+# n_head x head_dim: the pool's rows narrower than, equal to and wider
+# than 128 lanes (tests/serving/test_kv_pool.py)
+HEADS = {"4x16": (4, 16), "2x64": (2, 64), "2x128": (2, 128)}
 
 QUANT_MODES = {
     "int8w": dict(weight_dtype="int8"),
@@ -116,6 +126,36 @@ def test_kv_quantize_round_trip_bound():
     assert bool(jnp.all(qz == 0)) and bool(jnp.all(sz > 0))
     np.testing.assert_array_equal(np.asarray(dequantize_kv(qz, sz)),
                                   np.zeros((2, 3, 8), np.float32))
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_int8_bank_round_trip_through_the_pool(heads):
+    """The int8 bank keeps its values in the pool's lane-dense rows and
+    its scales one per (position, head): a prompt written and gathered
+    back is exactly dequantize(quantize(cache)), within scale/2 of the
+    cache, and the capacity meter reads hd*4/(hd+4) off the live bank."""
+    nh, hd = HEADS[heads]
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=nh * hd, n_layer=2,
+                            n_head=nh)
+    kp, vp = init_pages(cfg, 8, 4, kv_dtype="int8")
+    assert kp["q"].shape == (2, 8, 4, nh * hd) and kp["q"].dtype == jnp.int8
+    assert kp["scale"].shape == (2, 8, 4, nh)
+    rng = np.random.RandomState(0)
+    cache = {n: jnp.asarray(rng.randn(2, 1, 10, nh, hd), jnp.float32)
+             for n in ("k", "v")}
+    phys = jnp.asarray([5, 2, 7, 0], jnp.int32)
+    kp, vp = write_prompt_pages(kp, vp, cache, phys, 0, 4)
+    for bank, name in ((kp, "k"), (vp, "v")):
+        got = np.asarray(gather_pages(bank, phys[None], hd))[:, 0, :10]
+        q, s = quantize_kv(cache[name][:, 0])
+        np.testing.assert_array_equal(got, np.asarray(dequantize_kv(q, s)))
+        err = np.abs(got - np.asarray(cache[name][:, 0]))
+        assert (err <= 0.5 * np.asarray(s)[..., None] + 1e-7).all()
+    eng = ServingEngine(bloom.init_params(cfg, jax.random.PRNGKey(0)), cfg,
+                        num_slots=1, num_pages=8, page_size=4,
+                        max_context=16, kv_dtype="int8")
+    assert eng.memory_report()["kv"]["page_capacity_ratio"] == \
+        pytest.approx(hd * 4 / (hd + 4), abs=1e-3)
 
 
 # --- greedy parity: single device, the full mode matrix ---------------------

@@ -68,6 +68,17 @@ def test_attribute_op_times_buckets_and_joins_schedule():
     assert att["top_ops"][0]["name"] == "dot.1"
 
 
+@pytest.mark.parametrize("container", ["while.4", "conditional.2", "call"])
+def test_attribute_op_times_counts_a_loop_by_its_body(container):
+    """A while (conditional, call) event spans its body's ops, which
+    are events of their own: counting both doubled the compute time of
+    every program with a layer loop."""
+    events = [_ev(container, 90.0), _ev("dot.1", 50.0), _ev("fusion.3", 30.0)]
+    att = attribute_op_times(events, steps=1, n_devices=1)
+    assert att["compute_s"] == pytest.approx(80e-6)
+    assert container not in att["per_op"]
+
+
 def test_op_events_module_filter_and_name_fallback():
     """Primary selection is args.hlo_module == module; traces whose op
     events carry no args fall back to the compiled module's
