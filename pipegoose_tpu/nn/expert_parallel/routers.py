@@ -7,13 +7,23 @@ routers.py:18-34), softmax, top-k selection, Switch aux load-balancing
 loss (:73-89), ST-MoE router z-loss (:91-97), and expert-capacity
 truncation (:133-143).
 
-The decisive difference is the OUTPUT: the reference returns a dynamic
-dispatching order consumed by index_select loops (experts.py:99-102),
-which cannot be jit-compiled. Here the router emits dense one-hot
-dispatch/combine tensors with STATIC (tokens, experts, capacity) shapes
-— the Mesh-TensorFlow/GShard formulation — so the whole MoE layer
-compiles onto the MXU and the dispatch becomes two einsums around an
-``all_to_all``.
+Two output forms, both with static shapes (the reference returns a
+dynamic dispatching order consumed by index_select loops,
+experts.py:99-102, which cannot be jit-compiled):
+
+- ``SigmoidTopKRouter`` returns ``(T, k)`` expert ids and combine
+  weights (``TopKRouting``) and nothing else: no capacity, no dropped
+  token. ``experts.grouped_experts`` sorts the picks by expert and runs
+  a grouped matrix product over the groups, so memory and work grow
+  with ``T * k`` rows. This is the form the expert models of the
+  benchmark run (models/glm4_moe_lite.py).
+- ``TopKRouter`` returns dense one-hot dispatch/combine tensors of
+  shape ``(T, E, C)`` (``RouterOutput``; the Mesh-TensorFlow/GShard
+  formulation), consumed by two einsums around an ``all_to_all`` in
+  ``experts.moe_layer``. With no drops ``C`` is ``T`` and that tensor
+  is quadratic in the tokens: right for the tiny sizes of tests and the
+  convergence demos (bloom_moe, mixtral), and the reference the grouped
+  form is tested against.
 
 Losses are returned functionally in ``RouterOutput`` (no process-global
 ExpertContext singleton, expert_context.py:7-32).
@@ -32,6 +42,46 @@ class RouterOutput(NamedTuple):
     combine: jax.Array  # (T, E, C) gate-weighted dispatch
     aux_loss: jax.Array  # scalar, Switch load-balancing loss
     z_loss: jax.Array  # scalar, ST-MoE router z-loss
+
+
+class TopKRouting(NamedTuple):
+    experts: jax.Array  # (T, k) int32 ids over ALL experts, best first
+    weights: jax.Array  # (T, k) float32 combine weights
+    scores: jax.Array  # (T, E) float32 router scores (counters, tests)
+
+
+@dataclasses.dataclass(frozen=True)
+class SigmoidTopKRouter:
+    """Sigmoid scores, selection on score + bias, weights from the
+    scores alone (DeepSeek-V3's ``noaux_tc`` with one group; HF
+    ``glm4_moe_lite``/``deepseek_v3`` gate): ``s = sigmoid(x W_g)`` in
+    float32; the ``top_k`` experts are the largest of ``s + b``; their
+    weights are ``s[chosen]``, renormalised over the chosen
+    (``normalize``), times ``scaling``. The bias ``b`` moves the
+    SELECTION only: it gets no gradient (its update is a training
+    recipe's, outside the loss) and the weights never see it. No
+    capacity and no drop: every token keeps its ``top_k`` picks."""
+
+    num_experts: int
+    top_k: int
+    scaling: float = 1.0
+    normalize: bool = True
+
+    def __call__(self, params: dict, x: jax.Array) -> TopKRouting:
+        """``params``: ``{"gate": {"kernel": (H, E)}, "bias": (E,)}``;
+        ``x``: (T, H) flat tokens."""
+        scores = jax.nn.sigmoid(jnp.dot(
+            x, params["gate"]["kernel"], preferred_element_type=jnp.float32
+        ))
+        choice = scores + jax.lax.stop_gradient(
+            params["bias"].astype(jnp.float32)
+        )
+        _, experts = jax.lax.top_k(choice, self.top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if self.normalize:
+            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        return TopKRouting(experts.astype(jnp.int32),
+                           weights * self.scaling, scores)
 
 
 @dataclasses.dataclass(frozen=True)

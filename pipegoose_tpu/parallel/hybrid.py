@@ -73,6 +73,27 @@ def sync_replicated_grads(grads: Any, param_specs: Any, axes: tuple) -> Any:
     )
 
 
+def trainable(tree: Any, frozen: Any) -> Any:
+    """``tree`` less the leaves ``frozen`` marks True (a pytree of bools
+    shaped like the params; None = nothing frozen). A frozen leaf becomes
+    ``None``, which jax takes for an empty subtree: gradients, the
+    optimizer and its state never see it. ``tree`` may hold
+    PartitionSpecs at the leaves."""
+    if frozen is None:
+        return tree
+    return jax.tree_util.tree_map(lambda f, x: None if f else x, frozen, tree)
+
+
+def with_frozen(frozen: Any, params: Any, new_trainable: Any) -> Any:
+    """The params tree again: updated leaves from ``new_trainable``, the
+    frozen ones as ``params`` has them."""
+    if frozen is None:
+        return new_trainable
+    return jax.tree_util.tree_map(
+        lambda f, p, n: p if f else n, frozen, params, new_trainable
+    )
+
+
 def zero_state_spec(
     optimizer: DistributedOptimizer, params: Any, param_specs: Any, mesh
 ) -> ZeroState:
@@ -95,6 +116,7 @@ def train_step_intended_specs(
     mesh,
     batch_spec: P = P("data"),
     with_rng: bool = False,
+    frozen: Any = None,
 ) -> tuple:
     """The INTENDED PartitionSpec tuple for a hybrid train step's
     ``(params, opt_state, batch[, rng])`` arguments — what the mesh
@@ -104,7 +126,9 @@ def train_step_intended_specs(
     as a compile-time diff instead of a slow step."""
     specs = (
         param_specs,
-        zero_state_spec(optimizer, params, param_specs, mesh),
+        # the optimizer's state covers the trainable leaves alone
+        zero_state_spec(optimizer, trainable(params, frozen),
+                        trainable(param_specs, frozen), mesh),
         batch_spec,
     )
     return specs + ((P(),) if with_rng else ())
@@ -151,6 +175,8 @@ def hybrid_build_config(
     with_health: bool = False,
     grad_comm: Optional[str] = None,
     overlap_tp: bool = False,
+    has_aux: bool = False,
+    frozen: Any = None,
 ) -> dict:
     """Capture everything :func:`make_hybrid_train_step` needs EXCEPT
     the ``ParallelContext`` — the step-rebuild hook. The trainer stores
@@ -171,6 +197,8 @@ def hybrid_build_config(
         with_health=with_health,
         grad_comm=grad_comm,
         overlap_tp=overlap_tp,
+        has_aux=has_aux,
+        frozen=frozen,
     )
 
 
@@ -232,6 +260,8 @@ def make_hybrid_train_step(
     with_health: bool = False,
     grad_comm: Optional[str] = None,
     overlap_tp: bool = False,
+    has_aux: bool = False,
+    frozen: Any = None,
 ):
     """Build (init_fn, step_fn), both jitted over the context's mesh.
 
@@ -278,6 +308,18 @@ def make_hybrid_train_step(
     analog of ``grad_sync_axes=((ax, "mean"), ...)`` — combining both
     for the same axis raises). Docs: docs/comm.md.
 
+    ``has_aux=True``: ``loss_fn`` returns ``(loss, aux)`` and step_fn
+    returns ``aux`` last: a small pytree of counters (rows per expert,
+    loss terms apart, ...), averaged over the loss axes like the loss
+    and replicated. Resolved at build time like ``with_health``: off,
+    the step lowers to the byte-identical program
+    (tests/trainer/test_step_aux.py).
+
+    ``frozen``: a pytree of bools shaped like the params; a True leaf
+    takes no gradient and no optimizer state and comes back from the
+    step as it went in (a router's selection bias, whose update rule is
+    no gradient's). None: every leaf trains.
+
     ``overlap_tp``: declare that ``loss_fn`` runs the ring
     collective-matmul path (``config.overlap_tp`` on the model) — the
     flag only drives telemetry (``comm.overlap_enabled``) and the
@@ -301,23 +343,31 @@ def make_hybrid_train_step(
     plain_dp_comm = comm_mode != "fp32" and optimizer.axis_name is None
 
     if n_accum > 1:
+        if has_aux:
+            raise ValueError("has_aux with n_accum > 1: the accumulating "
+                             "loss carries one scalar")
         from pipegoose_tpu.core.accumulation import make_accumulating_loss
 
         loss_fn = make_accumulating_loss(loss_fn, n_accum)
 
+    # what the gradient and the optimizer see (all of it unless frozen)
+    train_specs = trainable(param_specs, frozen)
+
     def _state_spec_for(params):
-        return zero_state_spec(optimizer, params, param_specs, mesh)
+        return zero_state_spec(
+            optimizer, trainable(params, frozen), train_specs, mesh
+        )
 
     def init_fn(params):
         spec = _state_spec_for(params)
         f = shard_map(
             optimizer.init,
             mesh=mesh,
-            in_specs=(param_specs,),
+            in_specs=(train_specs,),
             out_specs=spec,
             check_vma=False,
         )
-        return jax.jit(f)(params)
+        return jax.jit(f)(trainable(params, frozen))
 
     loss_axes = loss_axis if isinstance(loss_axis, tuple) else (loss_axis,)
     if plain_dp_comm:
@@ -341,10 +391,24 @@ def make_hybrid_train_step(
         synced = {e[0] if isinstance(e, tuple) else e for e in grad_sync_axes}
         health_mean_axes = tuple(a for a in loss_axes if a not in synced)
 
-    def _step(params, opt_state, batch, *rng):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch, *rng)
+    def _loss_and_grads(params, batch, *rng):
+        """(loss, aux or None, grads over the trainable leaves)."""
+        if frozen is None:
+            out, grads = jax.value_and_grad(loss_fn, has_aux=has_aux)(
+                params, batch, *rng
+            )
+        else:
+            out, grads = jax.value_and_grad(
+                lambda t: loss_fn(with_frozen(frozen, params, t), batch, *rng),
+                has_aux=has_aux,
+            )(trainable(params, frozen))
+        return (*out, grads) if has_aux else (out, None, grads)
+
+    def _step(all_params, opt_state, batch, *rng):
+        loss, aux, grads = _loss_and_grads(all_params, batch, *rng)
+        params = trainable(all_params, frozen)
         if grad_sync_axes:
-            grads = sync_replicated_grads(grads, param_specs, grad_sync_axes)
+            grads = sync_replicated_grads(grads, train_specs, grad_sync_axes)
         if plain_dp_comm:
             from pipegoose_tpu.distributed.compressed import (
                 compressed_all_reduce_mean,
@@ -361,27 +425,32 @@ def make_hybrid_train_step(
                 return g
 
             grads = jax.tree_util.tree_map(
-                comp_sync, grads, param_specs,
+                comp_sync, grads, train_specs,
                 is_leaf=lambda x: isinstance(x, P),
             )
         new_params, new_state = optimizer.step(grads, opt_state, params)
         for ax in loss_axes:
             loss = lax.pmean(loss, ax)
-        if not with_health:
-            return new_params, new_state, loss
-        health = health_stats(
-            grads, params, new_params, param_specs,
-            axes=tuple(mesh.axis_names), mean_axes=health_mean_axes,
-        )
-        return new_params, new_state, loss, health
+        out = (with_frozen(frozen, all_params, new_params), new_state, loss)
+        if with_health:
+            out += (health_stats(
+                grads, params, new_params, train_specs,
+                axes=tuple(mesh.axis_names), mean_axes=health_mean_axes,
+            ),)
+        if has_aux:
+            for ax in loss_axes:
+                aux = lax.pmean(aux, ax)
+            out += (aux,)
+        return out
 
     def make_step(params):
         _set_comm_gauges(params, mesh, optimizer, comm_mode, overlap_tp,
                          loss_axes[0])
         spec = _state_spec_for(params)
         in_specs = (param_specs, spec, batch_spec) + ((P(),) if with_rng else ())
-        # the health tree is all replicated scalars: one P() prefix spec
-        out_specs = (param_specs, spec, P()) + ((P(),) if with_health else ())
+        # the health tree is all replicated scalars, the counters are
+        # replicated too: one P() prefix spec each
+        out_specs = (param_specs, spec, P()) + (P(),) * (with_health + has_aux)
         f = shard_map(
             _step,
             mesh=mesh,

@@ -47,7 +47,7 @@ mirroring the taxonomy onto training fit loops.
 See docs/observability.md for the metric catalog and the MFU
 methodology.
 """
-from pipegoose_tpu.telemetry.callback import TelemetryCallback
+from pipegoose_tpu.telemetry.callback import AuxRecorder, TelemetryCallback
 from pipegoose_tpu.telemetry.chrometrace import (
     ChromeTraceExporter,
     goodput_trace_events,
@@ -173,6 +173,7 @@ __all__ = [
     "TailSampler",
     "ShardingRegressionError",
     "ShardingReport",
+    "AuxRecorder",
     "TelemetryCallback",
     "TrainerGoodput",
     "TriggerEvent",

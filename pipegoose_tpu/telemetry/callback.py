@@ -166,3 +166,65 @@ class TelemetryCallback(Callback):
             "comm_bytes": stats["comm_bytes"],
             "comm_by_op": stats["comm_by_op"],
         })
+
+
+class AuxRecorder(Callback):
+    """Keeps what the step's counter channel returned (``Trainer(
+    has_aux=True)``: ``state.last_aux``), one device pytree a step, and
+    fetches a step's counters only ``LAG`` steps later, when its device
+    work has long retired: a fetch of the newest would stall the
+    dispatch queue. ``take()`` hands over every step recorded so far as
+    host values (and blocks for the newest).
+
+    With the registry enabled, each fetched step also lands there by
+    name: ``names`` maps a counter's key to ``("gauge" | "histogram",
+    metric name)`` (e.g. ``glm4_moe_lite.COUNTER_METRICS``); a histogram
+    observes every element of an array (rows per expert), a gauge takes
+    a scalar."""
+
+    order = 6
+    LAG = 2        # steps behind the newest that a fetch stays
+    KEEP = 4096    # fetched steps kept until ``take()``
+
+    def __init__(self, registry: Optional[MetricsRegistry] = None,
+                 names: Optional[dict] = None):
+        self.registry = registry if registry is not None else get_registry()
+        self.names = dict(names or {})
+        self._pending: list = []    # device pytrees, oldest first
+        self._host: list = []       # fetched, oldest first
+
+    def on_step_end(self, trainer: Any, step: int, loss: Any) -> None:
+        aux = trainer.state.last_aux
+        if aux is None:
+            return
+        self._pending.append(aux)
+        while len(self._pending) > self.LAG:
+            self._fetch_oldest()
+
+    def _fetch_oldest(self) -> None:
+        import numpy as np
+
+        host = jax.tree_util.tree_map(np.asarray, self._pending.pop(0))
+        self._host.append(host)
+        del self._host[:-self.KEEP]
+        if not self.registry.enabled or not isinstance(host, dict):
+            return
+        for key, (kind, name) in self.names.items():
+            if key not in host:
+                continue
+            if kind == "histogram":
+                # counts, not seconds: powers of two up to 2**20
+                h = self.registry.histogram(
+                    name, buckets=[float(2 ** i) for i in range(21)])
+                for v in np.ravel(host[key]):
+                    h.observe(float(v))
+            else:
+                self.registry.gauge(name).set(float(host[key]))
+
+    def take(self) -> list:
+        """Every recorded step's counters as host values, oldest first;
+        the recorder starts empty again."""
+        while self._pending:
+            self._fetch_oldest()
+        out, self._host = self._host, []
+        return out

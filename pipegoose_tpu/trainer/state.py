@@ -68,3 +68,6 @@ class TrainerState:
     # most recent in-graph health pytree (device scalars) when the
     # trainer runs with with_health=True; None otherwise
     last_health: Optional[Any] = None
+    # most recent counters pytree (device arrays) the loss_fn returned
+    # beside the loss when the trainer runs with has_aux=True
+    last_aux: Optional[Any] = None

@@ -35,6 +35,8 @@ class Trainer:
         with_rng: bool = False,
         n_accum: int = 1,
         with_health: bool = False,
+        has_aux: bool = False,
+        frozen: Any = None,
         callbacks: Sequence[Callback] = (),
         logger: Optional[DistributedLogger] = None,
         resume_dir: Optional[str] = None,
@@ -48,6 +50,13 @@ class Trainer:
         # health pytree (telemetry/health.py), kept on-device in
         # state.last_health for callbacks (FlightRecorder) to consume
         self.with_health = with_health
+        # has_aux: loss_fn returns (loss, counters) and the compiled
+        # step hands the counters back (parallel/hybrid.py), kept
+        # on-device in state.last_aux for callbacks (AuxRecorder)
+        self.has_aux = has_aux
+        # frozen: pytree of bools, True = a leaf with no gradient and no
+        # optimizer state (e.g. glm4_moe_lite.frozen_leaves)
+        self.frozen = frozen
         self.tokens_per_step = 0  # updated from batch shapes each step
         # TelemetryCallback's cost-probe input: valid only DURING the
         # step-end callback round, cleared right after so the trainer
@@ -76,6 +85,8 @@ class Trainer:
             with_rng=with_rng,
             n_accum=n_accum,
             with_health=with_health,
+            has_aux=has_aux,
+            frozen=frozen,
         )
         init_fn, make_step = build_hybrid_train_step(
             self._hybrid_config, self.parallel_context
@@ -151,7 +162,7 @@ class Trainer:
         return step
 
     def _restore(self, directory: str, step: int, opt_state_like) -> None:
-        from pipegoose_tpu.parallel.hybrid import zero_state_spec
+        from pipegoose_tpu.parallel.hybrid import trainable, zero_state_spec
         from pipegoose_tpu.utils.checkpoint import restore_train_state
 
         like = {"params": self.params, "opt_state": opt_state_like}
@@ -161,7 +172,8 @@ class Trainer:
         specs = {
             "params": self.param_specs,
             "opt_state": zero_state_spec(
-                self.optimizer, self.params, self.param_specs,
+                self.optimizer, trainable(self.params, self.frozen),
+                trainable(self.param_specs, self.frozen),
                 self.parallel_context.mesh,
             ),
         }
@@ -223,6 +235,8 @@ class Trainer:
 
             def eval_step(params, batch, *rng):
                 loss = self._loss_fn(params, batch, *rng)
+                if self.has_aux:
+                    loss = loss[0]
                 axes = (
                     self._loss_axis
                     if isinstance(self._loss_axis, tuple)
@@ -281,7 +295,7 @@ class Trainer:
         intended = train_step_intended_specs(
             self.optimizer, self.params, self.param_specs,
             self.parallel_context.mesh, batch_spec=self._batch_spec,
-            with_rng=self.with_rng,
+            with_rng=self.with_rng, frozen=self.frozen,
         )
         if self.with_rng:
             args = args + (jax.random.PRNGKey(0),)
@@ -325,7 +339,7 @@ class Trainer:
         final: dict = {}
 
         def update(out, cur):
-            # out = (params, opt_state, loss[, health]); batch and rng
+            # out = (params, opt_state, loss[, health][, aux]); batch and rng
             # (when present) repeat — profiling measures the step, not
             # the data pipeline
             final["params"], final["opt_state"] = out[0], out[1]
@@ -427,15 +441,15 @@ class Trainer:
                 # backpressures to device step time. TelemetryCallback
                 # (fence=True) gives exact per-step device attribution.
                 with span("train.step"):
+                    self.params, self.opt_state, loss, *extra = (
+                        self._step_fn(*args)
+                    )
+                    # device pytrees, same async-dispatch rule as the
+                    # loss: consumers fetch when they actually look
                     if self.with_health:
-                        self.params, self.opt_state, loss, health = (
-                            self._step_fn(*args)
-                        )
-                        # device pytree, same async-dispatch rule as the
-                        # loss: consumers fetch when they actually look
-                        self.state.last_health = health
-                    else:
-                        self.params, self.opt_state, loss = self._step_fn(*args)
+                        self.state.last_health = extra[0]
+                    if self.has_aux:
+                        self.state.last_aux = extra[-1]
                 # keep loss as a device array: float() here would block the
                 # host every step and kill JAX's async dispatch; callbacks
                 # convert only when they actually log
