@@ -16,14 +16,31 @@ Kernel structure (canonical TPU flash attention):
   dq:  grid (bh, nq, nk), kv sequential, accumulates dS @ K;
   dkv: grid (bh, nk, nq), q sequential, accumulates dS^T @ Q and P^T @ dO;
   with delta = rowsum(dO * O) computed in plain XLA.
+- blocks: ``_pick_blocks(seq, head width, itemsize, kind, vmem limit)``
+  gives each kernel the largest (block_q, block_k) whose working set
+  fits three quarters of the scoped VMEM it asks for, half the device's
+  — 1024 x 1024 at the trained shapes on a v5e: a grid step costs
+  ~0.35 us whatever it computes, and a 128 x 512 step held 85 ns of
+  matrix work;
+- matrix-unit operands stay in the dtype they arrive in (bf16 in the
+  models) with float32 accumulation; ``p`` and ``ds`` are rounded to
+  that dtype before their products, as the models' plain paths round
+  the softmax. Statistics, ``exp``, lse, delta and the accumulators are
+  float32;
 - per-head ALiBi slope arrives via scalar prefetch (SMEM);
 - padding masks are supported via two per-key arrays: ``kv_pos`` (the
   mask-aware ALiBi position, matching BLOOM's (cumsum(mask)-1)*mask)
   and ``kv_neg`` (0 for valid keys, NEG_INF for padded ones). The
   finite NEG_INF keeps fully-masked rows NaN-free (uniform garbage
   probs; those rows are masked out of the loss downstream).
-- blocks fully above the causal diagonal are skipped with pl.when —
-  ~2x fewer FLOPs for causal attention.
+- blocks fully above the causal diagonal (or below the sliding window)
+  are skipped with pl.when — ~2x fewer FLOPs for causal attention — and
+  fetch nothing: their index maps are clamped to the last block the
+  rule keeps, which is already in VMEM.
+
+The three ``flash_ring_*`` kernels below were copied from these before
+PR 30 and keep the old step (128 x 512 blocks, float32 operands, every
+block fetched): no benchmark cell runs them (ROADMAP B6c).
 
 Reference framework has no kernels at all (its README advertises "fused
 kernels"; grep finds none — SURVEY.md, "Scale/completeness caveat").
@@ -41,19 +58,94 @@ NEG_INF = -1e9
 
 def _pick_block(n: int, target: int = 128) -> int:
     """Largest power-of-two block <= target dividing n (sequence lengths
-    here are powers of two in practice; tiny/odd n fall back to n).
-
-    Defaults tuned on a v5e (scripts/sweep_tpu_perf.py, S=2048 bf16):
-    kv blocks of 512 run the fwd kernel 2.5x faster than 128 (fewer
-    grid steps per (bh, q) program, better MXU occupancy); 1024 did not
-    compile then and has not been re-tried. Query blocks stay at 128
-    (the parallel dim)."""
+    here are powers of two in practice; tiny/odd n fall back to n)."""
     b = target
     while b >= 8:
         if n % b == 0:
             return b
         b //= 2
     return n
+
+
+# Mosaic's scoped-VMEM limit for a kernel that asks for nothing.
+_DEFAULT_VMEM_LIMIT_BYTES = 16 * 2**20
+# Past 1024 a side no kernel got faster at any of the measured widths
+# (PERF.md, PR 30): the causal skip grows coarser as fast as the grid
+# step is amortised.
+_MAX_BLOCK = 1024
+
+# What one grid step of each kernel holds, in tiles: pipelined (BQ, hd)
+# and (BK, hd) operand/result tiles, float32 (BQ, hd) and (BK, hd)
+# scratch, and the (BQ, BK) score tiles its body keeps alive, float32
+# ones and copies in the operands' dtype for the matrix unit.
+_STEP_TILES = {
+    # q o | k v | acc m l | - | s p select | p
+    "fwd": (2, 2, 3, 0, 3, 1),
+    # q dO dQ | k v | dQ | - | p dp ds select | ds
+    "dq": (3, 2, 1, 0, 4, 1),
+    # q dO | k v dK dV | - | dK dV | p dp ds select | p ds, each transposed
+    "dkv": (2, 4, 0, 2, 4, 4),
+}
+
+
+def _vmem_limit_bytes() -> int:
+    """Scoped VMEM the three non-ring kernels ask of the compiler: half
+    of what the device's core has (64 MiB of a v5e's 128), and the
+    compiler's default where that is no more or the device is not a TPU
+    Pallas knows (interpret mode, a lowering with no device)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    try:
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:
+        return _DEFAULT_VMEM_LIMIT_BYTES
+    return max(capacity // 2, _DEFAULT_VMEM_LIMIT_BYTES)
+
+
+def _working_set_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
+                       itemsize: int) -> int:
+    """VMEM one grid step of kernel ``kind`` ("fwd", "dq", "dkv") holds,
+    by its own arithmetic: ``_STEP_TILES``' pipelined tiles twice (double
+    buffering), the scratch, the score tiles, and the per-query and
+    per-key float32 rows (two each side, pipelined). An upper bound: a
+    v5e's compiler took each kernel with 1.1-1.5x less at head widths of
+    256 and more, where a budget binds, and with 2-4x less at 64
+    (PERF.md, PR 30); tests/ops/test_chip_compile.py holds it to that."""
+    q_io, k_io, q_f32, k_f32, s_f32, s_narrow = _STEP_TILES[kind]
+    lanes = -(-head_dim // 128) * 128    # a tile's rows pad to 128 lanes
+    tiles = (2 * (q_io * block_q + k_io * block_k) * itemsize
+             + (q_f32 * block_q + k_f32 * block_k) * 4) * lanes
+    rows = 2 * 2 * 8 * (block_q + block_k) * 4    # (1, n): 8 sublanes
+    return tiles + rows + block_q * block_k * (4 * s_f32 + itemsize * s_narrow)
+
+
+def _pick_blocks(seq: int, head_dim: int, itemsize: int, kind: str,
+                 vmem_limit_bytes: int):
+    """``(block_q, block_k)`` for kernel ``kind`` from the operands'
+    shape and the scoped VMEM the kernel will ask for: the largest
+    power-of-two blocks (at most ``_MAX_BLOCK`` a side) that divide
+    ``seq`` and whose working set, by ``_working_set_bytes``, fits three
+    quarters of the limit (the rest is the compiler's: its temporaries,
+    semaphores, alignment); tiny and odd ``seq`` fall back as
+    ``_pick_block`` does.
+
+    A grid step costs ~0.35 us whatever it computes, and what bounds a
+    step's body is per-score work outside the matrix unit, so the step
+    should hold as many scores as VMEM lets it (v5e, PR 30: 1024 x 1024
+    runs the three kernels 2.3-2.7x faster than 128 x 512 at head
+    widths 64, 128 and 256). Where the budget binds the larger side is
+    halved, the query side on a tie; which side is better kept has not
+    been measured (no trained shape binds a v5e's budget)."""
+    block_q = block_k = _pick_block(seq, _MAX_BLOCK)
+    while (_working_set_bytes(kind, block_q, block_k, head_dim, itemsize)
+           > vmem_limit_bytes * 3 // 4):
+        if block_q >= block_k and block_q % 16 == 0:
+            block_q //= 2
+        elif block_k % 16 == 0:
+            block_k //= 2
+        else:
+            break
+    return block_q, block_k
 
 
 def mask_to_kv_bias(attention_mask: jax.Array):
@@ -66,24 +158,76 @@ def mask_to_kv_bias(attention_mask: jax.Array):
     return kv_pos, kv_neg
 
 
-def _bias_block(slope, kpos_ref, kneg_ref, q_start, k_start, block_q, block_k,
-                causal, window=None):
-    """Additive bias for one (BQ, BK) score block: ALiBi + padding +
-    causal (+ optional sliding window: key within ``window`` positions
-    behind the query, Mistral/Mixtral semantics)."""
-    kp = kpos_ref[0, 0].astype(jnp.float32)  # (BK,)
-    kn = kneg_ref[0, 0].astype(jnp.float32)
-    bias = slope * kp[None, :] + kn[None, :]
+def _keep_block(q_start, k_start, block_q, block_k, causal, window):
+    """Whether the causal / sliding-window rule keeps ANY score of the
+    (BQ, BK) block at ``(q_start, k_start)``: blocks fully above the
+    diagonal or fully below the window are skipped. Python ``True`` when
+    there is no rule, a scalar otherwise."""
+    keep = True
+    if causal:
+        keep = k_start <= q_start + block_q - 1
+    if window is not None:
+        keep = keep & (k_start + block_k - 1 >= q_start - window + 1)
+    return keep
+
+
+def _kv_block(i, j, block_q, block_k, causal, window):
+    """The key block that step ``(i, j)`` of a (bh, nq, nk) grid names:
+    ``j`` clamped to the blocks the rule keeps for query block ``i``, so
+    a skipped step re-names the block already in VMEM and the pipeline
+    issues no copy."""
+    if causal:
+        j = jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
+    if window is not None:
+        j = jnp.maximum(j, jnp.maximum(i * block_q - window + 1, 0) // block_k)
+    return j
+
+
+def _kv_index_maps(g, block_q, block_k, causal, window):
+    """Index maps of a (bh, nq, nk) grid for the K/V tiles ``(1, BK,
+    hd)`` and the per-key rows ``(1, 1, BK)``: kv head ``b // g`` (GQA),
+    key block clamped by ``_kv_block``."""
+    def kv_map(b, i, j):
+        return (b // g, _kv_block(i, j, block_q, block_k, causal, window), 0)
+
+    def kv_row_map(b, i, j):
+        return (b // g, 0, _kv_block(i, j, block_q, block_k, causal, window))
+
+    return kv_map, kv_row_map
+
+
+def _q_block(j, i, block_q, block_k, causal, window, nq):
+    """The mirror image for the (bh, nk, nq) grid of dK/dV: query block
+    ``i`` clamped to the blocks the rule keeps for key block ``j``."""
+    if causal:
+        i = jnp.maximum(i, (j * block_k) // block_q)
+    if window is not None:
+        last = (j * block_k + block_k + window - 2) // block_q
+        i = jnp.minimum(i, jnp.minimum(last, nq - 1))
+    return i
+
+
+def _scores(q, k, slope, kpos_ref, kneg_ref, scale, q_start, k_start,
+            causal, window):
+    """One (BQ, BK) block of ``q k^T * scale + bias``, float32: the
+    per-key row (ALiBi + padding) and the causal / window mask."""
+    block_q, block_k = q.shape[0], k.shape[0]
+    s_blk = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    ) * scale
+    bias = (slope * kpos_ref[0].astype(jnp.float32)
+            + kneg_ref[0].astype(jnp.float32))  # (1, BK)
     if causal or window is not None:
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_idx = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        keep = jnp.ones((block_q, block_k), bool)
+        shape = (block_q, block_k)
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        k_idx = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        keep = jnp.ones(shape, bool)
         if causal:
             keep = keep & (k_idx <= q_pos)
         if window is not None:
             keep = keep & (q_pos - k_idx < window)
         bias = jnp.where(keep, bias, NEG_INF)
-    return bias
+    return s_blk + bias
 
 
 def _flash_fwd_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
@@ -112,43 +256,32 @@ def _flash_fwd_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
         q_start = qi * block_q
         k_start = ki * block_k
 
-        # skip blocks fully above the causal diagonal or fully below
-        # the sliding window
-        keep_blk = k_start <= q_start + block_q - 1 if causal else True
-        if window is not None:
-            keep_blk = keep_blk & (k_start + block_k - 1 >= q_start - window + 1)
-
-        @pl.when(keep_blk)
+        # blocks fully above the causal diagonal or fully below the
+        # sliding window are skipped
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
         def _compute():
-            qb = q_ref[0].astype(jnp.float32)  # (BQ, hd)
-            kb = k_ref[0].astype(jnp.float32)  # (BK, hd)
-            vb = v_ref[0].astype(jnp.float32)
-            s_blk = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (BQ, BK)
-            s_blk = s_blk + _bias_block(
-                slope, kpos_ref, kneg_ref,
-                q_start, k_start, block_q, block_k, causal, window,
-            )
-
-            m_prev = m_sc[:, 0]
-            m_new = jnp.maximum(m_prev, s_blk.max(axis=1))
-            p = jnp.exp(s_blk - m_new[:, None])
+            vb = v_ref[0]
+            s_blk = _scores(q_ref[0], k_ref[0], slope, kpos_ref, kneg_ref,
+                            scale, q_start, k_start, causal, window)
+            m_prev = m_sc[:]  # (BQ, 1)
+            m_new = jnp.maximum(m_prev, s_blk.max(axis=1, keepdims=True))
+            p = jnp.exp(s_blk - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            l_sc[:, 0] = l_sc[:, 0] * alpha + p.sum(axis=1)
-            acc_sc[:] = acc_sc[:] * alpha[:, None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
+            l_sc[:] = l_sc[:] * alpha + p.sum(axis=1, keepdims=True)
+            acc_sc[:] = acc_sc[:] * alpha + jax.lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_sc[:, 0] = m_new
+            m_sc[:] = m_new
 
         @pl.when(ki == nk - 1)
         def _finish():
-            l = jnp.maximum(l_sc[:, 0], 1e-30)
-            o_ref[0] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
-            lse_ref[0, 0] = m_sc[:, 0] + jnp.log(l)
+            l = jnp.maximum(l_sc[:], 1e-30)
+            o_ref[0] = (acc_sc[:] / l).astype(o_ref.dtype)
+            lse_ref[0, 0] = (m_sc[:] + jnp.log(l))[:, 0]
 
+    kv_map, kv_row_map = _kv_index_maps(g, block_q, block_k, causal, window)
     grid = (bh, nq, nk)
     out, lse = pl.pallas_call(
         kernel,
@@ -158,10 +291,10 @@ def _flash_fwd_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
             in_specs=[
                 pl.BlockSpec((bh,), lambda b, i, j: (0,), memory_space=pltpu.SMEM),
                 pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // g, j, 0)),
-                pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // g, j, 0)),
-                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // g, 0, j)),
-                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // g, 0, j)),
+                pl.BlockSpec((1, block_k, hd), kv_map),
+                pl.BlockSpec((1, block_k, hd), kv_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
@@ -179,6 +312,7 @@ def _flash_fwd_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
         ),
         interpret=interpret,
         name="flash_fwd",
@@ -207,32 +341,20 @@ def _flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         q_start = qi * block_q
         k_start = ki * block_k
 
-        keep_blk = k_start <= q_start + block_q - 1 if causal else True
-        if window is not None:
-            keep_blk = keep_blk & (k_start + block_k - 1 >= q_start - window + 1)
-
-        @pl.when(keep_blk)
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
         def _compute():
-            qb = q_ref[0].astype(jnp.float32)
-            kb = k_ref[0].astype(jnp.float32)
-            vb = v_ref[0].astype(jnp.float32)
-            dob = do_ref[0].astype(jnp.float32)
-            s_blk = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s_blk = s_blk + _bias_block(
-                slope, kpos_ref, kneg_ref,
-                q_start, k_start, block_q, block_k, causal, window,
-            )
+            kb = k_ref[0]
+            s_blk = _scores(q_ref[0], kb, slope, kpos_ref, kneg_ref,
+                            scale, q_start, k_start, causal, window)
             p = jnp.exp(s_blk - lse_ref[0, 0][:, None])  # (BQ, BK)
             dp = jax.lax.dot_general(
-                dob, vb, (((1,), (1,)), ((), ())),
+                do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # (BQ, BK)
             ds = p * (dp - delta_ref[0, 0][:, None])
             dq_sc[:] += scale * jax.lax.dot_general(
-                ds, kb, (((1,), (0,)), ((), ())),
+                ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
 
@@ -240,6 +362,7 @@ def _flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         def _finish():
             dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
 
+    kv_map, kv_row_map = _kv_index_maps(g, block_q, block_k, causal, window)
     grid = (bh, nq, nk)
     return pl.pallas_call(
         kernel,
@@ -249,13 +372,13 @@ def _flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
             in_specs=[
                 pl.BlockSpec((bh,), lambda b, i, j: (0,), memory_space=pltpu.SMEM),
                 pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // g, j, 0)),
-                pl.BlockSpec((1, block_k, hd), lambda b, i, j: (b // g, j, 0)),
+                pl.BlockSpec((1, block_k, hd), kv_map),
+                pl.BlockSpec((1, block_k, hd), kv_map),
                 pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
                 pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
-                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // g, 0, j)),
-                pl.BlockSpec((1, 1, block_k), lambda b, i, j: (b // g, 0, j)),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
             ],
             out_specs=pl.BlockSpec((1, block_q, hd), lambda b, i, j: (b, i, 0)),
             scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
@@ -263,6 +386,7 @@ def _flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
         ),
         interpret=interpret,
         name="flash_dq",
@@ -295,36 +419,25 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         q_start = qi * block_q
         k_start = kj * block_k
 
-        keep_blk = k_start <= q_start + block_q - 1 if causal else True
-        if window is not None:
-            keep_blk = keep_blk & (k_start + block_k - 1 >= q_start - window + 1)
-
-        @pl.when(keep_blk)
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
         def _compute():
-            qb = q_ref[0].astype(jnp.float32)
-            kb = k_ref[0].astype(jnp.float32)
-            vb = v_ref[0].astype(jnp.float32)
-            dob = do_ref[0].astype(jnp.float32)
-            s_blk = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s_blk = s_blk + _bias_block(
-                slope, kpos_ref, kneg_ref,
-                q_start, k_start, block_q, block_k, causal, window,
-            )
+            qb = q_ref[0]
+            dob = do_ref[0]
+            s_blk = _scores(qb, k_ref[0], slope, kpos_ref, kneg_ref,
+                            scale, q_start, k_start, causal, window)
             p = jnp.exp(s_blk - lse_ref[0, 0][:, None])  # (BQ, BK)
             dv_sc[:] += jax.lax.dot_general(
-                p, dob, (((0,), (0,)), ((), ())),
+                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # P^T @ dO -> (BK, hd)
             dp = jax.lax.dot_general(
-                dob, vb, (((1,), (1,)), ((), ())),
+                dob, v_ref[0], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             ds = p * (dp - delta_ref[0, 0][:, None])
             dk_sc[:] += scale * jax.lax.dot_general(
-                ds, qb, (((0,), (0,)), ((), ())),
+                ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # dS^T @ Q -> (BK, hd)
 
@@ -332,6 +445,12 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         def _finish():
             dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+
+    def q_map(b, j, i):
+        return (b, _q_block(j, i, block_q, block_k, causal, window, nq), 0)
+
+    def q_row_map(b, j, i):
+        return (b, 0, _q_block(j, i, block_q, block_k, causal, window, nq))
 
     grid = (bh, nk, nq)
     return pl.pallas_call(
@@ -341,12 +460,12 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bh,), lambda b, j, i: (0,), memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_q, hd), lambda b, j, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, hd), q_map),
                 pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // g, j, 0)),
                 pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // g, j, 0)),
-                pl.BlockSpec((1, block_q, hd), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
-                pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+                pl.BlockSpec((1, block_q, hd), q_map),
+                pl.BlockSpec((1, 1, block_q), q_row_map),
+                pl.BlockSpec((1, 1, block_q), q_row_map),
                 pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // g, 0, j)),
                 pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // g, 0, j)),
             ],
@@ -365,6 +484,7 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
         ),
         interpret=interpret,
         name="flash_dkv",
@@ -742,12 +862,18 @@ def _resolve_interpret(interpret):
     return interpret
 
 
+def _blocks(q, kind):
+    """``_pick_blocks`` for flattened ``q`` (bh, seq, hd) on this device."""
+    return _pick_blocks(q.shape[1], q.shape[2], q.dtype.itemsize, kind,
+                        _vmem_limit_bytes())
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
 def _flash(q, k, v, slopes, kpos, kneg, scale, causal, interpret, g=1,
            window=None):
     out, _ = _flash_fwd_pallas(
         q, k, v, slopes, kpos, kneg, scale, causal,
-        _pick_block(q.shape[1], 128), _pick_block(q.shape[1], 512),
+        *_blocks(q, "fwd"),
         _resolve_interpret(interpret), g, window,
     )
     return out
@@ -757,7 +883,7 @@ def _flash_fwd(q, k, v, slopes, kpos, kneg, scale, causal, interpret, g=1,
                window=None):
     out, lse = _flash_fwd_pallas(
         q, k, v, slopes, kpos, kneg, scale, causal,
-        _pick_block(q.shape[1], 128), _pick_block(q.shape[1], 512),
+        *_blocks(q, "fwd"),
         _resolve_interpret(interpret), g, window,
     )
     return out, (q, k, v, slopes, kpos, kneg, out, lse)
@@ -766,15 +892,14 @@ def _flash_fwd(q, k, v, slopes, kpos, kneg, scale, causal, interpret, g=1,
 def _flash_bwd(scale, causal, interpret, g, window, res, ct):
     q, k, v, slopes, kpos, kneg, out, lse = res
     interpret = _resolve_interpret(interpret)
-    bq, bk = _pick_block(q.shape[1], 128), _pick_block(q.shape[1], 512)
     delta = (ct.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)  # (bh, s)
     dq = _flash_dq_pallas(
-        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal, bq, bk,
-        interpret, g, window,
+        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal,
+        *_blocks(q, "dq"), interpret, g, window,
     )
     dk, dv = _flash_dkv_pallas(
-        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal, bq, bk,
-        interpret, g, window,
+        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal,
+        *_blocks(q, "dkv"), interpret, g, window,
     )
     if g > 1:
         # per-query-head contributions -> shared kv heads (rows ordered

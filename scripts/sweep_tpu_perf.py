@@ -78,18 +78,16 @@ def kernel_sweep():
     slopes = jnp.asarray([2.0 ** (-(i + 1)) for i in range(nh)], jnp.float32)
 
     results = {}
-    orig = fa._pick_block
+    orig = fa._pick_blocks
     for bq in (128, 256, 512):
         for bk in (128, 256, 512, 1024):
             if bq > s or bk > s:
                 continue
 
-            # the production call sites pass target=128 for q blocks and
-            # target=512 for kv blocks — dispatch the override on that
-            def pick(n, target=128, _bq=bq, _bk=bk):
-                return _bq if target == 128 else _bk
-
-            fa._pick_block = pick
+            # the production call sites take (block_q, block_k) from
+            # _pick_blocks(seq, head_dim, itemsize, kind, vmem limit):
+            # override that
+            fa._pick_blocks = lambda *shape, _b=(bq, bk): _b
 
             def fwd(x):
                 return fa.flash_attention(
@@ -113,7 +111,7 @@ def kernel_sweep():
                 }
             print(f"bq{bq}_bk{bk}", json.dumps(results[f"bq{bq}_bk{bk}"]),
                   flush=True)
-    fa._pick_block = orig
+    fa._pick_blocks = orig
     print(json.dumps(results))
 
 

@@ -21,6 +21,7 @@ from pipegoose_tpu.models import bloom
 from pipegoose_tpu.nn.sequence_parallel.ring_attention import (
     ring_flash_attention,
 )
+from pipegoose_tpu.ops import flash_attention as fa
 from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 from pipegoose_tpu.ops.paged_attention import paged_attention
@@ -55,12 +56,28 @@ def one_chip():
     compilation_cache.reset_cache()
 
 
-def _flash(grad):
-    qkv = [((B, S, NH, HD), jnp.bfloat16)] * 3
-    slopes = ((NH,), jnp.float32)
+@pytest.fixture
+def as_default_device(one_chip):
+    """The described chip as the default device while a case is traced:
+    the flash kernels ask that device for its VMEM (``_vmem_limit_bytes``;
+    with the CPU there they plan for the compiler's default limit)."""
+    with jax.default_device(next(iter(one_chip.device_set))):
+        yield
+
+
+# the two BLOOM train cells' attention as one chip sees it: bloom-560m 8
+# rows x 16 heads x 64, bloom-1b7 tp2dp2 8 rows x 8 local heads x 128,
+# both at 2,048 positions: (rows, seq, heads, head_dim)
+CELL_SHAPES = {"cell_560m": (8, 2048, 16, 64), "cell_1b7_tp2": (8, 2048, 8, 128)}
+
+
+def _flash(grad, shape=(B, S, NH, HD), dtype=jnp.bfloat16, **rule):
+    qkv = [(shape, dtype)] * 3
+    slopes = ((shape[2],), jnp.float32)
 
     def fwd(q, k, v, sl):
-        return flash_attention(q, k, v, alibi_slopes=sl, interpret=False)
+        return flash_attention(q, k, v, alibi_slopes=sl, interpret=False,
+                               **rule)
 
     if not grad:
         return fwd, qkv + [slopes]
@@ -129,6 +146,17 @@ def _paged(quantized, ps=PS, c=1, nh=NH, hd=HD):
 CASES = {
     "flash_fwd": lambda: _flash(False),
     "flash_fwd_bwd": lambda: _flash(True),
+    "flash_fwd_bwd_cell_560m": lambda: _flash(True, CELL_SHAPES["cell_560m"]),
+    "flash_fwd_bwd_cell_1b7_tp2": lambda: _flash(True, CELL_SHAPES["cell_1b7_tp2"]),
+    # the callers no cell runs, whose index maps clamp by their own rule:
+    # a sliding window (mixtral), no rule at all (albert, ulysses)
+    "flash_fwd_bwd_window": lambda: _flash(True, (8, 2048, 16, 64), window=700),
+    "flash_fwd_bwd_noncausal": lambda: _flash(True, (8, 2048, 16, 64),
+                                              causal=False),
+    # float32 at width 512: the working set passes a v5e's budget at
+    # 1,024 x 1,024 and dK/dV takes 512 x 1,024
+    "flash_fwd_bwd_f32_w512": lambda: _flash(True, (1, 2048, 16, 512),
+                                             jnp.float32),
     "ring_chunk": _ring_chunk,
     "ring_chunk_bwd": lambda: _ring_chunk(True),
     "fused_ce_fwd": lambda: _fused_ce(False),
@@ -154,6 +182,11 @@ CASES = {
 KERNELS = {
     "flash_fwd": ["flash_fwd"],
     "flash_fwd_bwd": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "flash_fwd_bwd_cell_560m": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "flash_fwd_bwd_cell_1b7_tp2": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "flash_fwd_bwd_window": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "flash_fwd_bwd_noncausal": ["flash_fwd", "flash_dq", "flash_dkv"],
+    "flash_fwd_bwd_f32_w512": ["flash_fwd", "flash_dq", "flash_dkv"],
     "ring_chunk": ["flash_ring_fwd"],
     "ring_chunk_bwd": ["flash_ring_fwd", "flash_ring_dq", "flash_ring_dkv"],
     "fused_ce_fwd": ["fused_ce_fwd"],
@@ -171,7 +204,7 @@ def _shapes(shapes, **kw):
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_kernel_compiles_for_v5e(one_chip, case):
+def test_kernel_compiles_for_v5e(one_chip, as_default_device, case):
     fn, shapes = CASES[case]()
     text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
         .compile().as_text()
@@ -184,6 +217,102 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     for name in KERNELS.get(case, ["paged_attention"]):
         assert any(name in instruction for instruction in called), \
             (name, called)
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_flash_results_keep_the_shapes_the_roofline_reader_tells_them_by(
+        one_chip, as_default_device, cell):
+    """``flash_attn_roofline.train`` tells the three kernels apart by
+    their results (first ``bf16[rows*heads, seq, head_dim]``; a float32
+    row statistic second = forward, a second tensor = dK/dV, alone =
+    dQ): compiled at the cell's shape, each named kernel is the kind
+    the reader says, and no call is lost."""
+    import os
+
+    from benchmark import harness
+
+    reader = harness.load_module(os.path.join(
+        os.path.dirname(harness.__file__), "layer_metrics",
+        "flash_attn_roofline.train.py"))
+    rows, seq, heads, hd = CELL_SHAPES[cell]
+    fn, shapes = _flash(True, CELL_SHAPES[cell])
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
+    kinds = {}
+    for ln in text.splitlines():
+        if " custom-call(" in ln and "tpu_custom_call" in ln:
+            kind = reader.classify(ln.strip(), (rows * heads, seq, hd))
+            kinds.setdefault(kind, []).append(ln.split(" = ")[0].strip())
+    assert sorted(kinds) == ["dkv", "dq", "fwd"], kinds
+    for kind, names in kinds.items():
+        assert len(names) == 1 and f"flash_{kind}" in names[0], kinds
+
+
+# width 256 at 4,096 positions is GLM-4.7-Flash's (its other kernels
+# are in test_chip_compile_glm.py)
+PLANNED_SHAPES = dict(CELL_SHAPES, cell_glm=(4, 4096, 20, 256))
+
+
+@pytest.mark.parametrize("cell", sorted(PLANNED_SHAPES))
+def test_flash_blocks_planned_for_the_default_limit_compile_under_it(
+        one_chip, cell):
+    """Where the device is not a TPU Pallas knows (here: the CPU is the
+    default device) the kernels ask for the compiler's default 16 MiB and
+    plan their blocks for it: the budget binds at every cell's shape, and
+    the chip's compiler takes what the arithmetic let through."""
+    _, seq, _, hd = PLANNED_SHAPES[cell]
+    limit = fa._vmem_limit_bytes()
+    assert limit == 16 * 2**20
+    for kind in ("fwd", "dq", "dkv"):
+        bq, bk = fa._pick_blocks(seq, hd, 2, kind, limit)
+        assert (bq, bk) != (1024, 1024) and bq >= 256, (kind, bq, bk)
+    fn, shapes = _flash(True, PLANNED_SHAPES[cell])
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert name in text
+
+
+# (seq, width, dtype, blocks; None: those a v5e's limit gives): GLM's
+# shape; float32 at width 256, and at 512 where the budget binds (dK/dV
+# at 512 x 1,024); the 128 x 512 the kernels had
+V5E_LIMIT = 64 * 2**20
+WORKING_SETS = {
+    "bf16_w256": (4096, 256, jnp.bfloat16, None),
+    "f32_w256": (2048, 256, jnp.float32, None),
+    "f32_w512": (2048, 512, jnp.float32, None),
+    "bf16_w64_128x512": (2048, 64, jnp.bfloat16, (128, 512)),
+}
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("case", sorted(WORKING_SETS))
+def test_working_set_bounds_what_the_compiler_needs(one_chip, monkeypatch,
+                                                    case, kind):
+    """``_working_set_bytes`` against the compiler: given exactly the
+    bytes the arithmetic counts as its scoped-VMEM limit (no quarter to
+    spare), the chip's compiler takes the kernel. Sixteen heads, so no
+    operand is small enough for XLA to keep it in VMEM whole."""
+    seq, hd, dtype, blocks = WORKING_SETS[case]
+    itemsize = jnp.dtype(dtype).itemsize
+    bq, bk = blocks or fa._pick_blocks(seq, hd, itemsize, kind, V5E_LIMIT)
+    counted = fa._working_set_bytes(kind, bq, bk, hd, itemsize)
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: counted)
+    x, row, sl = ((16, seq, hd), dtype), ((16, seq), jnp.float32), \
+        ((16,), jnp.float32)
+    rule = (hd ** -0.5, True, bq, bk, False)
+    if kind == "fwd":
+        fn = lambda q, k, v, s, kp, kn: fa._flash_fwd_pallas(  # noqa: E731
+            q, k, v, s, kp, kn, *rule)
+        shapes = [x, x, x, sl, row, row]
+    else:
+        kernel = fa._flash_dq_pallas if kind == "dq" else fa._flash_dkv_pallas
+        fn = lambda q, k, v, do, lse, delta, s, kp, kn: kernel(  # noqa: E731
+            q, k, v, do, lse, delta, s, kp, kn, *rule)
+        shapes = [x, x, x, x, row, row, sl, row, row]
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
+    assert f"flash_{kind}" in text
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

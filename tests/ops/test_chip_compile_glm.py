@@ -1,6 +1,6 @@
 """What GLM-4.7-Flash's cell asks of the chip's compiler, compiled for
 a described (not attached) TPU v5e at the cell's sizes: the three flash
-kernels at 20 heads x 256 x 4,096, the fused cross entropy at hidden
+kernels at 4 rows x 20 heads x 256 x 4,096, the fused cross entropy at hidden
 2,048 over 19,456 padded rows (19,360 valid), and the expert layer's
 grouped products (``lax.ragged_dot`` forward, dx and dw at 65,536 static
 rows, 8 experts of 2,048 x 1,536). Nothing runs; times are the chip's
@@ -16,7 +16,7 @@ from pipegoose_tpu.nn.expert_parallel import swiglu_grouped
 from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 
-S, NH, HD, H, F = 4096, 20, 256, 2048, 1536
+ROWS, S, NH, HD, H, F = 4, 4096, 20, 256, 2048, 1536
 TOKENS, PICKS, HELD = 16384, 4, 8
 V_PADDED, V_VALID = 19456, 19360
 
@@ -43,7 +43,7 @@ def one_chip():
 
 
 def _flash():
-    qkv = [((1, S, NH, HD), jnp.bfloat16)] * 3
+    qkv = [((ROWS, S, NH, HD), jnp.bfloat16)] * 3
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, scale=HD ** -0.5, interpret=False)
@@ -88,11 +88,29 @@ CASES = {"flash_256": _flash, "fused_ce_padded": _fused_ce,
 def test_compiles_for_v5e_at_the_cells_sizes(one_chip, case):
     fn, shapes, names = CASES[case]()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    # the described chip as the default device while the case is traced:
+    # the flash kernels ask that device for its VMEM
+    with jax.default_device(next(iter(one_chip.device_set))):
+        compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     for name in names:
         assert name in text, f"{name} is not in the compiled program"
+    if case == "flash_256":
+        # the result shapes the flash roofline readers tell the kernels
+        # by: each named kernel is the kind the reader says
+        import os
+
+        from benchmark import harness
+
+        classify = harness.load_module(os.path.join(
+            os.path.dirname(harness.__file__), "layer_metrics",
+            "flash_attn_roofline.train.py")).classify
+        kinds = {classify(ln.strip(), (ROWS * NH, S, HD)): ln.split(" = ")[0]
+                 for ln in text.splitlines()
+                 if " custom-call(" in ln and "tpu_custom_call" in ln}
+        assert sorted(kinds) == ["dkv", "dq", "fwd"], kinds
+        assert all(f"flash_{kind}" in name for kind, name in kinds.items())
     if case == "grouped_products":
         # the chip's own grouped kernel, forward, dx and dw: no dense
         # product over every expert and no loop over the groups

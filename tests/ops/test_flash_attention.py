@@ -268,3 +268,171 @@ def test_gqa_grouped_kv_matches_repeated():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-4, atol=5e-5, err_msg=name
         )
+
+
+# -- the three kernels at explicit blocks, every kind of block ---------------
+#
+# One program of each case meets a skipped block (whose index map is
+# clamped to a kept one), a block wholly inside the rule and a block the
+# diagonal or the window's edge crosses, with block_q != block_k both
+# ways; the last cases take the blocks ``_pick_blocks`` gives a v5e for
+# their shape.
+
+from pipegoose_tpu.ops import flash_attention as fa  # noqa: E402
+
+# what ``_vmem_limit_bytes`` gives on a v5e (half of 128 MiB), and here,
+# where no TPU is attached (the compiler's default)
+V5E_LIMIT, DEFAULT_LIMIT = 64 * 2**20, 16 * 2**20
+
+
+def _dense(q, k, v, slopes, scale, causal, window, kpos, kneg, g):
+    """``_xla_reference`` where it has the semantics (no window, g = 1,
+    float32); else the same dense math with the window rule, the shared
+    K/V rows repeated, and ``p`` rounded to the operands' dtype before
+    ``p v`` as the models' plain paths do."""
+    if window is None and g == 1 and q.dtype == jnp.float32:
+        return _xla_reference(q, k, v, slopes, scale, causal, kpos, kneg)
+    s = q.shape[1]
+    k, v, kpos, kneg = (jnp.repeat(x, g, axis=0) for x in (k, v, kpos, kneg))
+    scores = jnp.einsum("bqd,bkd->bqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    scores = scores + slopes[:, None, None] * kpos[:, None, :] + kneg[:, None, :]
+    qi, ki = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+    keep = jnp.ones((s, s), bool)
+    if causal:
+        keep = keep & (ki <= qi)
+    if window is not None:
+        keep = keep & (qi - ki < window)
+    scores = jnp.where(keep[None], scores, fa.NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bqk,bkd->bqd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+# name: (seq, head width, g, causal, window, padded, dtype, blocks or None)
+KERNEL_CASES = {
+    "causal_64x128_hd64": (512, 64, 1, True, None, False, jnp.float32, (64, 128)),
+    "causal_128x64_hd128": (512, 128, 1, True, None, False, jnp.float32, (128, 64)),
+    "causal_64x256_hd256": (512, 256, 1, True, None, False, jnp.float32, (64, 256)),
+    "window_64x128": (512, 64, 1, True, 160, False, jnp.float32, (64, 128)),
+    "window_128x64": (512, 64, 1, True, 160, False, jnp.float32, (128, 64)),
+    "noncausal_64x128": (256, 64, 1, False, None, False, jnp.float32, (64, 128)),
+    "noncausal_window_128x64": (512, 64, 1, False, 96, False, jnp.float32, (128, 64)),
+    "padded_64x128": (512, 64, 1, True, None, True, jnp.float32, (64, 128)),
+    "padded_128x64": (512, 64, 1, True, None, True, jnp.float32, (128, 64)),
+    "gqa2_64x128": (512, 64, 2, True, None, True, jnp.float32, (64, 128)),
+    "gqa2_window_128x64": (512, 64, 2, True, 160, False, jnp.float32, (128, 64)),
+    "bf16_64x128_hd64": (512, 64, 1, True, None, False, jnp.bfloat16, (64, 128)),
+    "bf16_128x64_hd256": (512, 256, 1, True, None, True, jnp.bfloat16, (128, 64)),
+    "picked_blocks_hd64": (2048, 64, 1, True, None, False, jnp.float32, None),
+    "picked_blocks_hd128_window": (2048, 128, 1, True, 700, True, jnp.float32, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_kernels_at_explicit_blocks_match_the_dense_reference(case):
+    s, hd, g, causal, window, padded, dtype, blocks = KERNEL_CASES[case]
+    nkv = 1
+    bh = nkv * g
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q, do = (jax.random.normal(kk, (bh, s, hd)).astype(dtype) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (nkv, s, hd)).astype(dtype) for kk in ks[2:])
+    slopes = jnp.asarray(alibi_slopes(4))[:bh] if causal else jnp.zeros(bh)
+    valid = np.ones((nkv, s), np.float32)
+    if padded:
+        valid[:, s - 75:] = 0.0  # right padding, not on a block's edge
+    kpos, kneg = fa.mask_to_kv_bias(jnp.asarray(valid))
+    rows = np.asarray(jnp.repeat(jnp.asarray(valid), g, axis=0), bool)
+    # a model's loss masks the padded queries: their cotangent is zero
+    do = do * jnp.asarray(rows)[:, :, None].astype(dtype)
+    scale = hd ** -0.5
+    rule = (scale, causal)
+
+    kinds = ("fwd", "dq", "dkv")
+    bq_bk = {kind: blocks or fa._pick_blocks(s, hd, q.dtype.itemsize, kind,
+                                             V5E_LIMIT)
+             for kind in kinds}
+    out, lse = fa._flash_fwd_pallas(q, k, v, slopes, kpos, kneg, *rule,
+                                    *bq_bk["fwd"], True, g, window)
+    delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    dq = fa._flash_dq_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
+                             *rule, *bq_bk["dq"], True, g, window)
+    dk, dv = fa._flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
+                                  *rule, *bq_bk["dkv"], True, g, window)
+    dk, dv = (x.astype(jnp.float32).reshape(nkv, g, s, hd).sum(1)
+              for x in (dk, dv))
+
+    ref, vjp = jax.vjp(
+        lambda q, k, v: _dense(q, k, v, slopes, scale, causal, window,
+                               kpos, kneg, g), q, k, v)
+    rq, rk, rv = vjp(do)
+    if dtype == jnp.float32:
+        # today's relative tolerances; the absolute ones follow the
+        # length of the float32 sums (2e-6 and 5e-5 at today's 128)
+        fwd_tol = dict(rtol=2e-5, atol=2e-8 * s)
+        grad_tol = dict(rtol=1e-4, atol=4e-7 * s)
+    else:
+        fwd_tol = grad_tol = dict(rtol=3e-2, atol=3e-2)
+
+    def close(a, b, tol, name):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all(), name
+        np.testing.assert_allclose(a, b, err_msg=f"{case}: {name}", **tol)
+
+    close(np.asarray(out, np.float32)[rows], np.asarray(ref, np.float32)[rows],
+          fwd_tol, "out")
+    close(dq, rq, grad_tol, "dq")
+    close(dk, rk, grad_tol, "dk")
+    close(dv, rv, grad_tol, "dv")
+
+
+# -- the block function alone ------------------------------------------------
+
+KINDS = ("fwd", "dq", "dkv")
+
+
+def test_vmem_limit_is_the_compilers_default_where_no_tpu_is_attached():
+    assert fa._vmem_limit_bytes() == DEFAULT_LIMIT
+
+
+@pytest.mark.parametrize("limit", [DEFAULT_LIMIT, 32 * 2**20, V5E_LIMIT])
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("width", [64, 96, 128, 256, 512, 1024, 2048])
+def test_pick_blocks_divide_the_sequence_inside_the_vmem_budget(
+        kind, width, limit):
+    for seq in (128, 384, 512, 1024, 1536, 2048, 4096, 8192, 32768):
+        for itemsize in (2, 4):
+            bq, bk = fa._pick_blocks(seq, width, itemsize, kind, limit)
+            assert seq % bq == 0 and seq % bk == 0, (seq, bq, bk)
+            assert bq <= 1024 and bk <= 1024
+            used = fa._working_set_bytes(kind, bq, bk, width, itemsize)
+            if min(bq, bk) > 8:     # (8, 8) is the floor, fit or not
+                assert used <= limit * 3 // 4, (seq, itemsize, bq, bk, used)
+            # the largest that fits: the side halved last, twice as
+            # large again, would pass the budget
+            wider = (2 * bq, bk) if bq < bk else (bq, 2 * bk)
+            if max(wider) <= 1024 and seq % max(wider) == 0:
+                assert fa._working_set_bytes(kind, *wider, width, itemsize) \
+                    > limit * 3 // 4, (seq, itemsize, bq, bk)
+
+
+@pytest.mark.parametrize("seq,blocks", [(64, (64, 64)), (96, (32, 32)),
+                                        (200, (8, 8)), (100, (100, 100)),
+                                        (128, (128, 128))])
+def test_pick_blocks_keep_todays_answer_for_tiny_and_odd_sequences(seq, blocks):
+    for kind in KINDS:
+        for width in (64, 128, 256):
+            for limit in (DEFAULT_LIMIT, V5E_LIMIT):
+                assert fa._pick_blocks(seq, width, 4, kind, limit) == blocks
+            assert blocks == (fa._pick_block(seq, 128), fa._pick_block(seq, 512))
+
+
+@pytest.mark.parametrize("seq,width", [(2048, 64), (2048, 128), (4096, 256)])
+def test_pick_blocks_at_the_cells_shapes(seq, width):
+    """bf16 at the three train cells' shapes: 1,024 x 1,024 on a v5e; no
+    query block under 256 and more scores a grid step than the 128 x 512
+    the kernels had even under the compiler's default limit."""
+    for kind in KINDS:
+        assert fa._pick_blocks(seq, width, 2, kind, V5E_LIMIT) == (1024, 1024)
+        bq, bk = fa._pick_blocks(seq, width, 2, kind, DEFAULT_LIMIT)
+        assert bq >= 256 and bq * bk > 128 * 512, (kind, bq, bk)
