@@ -1,7 +1,6 @@
 """Continuous-batching BLOOM serving over the paged KV pool — mixed-
-length requests multiplexed through a fixed slot set, A/B'd against
-naive drain-then-refill padded batching (pipegoose_tpu/serving/,
-docs/serving.md).
+length requests multiplexed through a fixed slot set by
+``ServingEngine.run`` (pipegoose_tpu/serving/, docs/serving.md).
 
     python examples/serve_bloom.py --fake-devices 8 --tp 2
     python examples/serve_bloom.py --requests 12 --slots 4
@@ -35,7 +34,7 @@ def main():
         force_cpu_devices(args.fake_devices)
 
     from pipegoose_tpu.distributed import ParallelContext
-    from pipegoose_tpu.serving import serving_ab_benchmark
+    from pipegoose_tpu.serving import Request, ServingEngine
 
     cfg = bloom.BloomConfig(vocab_size=256, hidden_size=128, n_layer=2,
                             n_head=4)
@@ -44,13 +43,15 @@ def main():
     # a mixed-length workload: short chats next to long completions —
     # exactly where padded batching wastes decode steps
     rng = np.random.RandomState(args.seed)
-    specs = []
+    requests = []
     for _ in range(args.requests):
         prompt_len = int(rng.randint(2, args.max_context // 2))
         max_new = int(rng.randint(2, args.max_context - prompt_len))
         if args.steps:
             max_new = min(max_new, args.steps)
-        specs.append((prompt_len, max_new))
+        requests.append(Request(
+            prompt=rng.randint(1, cfg.vocab_size, (prompt_len,)),
+            max_new_tokens=max_new))
 
     ctx = mesh = param_specs = None
     if args.tp > 1:
@@ -60,21 +61,22 @@ def main():
         mesh, param_specs = ctx.mesh, bloom.tp_specs(params)
     try:
         pool_pages = 1 + args.slots * (args.max_context // args.page_size)
-        res = serving_ab_benchmark(
-            params, cfg, specs, num_slots=args.slots, num_pages=pool_pages,
+        engine = ServingEngine(
+            params, cfg, num_slots=args.slots, num_pages=pool_pages,
             page_size=args.page_size, max_context=args.max_context,
             mesh=mesh, param_specs=param_specs,
         )
+        outputs, metrics = engine.run(requests)
     finally:
         if ctx is not None:
             ctx.destroy()
 
-    print(json.dumps(res, indent=2))
+    print(json.dumps(metrics, indent=2))
     print(
-        f"done: {args.requests} requests through {args.slots} slots "
-        f"(tp={args.tp}), continuous/static decode-step ratio "
-        f"{res['continuous']['decode_steps']}/{res['static']['decode_steps']}"
-        f", throughput speedup {res['speedup']}x"
+        f"done: {len(outputs)} requests through {args.slots} slots "
+        f"(tp={args.tp}), {metrics['generated_tokens']} tokens in "
+        f"{metrics['decode_steps']} decode steps, slot occupancy "
+        f"{metrics['slot_occupancy']}"
     )
 
 

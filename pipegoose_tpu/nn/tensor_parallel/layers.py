@@ -228,7 +228,7 @@ def chunked_ce_sums(
     rematerializes them chunk by chunk. Bounds the logits working set to
     1/n_chunks — at bloom-560m bench shapes the full fp32 buffer is
     ~8 GB (b8 x s1024 x v250880), the single largest HBM consumer of
-    the train step (docs/perf_tpu_v5e.md).
+    the train step.
 
     The reference computes full logits then its VocabParallelCrossEntropy
     (loss.py:14-89); chunking composes with the same vocab-parallel CE,

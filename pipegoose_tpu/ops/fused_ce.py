@@ -1,9 +1,9 @@
 """Fused vocab-parallel cross-entropy (Pallas, TPU) — forward AND backward.
 
 The single largest HBM consumer of the bloom-560m train step is the
-(B, S, V) fp32 logits buffer (~8 GB at b8 x s1024 x v250880 —
-docs/perf_tpu_v5e.md); the chunked-CE fallback (chunked_ce_sums) bounds
-it but pays ~7% throughput for the chunk-boundary logit recompute. This
+(B, S, V) fp32 logits buffer (~8 GB at b8 x s1024 x v250880); the
+chunked-CE fallback (chunked_ce_sums) bounds it but pays ~7%
+throughput for the chunk-boundary logit recompute. This
 kernel computes the loss STRAIGHT from (hidden, embedding) with an
 online log-sum-exp over vocab tiles — the full logits tensor never
 exists in HBM, forward or backward:

@@ -15,8 +15,7 @@ over ``PEAK_FLOPS``, analytic pipeline bubble, HBM vs the chip budget
 
 Entry points: :func:`run_plan` (library),
 ``scripts/plan_parallelism.py`` (CLI + ``--check`` CI gate),
-``scripts/sweep_tpu_perf.py plan`` (measure the top-K, record
-predicted-vs-measured), ``examples/plan_parallelism_demo.py``.
+``examples/plan_parallelism_demo.py``.
 Docs: docs/planner.md.
 """
 from pipegoose_tpu.planner.bloom_builder import BloomPlanModel

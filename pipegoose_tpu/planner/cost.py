@@ -20,9 +20,8 @@ execution:
 - HBM feasibility: the doctor's per-device peak vs the chip budget —
   an infeasible candidate is pruned with the numbers in the reason.
 
-The model ranks layouts; it does not promise wall-clock accuracy. The
-``sweep_tpu_perf.py plan`` mode measures the top-K and records the
-predicted-vs-measured delta next to the plan artifact (docs/planner.md).
+The model ranks layouts; it does not promise wall-clock accuracy
+(docs/planner.md).
 """
 from __future__ import annotations
 

@@ -36,8 +36,8 @@ logger = logging.getLogger("pipegoose_tpu.planner")
 
 # the most recent PlanReport produced by run_plan in this process —
 # what the ops server's /debug/plan serves when wired to
-# last_plan_report (bench.py, the CLI, and ElasticRecovery's
-# planner-backed replan all route through run_plan, so one cache
+# last_plan_report (the CLI and ElasticRecovery's
+# planner-backed replan both route through run_plan, so one cache
 # covers every producer)
 _LAST_PLAN_REPORT: Optional[PlanReport] = None
 

@@ -10,17 +10,12 @@ docs/serving.md for the request lifecycle, page-table layout, the
 prefix-cache / COW / eviction semantics, and the quantization accuracy
 contract.
 """
-from pipegoose_tpu.serving.disagg import (
-    DisaggEngine,
-    disagg_serving_benchmark,
-)
+from pipegoose_tpu.serving.disagg import DisaggEngine
 from pipegoose_tpu.serving.engine import (
     ReplicaFault,
     RequestOutput,
     ServingEngine,
     make_skewed_replay,
-    prefix_replay_benchmark,
-    serving_ab_benchmark,
 )
 from pipegoose_tpu.serving.kv_pool import (
     NULL_PAGE,
@@ -51,14 +46,11 @@ __all__ = [
     "Status",
     "copy_page",
     "dequantize_kv",
-    "disagg_serving_benchmark",
     "gather_pages",
     "init_pages",
     "make_skewed_replay",
     "quantize_kv",
     "paged_decode_step",
     "paged_prefill_chunk",
-    "prefix_replay_benchmark",
-    "serving_ab_benchmark",
     "write_prompt_pages",
 ]

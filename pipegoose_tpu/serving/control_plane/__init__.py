@@ -31,9 +31,6 @@ from pipegoose_tpu.serving.control_plane.autoscaler import (
     Autoscaler,
     AutoscalerConfig,
 )
-from pipegoose_tpu.serving.control_plane.benchmark import (
-    control_plane_replay_benchmark,
-)
 from pipegoose_tpu.serving.control_plane.plane import ControlPlane
 from pipegoose_tpu.serving.control_plane.replica import Replica, ReplicaState
 from pipegoose_tpu.serving.control_plane.router import Router
@@ -51,5 +48,4 @@ __all__ = [
     "Router",
     "TenantLedger",
     "TenantSpec",
-    "control_plane_replay_benchmark",
 ]

@@ -90,7 +90,7 @@ class ControlPlane:
     resume. ``policy`` is the routing arm ("cache_aware" |
     "round_robin"). ``autoscaler`` (optional) consumes the fleet SLO
     monitor; without one, :meth:`scale_up` / :meth:`start_drain` are
-    the operator's manual controls (and the bench/test seam).
+    the operator's manual controls (and the test seam).
     """
 
     def __init__(self, replica_factory: Callable[[str, MetricsRegistry], Any],
@@ -121,8 +121,9 @@ class ControlPlane:
         ``probation_ticks``: dispatch cooldown after :meth:`rejoin`.
         ``pull_hints``: hint cross-replica KV pulls through the fleet
         prefix directory at placement (serving/kv_tier/); off, replicas
-        recompute what their own cache misses — the routing benchmark
-        disables it to isolate placement from fleet prefix sharing.
+        recompute what their own cache misses —
+        ``examples/control_plane_demo.py`` disables it to isolate
+        placement from fleet prefix sharing.
         ``fleet_tracer``: optional ``telemetry.fleettrace.FleetTracer``
         — the plane mints a ``trace_id`` per ingress, marks every hop
         hand-over, attaches one named ``RequestTracer`` per replica
@@ -388,7 +389,7 @@ class ControlPlane:
         return rep
 
     def clear_prefix_caches(self) -> None:
-        """Drop every live replica's unpinned cached pages — the bench
+        """Drop every live replica's unpinned cached pages — the demo
         and test seam for measuring a COLD-cache trace on warm-compiled
         engines (routing decides the hit rate only while caches are
         filling; a fully warmed fleet hits everywhere under any

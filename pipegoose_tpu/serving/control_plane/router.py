@@ -16,7 +16,7 @@ turns hit rate into a decision:
   clock moves, so probing N replicas costs N trie walks and perturbs
   none of them.
 - **round_robin**: rotate over admitting replicas — the baseline arm
-  every bench compares against.
+  cache-aware placement is compared against.
 
 Every decision lands in a bounded log (the ``/debug/fleet`` forensics
 and the Perfetto router track — ``telemetry.chrometrace.

@@ -13,14 +13,12 @@ model on one mesh sizes both pools wrong. This package splits them:
   admits via ``admit_with_pages``, owns the re-prefill fallback).
 - **engine.py** — ``DisaggEngine``, the one-host-thread orchestrator
   over both pools' steppable-run APIs.
-- **benchmark.py** — the disagg-vs-monolithic replay bench.
 
 Greedy output is token-identical to a single-engine run (pinned across
 fp/int8 KV and the tp 2 -> 1 reshard), and the request tracer's new
 ``transfer`` phase keeps queue + prefill + transfer + decode + stall
 == e2e exact. See docs/serving.md "Disaggregated prefill/decode".
 """
-from pipegoose_tpu.serving.disagg.benchmark import disagg_serving_benchmark
 from pipegoose_tpu.serving.disagg.engine import DisaggEngine
 from pipegoose_tpu.serving.disagg.transfer import (
     PageHandoff,
@@ -39,6 +37,5 @@ __all__ = [
     "PrefillWorker",
     "TransferError",
     "TransferQueue",
-    "disagg_serving_benchmark",
     "set_transfer_fault",
 ]

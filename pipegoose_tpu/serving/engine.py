@@ -74,8 +74,8 @@ of the PR 2 gauges/histograms it counts prefix-cache ``hit_tokens``/
 forwarded prefill tokens (the prefill-FLOP meter the cache shrinks),
 pool fragmentation, decode-step gaps, and speculative draft/accept
 tallies. The legacy aggregate dict keeps its exact keys —
-``serving_ab_benchmark`` and existing callers parse it; new information
-lands under NEW keys only.
+``benchmark/drivers/serve.py``, ``chip_smoke.py``, ``DisaggEngine`` and
+the examples parse it; new information lands under NEW keys only.
 """
 from __future__ import annotations
 
@@ -111,7 +111,7 @@ from pipegoose_tpu.serving.kv_tier.restore import (
 )
 from pipegoose_tpu.serving.prefix_cache import PrefixCache
 from pipegoose_tpu.serving.scheduler import Request, Scheduler, Status
-from pipegoose_tpu.telemetry.registry import Histogram, get_registry
+from pipegoose_tpu.telemetry.registry import get_registry
 from pipegoose_tpu.telemetry.spans import span
 
 
@@ -202,16 +202,14 @@ class ServingEngine:
     budget (it fixes the page-table width, i.e. the attention span the
     step compiles for). Pass ``mesh``/``param_specs`` for tensor
     parallelism (vocab/head-sharded params, same contract as
-    ``generate_tp``); ``continuous=False`` degrades the scheduler to
-    naive padded batching for A/B measurement. ``prefix_cache``/
-    ``prefill_chunk``/``speculative`` are the opt-in serving-perf modes
-    (module docstring); all default OFF, preserving the PR 1 engine
-    bit-for-bit."""
+    ``generate_tp``). ``prefix_cache``/``prefill_chunk``/``speculative``
+    are the opt-in serving-perf modes (module docstring); all default
+    OFF, preserving the PR 1 engine bit-for-bit."""
 
     def __init__(self, params, config, *, num_slots: int = 4,
                  num_pages: int = 64, page_size: int = 16,
                  max_context: int = 256, mesh=None, param_specs=None,
-                 tp_axis: str = "tensor", continuous: bool = True,
+                 tp_axis: str = "tensor",
                  registry=None, recorder=None, stall_patience: int = 100,
                  prefix_cache: bool = False,
                  prefill_chunk: Optional[int] = None,
@@ -432,7 +430,6 @@ class ServingEngine:
         # it can export them (serving/disagg/workers.py)
         self._handoff_hook = None
         self.sched = Scheduler(num_slots, self.pool, max_context,
-                               continuous=continuous,
                                prefix_cache=self.prefix_cache,
                                chunk_tokens=prefill_chunk,
                                tracer=tracer,
@@ -909,7 +906,7 @@ class ServingEngine:
 
     def set_peer_source(self, peer) -> None:
         """Default cross-replica pull source: every queued request
-        probes ``peer``'s prefix inventory before admission (bench /
+        probes ``peer``'s prefix inventory before admission (demo /
         two-engine tests; the control plane hints per request through
         the fleet directory instead). Requires a prefix cache."""
         if self.kv_tier is None:
@@ -1681,8 +1678,8 @@ class ServingEngine:
             "wall_time_s": round(wall, 6),
             "decode_steps": rs.steps,
             # summed decode-step wall time: generated / this = the
-            # decode-POOL rate (prefill stalls excluded) — the disagg
-            # bench's "prefill off the critical path" meter
+            # decode-POOL rate (prefill stalls excluded) — DisaggEngine's
+            # "prefill off the critical path" meter
             "decode_step_time_s": round(rs.step_time, 6),
             "prefills": rs.prefills,
             "generated_tokens": rs.generated_total,
@@ -1733,7 +1730,7 @@ class ServingEngine:
                 metrics["kv_tier"]["host"] = self.host_tier.stats()
         if self.memledger is not None:
             # peak per-class occupancy + fragmentation + leak/audit
-            # verdicts: the memory trajectory one bench row carries
+            # verdicts: the run's memory trajectory in one block
             metrics["memory"] = self.memledger.run_summary()
         if self.speculative is not None:
             metrics["speculative"] = {
@@ -1781,190 +1778,6 @@ class ServingEngine:
         )
 
 
-QUANT_BENCH_ARMS = {
-    "fp": {},
-    "int8w": {"weight_dtype": "int8"},
-    "int8kv": {"kv_dtype": "int8"},
-    "int8w+int8kv": {"weight_dtype": "int8", "kv_dtype": "int8"},
-}
-
-
-def _quant_arm_row(engine, outs, metrics):
-    """One quant-arm bench row: throughput, TTFT quantiles through the
-    shared telemetry Histogram, and the memory-report capacity numbers
-    — every arm reports the same fields so fp-vs-int8 divides
-    like-for-like."""
-    h_ttft = Histogram("quant_arm.ttft_seconds")  # standalone reservoir
-    for o in outs:
-        if o.ttft_s is not None:
-            h_ttft.observe(o.ttft_s)
-    mem = engine.memory_report()
-    return {
-        "decode_tokens_per_s": metrics["decode_tokens_per_s"],
-        "ttft_p50_s": round(h_ttft.quantile(0.5), 6),
-        "ttft_p99_s": round(h_ttft.quantile(0.99), 6),
-        "decode_steps": metrics["decode_steps"],
-        "wall_time_s": metrics["wall_time_s"],
-        "weights_bytes": mem["weights"]["total_bytes"],
-        "kv_bytes": mem["kv"]["total_bytes"],
-        "page_capacity_ratio": mem["kv"]["page_capacity_ratio"],
-    }
-
-
-def serving_ab_benchmark(params, config, request_specs, *, num_slots=4,
-                         num_pages=64, page_size=16, max_context=256,
-                         mesh=None, param_specs=None, tp_axis="tensor",
-                         seed=0, quant_arms=False, paged_kernel=False,
-                         **engine_kwargs):
-    """A/B the continuous-batching scheduler against naive padded
-    batching on ONE model + request mix; returns a JSON-able dict.
-
-    ``request_specs`` is a list of (prompt_len, max_new_tokens[, eos])
-    tuples; prompts are seeded-random tokens so both arms and repeat
-    runs see the identical workload. Each arm warms up once (compiles)
-    and is then measured on a fresh copy of the workload. Extra
-    ``engine_kwargs`` (prefix_cache, prefill_chunk, speculative) apply
-    to BOTH arms.
-
-    ``quant_arms=True`` adds a ``quant`` block measuring the SAME
-    workload through continuous engines at fp / int8w / int8kv /
-    int8w+int8kv (ROADMAP item 4): tokens/s, TTFT p50/p99, and the
-    HBM + page-capacity numbers from ``memory_report()``, each pinned
-    against the fp row of the same run.
-
-    ``paged_kernel=True`` adds a ``paged_kernel`` block A/B-ing the
-    fused Pallas paged-attention kernel against the XLA gather path on
-    the SAME int8-pool workload: tokens/s, measured wall, and the
-    ``profile()`` decode-step component split (compute/comm/idle
-    fractions — the kernel's regression surface for PerfSentinel),
-    plus the token-identity verdict and the chosen tile geometry.
-    """
-    rng = np.random.RandomState(seed)
-    vocab = getattr(config, "valid_vocab_size", None) or config.vocab_size
-    prompts = [rng.randint(1, vocab, (int(spec[0]),)) for spec in request_specs]
-
-    def make_requests():
-        return [
-            Request(prompt=p, max_new_tokens=int(spec[1]),
-                    eos_token_id=(int(spec[2]) if len(spec) > 2 else None))
-            for p, spec in zip(prompts, request_specs)
-        ]
-
-    results = {}
-    fp_arm = None            # (engine, outs, metrics) of the continuous arm
-    for label, continuous in (("continuous", True), ("static", False)):
-        engine = ServingEngine(
-            params, config, num_slots=num_slots, num_pages=num_pages,
-            page_size=page_size, max_context=max_context, mesh=mesh,
-            param_specs=param_specs, tp_axis=tp_axis, continuous=continuous,
-            **engine_kwargs,
-        )
-        engine.run(make_requests())          # warmup: compile every bucket
-        outs, metrics = engine.run(make_requests())
-        if continuous:
-            fp_arm = (engine, outs, metrics)
-        results[label] = {
-            "decode_tokens_per_s": metrics["decode_tokens_per_s"],
-            "decode_steps": metrics["decode_steps"],
-            "slot_occupancy": metrics["slot_occupancy"],
-            "page_occupancy": metrics["page_occupancy"],
-            "wall_time_s": metrics["wall_time_s"],
-        }
-    results["speedup"] = round(
-        results["continuous"]["decode_tokens_per_s"]
-        / max(results["static"]["decode_tokens_per_s"], 1e-9), 3,
-    )
-    results["num_slots"] = num_slots
-    results["requests"] = len(request_specs)
-    if quant_arms:
-        quant = {}
-        for label, qkw in QUANT_BENCH_ARMS.items():
-            if not qkw:
-                # the fp row IS the continuous arm measured above —
-                # same engine kwargs, same workload; don't re-jit and
-                # re-serve the whole thing a third time
-                quant[label] = _quant_arm_row(*fp_arm)
-                continue
-            engine = ServingEngine(
-                params, config, num_slots=num_slots, num_pages=num_pages,
-                page_size=page_size, max_context=max_context, mesh=mesh,
-                param_specs=param_specs, tp_axis=tp_axis, continuous=True,
-                **engine_kwargs, **qkw,
-            )
-            engine.run(make_requests())
-            outs, metrics = engine.run(make_requests())
-            quant[label] = _quant_arm_row(engine, outs, metrics)
-        fp = quant["fp"]
-        quant["summary"] = {
-            "tokens_per_s_vs_fp": {
-                k: round(v["decode_tokens_per_s"]
-                         / max(fp["decode_tokens_per_s"], 1e-9), 3)
-                for k, v in quant.items() if k != "fp"
-            },
-            "kv_capacity_ratio_int8": (
-                quant["int8kv"]["page_capacity_ratio"]
-            ),
-            "weight_bytes_ratio_int8": round(
-                fp["weights_bytes"]
-                / max(quant["int8w"]["weights_bytes"], 1), 3,
-            ),
-        }
-        results["quant"] = quant
-    if paged_kernel:
-        paged: dict = {}
-        pk_kwargs = dict(engine_kwargs)
-        # the kernel's headline case is wire-precision int8 pages; an
-        # explicit kv_dtype in engine_kwargs still wins
-        pk_kv = pk_kwargs.pop("kv_dtype", "int8")
-        arm_outs = {}
-        for label in ("gather", "paged"):
-            engine = ServingEngine(
-                params, config, num_slots=num_slots, num_pages=num_pages,
-                page_size=page_size, max_context=max_context, mesh=mesh,
-                param_specs=param_specs, tp_axis=tp_axis, continuous=True,
-                kv_dtype=pk_kv, attn_kernel=label, **pk_kwargs,
-            )
-            engine.run(make_requests())          # warmup: compile
-            outs, metrics = engine.run(make_requests())
-            arm_outs[label] = outs
-            prof = engine.profile(steps=3, warmup=1)
-            row = {
-                "decode_tokens_per_s": metrics["decode_tokens_per_s"],
-                "decode_step_time_s": metrics["decode_step_time_s"],
-                "wall_time_s": metrics["wall_time_s"],
-                # measured decode-step attribution (telemetry/xprof.py):
-                # the component fractions PerfSentinel tracks as the
-                # kernel's regression surface
-                "step_wall_s": round(prof.wall_step_s, 6),
-                "compute_fraction": round(prof.compute_fraction, 4),
-                "comm_fraction": round(prof.comm_fraction, 4),
-                "idle_fraction": round(prof.idle_fraction, 4),
-            }
-            if "max_decode_gap_s" in metrics:
-                row["max_decode_gap_s"] = metrics["max_decode_gap_s"]
-            if label == "paged":
-                row["tile"] = engine._paged_tile(n_queries=1)
-            paged[label] = row
-        identical = all(
-            np.array_equal(a.generated, b.generated)
-            for a, b in zip(arm_outs["gather"], arm_outs["paged"])
-        )
-        paged["summary"] = {
-            "kv_dtype": pk_kv or "fp",
-            "outputs_token_identical": bool(identical),
-            "tokens_per_s_vs_gather": round(
-                paged["paged"]["decode_tokens_per_s"]
-                / max(paged["gather"]["decode_tokens_per_s"], 1e-9), 3,
-            ),
-            "step_wall_vs_gather": round(
-                paged["paged"]["step_wall_s"]
-                / max(paged["gather"]["step_wall_s"], 1e-9), 3,
-            ),
-        }
-        results["paged_kernel"] = paged
-    return results
-
-
 def make_skewed_replay(*, n_requests: int, n_prefixes: int, prefix_len: int,
                        suffix_lens: Sequence[int], max_new: int,
                        vocab: int, seed: int = 0, zipf_a: float = 1.2,
@@ -1992,7 +1805,7 @@ def make_skewed_replay(*, n_requests: int, n_prefixes: int, prefix_len: int,
     a pool's HBM capacity instead of passing ``n_prefixes`` absolutely
     — factor 2.0 against (``num_pages``, ``page_size``) makes the
     prefix working set twice what the pool can hold, the guaranteed-
-    overflow replay the KV-tier bench needs (every factor > 1 forces
+    overflow replay the KV-tier tests need (every factor > 1 forces
     LRU eviction; the tier turns those evictions into restores instead
     of recomputes). Requires ``num_pages`` and ``page_size``;
     overrides ``n_prefixes``."""
@@ -2031,280 +1844,3 @@ def make_skewed_replay(*, n_requests: int, n_prefixes: int, prefix_len: int,
             tenant = f"t{int(rng.choice(n_tenants, p=t_weights))}"
             specs.append((prompt, max_new, tenant))
     return specs
-
-
-def prefix_replay_benchmark(params, config, *, n_requests=12, n_prefixes=3,
-                            prefix_len=16, suffix_lens=(2, 4, 6), max_new=6,
-                            seed=0, zipf_a=1.2, num_slots=4, num_pages=64,
-                            page_size=8, max_context=64, prefill_chunk=None,
-                            mesh=None, param_specs=None, tp_axis="tensor",
-                            include_speculative=False, speculative=(1, 3),
-                            trace=False, include_quant=False,
-                            include_tiered=False, tiered_working_set=2.0,
-                            tiered_budget_bytes=1 << 30):
-    """Measure the tentpole: the same skewed-prompt-reuse replay through
-    (a) the PR 1 baseline engine (monolithic prefill, no sharing),
-    (b) chunked prefill alone, (c) the prefix cache alone, (d) both, and
-    optionally (e) both + self-speculative decode. Per arm: tokens/s,
-    TTFT p50/p99, prefill tokens actually forwarded (the FLOP meter —
-    the cache arms' drop is proportional to the hit rate), and the max
-    decode-step gap (chunking bounds it by one chunk's compute).
-    JSON-able. The ``summary`` block compares the pure-cache arm to the
-    baseline: on prefill-compute-bound workloads (long shared prefixes
-    — the production shape) the TTFT win tracks the hit rate; the
-    chunked arms trade a little TTFT for never stalling neighbors.
-
-    ``trace=True`` additionally replays each arm ONCE MORE with a
-    ``RequestTracer`` attached — OUTSIDE the measured run, so the
-    measurement stays tracer-free — and returns a ``request_trace``
-    block: per-arm latency attribution (every request's additive
-    queue/prefill/decode/stall components, which sum to its measured
-    e2e) plus a cross-arm summary showing how much of the cached arm's
-    TTFT win the cache-savings share accounts for. This is what
-    bench.py writes to ``bench_request_trace.json``.
-
-    ``include_quant=True`` adds ``int8w`` / ``int8kv`` /
-    ``int8w+int8kv`` arms — the cached+chunked engine with ROADMAP
-    item 4's quantization knobs — each carrying its HBM bytes and
-    page-capacity ratio next to the usual tokens/s and TTFT columns,
-    and a ``summary.quant`` block pinning them against the fp
-    cached+chunked arm of the same run.
-
-    ``include_tiered=True`` adds the KV-memory-hierarchy block: a
-    SECOND replay whose prefix working set is ``tiered_working_set``
-    times the pool's HBM capacity (guaranteed eviction pressure) run
-    through (a) ``lru`` — the plain cached+chunked engine, every
-    eviction recomputes; (b) ``host_tier`` — the same engine with a
-    host-DRAM tier, evictions spill and later misses restore; and
-    (c) ``fleet_pull`` — a COLD replica pulling the prefixes a warm
-    peer already holds through the cross-replica transfer path. Each
-    arm reports tokens/s, TTFT p50/p99, hit rate, and the
-    restored-vs-recomputed token split; ``tiered.summary`` pins the
-    tier's hit-rate and TTFT-p99 wins over the LRU arm (the
-    acceptance meters)."""
-    vocab = getattr(config, "valid_vocab_size", None) or config.vocab_size
-    replay = make_skewed_replay(
-        n_requests=n_requests, n_prefixes=n_prefixes, prefix_len=prefix_len,
-        suffix_lens=suffix_lens, max_new=max_new, vocab=vocab, seed=seed,
-        zipf_a=zipf_a,
-    )
-
-    def requests():
-        return [Request(prompt=p, max_new_tokens=n) for p, n in replay]
-
-    chunk = prefill_chunk or page_size
-    arms = {
-        "baseline": {},
-        "chunked": {"prefill_chunk": chunk},
-        "cached": {"prefix_cache": True},
-        "cached+chunked": {"prefill_chunk": chunk, "prefix_cache": True},
-    }
-    if include_speculative:
-        arms["cached+spec"] = {
-            "prefill_chunk": chunk, "prefix_cache": True,
-            "speculative": tuple(speculative),
-        }
-    quant_labels = set()
-    if include_quant:
-        # quant arms ride the full cached+chunked configuration — the
-        # production shape — so the int8 rows answer "what does
-        # quantization cost/buy ON TOP of the PR 6 engine", and the
-        # shared-page/COW paths run quantized in the same breath
-        for qlabel, qkw in (("int8w", {"weight_dtype": "int8"}),
-                            ("int8kv", {"kv_dtype": "int8"}),
-                            ("int8w+int8kv", {"weight_dtype": "int8",
-                                              "kv_dtype": "int8"})):
-            arms[qlabel] = {"prefill_chunk": chunk, "prefix_cache": True,
-                            **qkw}
-            quant_labels.add(qlabel)
-    results = {}
-    arm_traces = {}
-    for label, kw in arms.items():
-        engine = ServingEngine(
-            params, config, num_slots=num_slots, num_pages=num_pages,
-            page_size=page_size, max_context=max_context, mesh=mesh,
-            param_specs=param_specs, tp_axis=tp_axis, **kw,
-        )
-        # two warmups: the first is COLD (compiles the miss paths and
-        # seeds the cache), the second exercises the WARM hit paths
-        # (short-tail chunk buckets, COW) so nothing compiles inside
-        # the measured replay
-        engine.run(requests())
-        engine.run(requests())
-        outs, metrics = engine.run(requests())
-        # TTFT quantiles through the shared telemetry Histogram (the
-        # registry's single source of truth for percentile math — same
-        # sorted-reservoir index rule the exporters report)
-        h_ttft = Histogram(f"replay.{label}.ttft_seconds")  # standalone
-        for o in outs:
-            if o.ttft_s is not None:  # shed rows carry no TTFT
-                h_ttft.observe(o.ttft_s)
-        row = {
-            "decode_tokens_per_s": metrics["decode_tokens_per_s"],
-            "ttft_p50_s": round(h_ttft.quantile(0.5), 6),
-            "ttft_p99_s": round(h_ttft.quantile(0.99), 6),
-            "decode_steps": metrics["decode_steps"],
-            "wall_time_s": metrics["wall_time_s"],
-        }
-        if trace:
-            # one EXTRA traced replay on the warm engine — attribution
-            # without perturbing the measured run above
-            from pipegoose_tpu.telemetry.reqtrace import RequestTracer
-
-            tracer = RequestTracer(registry=engine.registry,
-                                   keep_completed=max(n_requests, 1))
-            engine.attach_tracer(tracer)
-            engine.run(requests())
-            arm_traces[label] = tracer.attribution_summary()
-            engine.attach_tracer(None)
-        # one basis for every arm: prompt tokens the engine actually
-        # forwarded (metrics["prefill_tokens"]), so the cached arms'
-        # reduction divides like-for-like against the baseline
-        row["prefill_tokens"] = metrics["prefill_tokens"]
-        if label in quant_labels:
-            mem = engine.memory_report()
-            row["weights_bytes"] = mem["weights"]["total_bytes"]
-            row["kv_bytes"] = mem["kv"]["total_bytes"]
-            row["page_capacity_ratio"] = mem["kv"]["page_capacity_ratio"]
-        if "max_decode_gap_s" in metrics:
-            row["max_decode_gap_s"] = metrics["max_decode_gap_s"]
-        if "prefix_cache" in metrics:
-            row["hit_rate"] = metrics["prefix_cache"]["hit_rate"]
-        if "speculative" in metrics:
-            row["spec_acceptance_rate"] = (
-                metrics["speculative"]["acceptance_rate"])
-        results[label] = row
-    base = results["baseline"]
-    cached = results["cached"]
-    results["summary"] = {
-        "requests": n_requests,
-        "shared_prefix_len": prefix_len,
-        "hit_rate": cached.get("hit_rate", 0.0),
-        "prefill_token_reduction": round(
-            1.0 - cached["prefill_tokens"] / max(base["prefill_tokens"], 1),
-            4,
-        ),
-        "ttft_p99_speedup": round(
-            base["ttft_p99_s"] / max(cached["ttft_p99_s"], 1e-9), 3
-        ),
-        "tokens_per_s_speedup": round(
-            cached["decode_tokens_per_s"]
-            / max(base["decode_tokens_per_s"], 1e-9), 3,
-        ),
-    }
-    if include_quant:
-        both = results["int8w+int8kv"]
-        cc = results["cached+chunked"]
-        results["summary"]["quant"] = {
-            # the acceptance meters: HBM multiplier of the int8 pool and
-            # the throughput ratio vs the same engine at fp — both from
-            # THIS run's rows, not a spec sheet
-            "kv_page_capacity_ratio": both["page_capacity_ratio"],
-            "tokens_per_s_vs_fp_cached": round(
-                both["decode_tokens_per_s"]
-                / max(cc["decode_tokens_per_s"], 1e-9), 3,
-            ),
-            "ttft_p99_vs_fp_cached": round(
-                both["ttft_p99_s"] / max(cc["ttft_p99_s"], 1e-9), 3,
-            ),
-        }
-    if trace:
-        bt, ct = arm_traces["baseline"], arm_traces["cached"]
-        b_ttft = bt["mean_ttft_s"] or 0.0
-        c_ttft = ct["mean_ttft_s"] or 0.0
-        b_pre = bt["mean_ttft_components"]["prefill_s"]
-        c_pre = ct["mean_ttft_components"]["prefill_s"]
-        results["request_trace"] = {
-            "arms": arm_traces,
-            # where did the cached arm's TTFT win come from? The queue
-            # and prefill components decompose it, and the cache-savings
-            # share (hit tokens / prompt tokens) must account for the
-            # prefill-side reduction — ≈ prefill_token_reduction by
-            # construction (both count the same hits)
-            "summary": {
-                "baseline_mean_ttft_s": b_ttft,
-                "cached_mean_ttft_s": c_ttft,
-                "ttft_improvement_s": b_ttft - c_ttft,
-                "baseline_prefill_component_s": b_pre,
-                "cached_prefill_component_s": c_pre,
-                "prefill_component_reduction_s": b_pre - c_pre,
-                "cache_hit_share": ct["cache_hit_share"],
-                "prefill_token_reduction": (
-                    results["summary"]["prefill_token_reduction"]
-                ),
-                "cached_mean_cache_saved_est_s": (
-                    ct["mean_cache_saved_est_s"]
-                ),
-            },
-        }
-    if include_tiered:
-        from pipegoose_tpu.serving.kv_tier import HostTier
-
-        overflow = make_skewed_replay(
-            n_requests=n_requests, n_prefixes=n_prefixes,
-            prefix_len=prefix_len, suffix_lens=suffix_lens,
-            max_new=max_new, vocab=vocab, seed=seed + 1, zipf_a=zipf_a,
-            working_set_factor=tiered_working_set, num_pages=num_pages,
-            page_size=page_size,
-        )
-
-        def overflow_requests():
-            return [Request(prompt=p, max_new_tokens=n) for p, n in overflow]
-
-        def tier_engine(**kw):
-            return ServingEngine(
-                params, config, num_slots=num_slots, num_pages=num_pages,
-                page_size=page_size, max_context=max_context, mesh=mesh,
-                param_specs=param_specs, tp_axis=tp_axis,
-                prefill_chunk=chunk, prefix_cache=True, **kw,
-            )
-
-        def tier_row(engine, warmups=2):
-            for _ in range(warmups):
-                engine.run(overflow_requests())
-            outs, m = engine.run(overflow_requests())
-            h = Histogram("replay.tiered.ttft_seconds")  # standalone
-            for o in outs:
-                if o.ttft_s is not None:
-                    h.observe(o.ttft_s)
-            row = {
-                "decode_tokens_per_s": m["decode_tokens_per_s"],
-                "ttft_p50_s": round(h.quantile(0.5), 6),
-                "ttft_p99_s": round(h.quantile(0.99), 6),
-                "wall_time_s": m["wall_time_s"],
-                "hit_rate": m["prefix_cache"]["hit_rate"],
-                # the restore-vs-recompute split: prefill_tokens is the
-                # FLOP meter (what WAS recomputed), restored/pulled are
-                # tier/wire tokens that were not
-                "recomputed_tokens": m["prefill_tokens"],
-                "restored_tokens": m.get("kv_tier", {}).get(
-                    "restored_tokens", 0),
-                "pulled_tokens": m.get("kv_tier", {}).get(
-                    "pulled_tokens", 0),
-            }
-            return row, engine
-
-        tiered = {}
-        tiered["lru"], _ = tier_row(tier_engine())
-        tiered["host_tier"], warm_engine = tier_row(
-            tier_engine(host_tier=HostTier(tiered_budget_bytes)))
-        # fleet arm: a COLD replica (fresh cache, no tier) pulls from
-        # the warm host_tier engine above — one warm run compiles the
-        # puller; pulls keep happening in the measured run because the
-        # overflow working set evicts between runs
-        puller = tier_engine()
-        puller.set_peer_source(warm_engine)
-        tiered["fleet_pull"], _ = tier_row(puller, warmups=1)
-        lru, ht = tiered["lru"], tiered["host_tier"]
-        tiered["summary"] = {
-            "working_set_factor": tiered_working_set,
-            "hit_rate_lru": lru["hit_rate"],
-            "hit_rate_tiered": ht["hit_rate"],
-            "ttft_p99_speedup_vs_lru": round(
-                lru["ttft_p99_s"] / max(ht["ttft_p99_s"], 1e-9), 3),
-            "recompute_token_reduction": round(
-                1.0 - ht["recomputed_tokens"]
-                / max(lru["recomputed_tokens"], 1), 4),
-        }
-        results["tiered"] = tiered
-    return results

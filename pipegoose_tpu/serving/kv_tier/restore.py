@@ -126,8 +126,8 @@ class RestoreManager:
 
     Owns the lazily compiled transfer programs (one self-transfer for
     spill/restore, one :class:`PoolTransfer` per peer engine for
-    pulls), the per-run restored/pulled token accounting the bench
-    reads, and the one-probe-per-request bookkeeping that keeps the
+    pulls), the per-run restored/pulled token accounting ``finish_run``
+    reports, and the one-probe-per-request bookkeeping that keeps the
     hit/miss counters request-scoped rather than tick-scoped. Created
     by every paged-prefill engine (cheap — nothing compiles until the
     first spill or pull), so any engine with a prefix cache can serve
@@ -138,7 +138,7 @@ class RestoreManager:
         self.planner = RestorePlanner()
         self._self_xfer: Optional[PoolTransfer] = None
         self._peer_xfers: Dict[int, PoolTransfer] = {}
-        # uid -> peer engine: the control plane's (or bench's) routing
+        # uid -> peer engine: the control plane's routing
         # hint that a specific peer holds this request's prefix
         self.pull_hints: Dict[int, Any] = {}
         self.default_peer = None
@@ -152,7 +152,7 @@ class RestoreManager:
     # -- wiring ------------------------------------------------------------
 
     def set_peer_source(self, peer) -> None:
-        """Default pull source for every request (bench/tests; the
+        """Default pull source for every request (demo/tests; the
         control plane hints per request instead)."""
         self.default_peer = peer
 
@@ -233,8 +233,6 @@ class RestoreManager:
         re-walked every tick."""
         eng = self.engine
         sched = eng.sched
-        if not sched.continuous:
-            return
         while sched.queue and any(s is None for s in sched.slots):
             req = sched.queue[0]
             if req.uid in self._handled:

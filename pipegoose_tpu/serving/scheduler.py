@@ -33,12 +33,6 @@ shapes); the scheduler's job is to keep those slots full:
   cache for the shared prefix) and resumes decoding with the last
   generated token pending — token-for-token identical to an
   uninterrupted run.
-
-``continuous=False`` turns the same machinery into the naive padded
-baseline: a batch is admitted only into an EMPTY slot set and drains
-fully before the next one — slots idle behind the batch's longest
-member exactly the way padded ``generate`` rows do, which is the A/B
-the serving bench measures.
 """
 from __future__ import annotations
 
@@ -157,9 +151,8 @@ class Request:
 
 class Scheduler:
     def __init__(self, num_slots: int, pool: PagePool, max_context: int,
-                 continuous: bool = True, prefix_cache=None,
-                 chunk_tokens: Optional[int] = None, tracer=None,
-                 prefill_only: bool = False):
+                 prefix_cache=None, chunk_tokens: Optional[int] = None,
+                 tracer=None, prefill_only: bool = False):
         if num_slots < 1:
             raise ValueError("need at least one decode slot")
         if chunk_tokens is not None and (
@@ -172,7 +165,6 @@ class Scheduler:
         self.num_slots = num_slots
         self.pool = pool
         self.max_context = max_context
-        self.continuous = continuous
         self.cache = prefix_cache
         self.chunk_tokens = chunk_tokens
         # disaggregated prefill pool (serving/disagg/): requests here
@@ -355,8 +347,6 @@ class Scheduler:
         very admission it predicts."""
         if not any(s is None for s in self.slots):
             return False
-        if not self.continuous and any(s is not None for s in self.slots):
-            return False  # naive padded batching: drain before refill
         return self._admission_check(req)[0]
 
     def capacity_snapshot(self) -> dict:
@@ -437,8 +427,6 @@ class Scheduler:
         empty chunks at a time)."""
         self._shed_expired(now)
         admitted: List[Request] = []
-        if not self.continuous and any(s is not None for s in self.slots):
-            return admitted  # naive padded batching: drain before refill
         while self.queue:
             free_slots = [i for i, s in enumerate(self.slots) if s is None]
             if not free_slots:

@@ -15,7 +15,7 @@ registry spans. Adding this callback turns on:
   executable, so it is off by default — enable it for small models or
   pass ``flops_per_step`` measured offline for big ones. XLA reports
   the PER-DEVICE SPMD program's flops, and the peak table is per chip,
-  so the resulting MFU is per-device (the number bench.py quotes);
+  so the resulting MFU is per-device;
 - ``train.comm_bytes_per_step`` gauge from the same probe;
 - ``train.hbm_utilization`` gauge every ``hbm_every`` steps (0 = off;
   CPU backends report no memory stats and the gauge stays unset);

@@ -14,9 +14,9 @@ emitted. This module turns those into operator-facing numbers:
 - ``hbm_utilization``: live HBM occupancy from ``device_memory_stats``
   (empty off-TPU — CPU devices report no memory stats).
 
-``PEAK_FLOPS`` is the single source of truth for per-chip peak bf16
-FLOP/s — bench.py imports it from here rather than keeping its own
-copy.
+``PEAK_FLOPS`` is the library's one table of per-chip peak bf16
+FLOP/s (``TelemetryCallback``'s MFU and the planner's cost model read
+it).
 """
 from __future__ import annotations
 
@@ -122,8 +122,8 @@ def _kind_lookup(table: Dict[str, float], device_kind: Optional[str],
 
 
 def peak_flops_for(device_kind: Optional[str] = None) -> float:
-    """Peak FLOP/s for a device-kind string (substring match, like
-    bench.py always did); defaults to the first visible device. An
+    """Peak FLOP/s for a device-kind string (substring match);
+    defaults to the first visible device. An
     unknown kind raises, here and in the peer lookups below."""
     return _kind_lookup(PEAK_FLOPS, device_kind, "PEAK_FLOPS")
 

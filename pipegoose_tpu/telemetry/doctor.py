@@ -38,9 +38,9 @@ Reports serialize (``to_json``/``from_json``), pretty-print
 (``doctor.replicated_bytes``, ``doctor.resharding_bytes``,
 ``doctor.hbm_peak_bytes`` — :func:`set_doctor_gauges`) next to MFU.
 Entry points: :func:`diagnose` (any jitted/plain callable),
-``Trainer.doctor()``, ``ServingEngine.doctor()``, the
-``scripts/mesh_doctor.py`` CLI, and bench.py's ``BENCH_DOCTOR_JSON``
-artifact. See docs/observability.md ("Mesh doctor").
+``Trainer.doctor()``, ``ServingEngine.doctor()`` and the
+``scripts/mesh_doctor.py`` CLI. See docs/observability.md ("Mesh
+doctor").
 """
 from __future__ import annotations
 
@@ -237,8 +237,8 @@ class MemoryReport:
     top: List[dict]               # largest buffers: {path, per_device_bytes, role}
     # arg-group label -> {dtype string -> per-device bytes}: the dtype
     # split of each group, so a quantized serving engine's weight and
-    # KV-page drop reads straight off /debug/doctor and
-    # BENCH_DOCTOR_JSON (None on reports from older artifacts)
+    # KV-page drop reads straight off /debug/doctor (None on reports
+    # from older artifacts)
     by_dtype: Optional[Dict[str, Dict[str, int]]] = None
 
     def to_json(self) -> dict:

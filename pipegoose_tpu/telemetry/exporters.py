@@ -10,8 +10,8 @@ Two complementary shapes, both plain files (no daemon, no deps):
 - ``JSONLExporter`` — an append-only event stream (one JSON object per
   line). Attach it to a registry and every ``registry.event(...)`` /
   span exit lands as a line; ``export_snapshot`` additionally embeds a
-  full metrics snapshot as a ``"snapshot"`` event. The format bench.py
-  and scripts consume for time series (occupancy, step durations).
+  full metrics snapshot as a ``"snapshot"`` event: a time series
+  (occupancy, step durations) any line reader can follow.
 - ``PrometheusTextfileExporter`` — the node-exporter textfile-collector
   convention: one atomic snapshot file a scraper ingests. Written via
   tmp+rename so a concurrent scrape never sees a torn file.
@@ -65,7 +65,7 @@ class JSONLExporter:
                  rank: Optional[int] = 0, mode: str = "a"):
         """``mode="a"`` (default) appends across exporter lifetimes —
         one long-lived stream; ``mode="w"`` truncates on first write,
-        for per-run artifacts (bench.py) where stale events from a
+        for per-run artifacts where stale events from a
         previous attempt must not interleave."""
         if mode not in ("a", "w"):
             raise ValueError(f"mode must be 'a' or 'w', got {mode!r}")

@@ -276,9 +276,9 @@ class FleetTracer:
     def reset(self) -> None:
         """Drop every stitched trace (active, completed ring, tail,
         uid index) but keep replica registrations and the trace-id
-        sequence — the bench's traced arm resets between the compile
-        warmup and the measured replay so warmup traces never land in
-        the reported attribution."""
+        sequence — reset between a compile warmup and the measured
+        replay so warmup traces never land in the reported
+        attribution."""
         with self._lock:
             self.active.clear()
             self.completed.clear()
@@ -555,7 +555,7 @@ class FleetTracer:
 
     def summary_payload(self, top_n: int = 3) -> Dict[str, Any]:
         """Per-hop p50/p99 over the completed ring + top-N exemplars
-        per objective — the ``bench_fleet_trace.json`` block."""
+        per objective."""
         with self._lock:
             done = [t for t in self.completed if not t.lost]
             rows = [(t.hops(), t.replica_s()) for t in done]

@@ -586,8 +586,7 @@ class MemoryLedger:
         return report
 
     def run_summary(self) -> dict:
-        """Compact per-run block for ``finish_run`` metrics and the
-        bench rows: peaks, conservation verdict, audit tallies, and
+        """Compact per-run block for ``finish_run`` metrics: peaks, conservation verdict, audit tallies, and
         the forecast floor — the memory trajectory one JSONL row can
         carry."""
         bpp = self.bytes_per_page

@@ -370,7 +370,7 @@ def _prom_value(v: float) -> str:
 #
 # Library instrumentation targets this registry; it starts DISABLED so
 # un-observed runs pay only the enabled-flag branch. Entry points that
-# want telemetry (TelemetryCallback, bench.py, examples/telemetry_demo)
+# want telemetry (TelemetryCallback, examples/telemetry_demo)
 # call enable().
 _default = MetricsRegistry(enabled=False)
 

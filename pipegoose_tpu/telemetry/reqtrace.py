@@ -17,16 +17,15 @@ request's latency into additive wall-clock components:
 
 The four components are CONTIGUOUS lifecycle segments, accumulated at
 each phase transition, so by construction they sum to the measured
-submit→done e2e exactly (the replay bench pins the sum within 1%).
+submit→done e2e exactly (tests/serving/test_request_tracing.py pins
+the sum within 1%).
 TTFT decomposes the same way: ``ttft_components`` snapshots the
 accumulators at the first-token instant, so ``ttft = queue + prefill
 (+ stall)`` — the question "is p99 TTFT queueing or compute?" becomes a
 field lookup. Cache savings cannot be a wall segment of the SAME run
 (the hit time never happened); it is estimated from the per-token
 prefill rate this request actually paid:
-``cache_saved_est_s = prefill_s * hit_tokens / forwarded_tokens``, and
-the replay bench's per-arm summary cross-checks that estimate against
-the baseline arm's measured TTFT.
+``cache_saved_est_s = prefill_s * hit_tokens / forwarded_tokens``.
 
 Completed timelines land in ``serving.attrib.*`` histograms (one
 observation per request per component), a bounded ``completed`` ring
@@ -667,8 +666,7 @@ class RequestTracer(NullRequestTracer):
 
     def attribution_summary(self) -> Dict[str, Any]:
         """Aggregate attribution over the completed ring: per-request
-        rows plus component means and the cache-hit share — the per-arm
-        block ``bench_request_trace.json`` is built from."""
+        rows plus component means and the cache-hit share."""
         with self._lock:
             done = list(self.completed)
         rows = [tl.attribution() for tl in done]

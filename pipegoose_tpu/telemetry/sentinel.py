@@ -24,13 +24,14 @@ twin of the doctor's compile-time guards:
 - every observation exports the ``perf.{compute,comm,idle}_fraction``
   gauges (when the run carries a profile) and ``perf.tokens_per_s``.
 
-Baselines can be seeded from ``BENCH_HISTORY.jsonl`` — the one-row-
-per-bench-run perf trajectory bench.py appends — via
+Baselines can be seeded from a ``BENCH_HISTORY.jsonl`` — one JSON row
+of components per earlier run; nothing in the repo writes one any more
+(ROADMAP C5a) — via
 :func:`read_bench_history` / :meth:`PerfSentinel.from_history`, so a
 fresh process compares its first run against the recorded trajectory
 instead of flying blind. Everything is opt-in and host-side: nothing
-observes unless a caller (``ServingEngine(sentinel=...)``, bench.py)
-passes a sentinel, and the disabled cost is one attribute read +
+observes unless a caller (``ServingEngine(sentinel=...)``) passes a
+sentinel, and the disabled cost is one attribute read +
 branch (guard-tested < 5 µs, the established contract).
 """
 from __future__ import annotations
@@ -148,8 +149,7 @@ class PerfSentinel:
         cls, path: str, device: Optional[str] = None, **kwargs: Any
     ) -> "PerfSentinel":
         """A sentinel whose baseline window is seeded from the tail of
-        ``BENCH_HISTORY.jsonl`` — the machine-readable perf trajectory
-        bench.py appends one row per run to.
+        ``BENCH_HISTORY.jsonl``, one row of components per earlier run.
 
         Rows carrying a ``perf_regression`` stamp are SKIPPED (the
         regressed-runs-never-enter-the-baseline invariant holds across

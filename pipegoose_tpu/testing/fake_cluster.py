@@ -5,9 +5,9 @@ The ONE way this repo simulates a TPU slice on a host: XLA's
 pin, so jit/shard_map programs compile and run against a real N-device
 mesh without hardware (the reference spawned N OS processes over
 gloo/TCP instead — testing/utils.py:32-41; SURVEY.md §4). Previously
-copy-pasted between bench.py, tests/conftest.py, the mesh-doctor CLI,
-and every example; now bench, the parallelism planner
-(pipegoose_tpu/planner/), the CLIs, and the test suite all call here.
+copy-pasted between tests/conftest.py, the mesh-doctor CLI and every
+example; now the parallelism planner (pipegoose_tpu/planner/), the
+CLIs, and the test suite all call here.
 
 Two entry points, split by WHEN they may run:
 

@@ -195,48 +195,6 @@ assert ratio >= 1.8, f"page capacity {ratio} < 1.8x"
 print(f"quant smoke OK: greedy token-identical, {ratio}x page capacity")
 PY
 
-# Control-plane router smoke (serving/control_plane/, ISSUE 12): two
-# replicas serving the same multi-tenant Zipf-skewed replay — the
-# cache-aware arm must forward strictly fewer prefill tokens than
-# round-robin (placement turns hit rate from luck into a decision),
-# and a forced scale-down drain must migrate in-flight work and finish
-# every request with token streams identical to the no-drain run.
-echo "== control-plane router smoke (2 replicas, cache-aware vs RR) =="
-env $JAX_SERVING_CACHE_ENV python - <<'PY'
-from pipegoose_tpu.testing import force_cpu_devices
-
-force_cpu_devices(1)
-
-import jax
-
-from pipegoose_tpu.models import bloom
-from pipegoose_tpu.serving.control_plane import (
-    control_plane_replay_benchmark,
-)
-
-cfg = bloom.BloomConfig(vocab_size=64, hidden_size=32, n_layer=2, n_head=2)
-params = bloom.init_params(cfg, jax.random.PRNGKey(0))
-# one implementation of the warmup/clear/measure/drain choreography —
-# the packaged benchmark bench.py and the TPU sweep also run
-res = control_plane_replay_benchmark(
-    params, cfg, n_requests=12, n_prefixes=3, prefix_len=48,
-    suffix_lens=(2, 4), max_new=2, n_tenants=3, n_replicas=2,
-    num_slots=1, num_pages=33, page_size=8, max_context=96,
-)
-rr, ca = res["round_robin"], res["cache_aware"]
-assert ca["prefill_tokens"] < rr["prefill_tokens"], (ca, rr)
-assert res["summary"]["prefill_token_reduction"] > 0, res["summary"]
-assert ca["shed_requests"] == 0 and rr["shed_requests"] == 0
-drain = res["drain"]
-assert drain["performed"] and drain["dropped"] == 0, drain
-assert drain["outputs_token_identical"] is True, drain
-print(f"router smoke OK: cache-aware forwarded {ca['prefill_tokens']} vs "
-      f"round-robin {rr['prefill_tokens']} prefill tokens "
-      f"({res['summary']['prefill_token_reduction']:.0%} reduction); "
-      f"drain dropped {drain['dropped']} of {drain['finished']} "
-      f"(token-identical)")
-PY
-
 # Disagg smoke (serving/disagg/, ISSUE 13): a 2-pool CPU run — prefill
 # pool streaming int8 KV pages into a decode pool — must emit token
 # streams identical to one monolithic engine, with the tracer's new

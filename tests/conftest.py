@@ -36,7 +36,7 @@ jax.config.update("jax_platforms", "cpu")
 # thresholds drop to 0 because these programs each compile in
 # milliseconds — the default 1s floor would cache nothing.
 # JAX's own variable where set; else the checkout's fixed path, the
-# same one chip_smoke.py and bench.py use.
+# same one chip_smoke.py and benchmark/run.py use.
 JAX_CACHE_DIR = os.environ.get(
     "JAX_COMPILATION_CACHE_DIR",
     os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

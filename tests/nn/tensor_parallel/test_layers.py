@@ -209,8 +209,8 @@ def test_chunked_ce_matches_plain(ctx):
     """chunked_ce_sums == full-logits CE (loss AND grads), single-device
     and under TP, with a ragged mask and a chunk-count that doesn't
     divide the sequence (pad path). The chunking bounds the logits
-    working set to 1/n_chunks — the 8 GB fp32 buffer fix of
-    docs/perf_tpu_v5e.md."""
+    working set to 1/n_chunks (the 8 GB fp32 buffer at bloom-560m's
+    b8 x s1024)."""
     import dataclasses
 
     from pipegoose_tpu.models import bloom

@@ -1,6 +1,6 @@
 """Continuous-batching scheduler lifecycle: FIFO admission against the
-page budget, lazy page growth, eviction/reclamation, and the
-``continuous=False`` degradation to naive padded batching."""
+page budget, lazy page growth, eviction/reclamation, and the refill
+of a slot freed mid-stream."""
 import numpy as np
 import pytest
 
@@ -77,22 +77,20 @@ def test_eos_finishes_early_and_frees_slot():
     assert a.t_first_token == a.t_done == 1.0
 
 
-def test_continuous_refills_mid_stream_static_drains():
-    """The one-flag A/B the serving bench builds on: continuous admission
-    backfills a freed slot immediately; static waits for a full drain."""
-    def drive(continuous):
-        sched = Scheduler(2, PagePool(33, 4), max_context=32,
-                          continuous=continuous)
-        for _ in range(3):
-            sched.submit(_req(4, 4, eos=5), now=0.0)
-        first = sched.admit(now=0.0)
-        assert len(first) == 2
-        sched.record_token(first[0], 5, now=1.0)  # finishes, slot frees
-        sched.record_token(first[1], 1, now=1.0)  # still decoding
-        return sched.admit(now=2.0)
-
-    assert len(drive(continuous=True)) == 1   # backfilled mid-stream
-    assert len(drive(continuous=False)) == 0  # drains first
+def test_freed_slot_is_refilled_mid_stream():
+    """Continuous admission: a slot freed while its neighbour is still
+    decoding is refilled by the very next ``admit``."""
+    sched = Scheduler(2, PagePool(33, 4), max_context=32)
+    reqs = [_req(4, 4, eos=5) for _ in range(3)]
+    for r in reqs:
+        sched.submit(r, now=0.0)
+    first = sched.admit(now=0.0)
+    assert [r.uid for r in first] == [0, 1]
+    sched.record_token(first[0], 5, now=1.0)  # finishes, slot frees
+    sched.record_token(first[1], 1, now=1.0)  # still decoding
+    assert [r.uid for r in sched.admit(now=2.0)] == [2]
+    assert first[1].status is not Status.DONE
+    assert sorted(r.uid for r in sched.slots) == [1, 2]
 
 
 def test_preempt_requeues_in_original_submit_order():

@@ -30,7 +30,7 @@ SERVING_DEMOS = {
 }
 CACHE_ENV = {
     # JAX's own variable where set; else the checkout's fixed path,
-    # the same one chip_smoke.py and bench.py use
+    # the same one chip_smoke.py and benchmark/run.py use
     "JAX_COMPILATION_CACHE_DIR": os.environ.get(
         "JAX_COMPILATION_CACHE_DIR", str(REPO / ".jax_cache")),
     "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
