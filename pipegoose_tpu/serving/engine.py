@@ -103,6 +103,8 @@ from pipegoose_tpu.serving.kv_pool import (
     init_pages,
     paged_decode_step,
     paged_prefill_chunk,
+    walk_plan,
+    walked_chunks,
     write_prompt_pages,
 )
 from pipegoose_tpu.serving.kv_tier.restore import (
@@ -167,6 +169,7 @@ class _RunState:
         "prefills", "chunks", "spec_drafted", "spec_accepted",
         "occ_slots", "occ_pages", "stalled", "tick", "t_last_decode",
         "max_gap", "step_time", "phase_s", "table", "seq_lens", "tokens",
+        "keys_walked", "keys_reached",
     )
 
     def __init__(self, engine: "ServingEngine", now, tick_hook):
@@ -187,6 +190,8 @@ class _RunState:
         self.t_last_decode: Optional[float] = None
         self.max_gap = 0.0
         self.step_time = 0.0            # summed decode-step wall time
+        # key columns the plain decode steps walked / could have reached
+        self.keys_walked = self.keys_reached = 0
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.table = np.zeros((engine.num_slots, engine.table_width),
                               np.int32)
@@ -276,12 +281,17 @@ class ServingEngine:
         attribute read + branch (guard-tested < 5 µs).
 
         ``attn_kernel`` ("gather" | "paged", default "gather"): decode/
-        chunk attention implementation. "paged" routes every paged
-        program (decode step, speculative draft/verify, chunked
+        chunk attention implementation. "gather" reads the pool's rows
+        as they are stored (kv_pool._attend_rows): the page table is
+        walked in chunks of whole pages, only as far as the longest
+        live sequence of the call, each chunk's rows contracted on the
+        matrix unit against a block-diagonal query, never split into
+        heads or widened. ``finish_run()["decode_key_share"]`` (gauge
+        ``serving.decode_key_share``) is the share of the table's key
+        columns the plain decode steps walked. "paged" routes every
+        paged program (decode step, speculative draft/verify, chunked
         prefill) through the fused Pallas kernel
-        (ops/paged_attention.py) — one HBM pass over raw pages at wire
-        precision, no contiguous KV materialization. "gather" is the
-        two-pass XLA reference the kernel is parity-pinned against."""
+        (ops/paged_attention.py), a grid step a page."""
         t_build = time.perf_counter()
         if max_context % page_size:
             raise ValueError("max_context must be a multiple of page_size")
@@ -356,6 +366,7 @@ class ServingEngine:
         # room half of the admission ledger, and the router's tie-break
         self._m_evictable = reg.gauge("serving.prefix_cache.evictable_pages")
         self._m_frag = reg.gauge("serving.pool.fragmentation")
+        self._m_key_share = reg.gauge("serving.decode_key_share")
         self._m_prefill_tok = reg.counter("serving.prefill_tokens_total")
         self._m_chunks = reg.counter("serving.prefill_chunks_total")
         self._m_gap = reg.histogram("serving.decode_gap_seconds")
@@ -385,6 +396,10 @@ class ServingEngine:
         self.kv_dtype = check_kv_dtype(kv_dtype)
         check_attn_impl(attn_kernel)
         self.attn_kernel = attn_kernel
+        # key columns a trip of the "gather" read's walk visits, and all
+        # a table reaches: the host counts what the decode program walks
+        self._walk_keys = walk_plan(page_size, self.table_width)[0] * page_size
+        self._reach_keys = self.table_width * page_size
         self.quant_spec = None
         if weight_dtype is not None:
             from pipegoose_tpu.quant import (
@@ -1530,6 +1545,12 @@ class ServingEngine:
             rs.t_last_decode = t
             rs.steps += 1
             rs.step_time += t - t_step
+            if not use_spec:
+                # the program's own arithmetic on the lengths it was sent
+                rs.keys_walked += min(
+                    walked_chunks(int(rs.seq_lens.max()), self._walk_keys)
+                    * self._walk_keys, self._reach_keys)
+                rs.keys_reached += self._reach_keys
             slot_occ = len(active) / self.num_slots
             page_occ = self.pool.used_count / self.pool.capacity
             rs.occ_slots += slot_occ
@@ -1709,6 +1730,13 @@ class ServingEngine:
                 },
             },
         }
+        if self.attn_kernel == "gather":
+            # key columns the plain decode steps walked over those their
+            # tables reach: how far the read's walk engaged
+            share = (rs.keys_walked / rs.keys_reached
+                     if rs.keys_reached else 0.0)
+            metrics["decode_key_share"] = round(share, 6)
+            self._m_key_share.set(share)
         if self._paged_prefill:
             metrics["prefill_chunks"] = rs.chunks
             metrics["max_decode_gap_s"] = round(rs.max_gap, 6)

@@ -14,7 +14,16 @@ row, heads major (:func:`init_pages` says why), and every program
 updates the pool IN PLACE: rows are addressed by (layer, page, offset)
 on the donated buffer, never on a slice of it. Values keep
 ``(.., nh, hd)`` at the edges (:func:`gather_pages`, the wire slabs).
-Three pieces live here:
+
+The attention READ (:func:`_attend_rows`, the default
+``attn_impl="gather"``) takes the rows as they are stored: a page
+table is walked in chunks of whole pages (:func:`walk_plan`), only as
+far as the furthest live query of the call (:func:`walked_chunks`, a
+trip count on the device), and each gathered chunk, still ``nh*hd``
+lanes of the pool's dtype, is contracted on the matrix unit against a
+block-diagonal query under an online softmax. No row is split into
+heads or widened in memory, and the pages past the longest live
+sequence are not read (PERF.md, PR 32). Four pieces live here:
 
 - :class:`PagePool` — the HOST-side free-list allocator. Allocation is a
   LIFO stack pop, so placement is deterministic given the request/evict
@@ -29,11 +38,14 @@ Three pieces live here:
   share this one program shape).
 - :func:`paged_decode_step` — one decode step over the ragged active
   batch: each slot's pending token is written through its page table,
-  attention reads the gathered page view, and invalid key columns
+  attention reads the pool's rows through it, and invalid key columns
   (beyond ``seq_lens``, stale page tails, null-page garbage) are masked
   to exactly zero softmax weight. Both run ONE layer loop with the SAME
-  qkv projection and attention core as the contiguous path
-  (models/generate.py:_qkv_proj/_attn_core) so numerics cannot drift.
+  qkv projection as the contiguous path (models/generate.py:_qkv_proj)
+  and the arithmetic of its attention core (``_attn_core``: operands in
+  the cache's dtype, float32 scores, softmax and accumulation,
+  probabilities rounded before the value product), which the parity
+  tests hold it to.
 - :func:`write_prompt_pages` — scatter a prefill's contiguous cache
   into the pool, repacking a LEFT-padded prompt to logical positions
   0..len-1 (the unpadded layout the decode bias assumes).
@@ -44,9 +56,10 @@ engine pairs the local logits with ``global_greedy_pick``.
 
 ``init_pages(kv_dtype="int8")`` swaps each bank for an int8 pytree with
 a per-page scale plane (one fp32 per layer/page-slot/head): writes
-quantize (:func:`quantize_kv`), the attention gather dequantizes
-(:func:`gather_pages`), ``copy_page`` COW-copies values and scales
-together, and every signature stays identical — the quantized pool is
+quantize (:func:`quantize_kv`), the attention read applies the
+scales to its scores and probabilities (the reconstruction,
+:func:`gather_pages`, dequantizes), ``copy_page`` COW-copies values
+and scales together, and every signature stays identical — the quantized pool is
 a drop-in for the fp one at ~``hd/(hd+4)``x fewer KV bytes per page.
 """
 from __future__ import annotations
@@ -59,7 +72,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from pipegoose_tpu.models.bloom import NEG_INF, alibi_slopes, bloom_gelu, layer_norm, logits_fn
-from pipegoose_tpu.models.generate import _attn_core, _qkv_proj
+from pipegoose_tpu.models.generate import _qkv_proj
 from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.nn.tensor_parallel.layers import (
     column_parallel_linear,
@@ -72,6 +85,11 @@ NULL_PAGE = 0
 KV_DTYPES = (None, "fp", "int8")
 
 ATTN_IMPLS = ("gather", "paged")
+
+# key columns one trip of the "gather" read's walk visits, in whole
+# pages (:func:`walk_plan`). Measured on the chip at 128 / 256 / 512
+# (PERF.md, PR 32).
+WALK_KEYS = 256
 
 
 def check_attn_impl(attn_impl: str) -> str:
@@ -354,31 +372,27 @@ def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size):
     return _write_rows(k_pages, idx, k_seq), _write_rows(v_pages, idx, v_seq)
 
 
-def _gather(arr, page_table, layer):
+def _gather(arr, page_table):
     """Read ``(.., P, ps, X)`` through a (B, W) page table: the table
     dims replace the page dim, then W and ps merge into the contiguous
-    (.., B, W*ps, X) view. With ``layer``, one gather addressed by
-    (layer, page) reads a whole bank: no plane is sliced out first."""
+    (.., B, W*ps, X) view."""
     _, w = page_table.shape
-    if layer is None:
-        view = jnp.take(arr, page_table, axis=-3)
-    else:
-        view = arr[layer, page_table]
+    view = jnp.take(arr, page_table, axis=-3)
     return view.reshape(view.shape[:-3] + (w * arr.shape[-2], arr.shape[-1]))
 
 
-def gather_pages(pages, page_table, head_dim: int, layer=None):
+def gather_pages(pages, page_table, head_dim: int):
     """Read the pool through a page table: (B, W) int32 -> the per-slot
     contiguous view (.., B, W * page_size, nh, hd), the rows split back
-    into heads of ``head_dim``. The read path of the paged attention
-    (``layer``: one layer of a whole bank, picked inside the gather);
-    exposed for the reconstruction tests. An int8 bank dequantizes HERE
-    — inside the gather, per (position, head) — so the attention core
-    sees fp values and the pool keeps 1-byte pages."""
+    into heads of ``head_dim``, an int8 bank dequantized per (position,
+    head). The RECONSTRUCTION of what a table holds: the oracle of the
+    parity tests (with :func:`_key_bias` and ``_attn_core``) and of
+    ``ops/paged_attention.py``. No program reads the pool this way: the
+    decode read is :func:`_attend_rows`, over the rows as stored."""
     if _is_quantized(pages):
-        q = _heads(_gather(pages["q"], page_table, layer), head_dim)
-        return dequantize_kv(q, _gather(pages["scale"], page_table, layer))
-    return _heads(_gather(pages, page_table, layer), head_dim)
+        q = _heads(_gather(pages["q"], page_table), head_dim)
+        return dequantize_kv(q, _gather(pages["scale"], page_table))
+    return _heads(_gather(pages, page_table), head_dim)
 
 
 def page_size_of(pages) -> int:
@@ -413,6 +427,112 @@ def _key_bias(slopes, q_pos, n_keys):
     return bias + jnp.where(keep[:, None, :, :], 0.0, NEG_INF)
 
 
+def walk_plan(page_size: int, table_width: int) -> Tuple[int, int]:
+    """How the "gather" read walks a (B, W) page table: (pages a trip,
+    trips that cover the table). A trip visits whole pages, about
+    ``WALK_KEYS`` key columns; a narrower table is one trip."""
+    pages = max(1, min(table_width, WALK_KEYS // page_size))
+    return pages, -(-table_width // pages)
+
+
+def walked_chunks(max_pos, chunk_keys: int):
+    """Trips the walk makes when the furthest live query stands at
+    position ``max_pos`` (a position inside the table): every chunk up
+    to the one that holds it. Pure arithmetic, on a traced value and on
+    the host's int alike: the decode program takes its trip count from
+    it and the engine its ``decode_key_share``."""
+    return max_pos // chunk_keys + 1
+
+
+def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
+                 out_dtype):
+    """Softmax attention of ``q`` (B, C, nh, hd) at global positions
+    ``pos`` (B, C) over layer ``layer`` of the pool, read through
+    ``page_table`` (B, W) AS STORED: a gathered row keeps its
+    ``nh*hd`` lanes and the pool's dtype, and is never split into heads
+    or widened in memory.
+
+    All heads' scores come from one matrix-unit contraction of the rows
+    against a block-diagonal query (row ``c*nh + h`` holds q[c, h] in
+    head h's lanes and zeros elsewhere, so the other heads' lanes add
+    exact zeros); the context product gives every (query, head) row all
+    ``nh*hd`` lanes, of which the head keeps its own. Accumulation and
+    softmax are float32; the probabilities are rounded to the operands'
+    dtype before the value product, as :func:`_attn_core` rounds them.
+    An int8 bank's per-(position, head) scales multiply the scores and
+    the probabilities, never a dequantized copy of the rows.
+
+    The keys are visited in chunks of whole pages (:func:`walk_plan`)
+    under an online softmax, and only as far as the furthest live query:
+    :func:`walked_chunks` of the largest position among the queries
+    ``qmask`` keeps. Chunks beyond are not gathered; inside the walk the
+    bias masks what ``_key_bias`` masks (columns past a query's own
+    position: unwritten offsets, stale tails, NULL-page garbage).
+    Returns (B, C, nh*hd) in ``out_dtype``, pad queries zero."""
+    b, c, nh, hd = q.shape
+    n, width = c * nh, nh * hd
+    ps = page_size_of(k_pages)
+    pages, n_chunks = walk_plan(ps, page_table.shape[1])
+    chunk_keys = pages * ps
+    # a last chunk that overhangs the table reads NULL pages, whose key
+    # positions lie past every query's
+    table = jnp.pad(page_table,
+                    ((0, 0), (0, pages * n_chunks - page_table.shape[1])),
+                    constant_values=NULL_PAGE)
+    quantized = _is_quantized(k_pages)
+    operand = q.dtype if quantized else k_pages.dtype
+    # own[h, r]: lane r of a row belongs to head h
+    own = jnp.arange(width)[None, :] // hd == jnp.arange(nh)[:, None]
+    q_bd = jnp.where(own, _rows(q)[:, :, None, :], 0)
+    q_bd = q_bd.reshape(b, n, width).astype(operand)
+    q_pos = jnp.repeat(pos, nh, axis=1)[:, :, None]          # (B, C*nh, 1)
+    slope = jnp.tile(slopes, c)[None, :, None]               # (1, C*nh, 1)
+    live = pos if qmask is None else jnp.where(qmask, pos, 0)
+    trips = jnp.minimum(walked_chunks(jnp.max(live), chunk_keys), n_chunks)
+
+    def rows_of(bank, ids):
+        return _values(bank)[layer, ids].reshape(
+            b, chunk_keys, width).astype(operand)
+
+    def scales_of(bank, ids):
+        """(B, K, nh) -> the scores' layout (B, C*nh, K)."""
+        s = bank["scale"][layer, ids].reshape(b, chunk_keys, nh)
+        return jnp.tile(jnp.swapaxes(s, 1, 2), (1, c, 1))
+
+    def chunk(i, carry):
+        m, denom, acc = carry
+        ids = lax.dynamic_slice_in_dim(table, i * pages, pages, axis=1)
+        key_pos = i * chunk_keys + jnp.arange(chunk_keys)
+        s = jnp.einsum("bnr,bkr->bnk", q_bd, rows_of(k_pages, ids),
+                       preferred_element_type=jnp.float32)
+        if quantized:
+            s = s * scales_of(k_pages, ids)
+        s = s * (hd ** -0.5) + slope * key_pos.astype(jnp.float32)
+        s = s + jnp.where(key_pos <= q_pos, 0.0, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        denom = denom * alpha + p.sum(-1)
+        if quantized:
+            p = p * scales_of(v_pages, ids)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bnk,bkr->bnr", p.astype(operand), rows_of(v_pages, ids),
+            preferred_element_type=jnp.float32)
+        return m_new, denom, acc
+
+    _, denom, acc = lax.fori_loop(0, trips, chunk, (
+        jnp.full((b, n), NEG_INF, jnp.float32),
+        jnp.zeros((b, n), jnp.float32),
+        jnp.zeros((b, n, width), jnp.float32)))
+    # position 0 is a key of every query, so denom >= 1
+    ctx = (acc / denom[..., None]).reshape(b, c, nh, width)
+    ctx = jnp.sum(jnp.where(own, ctx, 0.0), axis=2)          # (B, C, nh*hd)
+    if qmask is not None:
+        # pad-query context is ZERO in every attention path
+        ctx = ctx * qmask[:, :, None].astype(ctx.dtype)
+    return ctx.astype(out_dtype)
+
+
 def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                    dest_page, dest_off, qmask, config, tp_axis, attn_impl,
                    n_layers=None):
@@ -420,22 +540,19 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     global positions ``pos`` (B, C) through the first ``n_layers``
     blocks (all by default) and the final layer norm. The pool rides
     the layer loop's CARRY: layer ``l`` writes its B x C rows at (l,
-    dest_page, dest_off) and attention reads through a gather addressed
-    by (l, page_table), so the donated pool is updated in place —
-    scanned in and stacked out (xs/ys) it is copied once a call and
-    each layer's plane twice more. Returns (hidden, k_pages, v_pages)."""
+    dest_page, dest_off) and attention reads through gathers addressed
+    by (l, page) (:func:`_attend_rows`), so the donated pool is updated
+    in place — scanned in and stacked out (xs/ys) it is copied once a
+    call and each layer's plane twice more. Returns (hidden, k_pages,
+    v_pages)."""
     check_attn_impl(attn_impl)
     b, c = tokens.shape
-    n_keys = page_table.shape[1] * page_size_of(k_pages)
-    hd = config.head_dim
 
     x = vocab_parallel_embedding(params["embed"], tokens, tp_axis)
     x = x.astype(config.dtype)
     x = layer_norm(params["embed_ln"], x, config.layer_norm_epsilon)
     slopes = _local_slopes(config, tp_axis)
     all_layers, num_pages = _values(k_pages).shape[:2]
-    if attn_impl == "gather":
-        bias = _key_bias(slopes, pos, n_keys)
 
     def layer(l, carry):
         h, kp, vp = carry
@@ -456,9 +573,8 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                 ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
             ctx = ctx.astype(h.dtype).reshape(b, c, -1)
         else:
-            keys = gather_pages(kp, page_table, hd, layer=l)
-            vals = gather_pages(vp, page_table, hd, layer=l)
-            ctx = _attn_core(q, keys, vals, bias, qmask, h.dtype)
+            ctx = _attend_rows(q, kp, vp, l, page_table, pos, qmask, slopes,
+                               h.dtype)
         h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
         ln2 = layer_norm(blk["ln_2"], h, config.layer_norm_epsilon)
         up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
@@ -482,8 +598,8 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     token), ``seq_lens`` (B,) the number of tokens already cached per
     slot — the pending token's position. Each slot's k/v row is written
     in place through its ``page_table`` (B, W) row at page ``seq_len //
-    ps``, offset ``seq_len % ps``; attention reads the gathered page
-    view (the loop is :func:`_paged_forward`'s). Padded slots must
+    ps``, offset ``seq_len % ps``; attention reads the pool's rows
+    through it (the loop is :func:`_paged_forward`'s). Padded slots must
     point every table entry at the NULL page (their writes and reads
     are garbage-in/garbage-out, masked by the bias and discarded by the
     scheduler).
@@ -499,10 +615,12 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     byte-identical values, since layer i's k/v depend only on the token
     sequence and layers < i).
 
-    ``attn_impl``: ``"gather"`` (default) materializes the page view
-    (gather_pages + _attn_core, the parity reference); ``"paged"`` walks
-    the page table in one fused Pallas pass (ops/paged_attention.py) —
-    same mask/bias semantics, int8 pages dequantized in-register.
+    ``attn_impl``: ``"gather"`` (default) walks the page table in
+    chunks of whole pages as far as the longest ``seq_lens`` and
+    contracts the gathered rows as stored (:func:`_attend_rows`);
+    ``"paged"`` walks it a page a grid step in one fused Pallas pass
+    (ops/paged_attention.py) — same mask/bias semantics, int8 pages
+    dequantized in-register.
 
     Returns (logits (B, V_local), k_pages, v_pages). Under ``tp_axis``
     the logits are the LOCAL vocab shard — pair with
@@ -611,7 +729,7 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     ``attn_impl="paged"`` reads through the fused Pallas page-table
     walk in its ragged multi-token mode — the decode step's kernel, with
     ``start`` as the per-row global query origin; pad queries beyond
-    ``n_valid`` are zeroed by the gather path's qmask multiply.
+    ``n_valid`` are zeroed by the same qmask multiply.
     """
     c = tokens.shape[1]
     ps = page_size_of(k_pages)

@@ -10,6 +10,7 @@ The topology is described inside a fixture of THIS file only: the worker
 that runs the file loads the TPU library, every other worker never does.
 """
 import functools
+import math
 import re
 
 import jax
@@ -26,7 +27,7 @@ from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.quant.matmul import quantized_matmul
-from pipegoose_tpu.serving import ServingEngine
+from pipegoose_tpu.serving import ServingEngine, kv_pool
 from pipegoose_tpu.serving.kv_pool import import_page_slab
 
 # bloom-560m: hidden 1024, 16 heads x 64, padded vocab 250880; train
@@ -342,7 +343,8 @@ POOL_L, POOL_PAGES, POOL_SLOTS, POOL_CONTEXT, POOL_BUCKET = 2, 320, 2, 256, 64
 POOL_PROGRAMS = ("step", "write", "chunk", "copy", "import")
 
 
-def _pool_program(one_chip, heads, program):
+def _pool_program(one_chip, heads, program, context=POOL_CONTEXT,
+                  chunk_tokens=PS):
     """(lowered program, one bank's bytes) of a default engine at small
     depth and pool: 2 slots x 16 table entries reach 32 of 320 pages, so
     nothing a program may legitimately gather is as large as a plane."""
@@ -360,7 +362,7 @@ def _pool_program(one_chip, heads, program):
         lambda k: bloom.init_params(cfg, k), jax.random.PRNGKey(0)))
     eng = ServingEngine(params, cfg, num_slots=POOL_SLOTS,
                         num_pages=POOL_PAGES, page_size=PS,
-                        max_context=POOL_CONTEXT)
+                        max_context=context)
     kp, vp = sds(eng.k_pages), sds(eng.v_pages)
     assert kp.shape == (POOL_L, POOL_PAGES, PS, nh * hd)
     slots, width = eng.num_slots, eng.table_width
@@ -368,7 +370,7 @@ def _pool_program(one_chip, heads, program):
         low = eng._step.lower(params, vec(slots), kp, vp, vec(slots, width),
                               vec(slots))
     elif program == "chunk":
-        low = eng._chunk.lower(params, vec(slots, PS), kp, vp,
+        low = eng._chunk.lower(params, vec(slots, chunk_tokens), kp, vp,
                                vec(slots, width), vec(slots), vec(slots))
     elif program == "write":
         cache = jax.tree_util.tree_map(sds, jax.eval_shape(
@@ -406,3 +408,54 @@ def test_pool_program_updates_the_pool_in_place(one_chip, program, heads):
         if elements % plane == 0:
             moved.append(m.group(0))
     assert not moved, moved
+
+
+# The decode read (PERF.md, PR 32). Until PR 32 the step gathered the
+# table's full width, widened the view to float32 and split it into
+# (.., nh, hd): 36 GB moved a step for ~1,200 live tokens. The read now
+# walks the table a chunk of pages at a time and contracts the rows as
+# stored; like the layout above, that holds in the compiled program or
+# not at all.
+
+# 80 table entries, five chunks of 256 keys: neither the view's element
+# count nor a chunk's is a weight's, nor (at 8 tokens a prefill chunk)
+# the (slots, tokens x nh, nh x hd) float32 accumulator's
+READ_CONTEXT, READ_TOKENS = 1280, 8
+
+
+@pytest.mark.parametrize("heads", sorted(POOL_HEADS))
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_decode_read_keeps_the_rows_as_stored(one_chip, program, heads):
+    """No array of the compiled program has the full gathered view's
+    element count (slots x table width x page size x nh x hd), in any
+    dtype (the walk gathers a chunk), and none is float32 or split into
+    (.., nh, hd) at the size of a chunk either; the pool still rides in
+    place and the walk is a loop inside the layer loop."""
+    nh, hd = POOL_HEADS[heads]
+    low, bank_bytes = _pool_program(one_chip, heads, program,
+                                    context=READ_CONTEXT,
+                                    chunk_tokens=READ_TOKENS)
+    compiled = low.compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == 2 * bank_bytes
+    text = compiled.as_text()
+    view = POOL_SLOTS * READ_CONTEXT * nh * hd
+    chunk = POOL_SLOTS * kv_pool.WALK_KEYS * nh * hd
+    assert view == 5 * chunk
+    widened, split, whole = [], [], []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        elements = math.prod(dims)
+        if elements == view:
+            whole.append(m.group(0))
+        if elements in (chunk, view):
+            if m.group(1) == "f32":
+                widened.append(m.group(0))
+            if dims[-2:] == [nh, hd]:
+                split.append(m.group(0))
+    assert not whole, whole[:3]
+    assert not widened, widened[:3]
+    assert not split, split[:3]
+    # a gather of one chunk's rows exists, in the pool's dtype
+    assert re.search(r"bf16\[(\d+,)*%d\]\S* (fusion|gather)\(" % (nh * hd),
+                     text)
+    assert text.count(" while(") == 2

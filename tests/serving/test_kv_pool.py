@@ -451,3 +451,143 @@ def test_int8_copy_page_of_transferred_page_carries_scale_plane(heads):
         np.asarray(gather_pages(kp, jnp.asarray([[7]], jnp.int32), cfg.head_dim)),
         np.asarray(gather_pages(kp, jnp.asarray([[1]], jnp.int32), cfg.head_dim)),
     )
+
+
+# -- the decode read over the rows as stored (PR 32) -------------------------
+#
+# ``_attend_rows`` contracts the gathered rows (B, K, nh*hd) against a
+# block-diagonal query, a chunk of whole pages at a time, as far as the
+# furthest live query. Its oracle is what the read was before: the
+# reconstruction (gather_pages) through _attn_core under _key_bias.
+
+READ_PS, READ_W, READ_WALK = 4, 7, 8     # 7 pages of 4: 3.5 chunks of 8 keys
+READ_FULL = READ_PS * READ_W - 1         # the table's last position
+# the largest valid position of each of three rows, at the walk's seams
+# (chunk = 8 keys); a row at 0 with no page of its own is a dead slot
+READ_LENGTHS = {
+    "empty": (0, 0, 0),
+    "one": (1, 0, 0),
+    "chunk-1": (READ_WALK - 1, 3, 0),
+    "chunk": (READ_WALK, 0, READ_WALK - 1),
+    "chunk+1": (READ_WALK + 1, READ_WALK, 1),
+    "full_among_dead": (READ_FULL, 0, 0),
+}
+
+
+def _oracle_read(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
+                 out_dtype):
+    """``_attend_rows``'s signature over the reconstructed view."""
+    from pipegoose_tpu.serving import kv_pool
+
+    hd = q.shape[-1]
+    k_l, v_l = jax.tree_util.tree_map(lambda a: a[layer], (k_pages, v_pages))
+    bias = kv_pool._key_bias(
+        slopes, pos, page_table.shape[1] * kv_pool.page_size_of(k_pages))
+    return gen._attn_core(q, gather_pages(k_l, page_table, hd),
+                          gather_pages(v_l, page_table, hd), bias, qmask,
+                          out_dtype)
+
+
+def _random_banks(cfg, num_pages, kv_dtype, seed, poison=None):
+    """Two banks of random values; page ``poison`` all NaN (an int8
+    bank's scales), so a read that touches it shows."""
+    from pipegoose_tpu.serving.kv_pool import _rows, quantize_kv
+
+    rng = np.random.RandomState(seed)
+    shape = (cfg.n_layer, num_pages, READ_PS, cfg.n_head, cfg.head_dim)
+
+    def bank():
+        vals = rng.randn(*shape).astype(np.float32)
+        if kv_dtype is None:
+            if poison is not None:
+                vals[:, poison] = np.nan
+            return _rows(jnp.asarray(vals))
+        q, scale = quantize_kv(jnp.asarray(vals))
+        if poison is not None:
+            scale = scale.at[:, poison].set(jnp.nan)
+        return {"q": _rows(q), "scale": scale}
+
+    return bank(), bank()
+
+
+@pytest.mark.parametrize("lengths", sorted(READ_LENGTHS))
+@pytest.mark.parametrize("c", [1, 3], ids=["c1", "c3"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_row_read_matches_the_reconstruction(monkeypatch, heads, kv_dtype, c,
+                                             lengths):
+    """The read over rows as stored gives the reconstruction's context
+    at every seam of the walk, and touches no page of a chunk past the
+    furthest live query: those hold NaN here."""
+    from pipegoose_tpu.serving import kv_pool
+
+    monkeypatch.setattr(kv_pool, "WALK_KEYS", READ_WALK)
+    cfg = _config(heads, n_layer=2)
+    nh, hd = cfg.n_head, cfg.head_dim
+    poison = 11
+    kp, vp = _random_banks(cfg, 12, kv_dtype, seed=len(lengths), poison=poison)
+    last = np.asarray(READ_LENGTHS[lengths])
+    n_valid = np.minimum(c, last + 1)
+    start = last - (n_valid - 1)
+    pos = jnp.asarray(start[:, None] + np.arange(c)[None, :], jnp.int32)
+    qmask = None if c == 1 else jnp.asarray(
+        np.arange(c)[None, :] < n_valid[:, None])
+    rng = np.random.RandomState(7)
+    # a live row owns pages up to its last position; a dead one none
+    table = np.zeros((3, READ_W), np.int32)
+    for r, n in enumerate(last):
+        if n or r == 0:
+            owned = n // READ_PS + 1
+            table[r, :owned] = rng.choice(np.arange(1, poison), owned,
+                                          replace=False)
+    pages, _ = kv_pool.walk_plan(READ_PS, READ_W)
+    walked = kv_pool.walked_chunks(int(last.max()), pages * READ_PS) * pages
+    poisoned = table.copy()
+    poisoned[:, walked:] = poison
+    q = jnp.asarray(rng.randn(3, c, nh, hd), jnp.float32)
+    slopes = jnp.asarray(bloom.alibi_slopes(nh))
+    got = kv_pool._attend_rows(q, kp, vp, 1, jnp.asarray(poisoned), pos,
+                               qmask, slopes, jnp.float32)
+    want = _oracle_read(q, kp, vp, 1, jnp.asarray(table), pos, qmask, slopes,
+                        jnp.float32)
+    assert got.shape == (3, c, nh * hd)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["fp", "int8"])
+@pytest.mark.parametrize("heads", sorted(HEADS))
+def test_paged_programs_match_the_reconstruction_read(monkeypatch, heads,
+                                                      kv_dtype, program):
+    """The decode step (one row ``write_ok=False``) and a ragged prefill
+    chunk through the whole layer loop: logits and both banks are what
+    the reconstruction read gives, walked a chunk of two pages at a
+    time over rows that end in different chunks."""
+    from pipegoose_tpu.serving import kv_pool
+
+    monkeypatch.setattr(kv_pool, "WALK_KEYS", READ_WALK)
+    cfg, params = _model(heads, n_layer=2)
+    kp, vp = _random_banks(cfg, 12, kv_dtype, seed=3)
+    table = jnp.asarray([[2, 4, 7, 9, 0, 0, 0], [5, 1, 0, 0, 0, 0, 0],
+                         [0] * READ_W], jnp.int32)
+    seq = jnp.asarray([13, 6, 0], jnp.int32)
+
+    def run():
+        if program == "step":
+            return paged_decode_step(
+                params, jnp.asarray([7, 9, 0], jnp.int32), kp, vp, table, seq,
+                cfg, write_ok=jnp.asarray([True, False, True]))
+        ids = jnp.asarray(np.random.RandomState(4).randint(1, 64, (3, 3)))
+        return kv_pool.paged_prefill_chunk(
+            params, ids, kp, vp, table, seq, jnp.asarray([3, 2, 0]), cfg,
+            all_logits=True)
+
+    got = run()
+    monkeypatch.setattr(kv_pool, "_attend_rows", _oracle_read)
+    want = run()
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               atol=2e-4)
+    for g, w in zip(jax.tree_util.tree_leaves(got[1:]),
+                    jax.tree_util.tree_leaves(want[1:])):
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), atol=2e-4)
