@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import gc
 import json
+import os
 import time
 
 import numpy as np
@@ -26,6 +27,9 @@ from benchmark import harness, program_bloom, traffic, weights
 from benchmark.reference import bloom_ref
 
 PAD_TO = 128        # the reference compiles one program per padded length
+# upper edges (ms) of the gap histogram on the ``serve`` line
+GAP_EDGES_MS = (4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 14, 16, 18, 20, 25, 30, 40,
+                60, 100, 1000)
 
 
 def _warm_up(engine, Request, buckets, vocab):
@@ -45,21 +49,31 @@ class Book:
     def __init__(self):
         self.watch = []          # (request, planned) not yet finished
         self.times = {}          # id(request) -> [t of each token]
+        self.stalled = {}        # id(request) -> [the gap BEFORE each token
+        #                          held another request's prefill]
         self.rows = []           # (request, planned, times) finished
 
     def add(self, req, planned):
         self.watch.append((req, planned))
         self.times[id(req)] = []
+        self.stalled[id(req)] = []
 
     def after_tick(self, t, done_status):
-        """Stamp tokens that appeared in this tick."""
+        """Stamp tokens that appeared in this tick. A tick in which some
+        request got its first token ran that request's prefill before
+        its decode step: every other request's gap ending here held it."""
+        admitted = any(req.generated and not self.times[id(req)]
+                       for req, _ in self.watch)
         still = []
         for req, planned in self.watch:
             ts = self.times[id(req)]
             n = len(req.generated)
             if n > len(ts):
+                held = admitted and bool(ts)
                 if not ts:
                     ts.append(req.t_first_token)
+                    self.stalled[id(req)].append(False)
+                self.stalled[id(req)].extend([held] * (n - len(ts)))
                 ts.extend([t] * (n - len(ts)))
             if req.status is done_status:
                 self.rows.append((req, planned, ts))
@@ -68,13 +82,35 @@ class Book:
         self.watch = still
 
 
+def _phase_clock(engine):
+    """The engine's running ``tick_phase_s`` accumulators, the dict that
+    ``finish_run()`` reports at the end, or None on a program that keeps
+    none: read after every tick, their differences are one tick's phases."""
+    return getattr(getattr(engine, "_run", None), "phase_s", None)
+
+
+def _phase_facts(names, per_tick):
+    """p50, p95 and the longest, in ms, of every phase over the window's
+    ticks; of ``prefill`` over the ticks that held one."""
+    out = {}
+    for i, name in enumerate(names):
+        xs = [1e3 * row[i] for row in per_tick
+              if name != "prefill" or row[i] > 0.0]
+        if xs:
+            out[name] = {"p50": harness.percentile(xs, 50),
+                         "p95": harness.percentile(xs, 95), "max": max(xs)}
+    return out
+
+
 def run(ctx):
+    t_run = time.perf_counter()
     import jax
     import jax.numpy as jnp
 
     from pipegoose_tpu.serving import Request, ServingEngine
     from pipegoose_tpu.serving.scheduler import Status
 
+    t_imported = time.perf_counter()
     w = ctx.workload
     sizes = ctx.config["sizes"]
     vocab = sizes["vocab_size"]
@@ -82,17 +118,27 @@ def run(ctx):
     spec = dict(w["traffic"], page_size=w["engine"]["page_size"])
     key = weights.seed_key(ctx.seed)
 
-    params = jax.jit(lambda k: program_bloom.to_tree(
-        weights.make(k, sizes, dtype)))(key)
+    params = jax.block_until_ready(jax.jit(lambda k: program_bloom.to_tree(
+        weights.make(k, sizes, dtype)))(key))
+    t_weights = time.perf_counter()
     engine = ServingEngine(params, program_bloom.make_config(ctx.config),
                            **w["engine"])
     del params
+    t_engine = time.perf_counter()
     _warm_up(engine, Request, spec["prompt_buckets"], vocab)
+    t_warm = time.perf_counter()
     seconds = ctx.seconds
     plan = traffic.plan(spec, vocab, ctx.seed,
                         traffic.n_requests(spec, seconds))
+    print("setup " + json.dumps({
+        "chip_to_driver_s": t_run - ctx.t_chip,
+        "import_s": t_imported - t_run, "weights_s": t_weights - t_imported,
+        "engine_build_s": t_engine - t_weights,
+        "warm_up_s": t_warm - t_engine,
+        "plan_s": time.perf_counter() - t_warm,
+        "lowerings_and_compiles": ctx.watch.count}), flush=True)
 
-    book, ticks, late = Book(), [], []
+    book, ticks, late, tick_phases = Book(), [], [], []
     nxt = 0
 
     def submit(t_now):
@@ -106,17 +152,23 @@ def run(ctx):
         late.append(t_now - p.due_s)
 
     def tick(t0):
+        before = tuple(clock.values()) if clock is not None else None
         with harness.annotate("serve.tick"):
             engine.tick_once()
         t = time.perf_counter()
+        if before is not None:
+            tick_phases.append(tuple(
+                b - a for a, b in zip(before, clock.values())))
         live = sum(r.cached_len for r in engine.sched.active()
                    if r.status is Status.DECODE)
         ticks.append((t - t0, live))
         book.after_tick(t, Status.DONE)
 
     compiles = ctx.watch.count
+    load_avg = os.getloadavg()
     with harness.traced_window(ctx):
         engine.start_run([], now=time.perf_counter)
+        clock = _phase_clock(engine)
         t0 = time.perf_counter()
         while True:
             now = time.perf_counter() - t0
@@ -138,6 +190,7 @@ def run(ctx):
             tick(t0)
         drained_at = time.perf_counter() - t0
     harness.refuse_compiles(ctx, compiles)
+    phase_names = tuple(clock or ())
     _, run_metrics = engine.finish_run()
     peak = harness.memory_peak_bytes(ctx.devices)
 
@@ -155,6 +208,9 @@ def run(ctx):
     pairs = [(a, b) for _, _, ts in done + cut for a, b in zip(ts, ts[1:])]
     gaps = [1e3 * (b - a) for a, b in pairs]
     in_window = [1e3 * (b - a) for a, b in pairs if b - t0 <= seconds]
+    held = [h for r, _, _ in done + cut for h in book.stalled[id(r)][1:]]
+    stalled = [g for g, h in zip(gaps, held) if h]
+    plain = [g for g, h in zip(gaps, held) if not h]
     e2e = {"itl_p95_ms": harness.percentile(gaps, 95)}
     print("serve " + json.dumps({
         "planned": len(plan), "submitted": nxt, "finished": len(done),
@@ -167,6 +223,17 @@ def run(ctx):
         "itl_gaps": len(gaps), "itl_gaps_in_window": len(in_window),
         "itl_p50_ms": harness.percentile(gaps, 50),
         "itl_p95_in_window_ms": harness.percentile(in_window, 95),
+        # a gap "holds a prefill" when its tick admitted another request
+        "itl_gaps_holding_prefill_pct": 100.0 * len(stalled) / len(gaps),
+        "itl_p50_holding_prefill_ms":
+            harness.percentile(stalled, 50) if stalled else None,
+        "itl_p50_plain_ms": harness.percentile(plain, 50) if plain else None,
+        "itl_p95_plain_ms": harness.percentile(plain, 95) if plain else None,
+        "itl_hist_upper_ms_count": _histogram(gaps),
+        # host from device in a run that reads high: the engine's own
+        # phase clock, tick by tick (prefill over the ticks that held one)
+        "tick_phase_ms": _phase_facts(phase_names, tick_phases),
+        "cpu_count": os.cpu_count(), "load_avg_at_start": list(load_avg),
         "slot_occupancy_pct": 100.0 * run_metrics["slot_occupancy"],
         "page_occupancy_pct": 100.0 * run_metrics["page_occupancy"],
         "longest_ticks_start_s_ms": _longest(ticks),
@@ -189,6 +256,14 @@ def run(ctx):
         extra={"cut_off": len(cut)},
         facts={"ticks": ticks, "run_metrics": run_metrics, "sizes": sizes,
                "peaks": ctx.peaks, "dtype": ctx.config["dtype"]})
+
+
+def _histogram(gaps):
+    """[upper edge in ms, gaps under it and not under the edge before]
+    for every edge that holds any: where p95 sits among the gaps."""
+    counts = np.histogram(gaps, bins=(0,) + GAP_EDGES_MS + (np.inf,))[0]
+    edges = GAP_EDGES_MS + (None,)
+    return [[e, int(c)] for e, c in zip(edges, counts) if c]
 
 
 def _longest(ticks, n=3):

@@ -44,6 +44,7 @@ def _feed(trainer, vocab, seed, batch, seq, first_step, n=None, deadline=None):
 
 
 def run(ctx):
+    t_run = time.perf_counter()
     import jax
     import jax.numpy as jnp
     import optax
@@ -55,6 +56,7 @@ def run(ctx):
     from pipegoose_tpu.optim.zero import DistributedOptimizer
     from pipegoose_tpu.trainer import Callback, Trainer
 
+    t_imported = time.perf_counter()
     w = ctx.workload
     sizes = ctx.config["sizes"]
     vocab = sizes["vocab_size"]
@@ -80,7 +82,9 @@ def run(ctx):
     specs = bloom.tp_specs(shapes)
     shard = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
                                    is_leaf=lambda x: isinstance(x, P))
-    params = jax.jit(fresh_tree, out_shardings=shard)(key)
+    params = jax.block_until_ready(
+        jax.jit(fresh_tree, out_shardings=shard)(key))
+    t_weights = time.perf_counter()
 
     def delta(p, k):
         now = program_bloom.from_tree(p)
@@ -97,11 +101,14 @@ def run(ctx):
 
         def __init__(self):
             self.grad_norm = self.delta_norm = None
+            self.step_ends = []      # host clock, the followed steps
             self._norms = jax.jit(lambda mu: bloom_ref.leaf_norms(
                 program_bloom.from_tree(mu)))
             self._delta = jax.jit(delta)
 
         def on_step_end(self, trainer, step, loss):
+            if step <= first_steps:
+                self.step_ends.append(time.perf_counter())
             if step == 1:
                 self.grad_norm = self._norms(trainer.opt_state.inner[0].mu)
             if step == first_steps:
@@ -137,11 +144,15 @@ def run(ctx):
     del params
 
     # the first steps: compile, warm up, and what the reference follows
+    t_built = time.perf_counter()
     trainer.fit(_feed(trainer, vocab, ctx.seed, batch, seq, 0, n=first_steps))
     first_losses = [float(x) for x in trainer.state.losses[:first_steps]]
     got_grad = {k: float(v) for k, v in probe.grad_norm.items()}
     got_delta = {k: float(v) for k, v in probe.delta_norm.items()}
     clock.seconds.clear()
+    print("setup " + json.dumps(setup_facts(
+        ctx, t_run, t_imported, t_weights, t_built, probe.step_ends,
+        first_steps)), flush=True)
 
     compiles = ctx.watch.count
     step0 = trainer.state.step
@@ -189,6 +200,24 @@ def run(ctx):
                "rows_per_replica": batch // dp, "tensor": tp,
                "chips": tp * dp, "sizes": sizes, "peaks": ctx.peaks,
                "dtype": ctx.config["dtype"], "memory_peak_bytes": peak})
+
+
+def setup_facts(ctx, t_run, t_imported, t_weights, t_built, step_ends,
+                first_steps) -> dict:
+    """Where a training cell's ``setup_s`` goes, by the host's clock:
+    the library's import, the seeded weights (waited for), the
+    ``Trainer``'s build, the first step to its hand-back (the compile or
+    the cache's read, then its dispatch), and all the steps the
+    reference follows with the fetch of their losses and norms."""
+    now = time.perf_counter()
+    return {"chip_to_driver_s": t_run - ctx.t_chip,
+            "import_s": t_imported - t_run,
+            "weights_s": t_weights - t_imported,
+            "trainer_build_s": t_built - t_weights,
+            "first_step_s": step_ends[0] - t_built if step_ends else None,
+            "followed_steps_s": now - t_built,
+            "steps_followed": first_steps,
+            "lowerings_and_compiles": ctx.watch.count}
 
 
 SPREAD_MIN = 1024     # leaves with no axis this long stay replicated

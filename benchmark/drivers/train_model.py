@@ -64,6 +64,7 @@ def _reference_steps(ctx, precision: str):
 
 
 def run(ctx):
+    t_run = time.perf_counter()
     import jax
     import jax.numpy as jnp
     import optax
@@ -78,6 +79,7 @@ def run(ctx):
     from pipegoose_tpu.telemetry import AuxRecorder
     from pipegoose_tpu.trainer import Callback, Trainer
 
+    t_imported = time.perf_counter()
     w = ctx.workload
     sizes = adapter.sizes(ctx.config)
     vocab = sizes["vocab_size"]
@@ -98,7 +100,9 @@ def run(ctx):
     specs = adapter.specs(shapes)
     shard = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), specs,
                                    is_leaf=lambda x: isinstance(x, P))
-    params = jax.jit(fresh_tree, out_shardings=shard)(key)
+    params = jax.block_until_ready(
+        jax.jit(fresh_tree, out_shardings=shard)(key))
+    t_weights = time.perf_counter()
 
     def delta(p, k):
         now = adapter.from_tree(p, ctx.config)
@@ -115,11 +119,14 @@ def run(ctx):
 
         def __init__(self):
             self.grad_norm = self.delta_norm = None
+            self.step_ends = []      # host clock, the followed steps
             self._norms = jax.jit(lambda mu: reference.leaf_norms(
                 adapter.from_tree(mu, ctx.config)))
             self._delta = jax.jit(delta)
 
         def on_step_end(self, trainer, step, loss):
+            if step <= first_steps:
+                self.step_ends.append(time.perf_counter())
             if step == 1:
                 self.grad_norm = self._norms(trainer.opt_state.inner[0].mu)
             if step == first_steps:
@@ -156,6 +163,7 @@ def run(ctx):
     del params
 
     # the first steps: compile, warm up, and what the reference follows
+    t_built = time.perf_counter()
     trainer.fit(_train._feed(trainer, vocab, ctx.seed, batch, seq, 0,
                              n=first_steps))
     first_losses = [float(x) for x in trainer.state.losses[:first_steps]]
@@ -165,6 +173,9 @@ def run(ctx):
     got_delta = {k: float(v) for k, v in probe.delta_norm.items()}
     clock.seconds.clear()
     first_counters = counters.take()
+    print("setup " + json.dumps(_train.setup_facts(
+        ctx, t_run, t_imported, t_weights, t_built, probe.step_ends,
+        first_steps)), flush=True)
 
     compiles = ctx.watch.count
     step0 = trainer.state.step

@@ -29,6 +29,9 @@ class Context:
     peaks: dict
     watch: object
     checks: object
+    # perf_counter when ``require_devices()`` returned: where ``setup_s``
+    # starts
+    t_chip: float = 0.0
     trace_summary: dict = None
     # left by a driver for its ``control`` (read_limits.py)
     reference: dict = None

@@ -1,19 +1,29 @@
 """One general traffic generator, driven by a workload's data file.
 
 Every seed gets the SAME multiset of prompt lengths, output lengths and
-inter-arrival gaps — the quantiles of the declared distributions — in
-another order, with other token ids. So runs differ by order and
-content, never by the amount of work.
+inter-arrival gaps — the quantiles of the declared distributions — with
+other token ids, and in another order unless the mix fixes one. So runs
+differ by content and at most by order, never by the amount of work.
 
     "traffic": {
       "rate_per_s": 4.0,                 # Poisson arrivals, open loop
+      "order_seed": 5105,                # optional: ONE order for every seed
       "prompt": {"dist": "lognormal", "median": 192, "sigma": 0.9,
                  "min": 32, "max": 1024},
       "output": {"dist": "lognormal", ...},
       "prompt_buckets": [64, 128, ...]   # snapped up, less 0..page_size-1
     }
 
-(``page_size`` comes from the workload's ``engine``.) A closed loop, a
+(``page_size`` comes from the workload's ``engine``.) Without
+``order_seed`` each seed permutes lengths and gaps afresh. With it every
+seed replays the ONE schedule that ``order_seed`` draws (which request
+arrives when, how long its prompt and its output are) and fills it with
+its own token ids. Under load a tail latency follows which requests
+overlap which (how many live rows each admission stalls, which long
+outputs fall into the drain): a fresh permutation a seed, and the one
+order entered at another request a seed, both moved ``itl_p95_ms`` by
+3-5% on one commit, more than a 10% bound can resolve (PERF.md, PR 34).
+A closed loop, a
 bursty arrival process or another length distribution is a branch here
 and a key there, added by the benchmark PR that brings the first cell
 to use it.
@@ -66,12 +76,14 @@ def plan(spec: dict, vocab_size: int, seed: int, n: int) -> list:
     """``n`` requests for this seed: the first is due at 0 and each next
     one a permuted gap later, so all ``n`` fall inside n / rate seconds."""
     rng = np.random.default_rng(int(seed))
+    order = (np.random.default_rng(int(spec["order_seed"]))
+             if "order_seed" in spec else rng)
     ps = spec["page_size"]
     buckets = spec["prompt_buckets"]
-    prompts = rng.permutation(draw_lengths(spec["prompt"], n))
-    outputs = rng.permutation(draw_lengths(spec["output"], n))
-    trims = rng.permutation(np.arange(n) % ps)
-    gaps = rng.permutation(exponential_gaps(spec["rate_per_s"], n))
+    prompts = order.permutation(draw_lengths(spec["prompt"], n))
+    outputs = order.permutation(draw_lengths(spec["output"], n))
+    trims = order.permutation(np.arange(n) % ps)
+    gaps = order.permutation(exponential_gaps(spec["rate_per_s"], n))
     due = np.concatenate([[0.0], np.cumsum(gaps[:-1])])
     out = []
     for i in range(n):

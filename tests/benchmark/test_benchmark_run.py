@@ -14,7 +14,8 @@ from tiny_root import build
 from benchmark import harness, rooflines, run
 from benchmark.reference import bloom_ref
 
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "process_start_s", "checks"}
 PEAKS = {"flops_per_s": {"bfloat16": 1e12}, "hbm_bytes_per_s": 1e11,
          "hbm_bytes": 1e10}
 
@@ -66,8 +67,18 @@ def test_train_cell_prints_the_contracts_last_line(root, capsys, no_chip_check,
         "loss_rel_gap_max", "grad_norm_gap_worst_leaf",
         "param_change_gap_worst_leaf"}
     assert all({"value", "limit", "ok"} <= set(c) for c in checks)
+    # ... and on the result line, as its last key
+    assert list(line)[-1] == "checks"
+    assert {k: (v["value"], v["limit"]) for k, v in line["checks"].items()} \
+        == {c["name"]: (c["value"], c["limit"]) for c in checks}
     # an option the program no longer has is dropped with a printed note
     assert any("an_option_a_later_pr_deleted" in x for x in out)
+    # where set-up went, by the host's clock
+    setup = json.loads(next(x for x in out if x.startswith("setup "))[6:])
+    assert {"import_s", "weights_s", "trainer_build_s", "first_step_s",
+            "followed_steps_s", "steps_followed"} <= set(setup)
+    assert setup["steps_followed"] == 3
+    assert 0 < setup["first_step_s"] <= setup["followed_steps_s"]
 
 
 def test_serve_cell_prints_the_contracts_last_line(root, capsys, no_chip_check):
@@ -77,8 +88,50 @@ def test_serve_cell_prints_the_contracts_last_line(root, capsys, no_chip_check):
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] == 40            # rate 20/s for 2 s, every seed
     assert line["cut_off"] == 0               # the drain finished them all
-    assert any(x.startswith("serve ") and "generator_late_ms" in x
-               for x in out)
+    serve = json.loads(next(x for x in out if x.startswith("serve "))[6:])
+    assert "generator_late_ms_p50" in serve
+    # host from device: the engine's phase clock tick by tick, the share
+    # of gaps whose tick admitted another request, the machine
+    assert {"dispatch", "fetch", "prefill"} <= set(serve["tick_phase_ms"])
+    assert all(p["p50"] <= p["p95"] <= p["max"]
+               for p in serve["tick_phase_ms"].values())
+    assert 0 < serve["itl_gaps_holding_prefill_pct"] < 100
+    assert sum(c for _, c in serve["itl_hist_upper_ms_count"]) \
+        == serve["itl_gaps"]
+    assert serve["cpu_count"] >= 1 and len(serve["load_avg_at_start"]) == 3
+    setup = json.loads(next(x for x in out if x.startswith("setup "))[6:])
+    assert {"import_s", "weights_s", "engine_build_s", "warm_up_s"} \
+        <= set(setup)
+
+
+@pytest.mark.parametrize("cell", ["tiny.train", "tiny.serve"])
+def test_setup_s_starts_when_the_chip_answers(root, capsys, monkeypatch, cell):
+    """The look for a chip (here a stand-in that takes a while, as the
+    backend coming up does) is not in ``setup_s``: it is
+    ``process_start_s``, a fact on the line that nothing judges."""
+    import time
+
+    answered = []
+
+    def slow_chip(chips):
+        time.sleep(0.5)
+        answered.append(time.perf_counter())
+        return jax.devices()[:chips]
+
+    monkeypatch.setattr(run, "require_devices", slow_chip)
+    monkeypatch.setattr(rooflines, "peaks_for", lambda kind: PEAKS)
+    t_call = time.perf_counter()
+    rc, line, _ = drive(root, capsys, cell, seconds=1)
+    t_done = time.perf_counter()
+    assert rc == 0 and line["correct"] is True
+    setup_s = line["metrics"]["setup_s"]["value"]
+    assert line["process_start_s"] == pytest.approx(
+        answered[0] - run.T_PROCESS_START, abs=0.2)
+    assert line["process_start_s"] >= 0.5
+    # set-up and the 1 s window both fit between the chip's answer and
+    # the end, so the half second before the answer is not in it
+    assert 0 < setup_s < (t_done - answered[0]) - 1.0
+    assert setup_s < (t_done - t_call) - 1.5
 
 
 def test_a_request_the_drain_does_not_finish_is_cut_off_not_failed(
