@@ -1,0 +1,170 @@
+"""What the paged programs and the engine know of a model: ONE
+description of its blocks and of its cache.
+
+``serving/kv_pool.py`` owns the pool, the page tables, the writes and
+the attention read; a model says, through a :class:`PagedModel`, how a
+token becomes a hidden state, what a layer computes before attention
+(``qkv``: norm, projections, rotary) and after it (``finish``: gate or
+none, output projection, feed-forward), and how a hidden state becomes
+logits. Layers come in :class:`LayerGroup` s: ``n`` layers of one shape
+stacked on a leading axis and walked by one ``fori_loop`` (BLOOM: one
+group), or a single unstacked layer where shapes differ (Laguna: a group
+a layer, 48 or 72 query heads, dense or sparse).
+
+A group's ``kind`` names the cache its layers keep:
+
+* ``"global"``: every page of a sequence, through a page table as wide
+  as ``max_context``;
+* ``"window"``: the last ``window + page_size`` keys in a ring of
+  ``ring_pages(window, page_size)`` pages, logical page ``j`` in ring
+  entry ``j % ring``; the read masks what has left the window.
+
+BLOOM is the first instance (:func:`bloom_model`), Laguna the second
+(``models/laguna.py:paged_model``). A config object that has a
+``paged_model(tp_axis)`` method describes itself; any other is taken
+for a BLOOM (:func:`describe`).
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Callable, Optional, Tuple
+
+GLOBAL, WINDOW = "global", "window"
+KINDS = (GLOBAL, WINDOW)
+
+
+@dataclass(frozen=True)
+class LayerGroup:
+    kind: str                 # the cache kind of the group's layers
+    n: int                    # layers in the group
+    stacked: bool             # params carry a leading (n,) axis
+    params: Callable          # params -> the group's subtree
+    # (blk, h (B, C, hidden), pos (B, C)) -> (q (B, C, H, hd),
+    #  k, v (B, C, KV, hd), saved): all before attention
+    qkv: Callable
+    # (blk, h, ctx (B, C, H * hd), saved, live (B, C) bool | None)
+    #  -> (h, counters | None): all after it
+    finish: Callable
+    # () -> (H,) ALiBi slopes of this shard's heads, called inside the
+    # program; None: no position bias on the scores (rotary models)
+    slopes: Optional[Callable] = None
+
+
+@dataclass(frozen=True)
+class PagedModel:
+    n_kv_head: int            # heads a cached row holds, all shards
+    head_dim: int
+    dtype: Any
+    groups: Tuple[LayerGroup, ...]
+    embed: Callable           # (params, tokens (B, C)) -> h (B, C, hidden)
+    final: Callable           # (params, h) -> the final norm's output
+    logits: Callable          # (params, h (B, C, hidden)) -> (B, C, V_local)
+    # the model's own forward over one bucketed prompt:
+    # (params, ids (1, S), mask (1, S)) -> (logits (1, V_local), cache)
+    # with cache {"k", "v"} (L, 1, S, KV, hd) for a one-kind model and
+    # {kind: {"k", "v"}} for a two-kind one
+    prefill: Callable
+    left_pad: bool = True     # the side a bucketed prompt is padded on
+    window: Optional[int] = None          # keys a window layer keeps
+    # the name of what the groups' ``finish`` brings out of a decode
+    # step (stacked over the layers that bring any); None: nothing
+    counters: Optional[str] = None
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return tuple(k for k in KINDS
+                     if any(g.kind == k for g in self.groups))
+
+    def layers_of(self, kind: str) -> int:
+        return sum(g.n for g in self.groups if g.kind == kind)
+
+    @property
+    def n_layer(self) -> int:
+        return sum(g.n for g in self.groups)
+
+
+def ring_pages(window: int, page_size: int) -> int:
+    """Pages in a window layer's ring: the window's keys can straddle
+    one page more than they fill."""
+    return -(-window // page_size) + 1
+
+
+def describe(config, tp_axis=None) -> PagedModel:
+    """The description of ``config``'s model: its own where the config
+    object gives one, BLOOM's otherwise. A :class:`PagedModel` passes
+    through."""
+    if isinstance(config, PagedModel):
+        return config
+    own = getattr(config, "paged_model", None)
+    return own(tp_axis) if own is not None else bloom_model(config, tp_axis)
+
+
+def bloom_model(config, tp_axis=None) -> PagedModel:
+    """BLOOM's block, as the paged programs have always run it: the
+    contiguous path's fused qkv projection, ALiBi on the scores, the
+    tanh GELU MLP; one group of ``n_layer`` stacked layers."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    from pipegoose_tpu.models.bloom import (
+        alibi_slopes,
+        bloom_gelu,
+        layer_norm,
+        logits_fn,
+    )
+    from pipegoose_tpu.models.generate import (
+        _qkv_proj,
+        forward_cached,
+        init_cache,
+    )
+    from pipegoose_tpu.nn.tensor_parallel.layers import (
+        column_parallel_linear,
+        row_parallel_linear,
+        vocab_parallel_embedding,
+    )
+
+    eps = config.layer_norm_epsilon
+
+    def slopes():
+        """This shard's ALiBi slope subset (all heads when unsharded)."""
+        tp = lax.axis_size(tp_axis) if tp_axis else 1
+        nh = config.n_head // tp
+        s = jnp.asarray(alibi_slopes(config.n_head))
+        if tp_axis:
+            s = lax.dynamic_slice_in_dim(s, lax.axis_index(tp_axis) * nh,
+                                         nh, 0)
+        return s
+
+    def embed(params, tokens):
+        x = vocab_parallel_embedding(params["embed"], tokens, tp_axis)
+        return layer_norm(params["embed_ln"], x.astype(config.dtype), eps)
+
+    def qkv(blk, h, pos):
+        ln1 = layer_norm(blk["ln_1"], h, eps)
+        return _qkv_proj({"qkv": blk["attn"]["qkv"]}, ln1, config,
+                         tp_axis) + (None,)
+
+    def finish(blk, h, ctx, saved, live):
+        h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
+        ln2 = layer_norm(blk["ln_2"], h, eps)
+        up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
+        return h + row_parallel_linear(blk["mlp"]["down"], bloom_gelu(up),
+                                       tp_axis), None
+
+    def prefill(params, ids, mask):
+        tp = lax.axis_size(tp_axis) if tp_axis else 1
+        cache = init_cache(config, 1, ids.shape[1], tp)
+        return forward_cached(params, ids, cache, 0, config, tp_axis,
+                              extras={"mask": mask})
+
+    return PagedModel(
+        n_kv_head=config.n_head, head_dim=config.head_dim,
+        dtype=config.dtype,
+        groups=(LayerGroup(
+            kind=GLOBAL, n=config.n_layer, stacked=True,
+            params=lambda p: p["blocks"], qkv=qkv, finish=finish,
+            slopes=slopes),),
+        embed=embed,
+        final=lambda p, h: layer_norm(p["ln_f"], h, eps),
+        logits=lambda p, h: logits_fn(p, h, tp_axis),
+        prefill=prefill, left_pad=True)

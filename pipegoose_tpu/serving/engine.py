@@ -39,6 +39,14 @@ changing its defaults:
   bundle. Accepted tokens are exactly the full model's greedy tokens —
   greedy parity is structural, not approximate.
 
+The engine serves a model by ONE description of its blocks and of its
+cache (serving/blocks.py: ``describe(config)``): BLOOM's, or the one a
+config object gives of itself (``paged_model``). A model whose layers
+keep two cache kinds (global layers every page, window layers a ring:
+models/laguna.py) gets a bank, a page table and an allocator a kind,
+under the same scheduler and tick; the opt-in modes below are built for
+one cache kind and refuse such a model by name.
+
 Everything device-side is compiled with STATIC shapes: the decode step
 is one program for the (num_slots, page-table-width) layout regardless
 of which slots are live, prefills bucket prompt lengths to page
@@ -94,7 +102,7 @@ from pipegoose_tpu.models._decode import (
     greedy_token,
     vocab_mask_for,
 )
-from pipegoose_tpu.models.generate import forward_cached, init_cache
+from pipegoose_tpu.serving.blocks import GLOBAL, WINDOW, describe, ring_pages
 from pipegoose_tpu.serving.kv_pool import (
     PagePool,
     check_attn_impl,
@@ -169,7 +177,9 @@ class _RunState:
         "prefills", "chunks", "spec_drafted", "spec_accepted",
         "occ_slots", "occ_pages", "stalled", "tick", "t_last_decode",
         "max_gap", "step_time", "phase_s", "table", "seq_lens", "tokens",
-        "keys_walked", "keys_reached",
+        "keys_walked", "keys_reached", "window_table", "window_keys_walked",
+        "window_keys_reached", "occ_window", "peak_pages", "recycled0",
+        "experts_touched", "expert_skew", "rows_routed",
     )
 
     def __init__(self, engine: "ServingEngine", now, tick_hook):
@@ -192,9 +202,23 @@ class _RunState:
         self.step_time = 0.0            # summed decode-step wall time
         # key columns the plain decode steps walked / could have reached
         self.keys_walked = self.keys_reached = 0
+        # the same for a window layer's ring, where the pool has one
+        self.window_keys_walked = self.window_keys_reached = 0
+        self.occ_window = 0.0
+        self.peak_pages = dict.fromkeys(engine.pool.kinds, 0)
+        self.recycled0 = engine.pool.recycled
+        # the decode steps' counters (a model whose blocks bring any):
+        # experts a step touched over its sparse layers, step by step;
+        # busiest expert's rows over the mean, summed; rows routed here
+        self.experts_touched: List[int] = []
+        self.expert_skew = 0.0
+        self.rows_routed = 0
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.table = np.zeros((engine.num_slots, engine.table_width),
                               np.int32)
+        self.window_table = (
+            np.zeros((engine.num_slots, engine.pool.ring), np.int32)
+            if engine.pool.window is not None else None)
         self.seq_lens = np.zeros((engine.num_slots,), np.int32)
         self.tokens = np.zeros((engine.num_slots,), np.int32)
 
@@ -303,12 +327,35 @@ class ServingEngine:
             )
         if stall_patience < 1:
             raise ValueError(f"stall_patience must be >= 1, got {stall_patience}")
+        model = describe(config)
+        if len(model.kinds) > 1:
+            # built for one cache kind, none half-carried over: a prefix
+            # page, a draft, a chunk or a transfer would each need the
+            # window layers' ring beside the global layers' pages
+            asked = {
+                "prefix_cache": prefix_cache, "speculative": speculative,
+                "prefill_chunk": prefill_chunk, "kv_dtype": kv_dtype,
+                "weight_dtype": weight_dtype, "host_tier": host_tier,
+                "prefill_only": prefill_only,
+                "attn_kernel": attn_kernel != "gather", "mesh": mesh,
+                "memledger": memledger,
+            }
+            for mode, value in asked.items():
+                if value is not None and value is not False \
+                        and value != "fp":
+                    raise ValueError(
+                        f"{mode} is not built for a model with "
+                        f"{len(model.kinds)} cache kinds {model.kinds}: "
+                        f"serve it with the engine's defaults")
+        elif mesh is not None:
+            model = describe(config, tp_axis)
+        self.model = model
         if speculative is not None:
             k, n = speculative
-            if not 1 <= k < config.n_layer:
+            if not 1 <= k < model.n_layer:
                 raise ValueError(
                     f"speculative draft depth {k} must be in "
-                    f"[1, n_layer={config.n_layer})"
+                    f"[1, n_layer={model.n_layer})"
                 )
             if n < 1:
                 raise ValueError(f"speculative draft length {n} must be >= 1")
@@ -367,6 +414,13 @@ class ServingEngine:
         self._m_evictable = reg.gauge("serving.prefix_cache.evictable_pages")
         self._m_frag = reg.gauge("serving.pool.fragmentation")
         self._m_key_share = reg.gauge("serving.decode_key_share")
+        # by cache kind (a one-kind pool sets the global gauge alone)
+        self._m_pages_kind = {
+            GLOBAL: reg.gauge("serving.pages_in_use.global"),
+            WINDOW: reg.gauge("serving.pages_in_use.window"),
+        }
+        self._m_recycled = reg.counter("serving.window_pages_recycled_total")
+        self._m_experts = reg.gauge("serving.experts_touched_share")
         self._m_prefill_tok = reg.counter("serving.prefill_tokens_total")
         self._m_chunks = reg.counter("serving.prefill_chunks_total")
         self._m_gap = reg.histogram("serving.decode_gap_seconds")
@@ -384,8 +438,9 @@ class ServingEngine:
         self.prefill_chunk = prefill_chunk
         self.speculative = speculative
         tp = mesh.shape[tp_axis] if mesh is not None else 1
-        if config.n_head % tp:
-            raise ValueError(f"n_head={config.n_head} not divisible by tp={tp}")
+        if model.n_kv_head % tp:
+            raise ValueError(
+                f"n_head={model.n_kv_head} not divisible by tp={tp}")
         # quantized inference knobs (ROADMAP item 4) — both default OFF.
         # "fp" is the explicit no-quantization alias both knobs accept
         # (check_kv_dtype does the same for kv_dtype), so a planner row's
@@ -400,6 +455,15 @@ class ServingEngine:
         # a table reaches: the host counts what the decode program walks
         self._walk_keys = walk_plan(page_size, self.table_width)[0] * page_size
         self._reach_keys = self.table_width * page_size
+        # a window layer keeps a ring of pages a slot, and the pool every
+        # ring its slots can hold (+ the kind's NULL page)
+        ring = (ring_pages(model.window, page_size)
+                if WINDOW in model.kinds else 0)
+        self._ring_keys = (walk_plan(page_size, ring)[1] * self._walk_keys
+                           if ring else 0)
+        # sparse layers and held experts over them, as the decode
+        # step's counters show them (0: the model brings none)
+        self._sparse_layers = self._experts_held = 0
         self.quant_spec = None
         if weight_dtype is not None:
             from pipegoose_tpu.quant import (
@@ -420,7 +484,9 @@ class ServingEngine:
             params = quantize_params(params, self.quant_spec)
             self.params = params
             self.param_specs = param_specs
-        self.pool = PagePool(num_pages, page_size)
+        self.pool = PagePool(num_pages, page_size,
+                             window_pages=num_slots * ring + 1 if ring else 0,
+                             ring=ring)
         self._run_prefill_tokens = self._run_hit_tokens = 0  # set per run()
         self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
         # KV memory hierarchy (serving/kv_tier/): optional host-DRAM
@@ -481,7 +547,8 @@ class ServingEngine:
                 self.host_tier.bind_registry(self.registry)
             self.prefix_cache.spill_hook = self.kv_tier.spill
         self.k_pages, self.v_pages = init_pages(
-            config, num_pages, page_size, kv_dtype=self.kv_dtype
+            model, num_pages, page_size, kv_dtype=self.kv_dtype,
+            window_pages=self.pool.window.num_pages if ring else 0,
         )
         valid = getattr(config, "valid_vocab_size", None)
         mask_fn = vocab_mask_for(config)
@@ -489,23 +556,30 @@ class ServingEngine:
 
         if mesh is None:
             def _prefill(params, ids, mask):
-                cache = init_cache(config, 1, ids.shape[1])
-                logits, cache = forward_cached(
-                    params, ids, cache, 0, config, extras={"mask": mask}
-                )
+                # the model's own forward over the bucketed prompt
+                logits, cache = model.prefill(params, ids, mask)
                 return greedy_token(logits, mask_fn), cache
 
-            def _write(k_pages, v_pages, cache, phys, pad):
-                return write_prompt_pages(
-                    k_pages, v_pages, cache, phys, pad, page_size
-                )
+            if model.left_pad:
+                def _write(k_pages, v_pages, cache, phys, pad):
+                    return write_prompt_pages(
+                        k_pages, v_pages, cache, phys, pad, page_size
+                    )
+            else:
+                def _write(k_pages, v_pages, cache, phys, pad, length):
+                    return write_prompt_pages(
+                        k_pages, v_pages, cache, phys, pad, page_size,
+                        length)
 
             def _step(params, tokens, k_pages, v_pages, table, seq_lens):
-                logits, k_pages, v_pages = paged_decode_step(
-                    params, tokens, k_pages, v_pages, table, seq_lens, config,
-                    attn_impl=attn_kernel,
+                # a fourth result: the blocks' counters ({} where a
+                # model's blocks bring none: no leaf, the same program)
+                logits, k_pages, v_pages, counters = paged_decode_step(
+                    params, tokens, k_pages, v_pages, table, seq_lens, model,
+                    attn_impl=attn_kernel, with_counters=True,
                 )
-                return greedy_token(logits, mask_fn), k_pages, v_pages
+                return (greedy_token(logits, mask_fn), k_pages, v_pages,
+                        counters)
 
             def _chunk(params, ids, k_pages, v_pages, table, start, n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
@@ -552,11 +626,7 @@ class ServingEngine:
             cspec = {"k": hspec, "v": hspec}
 
             def _prefill_body(params, ids, mask):
-                cache = init_cache(config, 1, ids.shape[1], tp)
-                logits, cache = forward_cached(
-                    params, ids, cache, 0, config, tp_axis,
-                    extras={"mask": mask},
-                )
+                logits, cache = model.prefill(params, ids, mask)
                 return global_greedy_pick(logits, tp_axis, valid), cache
 
             def _write_body(k_pages, v_pages, cache, phys, pad):
@@ -567,10 +637,10 @@ class ServingEngine:
             def _step_body(params, tokens, k_pages, v_pages, table, seq_lens):
                 logits, k_pages, v_pages = paged_decode_step(
                     params, tokens, k_pages, v_pages, table, seq_lens,
-                    config, tp_axis, attn_impl=attn_kernel,
+                    model, tp_axis, attn_impl=attn_kernel,
                 )
                 tok = global_greedy_pick(logits, tp_axis, valid)
-                return tok, k_pages, v_pages
+                return tok, k_pages, v_pages, {}
 
             def _chunk_body(params, ids, k_pages, v_pages, table, start,
                             n_valid):
@@ -619,7 +689,7 @@ class ServingEngine:
             self._step = jax.jit(shard_map(
                 _step_body, mesh=mesh,
                 in_specs=(param_specs, P(), pspec, pspec, P(), P()),
-                out_specs=(P(), pspec, pspec), check_vma=False,
+                out_specs=(P(), pspec, pspec, {}), check_vma=False,
             ), donate_argnums=(2, 3))
             self._chunk = jax.jit(shard_map(
                 _chunk_body, mesh=mesh,
@@ -671,7 +741,8 @@ class ServingEngine:
 
         i32 = jnp.int32
         tokens = jax.ShapeDtypeStruct((self.num_slots,), i32)
-        table = jax.ShapeDtypeStruct((self.num_slots, self.table_width), i32)
+        table = self._by_kind(lambda width: jax.ShapeDtypeStruct(
+            (self.num_slots, width), i32))
         seq_lens = jax.ShapeDtypeStruct((self.num_slots,), i32)
         intended = None
         if self.mesh is not None:
@@ -756,13 +827,14 @@ class ServingEngine:
             raise RuntimeError("profile() cannot run during a serving run")
         i32 = jnp.int32
         tokens = jnp.zeros((self.num_slots,), i32)
-        table = jnp.zeros((self.num_slots, self.table_width), i32)
+        table = self._by_kind(
+            lambda width: jnp.zeros((self.num_slots, width), i32))
         seq_lens = jnp.zeros((self.num_slots,), i32)
         final: dict = {}
 
         def update(out, cur):
-            # out = (next_tokens, k_pages, v_pages); the pages were
-            # donated — thread (and finally adopt) the new buffers
+            # out = (next_tokens, k_pages, v_pages, counters); the pages
+            # were donated — thread (and finally adopt) the new buffers
             final["k"], final["v"] = out[1], out[2]
             return (cur[0], cur[1], out[1], out[2], cur[4], cur[5])
 
@@ -805,11 +877,17 @@ class ServingEngine:
             key = str(leaf.dtype)
             kv_by[key] = kv_by.get(key, 0) + nbytes
         kv_total = int(sum(kv_by.values()))
-        cfg = self.config
+        model = self.model
         num_pages = self.pool.num_pages
-        fp_total = (2 * cfg.n_layer * num_pages * self.page_size
-                    * cfg.n_head * cfg.head_dim
-                    * int(np.dtype(cfg.dtype).itemsize))
+        row = (self.page_size * model.n_kv_head * model.head_dim
+               * int(np.dtype(model.dtype).itemsize))
+        by_kind = {
+            kind: {"layers": model.layers_of(kind),
+                   "num_pages": self.pool.of(kind).num_pages,
+                   "fp_bytes": 2 * model.layers_of(kind)
+                   * self.pool.of(kind).num_pages * row}
+            for kind in model.kinds}
+        fp_total = sum(k["fp_bytes"] for k in by_kind.values())
         report = {
             "weight_dtype": self.weight_dtype or "fp",
             "kv_dtype": self.kv_dtype or "fp",
@@ -821,6 +899,8 @@ class ServingEngine:
                 "bytes_per_page": kv_total // num_pages,
                 "fp_bytes_per_page": fp_total // num_pages,
                 "page_capacity_ratio": round(fp_total / max(kv_total, 1), 4),
+                # a bank a cache kind (one, "global", for most models)
+                "by_kind": by_kind,
             },
         }
         if self.host_tier is not None:
@@ -899,6 +979,43 @@ class ServingEngine:
         self._first_call_s[key] = None
         self.programs_run += 1
         return True
+
+    def _by_kind(self, make):
+        """``make(table width)`` for every cache kind of the model: the
+        bare value for a one-kind model, ``{kind: ..}`` for a two-kind
+        one (what the paged programs take as a page table)."""
+        if self.pool.window is None:
+            return make(self.table_width)
+        return {GLOBAL: make(self.table_width), WINDOW: make(self.pool.ring)}
+
+    def _phys_rows(self, req: Request):
+        """A request's page-table rows for the page write, by kind."""
+        def row(width, pages):
+            out = np.zeros((width,), np.int32)
+            out[:len(pages)] = pages
+            return jnp.asarray(out)
+
+        if self.pool.window is None:
+            return row(self.table_width, req.pages)
+        return {GLOBAL: row(self.table_width, req.pages),
+                WINDOW: row(self.pool.ring, req.window_pages)}
+
+    def _note_counters(self, rs, counters: dict) -> None:
+        """One plain decode step's counter channel: ``rows_per_expert``
+        (sparse layers, held experts), the rows each held expert was
+        sent."""
+        rows = counters.get("rows_per_expert")
+        if rows is None:
+            return
+        layers, held = rows.shape
+        self._sparse_layers, self._experts_held = layers, layers * held
+        touched = int((rows > 0).sum())
+        rs.experts_touched.append(touched)
+        total = rows.sum(axis=1)
+        rs.rows_routed += int(total.sum())
+        rs.expert_skew += float(
+            (rows.max(axis=1) * held / np.maximum(total, 1)).sum())
+        self._m_experts.set(touched / (layers * held))
 
     def _ledger_tick(self, rs) -> None:
         """Per-tick ledger hook (conservation check + forecast +
@@ -1002,23 +1119,25 @@ class ServingEngine:
             bucket = self.pool.pages_for(s) * self.page_size
             first = self._note_program("prefill", bucket)
             first_write = self._note_program("write", bucket)
-            pad = bucket - s
+            # the padding before the prompt (BLOOM) or behind it (a
+            # model whose positions count from the prompt's start)
+            left = self.model.left_pad
+            pad = bucket - s if left else 0
             ids = np.zeros((1, bucket), np.int32)
-            ids[0, pad:] = np.asarray(req.prompt, np.int32)
+            ids[0, pad:pad + s] = np.asarray(req.prompt, np.int32)
             mask = np.zeros((1, bucket), np.int32)
-            mask[0, pad:] = 1
+            mask[0, pad:pad + s] = 1
             t_call = now()
             tok, cache = self._prefill(
                 self.params, jnp.asarray(ids), jnp.asarray(mask)
             )
             t_write = now()
-            phys = np.zeros((self.table_width,), np.int32)
-            phys[:len(req.pages)] = req.pages
             # dispatched and never fetched: on the device the write runs
             # after this span has closed, in the next fetch's wait
             self.k_pages, self.v_pages = self._write(
-                self.k_pages, self.v_pages, cache, jnp.asarray(phys),
+                self.k_pages, self.v_pages, cache, self._phys_rows(req),
                 jnp.asarray(pad, jnp.int32),
+                *(() if left else (jnp.asarray(s, jnp.int32),)),
             )
             t_fetch = now()
             # the token fetch syncs the device, so the span's wall time
@@ -1513,13 +1632,21 @@ class ServingEngine:
                     rs.table[req.slot, :len(req.pages)] = req.pages
                     rs.seq_lens[req.slot] = req.cached_len
                     rs.tokens[req.slot] = req.generated[-1]
+                table = rs.table
+                if rs.window_table is not None:
+                    rs.window_table.fill(0)
+                    for req in active:
+                        rs.window_table[req.slot, :len(req.window_pages)] = \
+                            req.window_pages
+                    table = {GLOBAL: rs.table, WINDOW: rs.window_table}
             first = self._note_program("step", 0)
             t_step = now()
             with span("serving.decode_step", registry=reg):
                 with span("dispatch", registry=reg):
-                    nxt, self.k_pages, self.v_pages = self._step(
+                    nxt, self.k_pages, self.v_pages, counters = self._step(
                         self.params, jnp.asarray(rs.tokens), self.k_pages,
-                        self.v_pages, jnp.asarray(rs.table),
+                        self.v_pages, jax.tree_util.tree_map(
+                            jnp.asarray, table),
                         jnp.asarray(rs.seq_lens),
                     )
                 t_disp = now()
@@ -1528,6 +1655,10 @@ class ServingEngine:
                 # write dispatched by this tick's prefill)
                 with span("fetch", registry=reg):
                     nxt = np.asarray(nxt)  # host fetch syncs: span = work
+                    # the step's counters, out of the jitted step beside
+                    # its tokens ({} for a model whose blocks bring none)
+                    counters = {k: np.asarray(v)
+                                for k, v in counters.items()}
             t = now()
             if first:
                 self._first_call_s["step", 0] = t - t_step
@@ -1547,14 +1678,26 @@ class ServingEngine:
             rs.step_time += t - t_step
             if not use_spec:
                 # the program's own arithmetic on the lengths it was sent
-                rs.keys_walked += min(
-                    walked_chunks(int(rs.seq_lens.max()), self._walk_keys)
-                    * self._walk_keys, self._reach_keys)
+                walked = walked_chunks(
+                    int(rs.seq_lens.max()), self._walk_keys) * self._walk_keys
+                rs.keys_walked += min(walked, self._reach_keys)
                 rs.keys_reached += self._reach_keys
+                if self._ring_keys:
+                    # a window layer's read walks its ring, no further
+                    rs.window_keys_walked += min(walked, self._ring_keys)
+                    rs.window_keys_reached += self._ring_keys
+                if counters:
+                    self._note_counters(rs, counters)
             slot_occ = len(active) / self.num_slots
-            page_occ = self.pool.used_count / self.pool.capacity
+            used = self.pool.used_by_kind()
+            page_occ = used[GLOBAL] / self.pool.capacity
             rs.occ_slots += slot_occ
             rs.occ_pages += page_occ
+            for kind, n in used.items():
+                rs.peak_pages[kind] = max(rs.peak_pages[kind], n)
+                self._m_pages_kind[kind].set(n)
+            if self.pool.window is not None:
+                rs.occ_window += used[WINDOW] / self.pool.window.capacity
             # per-token decode latency: a plain step emits one token per
             # active slot; a speculative cycle may emit several — both
             # normalize to seconds per token per slot
@@ -1737,6 +1880,41 @@ class ServingEngine:
                      if rs.keys_reached else 0.0)
             metrics["decode_key_share"] = round(share, 6)
             self._m_key_share.set(share)
+        metrics["pages_by_kind"] = {
+            kind: {"capacity": self.pool.of(kind).capacity,
+                   "peak_in_use": rs.peak_pages[kind]}
+            for kind in self.pool.kinds}
+        if self.pool.window is not None:
+            steps = max(rs.steps, 1)
+            metrics["pages_by_kind"][GLOBAL]["occupancy"] = round(
+                rs.occ_pages / steps, 4)
+            metrics["pages_by_kind"][WINDOW]["occupancy"] = round(
+                rs.occ_window / steps, 4)
+            recycled = self.pool.recycled - rs.recycled0
+            metrics["window_pages_recycled"] = recycled
+            self._m_recycled.inc(recycled)
+            # a window layer's walk over its ring, and over what a global
+            # layer's walk of the same steps visited
+            metrics["decode_key_share_by_kind"] = {
+                GLOBAL: metrics.get("decode_key_share"),
+                WINDOW: round(rs.window_keys_walked
+                              / max(rs.window_keys_reached, 1), 6)}
+            metrics["window_key_share"] = round(
+                rs.window_keys_walked / max(rs.keys_walked, 1), 6)
+        if rs.experts_touched:
+            n = len(rs.experts_touched)
+            metrics["experts"] = {
+                # experts a decode step touched, summed over its sparse
+                # layers, step by step; the share of those held; rows on
+                # the busiest expert over the mean, a layer a step
+                "touched_by_step": rs.experts_touched,
+                "touched_share": round(
+                    sum(rs.experts_touched) / (n * self._experts_held), 6),
+                "rows_max_over_mean": round(
+                    rs.expert_skew / (n * self._sparse_layers), 4),
+                "rows_routed": rs.rows_routed,
+                "held_a_step": self._experts_held,
+            }
         if self._paged_prefill:
             metrics["prefill_chunks"] = rs.chunks
             metrics["max_decode_gap_s"] = round(rs.max_gap, 6)

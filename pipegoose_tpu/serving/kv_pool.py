@@ -71,13 +71,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from pipegoose_tpu.models.bloom import NEG_INF, alibi_slopes, bloom_gelu, layer_norm, logits_fn
-from pipegoose_tpu.models.generate import _qkv_proj
+from pipegoose_tpu.models.bloom import NEG_INF
 from pipegoose_tpu.ops.paged_attention import paged_attention
-from pipegoose_tpu.nn.tensor_parallel.layers import (
-    column_parallel_linear,
-    row_parallel_linear,
-    vocab_parallel_embedding,
+from pipegoose_tpu.serving.blocks import (
+    GLOBAL,
+    KINDS,
+    WINDOW,
+    describe,
+    ring_pages,
 )
 
 NULL_PAGE = 0
@@ -161,7 +162,11 @@ class PagePool:
     long-lived engine never accumulates host memory per request."""
 
     def __init__(self, num_pages: int, page_size: int,
-                 history_limit: int = 1024):
+                 history_limit: int = 1024, window_pages: int = 0,
+                 ring: int = 0):
+        """``window_pages`` > 0 adds the ``"window"`` kind: a second
+        free list over pages of the window layers' banks (its own NULL
+        page 0), of which a sequence holds at most ``ring``."""
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is the null page)")
         if page_size < 1:
@@ -185,6 +190,31 @@ class PagePool:
         # costs one attribute read + branch per pool event.
         self.ledger = None
         self.tag = None                  # owner tag for the NEXT event
+        if window_pages and ring < 1:
+            raise ValueError("a window kind needs its ring's length")
+        self.ring = ring
+        self.window: Optional[PagePool] = (
+            PagePool(window_pages, page_size, history_limit)
+            if window_pages else None)
+        # ring entries taken over by a later logical page, ever
+        self.recycled = 0
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return (GLOBAL,) if self.window is None else KINDS
+
+    def of(self, kind: str) -> "PagePool":
+        """The allocator of one kind's pages (this one for ``global``)."""
+        if kind == GLOBAL:
+            return self
+        if kind != WINDOW or self.window is None:
+            raise ValueError(f"the pool has no {kind!r} kind "
+                             f"(kinds: {self.kinds})")
+        return self.window
+
+    def used_by_kind(self) -> Dict[str, int]:
+        return {k: self.of(k).capacity - self.of(k).free_count
+                for k in self.kinds}
 
     @property
     def free_count(self) -> int:
@@ -192,7 +222,10 @@ class PagePool:
 
     @property
     def used_count(self) -> int:
-        return self.num_pages - 1 - len(self._free)
+        """Pages handed out and not yet back, of EVERY kind: 0 after a
+        drained run is the leak invariant."""
+        own = self.num_pages - 1 - len(self._free)
+        return own + (self.window.used_count if self.window else 0)
 
     @property
     def capacity(self) -> int:
@@ -207,8 +240,11 @@ class PagePool:
     def refcount(self, page: int) -> int:
         return self._ref.get(page, 0)
 
-    def pages_for(self, n_tokens: int) -> int:
-        return -(-n_tokens // self.page_size)
+    def pages_for(self, n_tokens: int, kind: str = GLOBAL) -> int:
+        """Pages of ``kind`` that hold a sequence of ``n_tokens``: all
+        of them for ``global``, the ring at most for ``window``."""
+        n = -(-n_tokens // self.page_size)
+        return n if kind == GLOBAL else min(n, self.ring)
 
     def fragmentation(self) -> float:
         """1 - (largest contiguous free run / free pages): 0.0 when the
@@ -238,7 +274,9 @@ class PagePool:
             led.on_pool_event(event, pages, self.tag)
             self.tag = None
 
-    def alloc(self, n: int) -> List[int]:
+    def alloc(self, n: int, kind: str = GLOBAL) -> List[int]:
+        if kind != GLOBAL:
+            return self.of(kind).alloc(n)
         if n > len(self._free):
             raise RuntimeError(
                 f"page pool exhausted: requested {n}, free {len(self._free)} "
@@ -263,10 +301,12 @@ class PagePool:
             self._ref[p] += 1
         self._record("share", tuple(pages), +1)
 
-    def release(self, pages: List[int]) -> None:
+    def release(self, pages: List[int], kind: str = GLOBAL) -> None:
         """Drop one reference per page; pages reaching refcount 0 return
         to the free list (LIFO — placement stays a pure function of the
         event order even under sharing)."""
+        if kind != GLOBAL:
+            return self.of(kind).release(pages)
         for p in pages:
             if p not in self._ref:
                 raise RuntimeError(f"freeing page {p} that is not allocated")
@@ -282,7 +322,7 @@ class PagePool:
 
 
 def init_pages(config, num_pages: int, page_size: int, tp: int = 1,
-               kv_dtype: Optional[str] = None):
+               kv_dtype: Optional[str] = None, window_pages: int = 0):
     """The pool's device buffers, ``(L, num_pages, page_size, nh*hd)``
     per bank: a position's heads in one row, heads major, so under TP
     each shard holds its nh/tp heads (create the GLOBAL array and shard
@@ -290,27 +330,54 @@ def init_pages(config, num_pages: int, page_size: int, tp: int = 1,
     half-fills the TPU's 128 lanes and the compiler puts the PAGES in
     the lanes instead: one page's rows lie strided across its plane and
     every access through a page table re-lays out the plane, or the
-    pool (PERF.md, PR 27).
+    pool (PERF.md, PR 27). ``nh`` is the model's KV heads
+    (``serving/blocks.py``): a row of Laguna's is 8 x 128 lanes whatever
+    a layer's query heads.
 
     ``kv_dtype=None`` (or "fp") keeps the fp pool: a bare array pair in
-    ``config.dtype``. ``"int8"`` stores each bank as a PYTREE
+    the model's dtype. ``"int8"`` stores each bank as a PYTREE
     ``{"q": int8 (L, P, ps, nh*hd), "scale": f32 (L, P, ps, nh)}`` —
     the per-page scale plane rides one fp32 scalar per (layer, page
     slot, head), ~hd x 4 bytes lighter than the values it scales. Every
     pool function below dispatches on the structure, so the engine's
     jitted programs, donation, and shard_map specs carry the pair as
-    one value either way."""
-    L, nh, hd = config.n_layer, config.n_head // tp, config.head_dim
-    kv_dtype = check_kv_dtype(kv_dtype)
-    shape = (L, num_pages, page_size, nh * hd)
-    if kv_dtype is None:
-        return jnp.zeros(shape, config.dtype), jnp.zeros(shape, config.dtype)
+    one value either way.
 
-    def bank():
+    A model with two cache kinds gets a bank a kind, ``{"global": (L_g,
+    num_pages, ..), "window": (L_w, window_pages, ..)}``: the layers of
+    a kind stacked in their order in the model."""
+    model = describe(config)
+    nh, hd = model.n_kv_head // tp, model.head_dim
+    kv_dtype = check_kv_dtype(kv_dtype)
+
+    def bank(layers, pages):
+        shape = (layers, pages, page_size, nh * hd)
+        if kv_dtype is None:
+            return jnp.zeros(shape, model.dtype)
         return {"q": jnp.zeros(shape, jnp.int8),
                 "scale": jnp.zeros(shape[:-1] + (nh,), jnp.float32)}
 
-    return bank(), bank()
+    if model.kinds == (GLOBAL,):
+        return bank(model.n_layer, num_pages), bank(model.n_layer, num_pages)
+    sizes = {GLOBAL: num_pages, WINDOW: window_pages}
+
+    def banks():
+        return {k: bank(model.layers_of(k), sizes[k]) for k in model.kinds}
+
+    return banks(), banks()
+
+
+def by_kind(x) -> dict:
+    """A pool bank, a page table or a prefill cache as ``{kind: ..}``:
+    a one-kind model's bare value is its ``global`` kind."""
+    if isinstance(x, dict) and GLOBAL in x:
+        return x
+    return {GLOBAL: x}
+
+
+def _like(x, kinds: dict):
+    """``kinds`` back in the form ``x`` came in."""
+    return kinds if isinstance(x, dict) and GLOBAL in x else kinds[GLOBAL]
 
 
 def _rows(x):
@@ -347,25 +414,52 @@ def _one_bank(pages):
         lambda a: a.reshape((-1,) + a.shape[2:]), pages)
 
 
-def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size):
+def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
+                      length=None):
     """Scatter a prefill's contiguous cache into the pool, in place (the
     L x S_pad rows addressed by layer, page and offset).
 
-    ``cache`` is forward_cached's (L, 1, S_pad, nh, hd) pair holding a
-    LEFT-padded prompt (``pad`` pad slots, then the prompt); logical
+    ``cache`` is the prefill's (L, 1, S_pad, nh, hd) pair holding a
+    padded prompt: ``pad`` pad slots, then the prompt, then (a
+    right-padded prompt, ``length`` its tokens) more padding. Logical
     prompt position p lands in page ``phys_pages[p // page_size]`` at
     offset ``p % page_size`` — the repack drops the padding, so decode
     sees the unpadded 0..len-1 layout. Pad positions route to the NULL
     page. ``phys_pages`` is the slot's full page-table row (fixed width,
     unused tail entries 0) so every bucket shares one compiled program.
+
+    With two cache kinds all three are ``{kind: ..}``; the ``window``
+    kind's row is its ring: position p lands in entry ``(p // page_size)
+    % ring``, and only the pages the ring still holds at the prompt's
+    end are written (the earlier ones would be overwritten anyway).
     """
+    if isinstance(cache, dict) and GLOBAL in cache:
+        out = {k: _write_prompt(k_pages[k], v_pages[k], cache[k],
+                                phys_pages[k], pad, page_size, length,
+                                ring=k == WINDOW)
+               for k in cache}
+        return ({k: o[0] for k, o in out.items()},
+                {k: o[1] for k, o in out.items()})
+    return _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
+                         length)
+
+
+def _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
+                  length=None, ring=False):
     k_seq, v_seq = cache["k"][:, 0], cache["v"][:, 0]  # (L, S_pad, nh, hd)
     s_pad = k_seq.shape[1]
     pos = jnp.arange(s_pad)
     logical = pos - pad
     valid = logical >= 0
+    if length is not None:
+        valid = valid & (logical < length)
     lclip = jnp.where(valid, logical, 0)
-    dest_page = jnp.where(valid, phys_pages[lclip // page_size], NULL_PAGE)
+    page = lclip // page_size
+    if ring:
+        n = phys_pages.shape[0]
+        valid = valid & (page > (length - 1) // page_size - n)
+        page = page % n
+    dest_page = jnp.where(valid, phys_pages[page], NULL_PAGE)
     dest_off = jnp.where(valid, lclip % page_size, 0)
     layers = jnp.arange(_values(k_pages).shape[0])[:, None]
     idx = (layers, dest_page[None], dest_off[None])
@@ -401,18 +495,6 @@ def page_size_of(pages) -> int:
     return _values(pages).shape[-2]
 
 
-def _local_slopes(config, tp_axis):
-    """This shard's ALiBi slope subset (all heads when unsharded)."""
-    tp = jax.lax.axis_size(tp_axis) if tp_axis else 1
-    nh = config.n_head // tp
-    slopes = jnp.asarray(alibi_slopes(config.n_head))
-    if tp_axis:
-        slopes = lax.dynamic_slice_in_dim(
-            slopes, jax.lax.axis_index(tp_axis) * nh, nh, 0
-        )
-    return slopes
-
-
 def _key_bias(slopes, q_pos, n_keys):
     """Additive attention bias for queries at GLOBAL positions ``q_pos``
     (B, C) over ``n_keys`` logical key positions: ALiBi over the key
@@ -445,22 +527,25 @@ def walked_chunks(max_pos, chunk_keys: int):
 
 
 def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
-                 out_dtype):
+                 out_dtype, window: Optional[int] = None):
     """Softmax attention of ``q`` (B, C, nh, hd) at global positions
     ``pos`` (B, C) over layer ``layer`` of the pool, read through
     ``page_table`` (B, W) AS STORED: a gathered row keeps its
-    ``nh*hd`` lanes and the pool's dtype, and is never split into heads
+    ``kv*hd`` lanes and the pool's dtype, and is never split into heads
     or widened in memory.
 
     All heads' scores come from one matrix-unit contraction of the rows
     against a block-diagonal query (row ``c*nh + h`` holds q[c, h] in
-    head h's lanes and zeros elsewhere, so the other heads' lanes add
-    exact zeros); the context product gives every (query, head) row all
-    ``nh*hd`` lanes, of which the head keeps its own. Accumulation and
-    softmax are float32; the probabilities are rounded to the operands'
-    dtype before the value product, as :func:`_attn_core` rounds them.
-    An int8 bank's per-(position, head) scales multiply the scores and
-    the probabilities, never a dequantized copy of the rows.
+    the lanes of its KV head ``h // g`` and zeros elsewhere, so the
+    other heads' lanes add exact zeros; ``g = nh / kv`` query heads
+    share a KV head, 1 for BLOOM); the context product gives every
+    (query, head) row all ``kv*hd`` lanes, of which the head keeps its
+    KV head's. Accumulation and softmax are float32; the probabilities
+    are rounded to the operands' dtype before the value product, as
+    :func:`_attn_core` rounds them. An int8 bank's per-(position, head)
+    scales multiply the scores and the probabilities, never a
+    dequantized copy of the rows. ``slopes`` (nh,) is ALiBi's bias on
+    the key position, ``None`` for none.
 
     The keys are visited in chunks of whole pages (:func:`walk_plan`)
     under an online softmax, and only as far as the furthest live query:
@@ -468,27 +553,43 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
     ``qmask`` keeps. Chunks beyond are not gathered; inside the walk the
     bias masks what ``_key_bias`` masks (columns past a query's own
     position: unwritten offsets, stale tails, NULL-page garbage).
+
+    ``window``: the layer keeps the keys with ``0 <= q_pos - k_pos <
+    window`` and ``page_table`` is a RING (``blocks.ring_pages``):
+    entry ``r`` holds the newest logical page ``j <= pos // page_size``
+    with ``j % ring == r``, so a key's position is read off the query's
+    own; the walk is the ring's few chunks however long the sequence
+    (one query a row: the ring holds one page more than the window).
     Returns (B, C, nh*hd) in ``out_dtype``, pad queries zero."""
     b, c, nh, hd = q.shape
-    n, width = c * nh, nh * hd
     ps = page_size_of(k_pages)
-    pages, n_chunks = walk_plan(ps, page_table.shape[1])
+    width = _values(k_pages).shape[-1]
+    kv = width // hd
+    g = nh // kv
+    n = c * nh
+    ring = page_table.shape[1]
+    if window is not None and c != 1:
+        raise ValueError("a window layer's ring is read a query a row")
+    pages, n_chunks = walk_plan(ps, ring)
     chunk_keys = pages * ps
     # a last chunk that overhangs the table reads NULL pages, whose key
     # positions lie past every query's
     table = jnp.pad(page_table,
-                    ((0, 0), (0, pages * n_chunks - page_table.shape[1])),
+                    ((0, 0), (0, pages * n_chunks - ring)),
                     constant_values=NULL_PAGE)
     quantized = _is_quantized(k_pages)
     operand = q.dtype if quantized else k_pages.dtype
-    # own[h, r]: lane r of a row belongs to head h
-    own = jnp.arange(width)[None, :] // hd == jnp.arange(nh)[:, None]
-    q_bd = jnp.where(own, _rows(q)[:, :, None, :], 0)
+    # own[h, r]: lane r of a row belongs to head h's KV head
+    own = jnp.arange(width)[None, :] // hd == jnp.arange(nh)[:, None] // g
+    lanes = _rows(q) if g == 1 else jnp.tile(q, (1, 1, 1, kv))
+    q_bd = jnp.where(own, lanes[:, :, None, :] if g == 1 else lanes, 0)
     q_bd = q_bd.reshape(b, n, width).astype(operand)
     q_pos = jnp.repeat(pos, nh, axis=1)[:, :, None]          # (B, C*nh, 1)
-    slope = jnp.tile(slopes, c)[None, :, None]               # (1, C*nh, 1)
+    slope = (None if slopes is None
+             else jnp.tile(slopes, c)[None, :, None])        # (1, C*nh, 1)
     live = pos if qmask is None else jnp.where(qmask, pos, 0)
     trips = jnp.minimum(walked_chunks(jnp.max(live), chunk_keys), n_chunks)
+    cur = pos[:, :1] // ps                                   # (B, 1)
 
     def rows_of(bank, ids):
         return _values(bank)[layer, ids].reshape(
@@ -502,15 +603,31 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
     def chunk(i, carry):
         m, denom, acc = carry
         ids = lax.dynamic_slice_in_dim(table, i * pages, pages, axis=1)
-        key_pos = i * chunk_keys + jnp.arange(chunk_keys)
+        if window is None:
+            key_pos = i * chunk_keys + jnp.arange(chunk_keys)
+            keep = key_pos <= q_pos
+        else:
+            entry = i * pages + jnp.arange(pages)            # (pages,)
+            page = cur - (cur - entry[None, :]) % ring       # (B, pages)
+            key_pos = (page[:, :, None] * ps
+                       + jnp.arange(ps)).reshape(b, 1, chunk_keys)
+            held = jnp.repeat((entry < ring)[None, :] & (page >= 0), ps,
+                              axis=1)[:, None, :]
+            keep = held & (key_pos <= q_pos) & (key_pos > q_pos - window)
         s = jnp.einsum("bnr,bkr->bnk", q_bd, rows_of(k_pages, ids),
                        preferred_element_type=jnp.float32)
         if quantized:
             s = s * scales_of(k_pages, ids)
-        s = s * (hd ** -0.5) + slope * key_pos.astype(jnp.float32)
-        s = s + jnp.where(key_pos <= q_pos, 0.0, NEG_INF)
+        s = s * (hd ** -0.5)
+        if slope is not None:
+            s = s + slope * key_pos.astype(jnp.float32)
+        s = s + jnp.where(keep, 0.0, NEG_INF)
         m_new = jnp.maximum(m, s.max(-1))
         p = jnp.exp(s - m_new[..., None])
+        if window is not None:
+            # a chunk of the ring may hold no key of a row's window yet
+            # (m still at its floor): such keys weigh nothing
+            p = jnp.where(keep, p, 0.0)
         alpha = jnp.exp(m - m_new)
         denom = denom * alpha + p.sum(-1)
         if quantized:
@@ -524,9 +641,17 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
         jnp.full((b, n), NEG_INF, jnp.float32),
         jnp.zeros((b, n), jnp.float32),
         jnp.zeros((b, n, width), jnp.float32)))
-    # position 0 is a key of every query, so denom >= 1
+    # a query is its own key (written before the read) and a dead slot
+    # reads key 0 of the NULL page, so denom >= 1
     ctx = (acc / denom[..., None]).reshape(b, c, nh, width)
-    ctx = jnp.sum(jnp.where(own, ctx, 0.0), axis=2)          # (B, C, nh*hd)
+    if g == 1:
+        ctx = jnp.sum(jnp.where(own, ctx, 0.0), axis=2)      # (B, C, nh*hd)
+    else:
+        # head h keeps the hd lanes of KV head h // g
+        mine = jnp.arange(kv)[None, :] == jnp.arange(nh)[:, None] // g
+        ctx = jnp.sum(jnp.where(mine[:, :, None],
+                                ctx.reshape(b, c, nh, kv, hd), 0.0), axis=3)
+        ctx = ctx.reshape(b, c, nh * hd)
     if qmask is not None:
         # pad-query context is ZERO in every attention path
         ctx = ctx * qmask[:, :, None].astype(ctx.dtype)
@@ -535,63 +660,109 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
 
 def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                    dest_page, dest_off, qmask, config, tp_axis, attn_impl,
-                   n_layers=None):
+                   n_layers=None, live=None):
     """The forward both paged programs share: ``tokens`` (B, C) at
-    global positions ``pos`` (B, C) through the first ``n_layers``
-    blocks (all by default) and the final layer norm. The pool rides
-    the layer loop's CARRY: layer ``l`` writes its B x C rows at (l,
-    dest_page, dest_off) and attention reads through gathers addressed
-    by (l, page) (:func:`_attend_rows`), so the donated pool is updated
-    in place — scanned in and stacked out (xs/ys) it is copied once a
-    call and each layer's plane twice more. Returns (hidden, k_pages,
-    v_pages)."""
+    global positions ``pos`` (B, C) through the model's blocks
+    (``serving/blocks.py``: the first ``n_layers`` of them, all by
+    default) and its final norm. The pool rides the layer loop's CARRY:
+    layer ``l`` writes its B x C rows at (l, dest_page, dest_off) and
+    attention reads through gathers addressed by (l, page)
+    (:func:`_attend_rows`), so the donated pool is updated in place —
+    scanned in and stacked out (xs/ys) it is copied once a call and
+    each layer's plane twice more.
+
+    A group of stacked layers is one ``fori_loop``; a group of one
+    unstacked layer is traced in line (its shapes are its own). With
+    two cache kinds ``k_pages``, ``v_pages``, ``page_table``,
+    ``dest_page`` are ``{kind: ..}`` and a layer's bank index counts
+    the layers of its kind before it. ``live`` (B, C) bool says which
+    positions are real, for a block that sends rows somewhere (the
+    experts). Returns (hidden, k_pages, v_pages, counters): what the
+    blocks' ``finish`` brought out, stacked over the layers that bring
+    any, ``{}`` for a model with none."""
     check_attn_impl(attn_impl)
+    model = describe(config, tp_axis)
     b, c = tokens.shape
+    kp, vp = by_kind(k_pages), by_kind(v_pages)
+    tables, dest = by_kind(page_table), by_kind(dest_page)
+    if attn_impl == "paged" and model.kinds != (GLOBAL,):
+        raise ValueError("the paged kernel reads one cache kind")
 
-    x = vocab_parallel_embedding(params["embed"], tokens, tp_axis)
-    x = x.astype(config.dtype)
-    x = layer_norm(params["embed_ln"], x, config.layer_norm_epsilon)
-    slopes = _local_slopes(config, tp_axis)
-    all_layers, num_pages = _values(k_pages).shape[:2]
+    x = model.embed(params, tokens)
+    seen = dict.fromkeys(model.kinds, 0)      # layers of a kind so far
+    # by default every layer the banks hold (a pool cut to fewer layers
+    # than the model runs the first of them)
+    left = (sum(_values(kp[k]).shape[0] for k in model.kinds)
+            if n_layers is None else n_layers)
+    brought = []
 
-    def layer(l, carry):
-        h, kp, vp = carry
-        blk = jax.tree_util.tree_map(
-            lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False),
-            params["blocks"])
-        ln1 = layer_norm(blk["ln_1"], h, config.layer_norm_epsilon)
-        q, k, v = _qkv_proj({"qkv": blk["attn"]["qkv"]}, ln1, config, tp_axis)
-        kp = _write_rows(kp, (l, dest_page, dest_off), k)
-        vp = _write_rows(vp, (l, dest_page, dest_off), v)
-        if attn_impl == "paged":
-            # the kernel takes one bank of pages: every layer's, the
-            # layer folded into the page id
-            ctx = paged_attention(q, _one_bank(kp), _one_bank(vp),
-                                  page_table + l * num_pages, pos[:, 0],
-                                  slopes=slopes)
-            if qmask is not None:
-                ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
-            ctx = ctx.astype(h.dtype).reshape(b, c, -1)
+    for grp in model.groups:
+        take = min(grp.n, left)
+        if take <= 0:
+            break
+        left -= take
+        kind, base = grp.kind, seen[grp.kind]
+        seen[kind] += grp.n
+        window = model.window if kind == WINDOW else None
+        slopes = grp.slopes() if grp.slopes is not None else None
+        blocks = grp.params(params)
+        num_pages = _values(kp[kind]).shape[1]
+
+        def layer(l, h, kpk, vpk, blk):
+            q, k, v, saved = grp.qkv(blk, h, pos)
+            kpk = _write_rows(kpk, (l, dest[kind], dest_off), k)
+            vpk = _write_rows(vpk, (l, dest[kind], dest_off), v)
+            if attn_impl == "paged":
+                # the kernel takes one bank of pages: every layer's, the
+                # layer folded into the page id
+                ctx = paged_attention(q, _one_bank(kpk), _one_bank(vpk),
+                                      tables[kind] + l * num_pages,
+                                      pos[:, 0], slopes=slopes)
+                if qmask is not None:
+                    ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
+                ctx = ctx.astype(h.dtype).reshape(b, c, -1)
+            else:
+                ctx = _attend_rows(q, kpk, vpk, l, tables[kind], pos, qmask,
+                                   slopes, h.dtype, window)
+            h, out = grp.finish(blk, h, ctx, saved, live)
+            return h, kpk, vpk, out
+
+        if grp.stacked:
+            def body(l, carry):
+                h, kpk, vpk = carry
+                # l counts the kind's layers, the stack the group's own
+                own = l - base if base else l
+                blk = jax.tree_util.tree_map(
+                    lambda a: lax.dynamic_index_in_dim(a, own, 0,
+                                                       keepdims=False),
+                    blocks)
+                return layer(l, h, kpk, vpk, blk)[:3]
+
+            x, kp[kind], vp[kind] = lax.fori_loop(
+                base, base + take, body, (x, kp[kind], vp[kind]))
         else:
-            ctx = _attend_rows(q, kp, vp, l, page_table, pos, qmask, slopes,
-                               h.dtype)
-        h = h + row_parallel_linear(blk["attn"]["out"], ctx, tp_axis)
-        ln2 = layer_norm(blk["ln_2"], h, config.layer_norm_epsilon)
-        up = column_parallel_linear(blk["mlp"]["up"], ln2, tp_axis)
-        h = h + row_parallel_linear(blk["mlp"]["down"], bloom_gelu(up), tp_axis)
-        return h, kp, vp
+            x, kp[kind], vp[kind], out = layer(base, x, kp[kind], vp[kind],
+                                               blocks)
+            if out is not None:
+                brought.append(out)
+    x = model.final(params, x)
+    counters = ({model.counters: jnp.stack(brought)}
+                if brought and model.counters else {})
+    return x, _like(k_pages, kp), _like(v_pages, vp), counters
 
-    x, k_pages, v_pages = lax.fori_loop(
-        0, all_layers if n_layers is None else n_layers, layer,
-        (x, k_pages, v_pages))
-    x = layer_norm(params["ln_f"], x, config.layer_norm_epsilon)
-    return x, k_pages, v_pages
+
+def _dest(page_table, page_idx, ring: bool):
+    """The physical page of logical page ``page_idx`` (B, C) in a (B, W)
+    table, or in a ring of W entries."""
+    if ring:
+        page_idx = page_idx % page_table.shape[1]
+    return jnp.take_along_axis(page_table, page_idx, axis=1)
 
 
 def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
                       config, tp_axis=None, write_ok=None,
                       draft_layers: Optional[int] = None,
-                      attn_impl: str = "gather"):
+                      attn_impl: str = "gather", with_counters: bool = False):
     """One decode step for every slot of the ragged active batch.
 
     ``tokens`` (B,) are the pending tokens (each slot's last emitted
@@ -602,7 +773,10 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     through it (the loop is :func:`_paged_forward`'s). Padded slots must
     point every table entry at the NULL page (their writes and reads
     are garbage-in/garbage-out, masked by the bias and discarded by the
-    scheduler).
+    scheduler). ``config`` is the model's config or its
+    ``blocks.PagedModel``; with two cache kinds ``k_pages``,
+    ``v_pages`` and ``page_table`` are ``{kind: ..}``, the ``window``
+    kind's table a ring.
 
     ``write_ok`` (B,) bool routes a row's k/v write to the NULL page
     when False — the self-speculative draft loop uses it to cap
@@ -622,22 +796,31 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     (ops/paged_attention.py) — same mask/bias semantics, int8 pages
     dequantized in-register.
 
-    Returns (logits (B, V_local), k_pages, v_pages). Under ``tp_axis``
-    the logits are the LOCAL vocab shard — pair with
+    Returns (logits (B, V_local), k_pages, v_pages), and with
+    ``with_counters`` a fourth: the blocks' counters (a slot with
+    ``seq_lens`` 0 holds no request and is sent to no expert). Under
+    ``tp_axis`` the logits are the LOCAL vocab shard — pair with
     ``_decode.global_greedy_pick`` like the sharded generate driver.
     """
-    ps = page_size_of(k_pages)
-    page_idx = seq_lens // ps
+    model = describe(config, tp_axis)
+    ps = page_size_of(by_kind(k_pages)[GLOBAL])
+    page_idx = (seq_lens // ps)[:, None]
     off = seq_lens % ps
-    phys = jnp.take_along_axis(page_table, page_idx[:, None], axis=1)[:, 0]
+    phys = {k: _dest(t, page_idx, k == WINDOW)[:, 0]
+            for k, t in by_kind(page_table).items()}
     if write_ok is not None:
-        phys = jnp.where(write_ok, phys, NULL_PAGE)
+        phys = {k: jnp.where(write_ok, p, NULL_PAGE) for k, p in phys.items()}
         off = jnp.where(write_ok, off, 0)
-    x, k_pages, v_pages = _paged_forward(
+    x, k_pages, v_pages, counters = _paged_forward(
         params, tokens[:, None], k_pages, v_pages, page_table,
-        seq_lens[:, None], phys[:, None], off[:, None], None, config,
-        tp_axis, attn_impl, n_layers=draft_layers)
-    logits = logits_fn(params, x, tp_axis)[:, 0]  # (B, V_local)
+        seq_lens[:, None], _like(page_table, {k: p[:, None]
+                                              for k, p in phys.items()}),
+        off[:, None], None, model, tp_axis, attn_impl,
+        n_layers=draft_layers,
+        live=(seq_lens > 0)[:, None] if model.counters else None)
+    logits = model.logits(params, x)[:, 0]  # (B, V_local)
+    if with_counters:
+        return logits, k_pages, v_pages, counters
     return logits, k_pages, v_pages
 
 
@@ -731,6 +914,10 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     ``start`` as the per-row global query origin; pad queries beyond
     ``n_valid`` are zeroed by the same qmask multiply.
     """
+    model = describe(config, tp_axis)
+    if model.kinds != (GLOBAL,):
+        raise ValueError("a prefill chunk reads one cache kind: a window "
+                         "layer's ring holds one query a row")
     c = tokens.shape[1]
     ps = page_size_of(k_pages)
     pos = start[:, None] + jnp.arange(c)[None, :]             # (B, C)
@@ -739,11 +926,11 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
         valid, jnp.take_along_axis(page_table, pos // ps, axis=1), NULL_PAGE
     )
     dest_off = jnp.where(valid, pos % ps, 0)
-    x, k_pages, v_pages = _paged_forward(
+    x, k_pages, v_pages, _ = _paged_forward(
         params, tokens, k_pages, v_pages, page_table, pos, dest_page,
-        dest_off, valid, config, tp_axis, attn_impl)
+        dest_off, valid, model, tp_axis, attn_impl)
     if all_logits:
-        return logits_fn(params, x, tp_axis), k_pages, v_pages  # (B, C, V)
+        return model.logits(params, x), k_pages, v_pages        # (B, C, V)
     last = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)
-    logits = logits_fn(params, last, tp_axis)[:, 0]             # (B, V_local)
+    logits = model.logits(params, last)[:, 0]                   # (B, V_local)
     return logits, k_pages, v_pages

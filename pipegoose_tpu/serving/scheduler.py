@@ -23,6 +23,13 @@ shapes); the scheduler's job is to keep those slots full:
   (refcount 1 -> 2), so a reservation made when a page looked evictable
   can never be stranded by a later hit; ``_alloc`` evicts
   least-recently-used unpinned cache pages on demand.
+- **cache kinds**: a pool with a ``window`` kind (``PagePool.kinds``;
+  a model whose sliding-window layers keep a ring of pages,
+  serving/blocks.py) is accounted kind by kind: admission reserves and
+  allocates ``pages_for(.., kind)`` of each, lazy growth fills the ring
+  until it is whole (after which a new logical page takes over the
+  oldest entry: ``PagePool.recycled``), finish and preemption return
+  both kinds.
 - **eviction** frees a finished request's pages and reservation the
   step its last token is emitted — shared pages just drop a reference —
   so the next ``admit`` can re-use both the slot and the pages
@@ -90,6 +97,10 @@ class Request:
     generated: List[int] = field(default_factory=list)
     slot: Optional[int] = None
     pages: List[int] = field(default_factory=list)
+    # the window kind's ring (pools that have one): entry r holds the
+    # newest logical page j with j % ring == r
+    window_pages: List[int] = field(default_factory=list)
+    window_logical: int = 0            # logical pages the ring has held
     outstanding: int = 0               # worst-case pages not yet allocated
     prefilled_len: int = 0             # tokens whose KV is in pages + forwarded
     hit_tokens: int = 0                # of those, tokens served by the cache
@@ -117,6 +128,8 @@ class Request:
         through preempt/admit; this is for when those paths raised)."""
         self.slot = None
         self.pages = []
+        self.window_pages = []
+        self.window_logical = 0
         self.outstanding = 0
         self.cow = None
         self.prefilled_len = self.hit_tokens = 0
@@ -334,7 +347,21 @@ class Scheduler:
         if (self.pool.free_count + evictable - pinned
                 - self._outstanding_total < need_new):
             return False, hit
+        if not self._window_fits(req):
+            return False, hit
         return True, hit
+
+    def _window_fits(self, req: Request) -> bool:
+        """The ledger of the pool's ``window`` kind, where it has one: a
+        request's ring at its worst case beside the entries of every
+        admitted ring not yet allocated."""
+        win = self.pool.window
+        if win is None:
+            return True
+        worst = self.pool.pages_for(self._worst_tokens(req), "window")
+        owed = sum(self.pool.pages_for(self._worst_tokens(r), "window")
+                   - len(r.window_pages) for r in self.active())
+        return win.free_count - owed >= worst
 
     def can_admit(self, req: Request) -> bool:
         """Side-effect-free admission probe: would :meth:`admit` admit
@@ -471,6 +498,8 @@ class Scheduler:
             )
             n_now = self.pool.pages_for(chunk_end) - len(req.pages)
             req.pages += self._alloc(n_now, tag=("req", req.uid))
+            req.window_pages = []
+            self._grow_window(req, chunk_end)
             req.outstanding = need_new - n_now
             self._outstanding_total += req.outstanding
             admitted.append(req)
@@ -728,6 +757,25 @@ class Scheduler:
             req.pages += self._alloc(1, owner=req, tag=("req", req.uid))
             req.outstanding -= 1
             self._outstanding_total -= 1
+        self._grow_window(req, n_tokens)
+
+    def _grow_window(self, req: Request, n_tokens: int) -> None:
+        """The window kind's ring of ``req`` grown to hold ``n_tokens``
+        positions: entries are allocated until the ring is whole; from
+        then on a new logical page takes over the oldest entry (counted
+        in ``PagePool.recycled``), and nothing is allocated or freed."""
+        pool = self.pool
+        if pool.window is None:
+            return
+        have = len(req.window_pages)
+        need = pool.pages_for(n_tokens, "window")
+        if need > have:
+            req.window_pages += pool.alloc(need - have, "window")
+        logical = pool.pages_for(n_tokens)
+        seen = req.window_logical
+        if logical > seen:
+            pool.recycled += max(logical, pool.ring) - max(seen, pool.ring)
+            req.window_logical = logical
 
     def ensure_page(self, req: Request) -> None:
         """Decode-step growth: cover the pending token's write position."""
@@ -787,6 +835,10 @@ class Scheduler:
                 self.pool.tag = ("req", req.uid)
             self.pool.release(req.pages)
             req.pages = []
+        if req.window_pages:
+            self.pool.release(req.window_pages, "window")
+            req.window_pages = []
+        req.window_logical = 0
 
     def _finish(self, req: Request, reason: str, now: float) -> None:
         req.status = Status.DONE

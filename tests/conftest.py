@@ -512,18 +512,6 @@ SLOW_TESTS = {
     "tests/serving/test_paged_kernel.py::test_profile_and_live_step_walls_rank_consistently",
     "tests/serving/test_paged_kernel.py::test_greedy_parity_cold_and_warm[fp]",
     "tests/serving/test_paged_kernel.py::test_mixed_imported_and_local_pages_parity[4x16-fp]",
-    # decided by the CPU's speed, not by the code (PERF.md section 7,
-    # since PR 26): with no drain it needs a request still decoding when
-    # the 2 s window ends, and seed 11's last one is due 14 ms before
-    # that with 9 tokens to go. A decode step of the tiny engine fell
-    # from 1.9 to 1.3 ms on this CPU when the pool went lane-dense (PR
-    # 27), so an idle machine now finishes it in time: 4 of 5 runs of
-    # tests/benchmark alone fail here, none of 4 at the parent; the
-    # driver's own run of PR 26 failed it once. The file is the
-    # benchmark's and not this PR's to edit; its sibling with a drain
-    # (test_serve_cell_prints_the_contracts_last_line, cut_off == 0)
-    # stays tier-1, and this one runs with the slow tier
-    "tests/benchmark/test_benchmark_run.py::test_a_request_the_drain_does_not_finish_is_cut_off_not_failed",
     # third re-curation pass from measured durations (the full
     # `not slow` run measured 868s against the 870s wall after the
     # ISSUE 20 suite landed — zero headroom for box drift): the three

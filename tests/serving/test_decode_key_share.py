@@ -38,7 +38,7 @@ def test_one_arithmetic_for_host_and_device():
         assert isinstance(kv_pool.walked_chunks(pos, WALK), int)
         assert int(traced(jnp.int32(pos))) == want
     assert kv_pool.WALK_KEYS == 256
-    assert kv_pool.walk_plan(16, 128) == (16, 8)     # serve-chat's table
+    assert kv_pool.walk_plan(16, 128) == (16, 8)     # serve-chat-r8's table
     assert kv_pool.walk_plan(16, 5) == (5, 1)        # narrower than a chunk
     assert kv_pool.walk_plan(512, 4) == (1, 4)       # a page over a chunk
     assert kv_pool.walk_plan(4, 7)[1] * kv_pool.walk_plan(4, 7)[0] >= 7
@@ -58,7 +58,12 @@ def test_key_share_is_what_the_steps_were_sent(setup, monkeypatch):
     step = eng._step
 
     def spy(p, tokens, kp, vp, table, seq_lens):
-        sent.append(np.asarray(seq_lens))
+        # a COPY: on the CPU ``jnp.asarray`` of an aligned numpy buffer
+        # shares it, and ``np.asarray`` of that is the engine's own
+        # ``seq_lens``, which the next tick refills (whether numpy's
+        # allocation is aligned varies run by run: half the runs read
+        # the NEXT step's lengths, 0.2604 for 0.25)
+        sent.append(np.array(seq_lens))
         return step(p, tokens, kp, vp, table, seq_lens)
 
     eng._step = spy
