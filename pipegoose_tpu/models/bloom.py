@@ -48,6 +48,7 @@ from pipegoose_tpu.nn.tensor_parallel.layers import (
     vocab_parallel_cross_entropy,
     vocab_parallel_embedding,
 )
+from pipegoose_tpu.ops.flash_attention import remat_policy
 
 NEG_INF = -1e9
 
@@ -65,13 +66,16 @@ class BloomConfig:
     dtype: Any = jnp.float32
     # rematerialize each block's activations in backward (HBM for FLOPs)
     remat: bool = False
-    # selective-remat policy under remat=True: None saves nothing (full
-    # remat); "dots" saves matmul outputs except batch-dim ones
+    # selective-remat policy under remat=True: None saves the block's
+    # input and nothing it computes; "dots" saves matmul outputs except
+    # batch-dim ones
     # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable);
     # "attn" saves only the per-block attention outputs
     # (checkpoint_name "attn_out", present on every attention variant)
     # so backward never re-runs attention — between full remat (slow,
-    # tiny HBM) and no remat (fast, 2x HBM)
+    # tiny HBM) and no remat (fast, 2x HBM). Under all three a block
+    # also keeps the flash kernel's result and logsumexp where it ran
+    # one (_remat_wrap), so backward never re-runs THAT forward
     remat_policy: Optional[str] = None
     # fused Pallas flash attention (ops/flash_attention.py): causal+alibi,
     # padding masks supported via the kernel's kv_pos/kv_neg bias inputs
@@ -109,21 +113,21 @@ class BloomConfig:
 
 def _remat_wrap(fn, config):
     """``jax.checkpoint`` honoring ``config.remat_policy`` (caller gates
-    on ``config.remat``)."""
-    policy = getattr(config, "remat_policy", None)
-    if policy == "dots":
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        )
-    if policy == "attn":
-        # save only the attention outputs (checkpoint_name "attn_out",
-        # set on every _attention/_attention_sp branch): backward
-        # recomputes the cheap elementwise/matmul parts but never
-        # re-runs attention — for ~(B,S,H) x n_layer extra HBM
-        return jax.checkpoint(
-            fn, policy=jax.checkpoint_policies.save_only_these_names("attn_out")
-        )
-    return jax.checkpoint(fn)
+    on ``config.remat``). Under every policy the block keeps what the
+    flash kernel left for its backward (``flash_attention.
+    RESIDUAL_NAMES``: its result and logsumexp, ~(B,S,H) a layer), so
+    backward never re-runs the forward kernel; a block that calls no
+    flash kernel has nothing by those names."""
+    policies = jax.checkpoint_policies
+    extra = {
+        "dots": policies.dots_with_no_batch_dims_saveable,
+        # the attention outputs as well (checkpoint_name "attn_out", set
+        # on every _attention/_attention_sp branch): backward recomputes
+        # the cheap elementwise/matmul parts but never re-runs attention
+        # of any kind — for ~(B,S,H) x n_layer extra HBM
+        "attn": policies.save_only_these_names("attn_out"),
+    }.get(getattr(config, "remat_policy", None))
+    return jax.checkpoint(fn, policy=remat_policy(extra))
 
 
 # -- init ------------------------------------------------------------------

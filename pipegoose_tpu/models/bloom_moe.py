@@ -32,6 +32,7 @@ from pipegoose_tpu.nn.expert_parallel.experts import moe_layer
 from pipegoose_tpu.nn.expert_parallel.loss import ExpertLoss
 from pipegoose_tpu.nn.expert_parallel.routers import TopKRouter
 from pipegoose_tpu.nn.tensor_parallel.layers import vocab_parallel_cross_entropy
+from pipegoose_tpu.ops.flash_attention import remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -151,7 +152,8 @@ def forward_hidden(
         )
         return out, (aux, z)
 
-    step = jax.checkpoint(scan_fn) if config.remat else scan_fn
+    step = (jax.checkpoint(scan_fn, policy=remat_policy())
+            if config.remat else scan_fn)
     x, (aux, z) = jax.lax.scan(step, x, (params["blocks"], layer_keys))
     return layer_norm(params["ln_f"], x, config.layer_norm_epsilon), aux, z
 

@@ -59,6 +59,7 @@ from pipegoose_tpu.nn.tensor_parallel.layers import (
     vocab_parallel_cross_entropy,
     vocab_parallel_embedding,
 )
+from pipegoose_tpu.ops.flash_attention import remat_policy
 
 
 # vocabulary rows a tile of the fused CE: at hidden 2048 with a padded
@@ -344,7 +345,10 @@ def _trunk(params, input_ids, attention_mask, config, tp_axis):
     bias = rope_attention_bias(attention_mask, c)
     block = _block
     if c.remat:
-        block = jax.checkpoint(block, static_argnums=(5, 6))
+        # the block's input and what the flash kernel left for its
+        # backward: nothing else is kept, and no forward kernel re-runs
+        block = jax.checkpoint(block, static_argnums=(5, 6),
+                               policy=remat_policy())
     x, _ = block(params["dense"], x, cos, sin, bias, c, tp_axis)
     x, rows = jax.lax.scan(
         lambda carry, blk: block(blk, carry, cos, sin, bias, c, tp_axis),

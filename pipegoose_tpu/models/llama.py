@@ -35,6 +35,7 @@ from pipegoose_tpu.nn.tensor_parallel.layers import (
     vocab_parallel_cross_entropy,
     vocab_parallel_embedding,
 )
+from pipegoose_tpu.ops.flash_attention import remat_policy
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,7 +148,7 @@ def forward_hidden(
 
     block = partial(_block, config=config, tp_axis=tp_axis)
     if config.remat:
-        block = jax.checkpoint(block)
+        block = jax.checkpoint(block, policy=remat_policy())
 
     def scan_fn(carry, blk):
         return block(blk, carry, cos, sin, bias), None
@@ -322,7 +323,7 @@ def loss_fn_1f1b(
 
     block = partial(_block, config=config, tp_axis=tp_axis)
     if config.remat:
-        block = jax.checkpoint(block)
+        block = jax.checkpoint(block, policy=remat_policy())
 
     if stage_layer_counts is not None:
         from pipegoose_tpu.nn.pipeline_parallel.partitioner import (
@@ -471,7 +472,8 @@ def loss_fn_sp(
     def scan_fn(carry, blk):
         return block(blk, carry), None
 
-    step = jax.checkpoint(scan_fn) if config.remat else scan_fn
+    step = (jax.checkpoint(scan_fn, policy=remat_policy())
+            if config.remat else scan_fn)
     x, _ = jax.lax.scan(step, x, params["blocks"])
 
     x = rms_norm(params["ln_f"], x, config.rms_eps)

@@ -32,6 +32,7 @@ from pipegoose_tpu.nn.tensor_parallel.layers import (
     vocab_parallel_cross_entropy,
     vocab_parallel_embedding,
 )
+from pipegoose_tpu.ops.flash_attention import remat_policy
 
 NEG_INF = -1e9
 
@@ -373,7 +374,8 @@ def forward_hidden(
         )
         return out, (aux, z)
 
-    step = jax.checkpoint(scan_fn) if config.remat else scan_fn
+    step = (jax.checkpoint(scan_fn, policy=remat_policy())
+            if config.remat else scan_fn)
     x, (aux, z) = jax.lax.scan(step, x, (params["blocks"], layer_keys))
     return rms_norm(params["ln_f"], x, config.rms_eps), aux, z
 
@@ -999,7 +1001,8 @@ def loss_fn_sp(
         )
         return out, (aux, z)
 
-    step = jax.checkpoint(scan_fn) if config.remat else scan_fn
+    step = (jax.checkpoint(scan_fn, policy=remat_policy())
+            if config.remat else scan_fn)
     x, (aux, z) = jax.lax.scan(step, x, (params["blocks"], layer_keys))
 
     x = rms_norm(params["ln_f"], x, config.rms_eps)

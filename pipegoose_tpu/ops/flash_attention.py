@@ -52,8 +52,24 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 NEG_INF = -1e9
+
+# What ``_flash_fwd`` leaves for ``_flash_bwd`` carries these names, in
+# this order: the kernel's result and its per-row logsumexp. A block's
+# ``jax.checkpoint`` that saves them (``remat_policy``) never re-runs
+# the forward kernel in backward; one that does not ignores them.
+RESIDUAL_NAMES = ("flash_out", "flash_lse")
+
+
+def remat_policy(extra=None):
+    """The ``jax.checkpoint`` policy that keeps the flash kernel's
+    residuals, and whatever ``extra`` (another policy) keeps."""
+    keep = jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES)
+    if extra is None:
+        return keep
+    return jax.checkpoint_policies.save_from_both_policies(keep, extra)
 
 
 def _pick_block(n: int, target: int = 128) -> int:
@@ -886,6 +902,10 @@ def _flash_fwd(q, k, v, slopes, kpos, kneg, scale, causal, interpret, g=1,
         *_blocks(q, "fwd"),
         _resolve_interpret(interpret), g, window,
     )
+    # named INSIDE the rule: what backward needs is these two values,
+    # and a name outside the custom call would leave lse to recompute
+    out, lse = (checkpoint_name(x, name)
+                for x, name in zip((out, lse), RESIDUAL_NAMES))
     return out, (q, k, v, slopes, kpos, kneg, out, lse)
 
 

@@ -10,7 +10,7 @@ repo's own test suite builds on (tests/conftest.py).
 """
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Callable
 
 from pipegoose_tpu.testing.chaos import (  # noqa: F401
     ChaosMonkey,
@@ -40,6 +40,8 @@ __all__ = [
     "parameter_similarity",
     "assert_trees_allclose",
     "random_input_ids",
+    "kernel_calls",
+    "saved_residuals",
 ]
 
 
@@ -105,3 +107,43 @@ def random_input_ids(vocab_size: int, shape: tuple, seed: int = 0):
     import numpy as np
 
     return jnp.asarray(np.random.RandomState(seed).randint(0, vocab_size, shape))
+
+
+def kernel_calls(jaxpr: Any, name: str) -> int:
+    """How often one run of ``jaxpr`` (``jax.make_jaxpr``'s result)
+    calls the Pallas kernel named ``name``: a call inside a ``scan``
+    counts once a trip, and every branch of a ``cond`` counts. What a
+    program runs is then a fact of its jaxpr, with no chip: the flash
+    forward once an attention layer, not twice
+    (tests/ops/test_flash_attention.py)."""
+    import jax
+
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    calls = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            calls += eqn.params["name"] == name
+            continue
+        inner = sum(kernel_calls(sub, name)
+                    for sub in jax.core.jaxprs_in_params(eqn.params))
+        trips = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        calls += trips * inner
+    return calls
+
+
+def saved_residuals(fn: Callable, *args: Any) -> list:
+    """``[(shape, source)]`` of what ``jax.grad(fn)`` keeps of what
+    ``fn`` COMPUTES (arguments and constants left out), sorted, as
+    ``jax.ad_checkpoint.print_saved_residuals`` words them: a shape
+    such as ``f32[2,64]`` and a source such as ``named 'flash_lse'``."""
+    import contextlib
+    import io
+
+    from jax.ad_checkpoint import print_saved_residuals
+
+    text = io.StringIO()
+    with contextlib.redirect_stdout(text):
+        print_saved_residuals(fn, *args)
+    rows = (line.split(" ", 1) for line in text.getvalue().splitlines())
+    return sorted((shape, rest.split(" from ")[0]) for shape, rest in rows
+                  if not rest.startswith("from "))

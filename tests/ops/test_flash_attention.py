@@ -7,6 +7,7 @@ import pytest
 
 from pipegoose_tpu.models.bloom import alibi_slopes
 from pipegoose_tpu.ops.flash_attention import _xla_reference, flash_attention
+from pipegoose_tpu.testing import kernel_calls, saved_residuals
 
 B, S, NH, HD = 2, 128, 4, 64
 
@@ -436,3 +437,92 @@ def test_pick_blocks_at_the_cells_shapes(seq, width):
         assert fa._pick_blocks(seq, width, 2, kind, V5E_LIMIT) == (1024, 1024)
         bq, bk = fa._pick_blocks(seq, width, 2, kind, DEFAULT_LIMIT)
         assert bq >= 256 and bq * bk > 128 * 512, (kind, bq, bk)
+
+
+# -- what a block's checkpoint keeps of the kernel -------------------------
+
+# name: (heads, kv heads, head width, window)
+REMAT_CASES = {
+    "mha_hd64": (4, 4, 64, None),
+    "gqa2": (4, 2, 64, None),
+    "window": (4, 4, 64, 48),
+    "hd256": (2, 2, 256, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REMAT_CASES))
+def test_a_checkpointed_block_keeps_the_kernels_residuals(case):
+    """Under ``remat_policy()`` backward takes ``out`` and ``lse`` from
+    the forward pass: the gradient of two scanned blocks holds ONE
+    ``flash_fwd`` a block (two under a bare ``jax.checkpoint``, which
+    ignores the names) and equals the gradient with no checkpoint."""
+    nh, nkv, hd, window = REMAT_CASES[case]
+    b, s, h = 2, 128, 32
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    ws = {
+        "qkv": jax.random.normal(ks[0], (2, h, (nh + 2 * nkv) * hd)) * h**-0.5,
+        "out": jax.random.normal(ks[1], (2, nh * hd, h)) * (nh * hd) ** -0.5,
+    }
+    x = jax.random.normal(ks[2], (b, s, h))
+    slopes = jnp.asarray(alibi_slopes(nh))
+
+    def block(w, x):
+        q, k, v = jnp.split(x @ w["qkv"], [nh * hd, (nh + nkv) * hd], axis=-1)
+        ctx = flash_attention(
+            q.reshape(b, s, nh, hd), k.reshape(b, s, nkv, hd),
+            v.reshape(b, s, nkv, hd), slopes, window=window, interpret=True)
+        return x + jnp.tanh(ctx.reshape(b, s, nh * hd) @ w["out"])
+
+    def loss(wrap):
+        step = wrap(block)
+        return lambda ws, x: (jax.lax.scan(
+            lambda c, w: (step(w, c), None), x, ws)[0] ** 2).sum()
+
+    grads = {
+        "plain": jax.grad(loss(lambda f: f), argnums=(0, 1)),
+        "bare": jax.grad(loss(jax.checkpoint), argnums=(0, 1)),
+        "kept": jax.grad(loss(lambda f: jax.checkpoint(
+            f, policy=fa.remat_policy())), argnums=(0, 1)),
+    }
+    jaxprs = {name: jax.make_jaxpr(g)(ws, x) for name, g in grads.items()}
+    # two blocks
+    assert {n: kernel_calls(j, "flash_fwd") for n, j in jaxprs.items()} == {
+        "plain": 2, "bare": 4, "kept": 2}
+    for name, j in jaxprs.items():
+        assert (kernel_calls(j, "flash_dq"),
+                kernel_calls(j, "flash_dkv")) == (2, 2), name
+    want = grads["plain"](ws, x)
+    for name in ("kept", "bare"):
+        got = grads[name](ws, x)
+        for a, b_ in zip(jax.tree_util.tree_leaves(got),
+                         jax.tree_util.tree_leaves(want)):
+            # the saved values are the ones the kernel would compute
+            # again: the same bits, not merely close ones
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b_),
+                                          err_msg=f"{case}: {name}")
+
+
+def test_remat_policy_keeps_what_the_callers_policy_keeps():
+    """``remat_policy(extra)`` saves the flash names AND what ``extra``
+    saves; alone, nothing a block computes but the two residuals."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    q = k = v = jnp.ones((1, 64, 2, 64))
+
+    def f(q, k, v):
+        ctx = flash_attention(q, k, v, None, interpret=True)
+        # squared, so that backward needs the named value itself
+        return (checkpoint_name(jnp.sin(ctx), "mine") ** 2).sum()
+
+    def kept(policy):
+        return set(saved_residuals(jax.checkpoint(f, policy=policy), q, k, v))
+
+    alone = kept(fa.remat_policy())
+    assert ("f32[2,64]", "named 'flash_lse'") in alone
+    # ``out`` goes on into the block, so jax reports the rounding it
+    # puts on a saved value that is also used, not the name
+    assert {shape for shape, _ in alone} == {"f32[2,64]", "f32[2,64,64]"}
+    both = kept(fa.remat_policy(
+        jax.checkpoint_policies.save_only_these_names("mine")))
+    assert alone < both
+    assert {shape for shape, _ in both - alone} == {"f32[1,64,2,64]"}
