@@ -11,7 +11,8 @@ stacked on a leading axis and walked by one ``fori_loop`` (BLOOM: one
 group), or a single unstacked layer where shapes differ (Laguna: a group
 a layer, 48 or 72 query heads, dense or sparse).
 
-A group's ``kind`` names the cache its layers keep:
+A group's ``kind`` names the cache of keys and values its layers'
+attention keeps, which is APPENDED to, a row a position:
 
 * ``"global"``: every page of a sequence, through a page table as wide
   as ``max_context``;
@@ -19,10 +20,20 @@ A group's ``kind`` names the cache its layers keep:
   ``ring_pages(window, page_size)`` pages, logical page ``j`` in ring
   entry ``j % ring``; the read masks what has left the window.
 
+A layer may keep a second cache beside it, which is OVERWRITTEN: a
+``state`` of fixed size a sequence (a recurrence's, a short
+convolution's last inputs), held a slot a layer in a bank beside the
+pool (``kv_pool.init_state``) and not paged. The model gives its shapes
+(``PagedModel.state``), its group a ``mix`` hook that reads and returns
+the bank in a decode step, and its ``prefill`` the state after the last
+real token of a bucketed prompt, which the engine puts in the slot at
+admission.
+
 BLOOM is the first instance (:func:`bloom_model`), Laguna the second
-(``models/laguna.py:paged_model``). A config object that has a
-``paged_model(tp_axis)`` method describes itself; any other is taken
-for a BLOOM (:func:`describe`).
+(``models/laguna.py:paged_model``: two kinds), Falcon-H1 the third
+(``models/falcon_h1.py:paged_model``: global pages and a state in every
+block). A config object that has a ``paged_model(tp_axis)`` method
+describes itself; any other is taken for a BLOOM (:func:`describe`).
 """
 from __future__ import annotations
 
@@ -35,7 +46,7 @@ KINDS = (GLOBAL, WINDOW)
 
 @dataclass(frozen=True)
 class LayerGroup:
-    kind: str                 # the cache kind of the group's layers
+    kind: str                 # the cache kind of the layers' keys and values
     n: int                    # layers in the group
     stacked: bool             # params carry a leading (n,) axis
     params: Callable          # params -> the group's subtree
@@ -48,6 +59,12 @@ class LayerGroup:
     # () -> (H,) ALiBi slopes of this shard's heads, called inside the
     # program; None: no position bias on the scores (rotary models)
     slopes: Optional[Callable] = None
+    # a mixer that keeps a state a slot beside the keys and values, run
+    # between ``qkv`` and ``finish`` of a decode step (row i is slot i):
+    # (blk, saved, bank {name: (L, slots, ..)}, layer, live (B,) bool)
+    #  -> (saved, bank), the bank's rows of ``layer`` read and overwritten
+    # (``kv_pool.update_state_rows``); None: the layers keep none
+    mix: Optional[Callable] = None
 
 
 @dataclass(frozen=True)
@@ -62,13 +79,17 @@ class PagedModel:
     # the model's own forward over one bucketed prompt:
     # (params, ids (1, S), mask (1, S)) -> (logits (1, V_local), cache)
     # with cache {"k", "v"} (L, 1, S, KV, hd) for a one-kind model and
-    # {kind: {"k", "v"}} for a two-kind one
+    # {kind: {"k", "v"}} for a two-kind one; a model with ``state`` adds
+    # "state": {name: (L, 1, ..)} after the prompt's last REAL token
     prefill: Callable
     left_pad: bool = True     # the side a bucketed prompt is padded on
     window: Optional[int] = None          # keys a window layer keeps
     # the name of what the groups' ``finish`` brings out of a decode
     # step (stacked over the layers that bring any); None: nothing
     counters: Optional[str] = None
+    # what a sequence leaves in a layer beside its keys and values, a
+    # slot: ((name, shape, dtype), ..); (): nothing
+    state: Tuple[tuple, ...] = ()
 
     @property
     def kinds(self) -> Tuple[str, ...]:

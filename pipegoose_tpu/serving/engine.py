@@ -44,8 +44,16 @@ cache (serving/blocks.py: ``describe(config)``): BLOOM's, or the one a
 config object gives of itself (``paged_model``). A model whose layers
 keep two cache kinds (global layers every page, window layers a ring:
 models/laguna.py) gets a bank, a page table and an allocator a kind,
-under the same scheduler and tick; the opt-in modes below are built for
-one cache kind and refuse such a model by name.
+under the same scheduler and tick. A model whose layers keep a STATE
+beside their keys and values (models/falcon_h1.py: a recurrence's and a
+short convolution's, overwritten every token, not appended to) gets a
+state bank, a row a slot a layer (kv_pool.init_state): at admission the
+prefill's result is put in the slot's row by the program that writes the
+prompt's pages, so a slot's leftover state is never read; every decode
+step reads and overwrites the rows of the live slots in place; a
+preempted request re-prefills prompt plus generated tokens, so no state
+is ever saved. The opt-in modes below are built for a cache that is
+global pages and nothing more, and refuse either kind of model by name.
 
 Everything device-side is compiled with STATIC shapes: the decode step
 is one program for the (num_slots, page-table-width) layout regardless
@@ -109,11 +117,15 @@ from pipegoose_tpu.serving.kv_pool import (
     check_kv_dtype,
     copy_page,
     init_pages,
+    init_state,
     paged_decode_step,
     paged_prefill_chunk,
+    state_walk_plan,
     walk_plan,
     walked_chunks,
+    walked_state_rows,
     write_prompt_pages,
+    write_state,
 )
 from pipegoose_tpu.serving.kv_tier.restore import (
     RestoreManager,
@@ -180,6 +192,8 @@ class _RunState:
         "keys_walked", "keys_reached", "window_table", "window_keys_walked",
         "window_keys_reached", "occ_window", "peak_pages", "recycled0",
         "experts_touched", "expert_skew", "rows_routed",
+        "state_rows_updated", "state_rows_live", "state_writes",
+        "state_peak_slots",
     )
 
     def __init__(self, engine: "ServingEngine", now, tick_hook):
@@ -213,6 +227,11 @@ class _RunState:
         self.experts_touched: List[int] = []
         self.expert_skew = 0.0
         self.rows_routed = 0
+        # the state bank (a model that has one): rows the decode steps'
+        # walks read and wrote, rows alive in them, prefill results put
+        # in a slot, the most slots holding a request in a step
+        self.state_rows_updated = self.state_rows_live = 0
+        self.state_writes = self.state_peak_slots = 0
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.table = np.zeros((engine.num_slots, engine.table_width),
                               np.int32)
@@ -328,10 +347,16 @@ class ServingEngine:
         if stall_patience < 1:
             raise ValueError(f"stall_patience must be >= 1, got {stall_patience}")
         model = describe(config)
-        if len(model.kinds) > 1:
-            # built for one cache kind, none half-carried over: a prefix
-            # page, a draft, a chunk or a transfer would each need the
-            # window layers' ring beside the global layers' pages
+        # a cache that is more than global pages
+        more = ([f"{len(model.kinds)} cache kinds {model.kinds}"]
+                if len(model.kinds) > 1 else []) + (
+            ["a state a slot beside its pages"] if model.state else [])
+        if more:
+            # built for global pages alone, none half-carried over: a
+            # prefix page, a draft, a chunk or a transfer would each need
+            # the window layers' ring, or the state as it stood there (a
+            # shared prefix's, a refused draft's, a chunk's), beside the
+            # global layers' pages
             asked = {
                 "prefix_cache": prefix_cache, "speculative": speculative,
                 "prefill_chunk": prefill_chunk, "kv_dtype": kv_dtype,
@@ -345,8 +370,8 @@ class ServingEngine:
                         and value != "fp":
                     raise ValueError(
                         f"{mode} is not built for a model with "
-                        f"{len(model.kinds)} cache kinds {model.kinds}: "
-                        f"serve it with the engine's defaults")
+                        f"{' and '.join(more)}: serve it with the "
+                        f"engine's defaults")
         elif mesh is not None:
             model = describe(config, tp_axis)
         self.model = model
@@ -421,6 +446,8 @@ class ServingEngine:
         }
         self._m_recycled = reg.counter("serving.window_pages_recycled_total")
         self._m_experts = reg.gauge("serving.experts_touched_share")
+        self._m_state_slots = reg.gauge("serving.state_slots_in_use")
+        self._m_state_writes = reg.counter("serving.state_writes_total")
         self._m_prefill_tok = reg.counter("serving.prefill_tokens_total")
         self._m_chunks = reg.counter("serving.prefill_chunks_total")
         self._m_gap = reg.histogram("serving.decode_gap_seconds")
@@ -550,6 +577,9 @@ class ServingEngine:
             model, num_pages, page_size, kv_dtype=self.kv_dtype,
             window_pages=self.pool.window.num_pages if ring else 0,
         )
+        # the state bank, a row a slot a layer ({}: the model keeps none)
+        self.state = init_state(model, num_slots)
+        self._state_rows = state_walk_plan(num_slots)[0]
         valid = getattr(config, "valid_vocab_size", None)
         mask_fn = vocab_mask_for(config)
         spec_k = speculative[0] if speculative else None
@@ -565,21 +595,33 @@ class ServingEngine:
                     return write_prompt_pages(
                         k_pages, v_pages, cache, phys, pad, page_size
                     )
-            else:
+            elif not model.state:
                 def _write(k_pages, v_pages, cache, phys, pad, length):
                     return write_prompt_pages(
                         k_pages, v_pages, cache, phys, pad, page_size,
                         length)
+            else:
+                def _write(k_pages, v_pages, cache, phys, pad, length,
+                           state, slot):
+                    # the prompt's pages and the slot's state in one
+                    # program: the next step finds both or neither
+                    return write_prompt_pages(
+                        k_pages, v_pages, cache, phys, pad, page_size,
+                        length) + (write_state(state, cache["state"], slot),)
 
-            def _step(params, tokens, k_pages, v_pages, table, seq_lens):
-                # a fourth result: the blocks' counters ({} where a
-                # model's blocks bring none: no leaf, the same program)
-                logits, k_pages, v_pages, counters = paged_decode_step(
-                    params, tokens, k_pages, v_pages, table, seq_lens, model,
-                    attn_impl=attn_kernel, with_counters=True,
-                )
+            def _step(params, tokens, k_pages, v_pages, table, seq_lens,
+                      state={}):
+                # a fourth result: the blocks' counters, and a fifth: the
+                # state bank ({} where a model has none: no leaf, the
+                # same program)
+                logits, k_pages, v_pages, counters, state = \
+                    paged_decode_step(
+                        params, tokens, k_pages, v_pages, table, seq_lens,
+                        model, attn_impl=attn_kernel, with_counters=True,
+                        state=state,
+                    )
                 return (greedy_token(logits, mask_fn), k_pages, v_pages,
-                        counters)
+                        counters, state)
 
             def _chunk(params, ids, k_pages, v_pages, table, start, n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
@@ -607,8 +649,9 @@ class ServingEngine:
                 return greedy_token(logits, mask_fn), k_pages, v_pages
 
             self._prefill = jax.jit(_prefill)
-            self._write = jax.jit(_write, donate_argnums=(0, 1))
-            self._step = jax.jit(_step, donate_argnums=(2, 3))
+            self._write = jax.jit(
+                _write, donate_argnums=(0, 1, 6) if model.state else (0, 1))
+            self._step = jax.jit(_step, donate_argnums=(2, 3, 6))
             self._chunk = jax.jit(_chunk, donate_argnums=(2, 3))
             self._copy = jax.jit(_copy, donate_argnums=(0, 1))
             self._draft = jax.jit(_draft, donate_argnums=(2, 3))
@@ -640,7 +683,7 @@ class ServingEngine:
                     model, tp_axis, attn_impl=attn_kernel,
                 )
                 tok = global_greedy_pick(logits, tp_axis, valid)
-                return tok, k_pages, v_pages, {}
+                return tok, k_pages, v_pages, {}, {}
 
             def _chunk_body(params, ids, k_pages, v_pages, table, start,
                             n_valid):
@@ -689,7 +732,7 @@ class ServingEngine:
             self._step = jax.jit(shard_map(
                 _step_body, mesh=mesh,
                 in_specs=(param_specs, P(), pspec, pspec, P(), P()),
-                out_specs=(P(), pspec, pspec, {}), check_vma=False,
+                out_specs=(P(), pspec, pspec, {}, {}), check_vma=False,
             ), donate_argnums=(2, 3))
             self._chunk = jax.jit(shard_map(
                 _chunk_body, mesh=mesh,
@@ -750,10 +793,10 @@ class ServingEngine:
                         P(), P())
         report = diagnose(
             self._step, self.params, tokens, self.k_pages, self.v_pages,
-            table, seq_lens,
+            table, seq_lens, *self._state_arg(),
             intended=intended,
             labels=("params", "tokens", "k_pages", "v_pages", "table",
-                    "seq_lens"),
+                    "seq_lens") + ("state",) * len(self._state_arg()),
             mesh=self.mesh, large_bytes=large_bytes,
         )
         if self.attn_kernel == "paged":
@@ -833,15 +876,17 @@ class ServingEngine:
         final: dict = {}
 
         def update(out, cur):
-            # out = (next_tokens, k_pages, v_pages, counters); the pages
-            # were donated — thread (and finally adopt) the new buffers
-            final["k"], final["v"] = out[1], out[2]
-            return (cur[0], cur[1], out[1], out[2], cur[4], cur[5])
+            # out = (next_tokens, k_pages, v_pages, counters, state); the
+            # pages and the state bank were donated — thread (and finally
+            # adopt) the new buffers
+            final["k"], final["v"], final["state"] = out[1], out[2], out[4]
+            return (cur[0], cur[1], out[1], out[2], cur[4], cur[5]) + (
+                (out[4],) if self.state else ())
 
         try:
             profile = profile_step(
                 self._step, self.params, tokens, self.k_pages, self.v_pages,
-                table, seq_lens,
+                table, seq_lens, *self._state_arg(),
                 steps=steps, warmup=warmup, update_args=update,
                 mesh=self.mesh, trace_dir=trace_dir,
                 registry=registry or self.registry,
@@ -853,6 +898,7 @@ class ServingEngine:
             # would touch deleted arrays
             if final:
                 self.k_pages, self.v_pages = final["k"], final["v"]
+                self.state = final["state"]
         self.last_step_profile = profile
         return profile
 
@@ -903,6 +949,10 @@ class ServingEngine:
                 "by_kind": by_kind,
             },
         }
+        if self.state:
+            # the state bank beside the pool: a row a slot a layer
+            report["state"] = {"slots": self.num_slots,
+                               "total_bytes": self._state_bytes()}
         if self.host_tier is not None:
             # exact slab census: pages x wire bytes (q+scale for int8
             # pools — never fp-sized), the ISSUE's pinned invariant
@@ -979,6 +1029,15 @@ class ServingEngine:
         self._first_call_s[key] = None
         self.programs_run += 1
         return True
+
+    def _state_arg(self) -> tuple:
+        """The state bank as the decode program's last argument: nothing
+        for a model without (its program takes no such argument)."""
+        return (self.state,) if self.state else ()
+
+    def _state_bytes(self) -> int:
+        return sum(int(x.size) * int(np.dtype(x.dtype).itemsize)
+                   for x in self.state.values())
 
     def _by_kind(self, make):
         """``make(table width)`` for every cache kind of the model: the
@@ -1104,18 +1163,17 @@ class ServingEngine:
 
     def _prefill_request(self, req: Request, now) -> None:
         """Legacy monolithic prefill: run the bucketed contiguous
-        forward, scatter the prompt KV into the request's pages, and
-        record the first generated token."""
-        if req.generated:
-            raise RuntimeError(
-                "re-admitting a preempted request requires the paged "
-                "prefill path — construct the engine with prefix_cache "
-                "and/or prefill_chunk"
-            )
+        forward, scatter the prompt KV into the request's pages (and,
+        for a model with a state a slot, the state after the last token
+        into the slot's row), and record the first generated token. A
+        request re-admitted after a preemption forwards its prompt plus
+        every generated token but the pending one (``target_len``) and
+        resumes decoding on that one: the forward's own pick repeats it
+        and is dropped."""
         tr = self.tracer
         t0 = now() if tr is not None else 0.0
         with span("serving.prefill", registry=self.registry):
-            s = req.prompt_len
+            s = req.target_len
             bucket = self.pool.pages_for(s) * self.page_size
             first = self._note_program("prefill", bucket)
             first_write = self._note_program("write", bucket)
@@ -1124,7 +1182,7 @@ class ServingEngine:
             left = self.model.left_pad
             pad = bucket - s if left else 0
             ids = np.zeros((1, bucket), np.int32)
-            ids[0, pad:pad + s] = np.asarray(req.prompt, np.int32)
+            ids[0, pad:pad + s] = req.tokens[:s]
             mask = np.zeros((1, bucket), np.int32)
             mask[0, pad:pad + s] = 1
             t_call = now()
@@ -1134,11 +1192,20 @@ class ServingEngine:
             t_write = now()
             # dispatched and never fetched: on the device the write runs
             # after this span has closed, in the next fetch's wait
-            self.k_pages, self.v_pages = self._write(
+            written = self._write(
                 self.k_pages, self.v_pages, cache, self._phys_rows(req),
                 jnp.asarray(pad, jnp.int32),
                 *(() if left else (jnp.asarray(s, jnp.int32),)),
+                *((self.state, jnp.asarray(req.slot, jnp.int32))
+                  if self.state else ()),
             )
+            self.k_pages, self.v_pages = written[:2]
+            if self.state:
+                # the slot starts from this prefill's state, whatever
+                # its last request left in the row
+                self.state = written[2]
+                self._run.state_writes += 1
+                self._m_state_writes.inc()
             t_fetch = now()
             # the token fetch syncs the device, so the span's wall time
             # covers the prefill's actual device work
@@ -1153,11 +1220,17 @@ class ServingEngine:
                 self._first_call_s["write", bucket] = t_fetch - t_write
             if tr is not None:
                 tr.on_prefill_chunk(req, t1, dur_s=t1 - t0, tokens=s)
-            self.sched.record_token(req, tok, t1)
+            if req.generated:
+                # resumed after preemption: nothing new to record
+                req.status = Status.DECODE
+                if tr is not None:
+                    tr.on_resume(req, t1)
+            else:
+                self.sched.record_token(req, tok, t1)
+                self._m_tokens.inc()  # the prefill's token
         self._m_prefill_tok.inc(s)
         self._run_prefill_tokens += s
         self._m_prefills.inc()
-        self._m_tokens.inc()  # the prefill's token
         self._observe_ttft(req)
 
     def _start_prefill(self, req: Request, now) -> None:
@@ -1643,11 +1716,12 @@ class ServingEngine:
             t_step = now()
             with span("serving.decode_step", registry=reg):
                 with span("dispatch", registry=reg):
-                    nxt, self.k_pages, self.v_pages, counters = self._step(
+                    (nxt, self.k_pages, self.v_pages, counters,
+                     self.state) = self._step(
                         self.params, jnp.asarray(rs.tokens), self.k_pages,
                         self.v_pages, jax.tree_util.tree_map(
                             jnp.asarray, table),
-                        jnp.asarray(rs.seq_lens),
+                        jnp.asarray(rs.seq_lens), *self._state_arg(),
                     )
                 t_disp = now()
                 # the host waiting on the device: what it waits for is
@@ -1688,6 +1762,16 @@ class ServingEngine:
                     rs.window_keys_reached += self._ring_keys
                 if counters:
                     self._note_counters(rs, counters)
+                if self.state:
+                    # the walk over the state bank, as the program made
+                    # it: every trip's rows up to the highest live slot
+                    trips = walked_state_rows(
+                        max(r.slot for r in active), self._state_rows)
+                    rs.state_rows_updated += trips * self._state_rows
+                    rs.state_rows_live += len(active)
+                    rs.state_peak_slots = max(rs.state_peak_slots,
+                                              len(active))
+                    self._m_state_slots.set(len(active))
             slot_occ = len(active) / self.num_slots
             used = self.pool.used_by_kind()
             page_occ = used[GLOBAL] / self.pool.capacity
@@ -1914,6 +1998,22 @@ class ServingEngine:
                     rs.expert_skew / (n * self._sparse_layers), 4),
                 "rows_routed": rs.rows_routed,
                 "held_a_step": self._experts_held,
+            }
+        if self.state:
+            metrics["state"] = {
+                "slots": self.num_slots,
+                "bytes_per_slot": self._state_bytes() // self.num_slots,
+                # the most slots holding a request in a decode step, and
+                # the mean share of them that did
+                "peak_slots_in_use": rs.state_peak_slots,
+                "occupancy": round(rs.occ_slots / rs.steps, 4)
+                if rs.steps else 0.0,
+                # rows whose state the decode steps read and wrote (their
+                # walks reach the highest live slot), rows alive in them
+                "rows_updated": rs.state_rows_updated,
+                "rows_live": rs.state_rows_live,
+                # prefill results put in a slot (admissions, re-admissions)
+                "writes": rs.state_writes,
             }
         if self._paged_prefill:
             metrics["prefill_chunks"] = rs.chunks

@@ -50,6 +50,18 @@ sequence are not read (PERF.md, PR 32). Four pieces live here:
   into the pool, repacking a LEFT-padded prompt to logical positions
   0..len-1 (the unpadded layout the decode bias assumes).
 
+A model whose layers keep a STATE beside their keys and values (a
+recurrence's, a short convolution's last inputs: ``serving/blocks.py``,
+models/falcon_h1.py) gets a second set of buffers, the state bank
+(:func:`init_state`): ``(L, num_slots, ..)`` a leaf, a row a slot a
+layer, not paged, because it does not grow with the sequence. It rides
+the layer loop's carry beside the pool, is donated by the same programs
+and OVERWRITTEN in place: a prefill's result is put in its slot's row
+(:func:`write_state`), and a decode step reads and writes the rows of
+its slots a few at a time, only as far as the highest live slot
+(:func:`update_state_rows`, :func:`walked_state_rows`). A model without
+state carries an empty dict, which adds nothing to a program.
+
 Under TP every function sees the LOCAL head subset (call inside
 shard_map with the pool's rows sharded over the tensor axis), and the
 engine pairs the local logits with ``global_greedy_pick``.
@@ -91,6 +103,11 @@ ATTN_IMPLS = ("gather", "paged")
 # pages (:func:`walk_plan`). Measured on the chip at 128 / 256 / 512
 # (PERF.md, PR 32).
 WALK_KEYS = 256
+
+# slots a trip of a decode step's walk over the state bank reads and
+# writes (:func:`state_walk_plan`): at Falcon-H1-34B's 4.2 MB a slot a
+# block, 34 MB in flight
+STATE_ROWS = 8
 
 
 def check_attn_impl(attn_impl: str) -> str:
@@ -414,6 +431,89 @@ def _one_bank(pages):
         lambda a: a.reshape((-1,) + a.shape[2:]), pages)
 
 
+def init_state(config, num_slots: int) -> dict:
+    """The state bank of ``config``'s model: ``{name: zeros (L,
+    num_slots, ..)}`` after ``PagedModel.state``, a row a slot a layer;
+    ``{}`` for a model whose layers keep none."""
+    model = describe(config)
+    return {name: jnp.zeros((model.n_layer, num_slots) + tuple(shape), dtype)
+            for name, shape, dtype in model.state}
+
+
+def write_state(state: dict, new: dict, slot):
+    """Put a prefill's state ``new`` ``{name: (L, 1, ..)}`` into row
+    ``slot`` of the bank, every layer at once and in place (one
+    ``dynamic_update_slice`` a leaf on the donated buffer): whatever the
+    slot's last request left there is gone."""
+    def put(bank, x):
+        start = (0, slot) + (0,) * (bank.ndim - 2)
+        return lax.dynamic_update_slice(bank, x.astype(bank.dtype), start)
+
+    return {name: put(bank, new[name]) for name, bank in state.items()}
+
+
+def state_walk_plan(num_slots: int) -> Tuple[int, int]:
+    """How a decode step walks the state bank's ``num_slots`` rows: (rows
+    a trip, trips that cover them). A trip takes ``STATE_ROWS`` rows, or
+    the largest divisor of ``num_slots`` under it (no trip overhangs)."""
+    rows = next(r for r in range(min(STATE_ROWS, num_slots), 0, -1)
+                if num_slots % r == 0)
+    return rows, num_slots // rows
+
+
+def walked_state_rows(highest_live, rows: int):
+    """Trips the walk makes when the highest live slot is
+    ``highest_live`` (-1: none is live): every group of ``rows`` up to
+    the one that holds it. The scheduler fills the lowest free slot, so
+    the live ones crowd the bank's start. Pure arithmetic, on a traced
+    value and on the host's int alike (as :func:`walked_chunks`)."""
+    return highest_live // rows + 1
+
+
+def update_state_rows(state: dict, layer, live, fn, xs):
+    """A decode step's read and write of layer ``layer`` of the state
+    bank, row ``i`` slot ``i``: ``fn(rows {name: (R, ..)}, xs_rows) ->
+    (rows, ys_rows)`` over ``R`` slots a trip (:func:`state_walk_plan`),
+    ``xs`` a pytree of (num_slots, ..) arrays cut the same way, as far
+    as the highest slot ``live`` (num_slots,) marks
+    (:func:`walked_state_rows`). A trip's rows are sliced out of the
+    donated bank by (layer, slot) and written back there, so the bank is
+    updated in place and what is in flight is a trip's rows, never a
+    layer's plane. Returns ``(state, ys (num_slots, ..))``, the rows
+    past the walk zeros (they hold no request)."""
+    n = live.shape[0]
+    rows, n_trips = state_walk_plan(n)
+    highest = jnp.max(jnp.where(live, jnp.arange(n), -1))
+    trips = jnp.minimum(walked_state_rows(highest, rows), n_trips)
+
+    def at(bank, i):
+        return (layer, i * rows) + (0,) * (bank.ndim - 2)
+
+    def one(state, xs, i):
+        """``fn`` over trip ``i``'s rows of the bank and of ``xs``."""
+        return fn(
+            {k: lax.dynamic_slice(b, at(b, i), (1, rows) + b.shape[2:])[0]
+             for k, b in state.items()},
+            jax.tree_util.tree_map(lambda x: lax.dynamic_slice_in_dim(
+                x, i * rows, rows, axis=0), xs))
+
+    def trip(i, carry):
+        state, ys = carry
+        new, y = one(state, xs, i)
+        state = {k: lax.dynamic_update_slice(
+            b, new[k][None].astype(b.dtype), at(b, i))
+            for k, b in state.items()}
+        ys = jax.tree_util.tree_map(
+            lambda buf, part: lax.dynamic_update_slice_in_dim(
+                buf, part.astype(buf.dtype), i * rows, axis=0), ys, y)
+        return state, ys
+
+    like = jax.eval_shape(lambda st, x: one(st, x, 0)[1], state, xs)
+    ys = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((n,) + a.shape[1:], a.dtype), like)
+    return lax.fori_loop(0, trips, trip, (state, ys))
+
+
 def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
                       length=None):
     """Scatter a prefill's contiguous cache into the pool, in place (the
@@ -660,7 +760,7 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
 
 def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                    dest_page, dest_off, qmask, config, tp_axis, attn_impl,
-                   n_layers=None, live=None):
+                   n_layers=None, live=None, state=None):
     """The forward both paged programs share: ``tokens`` (B, C) at
     global positions ``pos`` (B, C) through the model's blocks
     (``serving/blocks.py``: the first ``n_layers`` of them, all by
@@ -677,12 +777,21 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     ``dest_page`` are ``{kind: ..}`` and a layer's bank index counts
     the layers of its kind before it. ``live`` (B, C) bool says which
     positions are real, for a block that sends rows somewhere (the
-    experts). Returns (hidden, k_pages, v_pages, counters): what the
-    blocks' ``finish`` brought out, stacked over the layers that bring
-    any, ``{}`` for a model with none."""
+    experts) or keeps a state a slot. ``state`` is the state bank
+    (:func:`init_state`; ``{}`` or None for a model without): it rides
+    the same carry, and a group's ``mix`` reads and overwrites its rows
+    of the layer between ``qkv`` and ``finish`` (one query a row, row
+    ``i`` slot ``i``: a decode step). Returns (hidden, k_pages, v_pages,
+    counters, state): what the blocks' ``finish`` brought out, stacked
+    over the layers that bring any, ``{}`` for a model with none."""
     check_attn_impl(attn_impl)
     model = describe(config, tp_axis)
     b, c = tokens.shape
+    state = state or {}
+    if model.state and (not state or c != 1 or live is None):
+        raise ValueError("a model with a state a slot runs the paged "
+                         "forward as a decode step over its state bank: "
+                         "one query a row, row i slot i")
     kp, vp = by_kind(k_pages), by_kind(v_pages)
     tables, dest = by_kind(page_table), by_kind(dest_page)
     if attn_impl == "paged" and model.kinds != (GLOBAL,):
@@ -708,7 +817,7 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
         blocks = grp.params(params)
         num_pages = _values(kp[kind]).shape[1]
 
-        def layer(l, h, kpk, vpk, blk):
+        def layer(l, h, kpk, vpk, st, blk):
             q, k, v, saved = grp.qkv(blk, h, pos)
             kpk = _write_rows(kpk, (l, dest[kind], dest_off), k)
             vpk = _write_rows(vpk, (l, dest[kind], dest_off), v)
@@ -724,31 +833,33 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
             else:
                 ctx = _attend_rows(q, kpk, vpk, l, tables[kind], pos, qmask,
                                    slopes, h.dtype, window)
+            if grp.mix is not None:
+                saved, st = grp.mix(blk, saved, st, l, live[:, 0])
             h, out = grp.finish(blk, h, ctx, saved, live)
-            return h, kpk, vpk, out
+            return h, kpk, vpk, st, out
 
         if grp.stacked:
             def body(l, carry):
-                h, kpk, vpk = carry
+                h, kpk, vpk, st = carry
                 # l counts the kind's layers, the stack the group's own
                 own = l - base if base else l
                 blk = jax.tree_util.tree_map(
                     lambda a: lax.dynamic_index_in_dim(a, own, 0,
                                                        keepdims=False),
                     blocks)
-                return layer(l, h, kpk, vpk, blk)[:3]
+                return layer(l, h, kpk, vpk, st, blk)[:4]
 
-            x, kp[kind], vp[kind] = lax.fori_loop(
-                base, base + take, body, (x, kp[kind], vp[kind]))
+            x, kp[kind], vp[kind], state = lax.fori_loop(
+                base, base + take, body, (x, kp[kind], vp[kind], state))
         else:
-            x, kp[kind], vp[kind], out = layer(base, x, kp[kind], vp[kind],
-                                               blocks)
+            x, kp[kind], vp[kind], state, out = layer(
+                base, x, kp[kind], vp[kind], state, blocks)
             if out is not None:
                 brought.append(out)
     x = model.final(params, x)
     counters = ({model.counters: jnp.stack(brought)}
                 if brought and model.counters else {})
-    return x, _like(k_pages, kp), _like(v_pages, vp), counters
+    return x, _like(k_pages, kp), _like(v_pages, vp), counters, state
 
 
 def _dest(page_table, page_idx, ring: bool):
@@ -762,7 +873,8 @@ def _dest(page_table, page_idx, ring: bool):
 def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
                       config, tp_axis=None, write_ok=None,
                       draft_layers: Optional[int] = None,
-                      attn_impl: str = "gather", with_counters: bool = False):
+                      attn_impl: str = "gather", with_counters: bool = False,
+                      state=None):
     """One decode step for every slot of the ragged active batch.
 
     ``tokens`` (B,) are the pending tokens (each slot's last emitted
@@ -796,9 +908,15 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     (ops/paged_attention.py) — same mask/bias semantics, int8 pages
     dequantized in-register.
 
-    Returns (logits (B, V_local), k_pages, v_pages), and with
+    ``state``: the state bank of a model whose layers keep one
+    (:func:`init_state`), row ``i`` slot ``i`` of the batch: a row with
+    ``seq_lens`` > 0 has its state read and overwritten, any other keeps
+    what it holds.
+
+    Returns (logits (B, V_local), k_pages, v_pages), with
     ``with_counters`` a fourth: the blocks' counters (a slot with
-    ``seq_lens`` 0 holds no request and is sent to no expert). Under
+    ``seq_lens`` 0 holds no request and is sent to no expert), and where
+    ``state`` is given the bank last. Under
     ``tp_axis`` the logits are the LOCAL vocab shard — pair with
     ``_decode.global_greedy_pick`` like the sharded generate driver.
     """
@@ -811,17 +929,18 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     if write_ok is not None:
         phys = {k: jnp.where(write_ok, p, NULL_PAGE) for k, p in phys.items()}
         off = jnp.where(write_ok, off, 0)
-    x, k_pages, v_pages, counters = _paged_forward(
+    x, k_pages, v_pages, counters, new_state = _paged_forward(
         params, tokens[:, None], k_pages, v_pages, page_table,
         seq_lens[:, None], _like(page_table, {k: p[:, None]
                                               for k, p in phys.items()}),
         off[:, None], None, model, tp_axis, attn_impl,
         n_layers=draft_layers,
-        live=(seq_lens > 0)[:, None] if model.counters else None)
+        live=((seq_lens > 0)[:, None] if model.counters or model.state
+              else None),
+        state=state)
     logits = model.logits(params, x)[:, 0]  # (B, V_local)
-    if with_counters:
-        return logits, k_pages, v_pages, counters
-    return logits, k_pages, v_pages
+    out = (logits, k_pages, v_pages) + ((counters,) if with_counters else ())
+    return out if state is None else out + (new_state,)
 
 
 def export_page_slab(pages, page_ids, head_dim: int, wire_dtype=None):
@@ -918,6 +1037,9 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     if model.kinds != (GLOBAL,):
         raise ValueError("a prefill chunk reads one cache kind: a window "
                          "layer's ring holds one query a row")
+    if model.state:
+        raise ValueError("a prefill chunk is not built for a model with a "
+                         "state a slot: its prefill is the model's own")
     c = tokens.shape[1]
     ps = page_size_of(k_pages)
     pos = start[:, None] + jnp.arange(c)[None, :]             # (B, C)
@@ -926,7 +1048,7 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
         valid, jnp.take_along_axis(page_table, pos // ps, axis=1), NULL_PAGE
     )
     dest_off = jnp.where(valid, pos % ps, 0)
-    x, k_pages, v_pages, _ = _paged_forward(
+    x, k_pages, v_pages, _, _ = _paged_forward(
         params, tokens, k_pages, v_pages, page_table, pos, dest_page,
         dest_off, valid, model, tp_axis, attn_impl)
     if all_logits:

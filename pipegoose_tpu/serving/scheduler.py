@@ -30,6 +30,16 @@ shapes); the scheduler's job is to keep those slots full:
   until it is whole (after which a new logical page takes over the
   oldest entry: ``PagePool.recycled``), finish and preemption return
   both kinds.
+- **a state a slot** (a model whose layers keep a recurrence's state
+  beside their keys and values, serving/blocks.py): the state bank has
+  a row a SLOT, so the scheduler allocates nothing for it. What it owes
+  the state is its order: ``admit`` fills the LOWEST free slot (the
+  decode step walks the bank only as far as the highest live one), an
+  admitted request is prefilled before its first decode step (the
+  engine puts the prefill's state in the slot's row, so a slot's
+  leftover state is never read), and :meth:`Scheduler.preempt` keeps
+  the generated tokens, from which re-admission re-prefills: no state
+  is saved.
 - **eviction** frees a finished request's pages and reservation the
   step its last token is emitted — shared pages just drop a reference —
   so the next ``admit`` can re-use both the slot and the pages

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from pipegoose_tpu.models import bloom
+from pipegoose_tpu.models import bloom, falcon_h1
 from pipegoose_tpu.nn.sequence_parallel.ring_attention import (
     ring_flash_attention,
 )
@@ -459,3 +459,91 @@ def test_decode_read_keeps_the_rows_as_stored(one_chip, program, heads):
     assert re.search(r"bf16\[(\d+,)*%d\]\S* (fusion|gather)\(" % (nh * hd),
                      text)
     assert text.count(" while(") == 2
+
+
+# The state bank (PERF.md, PR 39): a model whose blocks keep a state a
+# slot (Falcon-H1's recurrence: 4.2 MB a block a slot at the published
+# widths, 1.6 GB over 64 slots and six blocks) hands it to the decode
+# step and to the page write donated, beside the pool. The step reads
+# and overwrites a few slots' rows a trip; the write puts one slot's
+# rows. Like the pool's layout, that holds in the compiled program or
+# not at all.
+
+# 24 slots, 3 trips of 8: a block's plane of the bank (24 x 4 x 32 x 256
+# float32: whole (8, 128) tiles, as the published 128 x 256 is; 3.1 MB,
+# over anything else the step holds) has no weight's element count, nor
+# has a trip's rows
+BANK_L, BANK_SLOTS, BANK_HEADS, BANK_HEAD, BANK_STATE = 2, 24, 4, 32, 256
+
+
+@pytest.mark.parametrize("program", ["step", "write"])
+def test_state_bank_is_updated_in_place(one_chip, program):
+    """The compiled program aliases pool AND bank, holds no temporary of
+    a block's plane of the bank, copies or re-lays out none, and (the
+    step) walks the bank's slots in a loop inside the layer loop, beside
+    the attention's walk over the keys."""
+    cfg = falcon_h1.FalconH1Config(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=BANK_L, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=128,
+        mamba_d_ssm=BANK_HEADS * BANK_HEAD, mamba_n_heads=BANK_HEADS,
+        mamba_d_head=BANK_HEAD, mamba_d_state=BANK_STATE, mamba_chunk_size=16,
+        dtype=jnp.bfloat16)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def vec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda k: falcon_h1.init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, num_slots=BANK_SLOTS,
+                        num_pages=POOL_PAGES, page_size=PS,
+                        max_context=POOL_CONTEXT)
+    kp, vp = sds(eng.k_pages), sds(eng.v_pages)
+    bank = jax.tree_util.tree_map(sds, eng.state)
+    assert bank["ssm"].shape == (BANK_L, BANK_SLOTS, BANK_HEADS, BANK_HEAD,
+                                 BANK_STATE)
+    assert bank["ssm"].dtype == jnp.float32
+    assert kv_pool.state_walk_plan(BANK_SLOTS) == (8, 3)
+    slots, width = eng.num_slots, eng.table_width
+    if program == "step":
+        low = eng._step.lower(params, vec(slots), kp, vp, vec(slots, width),
+                              vec(slots), bank)
+    else:
+        cache = jax.tree_util.tree_map(sds, jax.eval_shape(
+            eng._prefill, params, vec(1, POOL_BUCKET), vec(1, POOL_BUCKET))[1])
+        low = eng._write.lower(kp, vp, cache, vec(width), vec(), vec(), bank,
+                               vec())
+    compiled = low.compile()
+    pool_bytes = 2 * kp.size * kp.dtype.itemsize
+    bank_bytes = sum(x.size * x.dtype.itemsize for x in bank.values())
+    ma = compiled.memory_analysis()
+    # (the convolution's three inputs a slot lie in tiles of four)
+    conv_bytes = bank["conv"].size * bank["conv"].dtype.itemsize
+    assert 0 <= ma.alias_size_in_bytes - pool_bytes - bank_bytes \
+        <= conv_bytes // 3
+    plane = bank["ssm"].size // BANK_L           # elements of a block's plane
+    assert ma.temp_size_in_bytes < plane * 4, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    moved, held = [], []
+    for m in re.finditer(r"= (\w+)\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        elements = math.prod(int(d) for d in m.group(2).split(","))
+        if m.group(1) != "f32":
+            continue                    # the bank's planes are float32
+        if elements % plane == 0 and m.group(3) in ("copy", "transpose"):
+            moved.append(m.group(0))
+        # nothing has a plane's size but the bank itself, passed along
+        if elements == plane:
+            held.append(m.group(0))
+    assert not moved, moved
+    assert not held, held[:3]
+    if program == "step":
+        # the layer loop, and inside it the keys' walk and the slots' walk
+        assert text.count(" while(") == 3
+        # a trip's rows are sliced out of the bank and put back into it
+        trip = "f32[1,8,%d,%d,%d]" % (BANK_HEADS, BANK_HEAD, BANK_STATE)
+        assert trip in text
+        assert re.search(r"f32\[%d,%d,%d,%d,%d\]\S* dynamic-update-slice\("
+                         % bank["ssm"].shape, text)
