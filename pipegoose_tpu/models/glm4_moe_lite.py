@@ -62,9 +62,11 @@ from pipegoose_tpu.nn.tensor_parallel.layers import (
 from pipegoose_tpu.ops.flash_attention import remat_policy
 
 
-# vocabulary rows a tile of the fused CE: at hidden 2048 with a padded
-# (masked) vocabulary the dw kernel's default 512-row tile takes 16.6 MB
-# of VMEM against the chip's 16 MB (compiled for a described v5e)
+# vocabulary rows a tile of the fused CE. Chosen when the backward had a
+# dw kernel whose default 512-row tile took 16.6 MB of VMEM against the
+# compiler's 16 MB at hidden 2048; the one backward kernel plans its own
+# VMEM and the forward compiles at 512 too, but the cell's times were
+# measured at 256 and 512 is no faster in the backward (PERF.md, PR 41)
 _CE_BLOCK_V = 256
 
 

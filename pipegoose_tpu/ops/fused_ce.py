@@ -13,10 +13,16 @@ exists in HBM, forward or backward:
   triple; emits per-token local ``lse`` and ``target_logit``.
 - backward: dlogits = softmax - onehot is rematerialized tile-by-tile
   from the saved GLOBAL lse (Megatron's analytic CE backward, reference
-  loss.py:71-89, without ever holding more than one (BT, BV) tile):
-  dhidden: grid (token_blocks, vocab_blocks), vocab sequential,
-  accumulates dlogits @ W_tile; dweight: grid (vocab_blocks,
-  token_blocks), tokens sequential, accumulates dlogits^T @ h_tile.
+  loss.py:71-89, without ever holding more than one (BT, BV) tile), in
+  ONE kernel (``fused_ce_bwd``): each tile is formed once and feeds both
+  dhidden (dlogits @ W_tile) and dweight (dlogits^T @ h_tile), so a step
+  runs four head matmuls (forward 1, backward 3). Grid (token
+  super-block, vocab tile, token tile): the super-block's dhidden stays
+  in VMEM while the vocabulary is walked; dweight's tile is summed over
+  the super-block's tokens in VMEM and carried from one super-block to
+  the next through its float32 result in HBM (``_bwd_pallas``). The
+  super-block comes from the operands' shapes and the device's VMEM
+  (``_pick_super_block``), not from a caller.
 
 Tensor-parallel semantics match ``vocab_parallel_cross_entropy``
 (nn/tensor_parallel/layers.py): the kernel works on the LOCAL vocab
@@ -34,6 +40,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+from pipegoose_tpu.ops import flash_attention
 
 NEG_INF = -1e9
 
@@ -145,8 +153,8 @@ def _fwd_pallas(h, w, targets, offset, valid, block_t, block_v, interpret,
 def _dlogits_tile(hb, wb, tb, lse_b, g_b, off, vi, block_t, block_v, valid,
                   vh=True):
     """One (BT, BV) dlogits tile: g * (softmax - onehot), rebuilt from
-    the saved global lse. Shared by the dh and dw kernels. ``vh``: the
-    weight tile is (BV, H) (tied embedding) vs (H, BV) (untied head)."""
+    the saved global lse. ``vh``: the weight tile is (BV, H) (tied
+    embedding) vs (H, BV) (untied head)."""
     logits = jax.lax.dot_general(
         hb, wb, (((1,), (1,) if vh else (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -161,132 +169,205 @@ def _dlogits_tile(hb, wb, tb, lse_b, g_b, off, vi, block_t, block_v, valid,
     return g_b[:, None] * (p - jnp.where(hit, 1.0, 0.0))
 
 
-def _dh_pallas(h, w, targets, lse, g, offset, valid, block_t, block_v,
-               interpret, vh):
+# tokens a super-block of the backward holds at most: past this the
+# carried accumulator's traffic is a few percent of the memory's rate
+# and a larger resident dh buys nothing (PERF.md, PR 41)
+_MAX_SUPER_TOKENS = 4096
+
+
+def _bwd_working_set_bytes(super_t: int, block_t: int, block_v: int,
+                           hidden: int, itemsize: int) -> int:
+    """VMEM the backward kernel holds, by its own arithmetic: the
+    super-block's ``h`` and its float32 ``dh``; the pipelined weight
+    tile twice; the float32 ``dw`` tile three times (the sum over the
+    super-block's tokens, the carried value read, the sum written); a
+    grid step's temporaries, float32 all: both operand tiles, both
+    products before they are added, and the (BT, BV) tiles of logits,
+    ``p``, ``dlogits`` and a select. An upper bound, held to the chip's
+    compiler by tests/ops/test_chip_compile.py."""
+    w_tile, h_tile = block_v * hidden, block_t * hidden
+    return (super_t * hidden * (4 + itemsize)
+            + 2 * w_tile * itemsize + 3 * w_tile * 4
+            + 2 * (w_tile + h_tile) * 4 + 4 * block_t * block_v * 4)
+
+
+def _pick_super_block(tokens: int, block_t: int, block_v: int, hidden: int,
+                      itemsize: int, vmem_limit_bytes: int):
+    """``(token tiles a super-block, super-blocks)`` of the backward
+    from the operands' shape and the scoped VMEM the kernel will ask
+    for: the most tiles, a power of two, whose working set
+    (``_bwd_working_set_bytes``) fits three quarters of the limit, up to
+    ``_MAX_SUPER_TOKENS``; then evened out over the super-blocks so that
+    the tokens are padded by less than a tile each."""
+    nt = -(-tokens // block_t)
+    ni = 1
+    while (ni < nt and 2 * ni * block_t <= _MAX_SUPER_TOKENS
+           and _bwd_working_set_bytes(2 * ni * block_t, block_t, block_v,
+                                      hidden, itemsize)
+           <= vmem_limit_bytes * 3 // 4):
+        ni *= 2
+    n_super = -(-nt // ni)
+    return -(-nt // n_super), n_super
+
+
+def _bwd_pallas(h, w, targets, lse, g, offset, valid, block_t, block_v,
+                interpret, vh):
+    """``(dh, dw)``, both float32, from ONE pass over the (BT, BV) tiles
+    of ``dlogits``: each tile is formed once and feeds ``dlogits @ W``
+    into ``dh`` and ``dlogits^T @ h`` into ``dw`` (three matmuls of
+    2*T*V*H a call where a kernel a product ran four).
+
+    ``dh`` sums over vocabulary tiles and ``dw`` over token tiles, so
+    one of them crosses the outer grid axis. Grid (token super-block
+    ``s``, vocabulary tile ``j``, token tile ``i``): the super-block's
+    ``h`` and float32 ``dh`` stay in VMEM across ``(j, i)``, moved once
+    a super-block; ``dw_j`` is summed over ``i`` in VMEM and CARRIED
+    over ``s`` through the float32 result in HBM, read, added to and
+    written back a tile at a time (first visit: written, not read). The
+    copies are the kernel's own: a tile's read starts with its first
+    token tile and is waited for with its last; its write is waited for
+    a tile later, before the buffer is filled again."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     t_tot, hd = h.shape
     v_loc = w.shape[0] if vh else w.shape[1]
-    nt, nv = t_tot // block_t, v_loc // block_v
+    nv = v_loc // block_v
+    limit = flash_attention._vmem_limit_bytes()
+    ni, n_super = _pick_super_block(t_tot, block_t, block_v, hd,
+                                    h.dtype.itemsize, limit)
+    super_t = ni * block_t
+    pad = n_super * super_t - t_tot
+    if pad:  # weight-0 tokens: g = 0 makes their dlogits 0
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        targets, lse, g = (jnp.pad(x, (0, pad)) for x in (targets, lse, g))
+    w_tile = (block_v, hd) if vh else (hd, block_v)
 
-    def kernel(off_ref, h_ref, w_ref, t_ref, lse_ref, g_ref, dh_ref, dh_sc):
-        vi = pl.program_id(1)
+    def kernel(off_ref, h_hbm, w_ref, t_ref, lse_ref, g_ref, dh_hbm, dw_hbm,
+               h_sc, dh_sc, dw_sc, read_sc, write_sc, sems):
+        s, j, i = (pl.program_id(a) for a in range(3))
+        first_i, last_i = i == 0, i == ni - 1
 
-        @pl.when(vi == 0)
-        def _init():
-            dh_sc[:] = jnp.zeros_like(dh_sc)
+        own = pl.ds(s * super_t, super_t)      # the super-block's tokens
+        cols = pl.ds(j * block_v, block_v)
+        dw_j = dw_hbm.at[cols] if vh else dw_hbm.at[:, cols]
+        h_in = pltpu.make_async_copy(h_hbm.at[own], h_sc, sems.at[0])
+        dh_out = pltpu.make_async_copy(dh_sc, dh_hbm.at[own], sems.at[1])
+        dw_in = pltpu.make_async_copy(dw_j, read_sc, sems.at[2])
+        dw_out = pltpu.make_async_copy(write_sc, dw_j, sems.at[3])
 
-        hb = h_ref[...].astype(jnp.float32)
+        @pl.when(first_i & (j == 0))
+        def _enter_super_block():
+            h_in.start()
+            dh_sc[...] = jnp.zeros_like(dh_sc)
+            h_in.wait()
+
+        @pl.when(first_i)
+        def _enter_vocab_tile():
+            dw_sc[...] = jnp.zeros_like(dw_sc)
+
+        # a single vocabulary tile is read again right after it was
+        # written: its write has to land first
+        if nv == 1:
+            @pl.when(first_i & (s > 0))
+            def _land_before_reading():
+                dw_out.wait()
+
+        @pl.when(first_i & (s > 0))
+        def _read_carried():
+            dw_in.start()
+
+        rows = pl.ds(pl.multiple_of(i * block_t, block_t), block_t)
+        hb = h_sc[rows, :].astype(jnp.float32)
         wb = w_ref[...].astype(jnp.float32)
         dl = _dlogits_tile(
             hb, wb, t_ref[0], lse_ref[0], g_ref[0],
-            off_ref[0], vi, block_t, block_v, valid, vh,
+            off_ref[0], j, block_t, block_v, valid, vh,
         )
-        dh_sc[:] += jax.lax.dot_general(
+        dh_sc[rows, :] += jax.lax.dot_general(
             dl, wb, (((1,), (0,) if vh else (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-
-        @pl.when(vi == nv - 1)
-        def _finish():
-            dh_ref[...] = dh_sc[:].astype(dh_ref.dtype)
-
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=0,
-            grid=(nt, nv),
-            in_specs=[
-                pl.BlockSpec((1,), lambda i, j: (0,),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((block_t, hd), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_v, hd), lambda i, j: (j, 0))
-                if vh else
-                pl.BlockSpec((hd, block_v), lambda i, j: (0, j)),
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((block_t, hd), lambda i, j: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((block_t, hd), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct(h.shape, h.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="fused_ce_dh",
-    )(offset, h, w, targets[None, :], lse[None, :], g[None, :])
-
-
-def _dw_pallas(h, w, targets, lse, g, offset, valid, block_t, block_v,
-               interpret, vh):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    t_tot, hd = h.shape
-    v_loc = w.shape[0] if vh else w.shape[1]
-    nt, nv = t_tot // block_t, v_loc // block_v
-
-    def kernel(off_ref, h_ref, w_ref, t_ref, lse_ref, g_ref, dw_ref, dw_sc):
-        ti = pl.program_id(1)
-
-        @pl.when(ti == 0)
-        def _init():
-            dw_sc[:] = jnp.zeros_like(dw_sc)
-
-        hb = h_ref[...].astype(jnp.float32)
-        wb = w_ref[...].astype(jnp.float32)
-        dl = _dlogits_tile(
-            hb, wb, t_ref[0], lse_ref[0], g_ref[0],
-            off_ref[0], pl.program_id(0), block_t, block_v, valid, vh,
-        )
         if vh:
-            dw_sc[:] += jax.lax.dot_general(
+            dw_sc[...] += jax.lax.dot_general(
                 dl, hb, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # (BV, H)
         else:
-            dw_sc[:] += jax.lax.dot_general(
+            dw_sc[...] += jax.lax.dot_general(
                 hb, dl, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )  # (H, BV)
 
-        @pl.when(ti == nt - 1)
-        def _finish():
-            dw_ref[...] = dw_sc[:].astype(dw_ref.dtype)
+        @pl.when(last_i)
+        def _leave_vocab_tile():
+            if nv > 1:
+                @pl.when((s > 0) | (j > 0))
+                def _previous_write_landed():
+                    dw_out.wait()
 
-    return pl.pallas_call(
+            @pl.when(s == 0)
+            def _first_visit():
+                write_sc[...] = dw_sc[...]
+
+            @pl.when(s > 0)
+            def _later_visit():
+                dw_in.wait()
+                write_sc[...] = read_sc[...] + dw_sc[...]
+
+            dw_out.start()
+
+        @pl.when(last_i & (j == nv - 1))
+        def _leave_super_block():
+            dh_out.start()
+            dh_out.wait()
+
+        @pl.when(last_i & (j == nv - 1) & (s == n_super - 1))
+        def _last_write_landed():
+            dw_out.wait()
+
+    row = pl.BlockSpec((1, block_t), lambda s, j, i: (0, s * ni + i))
+    any_space = pl.BlockSpec(memory_space=pl.ANY)
+    dh, dw = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(nv, nt),
+            grid=(n_super, nv, ni),
             in_specs=[
-                pl.BlockSpec((1,), lambda j, i: (0,),
+                pl.BlockSpec((1,), lambda s, j, i: (0,),
                              memory_space=pltpu.SMEM),
-                pl.BlockSpec((block_t, hd), lambda j, i: (i, 0)),
-                pl.BlockSpec((block_v, hd), lambda j, i: (j, 0))
+                any_space,
+                pl.BlockSpec(w_tile, lambda s, j, i: (j, 0))
                 if vh else
-                pl.BlockSpec((hd, block_v), lambda j, i: (0, j)),
-                pl.BlockSpec((1, block_t), lambda j, i: (0, i)),
-                pl.BlockSpec((1, block_t), lambda j, i: (0, i)),
-                pl.BlockSpec((1, block_t), lambda j, i: (0, i)),
+                pl.BlockSpec(w_tile, lambda s, j, i: (0, j)),
+                row, row, row,
             ],
-            out_specs=pl.BlockSpec((block_v, hd), lambda j, i: (j, 0))
-            if vh else
-            pl.BlockSpec((hd, block_v), lambda j, i: (0, j)),
-            scratch_shapes=[pltpu.VMEM(
-                (block_v, hd) if vh else (hd, block_v), jnp.float32
-            )],
+            out_specs=[any_space, any_space],
+            scratch_shapes=[
+                pltpu.VMEM((super_t, hd), h.dtype),
+                pltpu.VMEM((super_t, hd), jnp.float32),
+                pltpu.VMEM(w_tile, jnp.float32),
+                pltpu.VMEM(w_tile, jnp.float32),
+                pltpu.VMEM(w_tile, jnp.float32),
+                pltpu.SemaphoreType.DMA((4,)),
+            ],
         ),
-        out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
+        out_shape=[
+            jax.ShapeDtypeStruct(h.shape, jnp.float32),
+            jax.ShapeDtypeStruct(w.shape, jnp.float32),
+        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",) * 3,
+            # where even one token tile a super-block passes the limit
+            # (a device Pallas does not know: the compiler's default),
+            # the kernel asks for what that one tile needs
+            vmem_limit_bytes=max(limit, _bwd_working_set_bytes(
+                super_t, block_t, block_v, hd, h.dtype.itemsize)),
         ),
         interpret=interpret,
-        name="fused_ce_dw",
+        name="fused_ce_bwd",
     )(offset, h, w, targets[None, :], lse[None, :], g[None, :])
+    return dh[:t_tot], dw
 
 
 @functools.partial(
@@ -334,19 +415,16 @@ def _fused_ce_bwd(axis_name, valid_size, block_t, block_v, interpret, vh,
     ct_loss, _ = cts  # weight_sum is a non-diff count
     g = (ct_loss * token_w).astype(jnp.float32)
     offset = _shard_offset(axis_name, w.shape[0] if vh else w.shape[1])
-    dh = _dh_pallas(
+    dh, dw = _bwd_pallas(
         h, w, targets, lse, g, offset, valid_size, block_t, block_v,
         interpret, vh,
     )
+    dh, dw = dh.astype(h.dtype), dw.astype(w.dtype)
     if axis_name:
         # each shard's dh holds only its vocab rows' contribution; the
         # true hidden cotangent is the sum — the f-operator all-reduce
         # (models/bloom.py logits_fn), fused into this backward
         dh = jax.lax.psum(dh, axis_name)
-    dw = _dw_pallas(
-        h, w, targets, lse, g, offset, valid_size, block_t, block_v,
-        interpret, vh,
-    )
     return dh, dw, None, None
 
 

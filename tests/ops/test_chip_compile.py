@@ -23,6 +23,7 @@ from pipegoose_tpu.nn.sequence_parallel.ring_attention import (
     ring_flash_attention,
 )
 from pipegoose_tpu.ops import flash_attention as fa
+from pipegoose_tpu.ops import fused_ce
 from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 from pipegoose_tpu.ops.paged_attention import paged_attention
@@ -105,13 +106,23 @@ def _ring_chunk(grad=False):
     return jax.grad(loss, argnums=(0, 1, 2)), shapes
 
 
-def _fused_ce(grad):
-    t = B * S
-    shapes = [((t, H), jnp.bfloat16), ((V, H), jnp.bfloat16),
+# the head as one chip of each train cell sees it: (tokens, local rows
+# of the vocabulary, hidden, valid_size, block_v); GLM's model passes
+# block_v 256 and masks the rows its vocabulary is padded by
+CE_CELLS = {"cell_560m": (16384, 250880, 1024, None, 512),
+            "cell_1b7_tp2": (16384, 125440, 2048, None, 512),
+            "cell_glm": (16384, 19456, 2048, 19360, 256)}
+
+
+def _fused_ce(grad, t=B * S, v=V, hidden=H, valid=None, block_v=512,
+              layout="vh"):
+    w_shape = (v, hidden) if layout == "vh" else (hidden, v)
+    shapes = [((t, hidden), jnp.bfloat16), (w_shape, jnp.bfloat16),
               ((t,), jnp.int32), ((t,), jnp.float32)]
 
     def loss(h, w, tgt, tw):
-        tot, cnt = fused_ce_sums(h, w, tgt, tw, interpret=False)
+        tot, cnt = fused_ce_sums(h, w, tgt, tw, None, valid, block_v=block_v,
+                                 interpret=False, weight_layout=layout)
         return tot / cnt
 
     return (jax.grad(loss, argnums=(0, 1)) if grad else loss), shapes
@@ -162,6 +173,9 @@ CASES = {
     "ring_chunk_bwd": lambda: _ring_chunk(True),
     "fused_ce_fwd": lambda: _fused_ce(False),
     "fused_ce_bwd": lambda: _fused_ce(True),
+    # the untied (H, V) head of llama and mixtral, which no cell runs:
+    # the carried dw tile is a block of columns
+    "fused_ce_bwd_hv": lambda: _fused_ce(True, layout="hv"),
     "matmul_int8": lambda: _quant_matmul(False),
     "matmul_int4": lambda: _quant_matmul(True),
     "paged_fp": lambda: _paged(False),
@@ -191,7 +205,8 @@ KERNELS = {
     "ring_chunk": ["flash_ring_fwd"],
     "ring_chunk_bwd": ["flash_ring_fwd", "flash_ring_dq", "flash_ring_dkv"],
     "fused_ce_fwd": ["fused_ce_fwd"],
-    "fused_ce_bwd": ["fused_ce_fwd", "fused_ce_dh", "fused_ce_dw"],
+    "fused_ce_bwd": ["fused_ce_fwd", "fused_ce_bwd"],
+    "fused_ce_bwd_hv": ["fused_ce_fwd", "fused_ce_bwd"],
     "matmul_int8": ["int8_matmul"],
     "matmul_int4": ["int4_matmul"],
 }
@@ -314,6 +329,80 @@ def test_working_set_bounds_what_the_compiler_needs(one_chip, monkeypatch,
     text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
         .compile().as_text()
     assert f"flash_{kind}" in text
+
+
+@pytest.mark.parametrize("cell", sorted(CE_CELLS))
+def test_fused_ce_backward_is_one_kernel_at_the_cells_shapes(
+        one_chip, as_default_device, cell):
+    """Forward and backward of the head at a train cell's own shape: the
+    backward is ONE instruction named ``fused_ce_bwd`` (the name
+    ``fused_ce_bwd_roofline.train`` finds it by), the super-block the
+    picker chose fits the scoped VMEM the kernel asks for, and the
+    carried float32 ``dw`` is the only buffer of its size among the
+    program's temporaries (the kernel reads and writes the one result,
+    there is no second copy to add into)."""
+    t, v, hidden, valid, block_v = CE_CELLS[cell]
+    fn, shapes = _fused_ce(True, t, v, hidden, valid, block_v)
+    compiled = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile()
+    called = [ln.split(" = ")[0] for ln in compiled.as_text().splitlines()
+              if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(called) == 2, called
+    assert sum("fused_ce_fwd" in c for c in called) == 1
+    assert sum("fused_ce_bwd" in c for c in called) == 1
+    limit = fa._vmem_limit_bytes()
+    ni, n_super = fused_ce._pick_super_block(t, 256, block_v, hidden, 2,
+                                             limit)
+    assert n_super > 1 and ni * n_super * 256 == t
+    assert fused_ce._bwd_working_set_bytes(
+        ni * 256, 256, block_v, hidden, 2) <= limit * 3 // 4
+    carried = v * hidden * 4
+    assert carried <= compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * carried
+
+
+@pytest.mark.parametrize("cell", sorted(CE_CELLS))
+def test_backward_working_set_bounds_what_the_compiler_needs(
+        one_chip, monkeypatch, cell):
+    """``_bwd_working_set_bytes`` against the compiler: given exactly
+    the bytes the arithmetic counts as its scoped-VMEM limit, the chip's
+    compiler takes the kernel at the super-block a v5e's limit gives."""
+    t, v, hidden, valid, block_v = CE_CELLS[cell]
+    plan = fused_ce._pick_super_block(t, 256, block_v, hidden, 2, V5E_LIMIT)
+    counted = fused_ce._bwd_working_set_bytes(plan[0] * 256, 256, block_v,
+                                              hidden, 2)
+    # the super-block planned for a v5e, no quarter to spare
+    monkeypatch.setattr(fused_ce, "_pick_super_block", lambda *a: plan)
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: counted)
+    row = ((t,), jnp.float32)
+
+    def fn(h, w, tgt, lse, g, off):
+        return fused_ce._bwd_pallas(h, w, tgt, lse, g, off, valid, 256,
+                                    block_v, False, True)
+
+    shapes = [((t, hidden), jnp.bfloat16), ((v, hidden), jnp.bfloat16),
+              ((t,), jnp.int32), row, row, ((1,), jnp.int32)]
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
+    assert "fused_ce_bwd" in text
+
+
+def test_train_step_holds_one_backward_kernel_and_none_of_the_old(
+        monkeypatch):
+    """A model's loss and its gradient, lowered for the TPU without one:
+    the head's kernels are ``fused_ce_fwd`` and ``fused_ce_bwd`` alone
+    (no ``fused_ce_dh`` / ``fused_ce_dw``)."""
+    monkeypatch.setattr(fused_ce, "_resolve_interpret", lambda interpret: False)
+    cfg = bloom.BloomConfig(vocab_size=1024, hidden_size=128, n_layer=1,
+                            n_head=2, fused_ce=True, dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: bloom.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    ids = jax.ShapeDtypeStruct((2, 129), jnp.int32)
+    step = jax.grad(lambda p, i: bloom.loss_fn(p, i, None, i, cfg))
+    text = jax.jit(step).trace(params, ids).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert sorted(re.findall(r'kernel_name = "([^"]*)"', text)) == [
+        "fused_ce_bwd", "fused_ce_fwd"]
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -54,16 +54,21 @@ def _flash():
 
 
 def _fused_ce():
-    shapes = [((TOKENS, H), jnp.bfloat16), ((V_PADDED, H), jnp.bfloat16),
+    # the head as the step passes it: twice on ONE weight (main head and
+    # MTP module), each pass its own forward and backward kernel
+    shapes = [((TOKENS, H), jnp.bfloat16), ((TOKENS, H), jnp.bfloat16),
+              ((V_PADDED, H), jnp.bfloat16),
               ((TOKENS,), jnp.int32), ((TOKENS,), jnp.float32)]
 
-    def loss(h, w, tgt, tw):
-        tot, cnt = fused_ce_sums(h, w, tgt, tw, None, V_VALID, block_v=256,
-                                 interpret=False)
-        return tot / cnt
+    def loss(h, h_mtp, w, tgt, tw):
+        def one(x):
+            tot, cnt = fused_ce_sums(x, w, tgt, tw, None, V_VALID,
+                                     block_v=256, interpret=False)
+            return tot / cnt
+        return one(h) + 0.3 * one(h_mtp)
 
-    return jax.grad(loss, argnums=(0, 1)), shapes, (
-        "fused_ce_fwd", "fused_ce_dh", "fused_ce_dw")
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes, (
+        "fused_ce_fwd", "fused_ce_bwd")
 
 
 def _grouped():
@@ -78,6 +83,12 @@ def _grouped():
         return swiglu_grouped(ep, rows, sizes).astype(jnp.float32).sum()
 
     return jax.grad(loss, argnums=(0, 1, 2, 3)), shapes, ("ragged-dot",)
+
+
+def _kernel_calls(text):
+    """The compiled program's lines that call a Pallas kernel."""
+    return [ln for ln in text.splitlines()
+            if " custom-call(" in ln and "tpu_custom_call" in ln]
 
 
 CASES = {"flash_256": _flash, "fused_ce_padded": _fused_ce,
@@ -96,6 +107,12 @@ def test_compiles_for_v5e_at_the_cells_sizes(one_chip, case):
     assert "tpu_custom_call" in text
     for name in names:
         assert name in text, f"{name} is not in the compiled program"
+    if case == "fused_ce_padded":
+        # one backward kernel a head pass, each dlogits tile formed once
+        called = [ln.split(" = ")[0] for ln in _kernel_calls(text)]
+        assert sorted(c.count("fused_ce_bwd") for c in called) == [0, 0, 1, 1]
+        assert sorted(c.count("fused_ce_fwd") for c in called) == [0, 0, 1, 1]
+        assert "fused_ce_dh" not in text and "fused_ce_dw" not in text
     if case == "flash_256":
         # the result shapes the flash roofline readers tell the kernels
         # by: each named kernel is the kind the reader says
@@ -107,8 +124,7 @@ def test_compiles_for_v5e_at_the_cells_sizes(one_chip, case):
             os.path.dirname(harness.__file__), "layer_metrics",
             "flash_attn_roofline.train.py")).classify
         kinds = {classify(ln.strip(), (ROWS * NH, S, HD)): ln.split(" = ")[0]
-                 for ln in text.splitlines()
-                 if " custom-call(" in ln and "tpu_custom_call" in ln}
+                 for ln in _kernel_calls(text)}
         assert sorted(kinds) == ["dkv", "dq", "fwd"], kinds
         assert all(f"flash_{kind}" in name for kind, name in kinds.items())
     if case == "grouped_products":

@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 from pipegoose_tpu.nn.tensor_parallel.layers import (
     vocab_parallel_cross_entropy,
 )
+from pipegoose_tpu.ops import fused_ce
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
 
 from pipegoose_tpu.distributed.compat import shard_map
@@ -64,6 +65,150 @@ def test_fused_matches_reference_grads(data):
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(fdw), np.asarray(rdw),
                                rtol=1e-4, atol=1e-5)
+
+
+# the one backward kernel against the dense head's dh and dw (float32,
+# interpret mode). (tokens, vocabulary, block_t, block_v, the most tokens
+# a super-block may hold, valid_size, visits of a carried dw tile)
+BWD_CASES = {
+    "one_super_block": (24, 128, 8, 32, 4096, None, 1),
+    # dw_j is written on the first visit and read, added to and written
+    # back on three more; four vocabulary tiles between two visits
+    "carried_four_visits": (64, 128, 8, 32, 16, None, 4),
+    # ONE vocabulary tile: a visit reads what the step before wrote
+    "carried_single_vocab_tile": (48, 32, 8, 32, 16, None, 3),
+    # a super-block of one token tile: read, add and write in one step
+    "carried_one_tile_super_blocks": (24, 64, 8, 32, 8, None, 3),
+    "padded_vocabulary": (40, 128, 8, 32, 16, 100, 3),
+    # 37 tokens: padded to 40 for the token block, then to 3 x 16 by the
+    # backward for its super-blocks
+    "ragged_tokens": (37, 128, 8, 32, 16, None, 3),
+}
+
+
+def _dense_loss(h, w_vh, targets, token_w, valid):
+    tot, cnt = _ref_sums(h, w_vh, targets, token_w, valid=valid)
+    return tot / cnt
+
+
+@pytest.mark.parametrize("tensor", [1, 2])
+@pytest.mark.parametrize("layout", ["vh", "hv"])
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_fused_backward_matches_the_dense_head(monkeypatch, devices, case,
+                                               layout, tensor):
+    """``dh`` and ``dw`` of ``fused_ce_bwd`` equal the dense head's, in
+    both weight layouts, alone and over a tensor axis of 2 (the shard's
+    column offset, ``dh`` summed over the axis), with zero-weight tokens
+    in every case; the carried cases visit each ``dw`` tile more than
+    once, so a read-modify-write that is missed or lands late shows."""
+    t, v, block_t, block_v, max_super, valid, visits = BWD_CASES[case]
+    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", max_super)
+    padded = -(-t // block_t) * block_t
+    assert fused_ce._pick_super_block(
+        padded, block_t, block_v, H, 4, 16 * 2**20)[1] == visits
+    rng = np.random.RandomState(3)
+    h = jnp.asarray(rng.randn(t, H), jnp.float32) * 0.3
+    w = jnp.asarray(rng.randn(v, H), jnp.float32) * 0.3
+    targets = jnp.asarray(rng.randint(0, valid or v, (t,)))
+    token_w = jnp.asarray((rng.rand(t) < 0.8).astype(np.float32))
+    rl, (rdh, rdw) = jax.value_and_grad(_dense_loss, argnums=(0, 1))(
+        h, w, targets, token_w, valid)
+    axis = "tensor" if tensor > 1 else None
+
+    def loss(h, w):
+        tot, cnt = fused_ce_sums(
+            h, w, targets, token_w, axis, valid, block_t=block_t,
+            block_v=block_v, interpret=True, weight_layout=layout)
+        return tot / cnt
+
+    fn = jax.value_and_grad(loss, argnums=(0, 1))
+    w_in = w if layout == "vh" else w.T
+    if tensor > 1:
+        w_spec = P("tensor") if layout == "vh" else P(None, "tensor")
+        mesh = jax.sharding.Mesh(np.asarray(devices[:tensor]), ("tensor",))
+        fn = jax.jit(shard_map(fn, mesh=mesh, in_specs=(P(), w_spec),
+                               out_specs=(P(), (P(), w_spec)),
+                               check_vma=False))
+    fl, (fdh, fdw) = fn(h, w_in)
+    assert abs(float(fl) - float(rl)) < 1e-4
+    np.testing.assert_allclose(np.asarray(fdh), np.asarray(rdh),
+                               rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(fdw if layout == "vh" else fdw.T), np.asarray(rdw),
+        rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout", ["vh", "hv"])
+def test_two_passes_over_one_weight_add_their_dw(monkeypatch, data, layout):
+    """GLM's shape of use: the head is passed twice a step (main head
+    and MTP module) on ONE weight, so autodiff adds two ``dw`` results,
+    each carried over its own super-blocks."""
+    h, w, targets, token_w = data
+    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", 8)
+    h2, targets2 = h[::-1] * 0.5, (targets + 7) % V
+
+    def dense(h, h2, w):
+        return (_dense_loss(h, w, targets, token_w, None)
+                + 0.3 * _dense_loss(h2, w, targets2, token_w, None))
+
+    def fused(h, h2, w_in):
+        def one(x, tg):
+            tot, cnt = fused_ce_sums(x, w_in, tg, token_w, block_t=8,
+                                     block_v=32, interpret=True,
+                                     weight_layout=layout)
+            return tot / cnt
+        return one(h, targets) + 0.3 * one(h2, targets2)
+
+    want = jax.grad(dense, argnums=(0, 1, 2))(h, h2, w)
+    got = jax.grad(fused, argnums=(0, 1, 2))(
+        h, h2, w if layout == "vh" else w.T)
+    got = got[:2] + (got[2] if layout == "vh" else got[2].T,)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+
+
+# (tokens, block_t, block_v, hidden, itemsize, limit MiB) -> (tiles a
+# super-block, super-blocks): the three train cells on a v5e's 64 MiB,
+# the compiler's default 16 MiB, a tile count no power of two divides
+@pytest.mark.parametrize("shape, want", [
+    ((16384, 256, 512, 1024, 2, 64), (16, 4)),
+    ((16384, 256, 512, 2048, 2, 64), (4, 16)),
+    ((16384, 256, 256, 2048, 2, 64), (8, 8)),
+    ((16384, 256, 512, 1024, 2, 16), (1, 64)),
+    ((61 * 256, 256, 512, 1024, 2, 64), (16, 4)),
+    ((24, 8, 32, 32, 4, 16), (3, 1)),
+])
+def test_super_block_comes_from_the_shapes_and_the_vmem(shape, want):
+    t, bt, bv, hd, itemsize, mib = shape
+    ni, n_super = fused_ce._pick_super_block(t, bt, bv, hd, itemsize,
+                                             mib * 2**20)
+    assert (ni, n_super) == want
+    # every token in a super-block, padded by less than a tile each
+    assert 0 <= ni * n_super - -(-t // bt) < n_super
+
+
+# the head as one chip of each train cell sees it: (tokens, block_v,
+# hidden); bloom-560m, bloom-1b7 over tensor 2, GLM's slice (block_v 256)
+@pytest.mark.parametrize("mib", [16, 64, 96])
+@pytest.mark.parametrize("shape", [(16384, 512, 1024), (16384, 512, 2048),
+                                   (16384, 256, 2048)])
+def test_super_block_fits_the_limit_it_is_given(shape, mib):
+    """Never zero tiles, every token in a super-block, and a super-block
+    of more than one tile is never what passes three quarters of the
+    limit (where ONE tile already does, at the compiler's default 16 MiB
+    and hidden 2,048, the kernel asks for that tile's bytes instead)."""
+    t, bv, hd = shape
+    limit = mib * 2**20
+    ni, n_super = fused_ce._pick_super_block(t, 256, bv, hd, 2, limit)
+    assert ni >= 1 and n_super >= 1 and ni * n_super * 256 >= t
+    assert ni * 256 <= fused_ce._MAX_SUPER_TOKENS
+    held = fused_ce._bwd_working_set_bytes(ni * 256, 256, bv, hd, 2)
+    assert held <= limit * 3 // 4 or ni == 1
+    # and it is the most the limit lets it hold
+    if ni * 256 < fused_ce._MAX_SUPER_TOKENS and held <= limit * 3 // 4:
+        assert fused_ce._bwd_working_set_bytes(
+            2 * ni * 256, 256, bv, hd, 2) > limit * 3 // 4
 
 
 def test_fused_valid_size_masks_padded_slots(data):
@@ -463,3 +608,4 @@ def test_pp_heads_fused_ce_match_default(devices):
                 assert abs(fused - ref) < 1e-4, (name, runtime, fused, ref)
     finally:
         ctx.destroy()
+
