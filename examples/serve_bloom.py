@@ -71,6 +71,7 @@ def main():
         if ctx is not None:
             ctx.destroy()
 
+    del metrics["tick_timeline"]    # a row a tick: too long to print
     print(json.dumps(metrics, indent=2))
     print(
         f"done: {len(outputs)} requests through {args.slots} slots "

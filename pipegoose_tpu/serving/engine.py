@@ -73,12 +73,17 @@ Metrics follow utils/profiler.py's convention of returning plain dicts
 the caller can JSON-dump. Three clocks are always on, each a handful of
 ``now()`` calls: ``tick_phase_s`` splits the host wall inside
 ``tick_once`` into admit / prefill / prepare / dispatch / fetch /
-record (``TICK_PHASES``), and ``setup`` keeps the wall of ``__init__``
-and of the first call of every jitted program, which is the call that
-compiled it or loaded it from the cache. The same regions are spans
-(telemetry/spans.py): ``serving.admit``, ``serving.prefill``,
-``serving.prepare``, ``serving.decode_step`` with its children
-``.dispatch`` and ``.fetch``, ``serving.record``. A span is always a
+record (``TICK_PHASES``), ``tick_timeline`` keeps the same boundaries
+tick by tick with the realtime clock at each tick's entry
+(``TIMELINE_COLUMNS``; ``dispatch`` split into ``upload`` and ``call``;
+``last_tick()`` reads the newest row of a live run), so that the ticks
+can be laid beside a profiler session's device line, and ``setup`` keeps
+the wall of ``__init__`` and of the first call of every jitted program,
+which is the call that compiled it or loaded it from the cache. The same
+regions are spans (telemetry/spans.py): ``serving.admit``,
+``serving.prefill``, ``serving.prepare``, ``serving.decode_step`` with
+its children ``.upload``, ``.dispatch`` (the call alone) and ``.fetch``,
+``serving.record``. A span is always a
 ``jax.profiler.TraceAnnotation``, so a profiler session shows what the
 host was doing in every gap of the device's line, and is recorded as
 ``span.<path>.seconds`` only when the registry is enabled. There is no
@@ -96,6 +101,7 @@ the examples parse it; new information lands under NEW keys only.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -175,6 +181,21 @@ class RequestOutput:
 # ``decode_step_time_s``.
 TICK_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch", "record")
 
+# the same clock's boundaries, one row a tick, the newest
+# ``TIMELINE_CAPACITY`` ticks of a run (``finish_run()["tick_timeline"]``,
+# ``last_tick()``). ``t_wall_ns`` is ``time.time_ns()`` and ``t_start`` the
+# run's ``now()``, both read at the tick's entry; the seven durations
+# follow in the order they ran, each from the boundary before it, so a
+# phase's ends on either clock are the entry plus the durations before
+# it. ``upload`` + ``call`` is ``dispatch``: the step's host arrays handed
+# to the device, then the call of the jitted step. ``rows``: requests in
+# the decode step (0 where none ran); ``prefills``: requests whose
+# prefill, or a chunk of it, ran in this tick (0 exactly where
+# ``prefill`` is 0.0). Each column sums to its ``tick_phase_s`` entry.
+TIMELINE_COLUMNS = ("t_wall_ns", "t_start", "admit", "prefill", "prepare",
+                    "upload", "call", "fetch", "record", "rows", "prefills")
+TIMELINE_CAPACITY = 32_768
+
 
 class _RunState:
     """Accumulators for one serving run — the state ``run()`` kept in
@@ -188,7 +209,8 @@ class _RunState:
         "per_request", "generated_total", "shed_count", "steps",
         "prefills", "chunks", "spec_drafted", "spec_accepted",
         "occ_slots", "occ_pages", "stalled", "tick", "t_last_decode",
-        "max_gap", "step_time", "phase_s", "table", "seq_lens", "tokens",
+        "max_gap", "step_time", "phase_s", "timeline", "table", "seq_lens",
+        "tokens",
         "keys_walked", "keys_reached", "window_table", "window_keys_walked",
         "window_keys_reached", "occ_window", "peak_pages", "recycled0",
         "experts_touched", "expert_skew", "rows_routed",
@@ -233,6 +255,7 @@ class _RunState:
         self.state_rows_updated = self.state_rows_live = 0
         self.state_writes = self.state_peak_slots = 0
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
+        self.timeline: deque = deque(maxlen=TIMELINE_CAPACITY)
         self.table = np.zeros((engine.num_slots, engine.table_width),
                               np.int32)
         self.window_table = (
@@ -240,6 +263,21 @@ class _RunState:
             if engine.pool.window is not None else None)
         self.seq_lens = np.zeros((engine.num_slots,), np.int32)
         self.tokens = np.zeros((engine.num_slots,), np.int32)
+
+    def close_tick(self, t_wall_ns, t_start, admit, prefill, prepare, upload,
+                   call, fetch, record, rows, prefills) -> None:
+        """Book one finished tick: its row (``TIMELINE_COLUMNS``) into
+        the ring and its durations into the phase sums, from the same
+        values, so every column sums to its phase."""
+        phase = self.phase_s
+        phase["admit"] += admit
+        phase["prefill"] += prefill
+        phase["prepare"] += prepare
+        phase["dispatch"] += upload + call
+        phase["fetch"] += fetch
+        phase["record"] += record
+        self.timeline.append((t_wall_ns, t_start, admit, prefill, prepare,
+                              upload, call, fetch, record, rows, prefills))
 
 
 class ServingEngine:
@@ -1572,6 +1610,16 @@ class ServingEngine:
     def run_in_progress(self) -> bool:
         return self._run is not None
 
+    def last_tick(self) -> Optional[dict]:
+        """The live run's newest tick by column (``TIMELINE_COLUMNS``):
+        what a driver reads after a ``tick_once`` for that tick's
+        phases. None with no run in progress or before its first
+        tick."""
+        rs = self._run
+        if rs is None or not rs.timeline:
+            return None
+        return dict(zip(TIMELINE_COLUMNS, rs.timeline[-1]))
+
     def tick_once(self) -> bool:
         """One scheduler iteration: admit, shed, advance prefills, one
         decode step over the active slots, record tokens. Returns True
@@ -1596,9 +1644,10 @@ class ServingEngine:
         reg = self.registry
         now = rs.now
         # host wall by phase (TICK_PHASES): every boundary closes the
-        # phase before it, so the six sum to the wall inside this call
-        phase = rs.phase_s
-        t_mark = now()
+        # phase before it, so the phases sum to the wall inside this
+        # call; ``close_tick`` books them, sums and timeline row alike
+        t_wall_ns = time.time_ns()
+        t_start = t_mark = now()
         rs.tick += 1
         with span("serving.admit", registry=reg):
             if rs.tick_hook is not None:
@@ -1618,8 +1667,9 @@ class ServingEngine:
                 self._m_shed.inc(len(shed_now))
                 rs.done.extend(shed_now)
         t = now()
-        phase["admit"] += t - t_mark
+        d_admit = t - t_mark
         t_mark = t
+        d_prefill = 0.0
         chunked_this_tick = 0
         if self._paged_prefill:
             for req in admitted:
@@ -1634,7 +1684,7 @@ class ServingEngine:
                     # lazy growth this very loop: back in the queue
                 self._prefill_chunk_tick(req, now)
                 t = now()
-                phase["prefill"] += t - t_mark
+                d_prefill += t - t_mark
                 t_mark = t
                 rs.chunks += 1
                 chunked_this_tick += 1
@@ -1646,11 +1696,13 @@ class ServingEngine:
             for req in admitted:
                 self._prefill_request(req, now)
                 t = now()
-                phase["prefill"] += t - t_mark
+                d_prefill += t - t_mark
                 t_mark = t
                 rs.prefills += 1
                 if req.status is Status.DONE:
                     rs.done.append(req)
+        prefilled = chunked_this_tick if self._paged_prefill \
+            else len(admitted)
         active = [r for r in self.sched.active()
                   if r.status is Status.DECODE]
         self._m_queue.set(len(self.sched.queue))
@@ -1670,7 +1722,8 @@ class ServingEngine:
                     self._stall(rs.steps, now() - rs.t0)
             rs.t_last_decode = None
             self._ledger_tick(rs)
-            phase["record"] += now() - t_mark
+            rs.close_tick(t_wall_ns, t_start, d_admit, d_prefill, 0.0, 0.0,
+                          0.0, 0.0, now() - t_mark, 0, prefilled)
             # everything admitted finished at prefill
             return bool(admitted or chunked_this_tick or shed_now)
         rs.stalled = 0
@@ -1683,7 +1736,7 @@ class ServingEngine:
             # a speculative cycle builds its own tables and interleaves
             # its draft and verify dispatches with their fetches: its
             # whole wall is booked under ``fetch``
-            t_step = t_disp = now()
+            t_step = t_call = t_disp = now()
             emitted, drafted, accepted, active = self._spec_cycle(
                 active, now, rs.done)
             rs.spec_drafted += drafted
@@ -1715,13 +1768,18 @@ class ServingEngine:
             first = self._note_program("step", 0)
             t_step = now()
             with span("serving.decode_step", registry=reg):
+                # the step's host arrays handed to the device, then the
+                # call: the two halves of ``dispatch``, told apart
+                with span("upload", registry=reg):
+                    tokens = jnp.asarray(rs.tokens)
+                    table = jax.tree_util.tree_map(jnp.asarray, table)
+                    seq_lens = jnp.asarray(rs.seq_lens)
+                t_call = now()
                 with span("dispatch", registry=reg):
                     (nxt, self.k_pages, self.v_pages, counters,
                      self.state) = self._step(
-                        self.params, jnp.asarray(rs.tokens), self.k_pages,
-                        self.v_pages, jax.tree_util.tree_map(
-                            jnp.asarray, table),
-                        jnp.asarray(rs.seq_lens), *self._state_arg(),
+                        self.params, tokens, self.k_pages, self.v_pages,
+                        table, seq_lens, *self._state_arg(),
                     )
                 t_disp = now()
                 # the host waiting on the device: what it waits for is
@@ -1737,9 +1795,6 @@ class ServingEngine:
             if first:
                 self._first_call_s["step", 0] = t - t_step
             emitted = len(active)
-        phase["prepare"] += t_step - t_mark
-        phase["dispatch"] += t_disp - t_step
-        phase["fetch"] += t - t_disp
         with span("serving.record", registry=reg):
             if not use_spec:
                 self._trace_tick(active, t_step, t)
@@ -1820,7 +1875,9 @@ class ServingEngine:
                     if req.status is Status.DONE:
                         rs.done.append(req)
             self._ledger_tick(rs)
-        phase["record"] += now() - t
+        rs.close_tick(t_wall_ns, t_start, d_admit, d_prefill,
+                      t_step - t_mark, t_call - t_step, t_disp - t_call,
+                      t - t_disp, now() - t, len(active), prefilled)
         return True
 
     def _build_output(self, r: Request) -> RequestOutput:
@@ -1946,6 +2003,13 @@ class ServingEngine:
             # host wall inside tick_once by phase (TICK_PHASES)
             "ticks": rs.tick,
             "tick_phase_s": {k: round(v, 6) for k, v in rs.phase_s.items()},
+            # the same clock tick by tick (TIMELINE_COLUMNS): the newest
+            # TIMELINE_CAPACITY rows, and how many older ones went
+            "tick_timeline": {
+                "columns": list(TIMELINE_COLUMNS),
+                "rows": [list(row) for row in rs.timeline],
+                "dropped": rs.tick - len(rs.timeline),
+            },
             # engine-lifetime facts, the same in every run's metrics:
             # the wall of __init__ and of each program's first call
             "setup": {

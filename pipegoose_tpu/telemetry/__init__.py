@@ -132,10 +132,7 @@ from pipegoose_tpu.telemetry.registry import (
     enable,
     get_registry,
 )
-from pipegoose_tpu.telemetry.sentinel import (
-    PerfSentinel,
-    read_bench_history,
-)
+from pipegoose_tpu.telemetry.sentinel import PerfSentinel
 from pipegoose_tpu.telemetry.spans import current_span_path, span
 from pipegoose_tpu.telemetry.xprof import (
     StepProfile,
@@ -204,7 +201,6 @@ __all__ = [
     "peak_flops_for",
     "pipeline_trace_events",
     "profile_step",
-    "read_bench_history",
     "register_pipeline_gauges",
     "request_trace_events",
     "set_doctor_gauges",

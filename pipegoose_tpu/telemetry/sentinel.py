@@ -24,20 +24,13 @@ twin of the doctor's compile-time guards:
 - every observation exports the ``perf.{compute,comm,idle}_fraction``
   gauges (when the run carries a profile) and ``perf.tokens_per_s``.
 
-Baselines can be seeded from a ``BENCH_HISTORY.jsonl`` — one JSON row
-of components per earlier run; nothing in the repo writes one any more
-(ROADMAP C5a) — via
-:func:`read_bench_history` / :meth:`PerfSentinel.from_history`, so a
-fresh process compares its first run against the recorded trajectory
-instead of flying blind. Everything is opt-in and host-side: nothing
-observes unless a caller (``ServingEngine(sentinel=...)``) passes a
-sentinel, and the disabled cost is one attribute read +
-branch (guard-tested < 5 µs, the established contract).
+Everything is opt-in and host-side: nothing observes unless a caller
+(``ServingEngine(sentinel=...)``) passes a sentinel, and the disabled
+cost is one attribute read + branch (guard-tested < 5 µs, the
+established contract).
 """
 from __future__ import annotations
 
-import json
-import os
 from collections import deque
 from typing import Any, Dict, List, Optional
 
@@ -78,28 +71,6 @@ def _components_of(run: Any) -> Dict[str, float]:
                                             or k == "tokens_per_s"):
             out[k] = float(v)
     return out
-
-
-def read_bench_history(
-    path: str, tail: Optional[int] = None
-) -> List[Dict[str, Any]]:
-    """Parse BENCH_HISTORY.jsonl (one JSON object per line; malformed
-    lines skipped — an interrupted append must not poison the reader).
-    ``tail`` keeps only the newest N rows — the sentinel's baseline
-    window."""
-    rows: List[Dict[str, Any]] = []
-    if not path or not os.path.exists(path):
-        return rows
-    with open(path) as f:
-        for line in f:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                rows.append(json.loads(line))
-            except ValueError:
-                continue
-    return rows[-tail:] if tail else rows
 
 
 class PerfSentinel:
@@ -143,32 +114,6 @@ class PerfSentinel:
         self._hist: deque = deque(maxlen=window)
         self.regressions = 0
         self.last_verdict: Optional[Dict[str, Any]] = None
-
-    @classmethod
-    def from_history(
-        cls, path: str, device: Optional[str] = None, **kwargs: Any
-    ) -> "PerfSentinel":
-        """A sentinel whose baseline window is seeded from the tail of
-        ``BENCH_HISTORY.jsonl``, one row of components per earlier run.
-
-        Rows carrying a ``perf_regression`` stamp are SKIPPED (the
-        regressed-runs-never-enter-the-baseline invariant holds across
-        processes, not just within one sentinel's lifetime — otherwise
-        a persistent regression fires once, poisons the next process's
-        median, and goes quiet). ``device`` (when given) keeps only
-        rows whose ``device`` field matches — a CPU-fallback bench run
-        must not be judged against (or drag down) a TPU baseline."""
-        s = cls(**kwargs)
-        rows = [
-            r for r in read_bench_history(path)
-            if not r.get("perf_regression")
-            and (device is None or r.get("device") == device)
-        ]
-        for row in rows[-s.window:]:
-            comps = _components_of(row)
-            if comps:
-                s._hist.append(comps)
-        return s
 
     @property
     def baseline_size(self) -> int:

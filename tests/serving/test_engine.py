@@ -543,7 +543,7 @@ def test_setup_keeps_one_first_call_per_program(setup, kwargs, families):
 
 
 def test_tick_spans_in_order_under_their_documented_paths(setup):
-    """The tick is the run of its phases: seven span paths, no parent
+    """The tick is the run of its phases: eight span paths, no parent
     that would rename ``serving.prefill`` and ``serving.decode_step``."""
     from pipegoose_tpu.telemetry import MetricsRegistry
 
@@ -558,8 +558,9 @@ def test_tick_spans_in_order_under_their_documented_paths(setup):
     spans = [e["span"] for e in events if e["kind"] == "span"]
     assert spans == [
         "serving.admit", "serving.prefill", "serving.prepare",
-        "serving.decode_step.dispatch", "serving.decode_step.fetch",
-        "serving.decode_step", "serving.record"]
+        "serving.decode_step.upload", "serving.decode_step.dispatch",
+        "serving.decode_step.fetch", "serving.decode_step",
+        "serving.record"]
     del events[:]
     eng.tick_once()                        # a pure decode tick
     assert [e["span"] for e in events if e["kind"] == "span"] == [
@@ -567,3 +568,153 @@ def test_tick_spans_in_order_under_their_documented_paths(setup):
     while not eng.sched.all_done():
         eng.tick_once()
     eng.finish_run()
+
+
+# -- the tick timeline: the phase clock's boundaries, a row a tick (PR 42) ----
+
+
+def _stepped_run(eng, requests):
+    """Drive the steppable run, keeping ``last_tick()`` after every tick."""
+    eng.start_run(requests)
+    assert eng.last_tick() is None         # a run, no tick yet
+    seen = []
+    while not eng.sched.all_done():
+        eng.tick_once()
+        seen.append(eng.last_tick())
+    metrics = eng.finish_run()[1]
+    assert eng.last_tick() is None         # no run in progress
+    return seen, metrics
+
+
+def _column(timeline, name):
+    i = timeline["columns"].index(name)
+    return [row[i] for row in timeline["rows"]]
+
+
+TIMELINE_ENGINES = [
+    pytest.param({}, id="plain"),
+    pytest.param({"prefill_chunk": 8}, id="chunked-prefill"),
+    pytest.param({"speculative": (1, 2)}, id="speculative"),
+]
+
+
+@pytest.mark.parametrize("kwargs", TIMELINE_ENGINES)
+def test_timeline_has_a_row_a_tick_and_sums_to_the_phase_clock(setup, kwargs):
+    from pipegoose_tpu.serving.engine import TIMELINE_COLUMNS
+
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=3, num_pages=32,
+                        page_size=4, max_context=64, **kwargs)
+    seen, m = _stepped_run(eng, _requests(prompts, MIXED))
+    tl = m["tick_timeline"]
+    assert tuple(tl["columns"]) == TIMELINE_COLUMNS
+    assert tl["dropped"] == 0 and len(tl["rows"]) == m["ticks"] == len(seen)
+    assert [dict(zip(tl["columns"], row)) for row in tl["rows"]] == seen
+    # every column sums to its phase; upload + call is dispatch
+    phases = m["tick_phase_s"]
+    for name in ("admit", "prefill", "prepare", "fetch", "record"):
+        assert sum(_column(tl, name)) == pytest.approx(phases[name], abs=1e-6)
+    assert sum(_column(tl, "upload")) + sum(_column(tl, "call")) == \
+        pytest.approx(phases["dispatch"], abs=1e-6)
+    for name in TIMELINE_COLUMNS[2:]:
+        assert all(v >= 0 for v in _column(tl, name)), name
+    # ticks follow one another on both clocks, and do not overlap
+    starts, walls = _column(tl, "t_start"), _column(tl, "t_wall_ns")
+    ends = [t + sum(row[2:9]) for t, row in zip(starts, tl["rows"])]
+    assert all(e <= s + 1e-9 for e, s in zip(ends, starts[1:]))
+    assert walls == sorted(walls) and isinstance(walls[0], int)
+    # a prefill's seconds exactly where a prefill (or a chunk) ran
+    assert all((p > 0.0) == (n > 0) for p, n in
+               zip(_column(tl, "prefill"), _column(tl, "prefills")))
+    assert sum(_column(tl, "prefills")) == (
+        m["prefill_chunks"] if "prefill_chunk" in kwargs else m["prefills"])
+    # a tick with a decode step has its rows and its wait, and no other
+    assert all((r > 0) == (f > 0.0) for r, f in
+               zip(_column(tl, "rows"), _column(tl, "fetch")))
+    assert sum(1 for r in _column(tl, "rows") if r) == m["decode_steps"]
+    if "speculative" in kwargs:
+        # a speculative cycle books its wall under fetch
+        assert sum(_column(tl, "upload")) == sum(_column(tl, "call")) == 0.0
+
+
+def test_timeline_rows_and_prefills_on_a_scripted_run(setup):
+    """Three requests of 3, 2 and 4 new tokens through two slots: the
+    third is admitted when the second leaves."""
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64)
+    _, m = _stepped_run(eng, [
+        Request(prompt=p, max_new_tokens=n)
+        for p, n in zip(prompts[:3], (3, 2, 4))])
+    tl = m["tick_timeline"]
+    # a prefill gives the first token; every decode step one more a row
+    assert _column(tl, "prefills") == [2, 1, 0, 0]
+    assert _column(tl, "rows") == [2, 2, 1, 1]
+    assert _column(tl, "prefill")[2:] == [0.0, 0.0]
+    assert m["generated_tokens"] == 9
+
+
+def test_an_idle_tick_is_admit_and_record(setup):
+    cfg, params, _ = setup
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64)
+    eng.start_run([])
+    eng.tick_once()
+    row = eng.last_tick()
+    _, m = eng.finish_run()
+    assert [row[k] for k in ("prefill", "prepare", "upload", "call", "fetch",
+                             "rows", "prefills")] == [0.0] * 5 + [0, 0]
+    assert row["admit"] > 0.0 and row["record"] > 0.0
+    assert m["tick_timeline"]["rows"] == [list(row.values())]
+
+
+def test_timeline_ring_keeps_the_newest_and_counts_the_rest(setup,
+                                                            monkeypatch):
+    import json
+
+    from pipegoose_tpu.serving import engine as engine_mod
+
+    cfg, params, prompts = setup
+    eng = ServingEngine(params, cfg, num_slots=3, num_pages=32,
+                        page_size=4, max_context=64)
+    seen, whole = _stepped_run(eng, _requests(prompts, MIXED))
+    assert len(seen) > 5
+    monkeypatch.setattr(engine_mod, "TIMELINE_CAPACITY", 5)
+    seen, m = _stepped_run(eng, _requests(prompts, MIXED))
+    tl = m["tick_timeline"]
+    assert len(tl["rows"]) == 5 and tl["dropped"] == m["ticks"] - 5 > 0
+    assert tl["rows"] == [list(row.values()) for row in seen[-5:]]
+    # the sums are kept apart from the ring: nothing of them is dropped
+    assert m["decode_steps"] == whole["decode_steps"]
+    assert sum(_column(tl, "fetch")) < m["tick_phase_s"]["fetch"]
+    # plain lists and numbers: the metrics are serialised whole in places
+    back = json.loads(json.dumps(m))["tick_timeline"]
+    assert back == tl
+
+
+def test_timeline_costs_a_tick_little_beside_an_empty_loop(setup):
+    """What a tick pays for its row: the wall clock, the row and the
+    sums, in iterations of an empty loop (~40 on the machine the bound
+    was set on; two microseconds more a tick would be ~150): each batch
+    timed back to back with its batch of empty iterations, so whatever
+    slows the machine meets both."""
+    import time
+
+    from pipegoose_tpu.serving.engine import _RunState
+
+    cfg, params, _ = setup
+    eng = ServingEngine(params, cfg, num_slots=2, num_pages=32,
+                        page_size=4, max_context=64)
+    rs = _RunState(eng, time.perf_counter, None)
+    n, ratios = 5_000, []
+    for _ in range(15):
+        t0 = time.perf_counter()
+        for _ in range(n):
+            rs.close_tick(time.time_ns(), time.perf_counter(), 1e-5, 0.0,
+                          1e-5, 1e-5, 1e-5, 1e-3, 1e-5, 4, 0)
+        t1 = time.perf_counter()
+        for _ in range(n):
+            pass
+        ratios.append((t1 - t0) / (time.perf_counter() - t1))
+    assert sorted(ratios)[len(ratios) // 2] < 120
+    assert len(rs.timeline) == rs.timeline.maxlen   # the ring went round

@@ -104,30 +104,38 @@ def test_disabled_registry_records_nothing():
 
 def test_disabled_overhead_under_5us():
     """The CI overhead guard (ISSUE 2): instrumentation stays ON in
-    library code because a disabled counter inc / span entry costs
-    < 5 µs median — measured over batches to beat timer noise."""
+    library code because a disabled counter inc / span entry costs next
+    to nothing. The bound is RELATIVE: 300 iterations of an empty loop
+    (5 µs where one takes 17 ns, as on the machine the bound was set on;
+    a span entry is ~130 there, quiet or loaded), each batch of calls
+    timed back to back with its batch of empty iterations, so whatever
+    slows the machine meets both."""
     from pipegoose_tpu.telemetry import span
 
     reg = MetricsRegistry(enabled=False)
     c = reg.counter("c")
     n = 2000
 
-    def med(fn):
-        samples = []
+    def empty_iterations(fn):
+        """A call of ``fn`` in iterations of an empty loop: the median
+        over 15 batches of n calls against n iterations."""
+        ratios = []
         for _ in range(15):
             t0 = time.perf_counter()
             for _ in range(n):
                 fn()
-            samples.append((time.perf_counter() - t0) / n)
-        return sorted(samples)[len(samples) // 2]
-
-    assert med(c.inc) < 5e-6
+            t1 = time.perf_counter()
+            for _ in range(n):
+                pass
+            ratios.append((t1 - t0) / (time.perf_counter() - t1))
+        return sorted(ratios)[len(ratios) // 2]
 
     def enter_span():
         with span("s", registry=reg):
             pass
 
-    assert med(enter_span) < 5e-6
+    assert empty_iterations(c.inc) < 300
+    assert empty_iterations(enter_span) < 300
 
 
 def test_tracer_and_trace_time_mutation_noop(reg):

@@ -1,8 +1,7 @@
 """Perf-regression sentinel (telemetry/sentinel.py, ISSUE 14):
-rolling-baseline math, component naming, flight-recorder black boxes,
-baseline hygiene, and the BENCH_HISTORY.jsonl seeding path. Host-only
-— no compiles (the engine-integration e2e lives in
-tests/serving/test_engine.py)."""
+rolling-baseline math, component naming, flight-recorder black boxes
+and baseline hygiene. Host-only — no compiles (the engine-integration
+e2e lives in tests/serving/test_engine.py)."""
 import json
 import os
 
@@ -10,10 +9,7 @@ import pytest
 
 from pipegoose_tpu.telemetry.flightrec import FlightRecorder
 from pipegoose_tpu.telemetry.registry import MetricsRegistry
-from pipegoose_tpu.telemetry.sentinel import (
-    PerfSentinel,
-    read_bench_history,
-)
+from pipegoose_tpu.telemetry.sentinel import PerfSentinel
 
 
 def _base_run(**over):
@@ -119,51 +115,3 @@ def test_profile_subdict_components_flatten():
     slow["profile"]["comm_by_axes"]["tensor"] = 0.008
     v = s.observe(slow)
     assert v is not None and "tensor-axis collective" in v["reason"]
-
-
-def test_read_bench_history_and_from_history(tmp_path):
-    path = tmp_path / "BENCH_HISTORY.jsonl"
-    rows = [{"run_id": f"r{i}", "tokens_per_s": 100.0 + i,
-             "profile": {"compute_s": 0.01, "idle_s": 0.002,
-                         "comm_by_axes": {"data": 0.001}}}
-            for i in range(5)]
-    with open(path, "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-        f.write("{truncated-append\n")   # torn line must be skipped
-    assert len(read_bench_history(str(path))) == 5
-    assert [r["run_id"] for r in read_bench_history(str(path), tail=2)] \
-        == ["r3", "r4"]
-    assert read_bench_history(str(tmp_path / "missing.jsonl")) == []
-
-    s = PerfSentinel.from_history(str(path), window=3, min_baseline=2)
-    assert s.baseline_size == 3   # the tail, window-bounded
-    assert s.baseline()["tokens_per_s"] == pytest.approx(103.0)
-    # a fresh process's FIRST run is judged against the trajectory
-    v = s.observe({"tokens_per_s": 50.0})
-    assert v is not None and "tokens/s" in v["reason"]
-
-
-def test_from_history_skips_regressed_and_other_device_rows(tmp_path):
-    """The cross-process baseline-hygiene contract: rows stamped
-    perf_regression never seed a baseline (a persistent regression
-    would otherwise fire once and go quiet), and a device filter keeps
-    a cpu-fallback run from being judged against a TPU trajectory."""
-    path = tmp_path / "BENCH_HISTORY.jsonl"
-    rows = [
-        {"run_id": "tpu1", "device": "v5e", "tokens_per_s": 100.0},
-        {"run_id": "cpu1", "device": "cpu-fallback", "tokens_per_s": 2.0},
-        {"run_id": "tpu2", "device": "v5e", "tokens_per_s": 30.0,
-         "perf_regression": "tokens/s 0.30x baseline"},
-        {"run_id": "tpu3", "device": "v5e", "tokens_per_s": 104.0},
-    ]
-    with open(path, "w") as f:
-        for r in rows:
-            f.write(json.dumps(r) + "\n")
-    s = PerfSentinel.from_history(str(path), device="v5e", window=8,
-                                  min_baseline=2)
-    assert s.baseline_size == 2   # cpu row + regressed row skipped
-    assert s.baseline()["tokens_per_s"] == pytest.approx(102.0)
-    # the persistent regression STILL fires for the next v5e run
-    v = s.observe({"tokens_per_s": 30.0})
-    assert v is not None and "0.29x" in v["reason"]
