@@ -417,13 +417,10 @@ def run_engine(params, cfg, reqs, **kw):
     toks = [np.asarray(o.generated) for o in outs]
     drained = eng.pool.used_count == 0
     shape = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
-    i32 = jnp.int32
     text = eng._step.lower(
-        eng.params, jax.ShapeDtypeStruct((eng.num_slots,), i32),
+        eng.params, jax.ShapeDtypeStruct((eng._carry_size,), jnp.int32),
         jax.tree_util.tree_map(shape, eng.k_pages),
         jax.tree_util.tree_map(shape, eng.v_pages),
-        jax.ShapeDtypeStruct((eng.num_slots, eng.table_width), i32),
-        jax.ShapeDtypeStruct((eng.num_slots,), i32),
     ).compile().as_text()
     del eng
     gc.collect()

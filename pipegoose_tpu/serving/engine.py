@@ -17,6 +17,17 @@
                                                       speculative cycle)
         record tokens; evict finished, reclaim pages (scheduler)
 
+A decode tick hands the device only what it does not hold. The step
+takes tokens, lengths and page table(s) as ONE packed int32 buffer and
+returns the next step's beside its picks (a live row's pick and its
+length plus one, the tables as they were), which stays on the device.
+The host fetches the picks as ever, prepares the same buffer from its
+requests and compares it with its copy of the device's: equal, and the
+tick transfers nothing; different (a row admitted, ended, retracted or
+grown by a page, or rows moved outside the step by a speculative
+cycle, a chunk or a transfer), and the buffer goes over whole, in one
+transfer (``finish_run()["step_uploads"]`` counts those steps).
+
 Three opt-in performance modes layer onto the PR 1 engine without
 changing its defaults:
 
@@ -75,8 +86,9 @@ the caller can JSON-dump. Three clocks are always on, each a handful of
 ``tick_once`` into admit / prefill / prepare / dispatch / fetch /
 record (``TICK_PHASES``), ``tick_timeline`` keeps the same boundaries
 tick by tick with the realtime clock at each tick's entry
-(``TIMELINE_COLUMNS``; ``dispatch`` split into ``upload`` and ``call``;
-``last_tick()`` reads the newest row of a live run), so that the ticks
+(``TIMELINE_COLUMNS``; ``dispatch`` split into ``upload``, 0.0 where
+the tick hands nothing over, and ``call``; ``last_tick()`` reads the
+newest row of a live run), so that the ticks
 can be laid beside a profiler session's device line, and ``setup`` keeps
 the wall of ``__init__`` and of the first call of every jitted program,
 which is the call that compiled it or loaded it from the cache. The same
@@ -187,22 +199,42 @@ TICK_PHASES = ("admit", "prefill", "prepare", "dispatch", "fetch", "record")
 # run's ``now()``, both read at the tick's entry; the seven durations
 # follow in the order they ran, each from the boundary before it, so a
 # phase's ends on either clock are the entry plus the durations before
-# it. ``upload`` + ``call`` is ``dispatch``: the step's host arrays handed
-# to the device, then the call of the jitted step. ``rows``: requests in
-# the decode step (0 where none ran); ``prefills``: requests whose
-# prefill, or a chunk of it, ran in this tick (0 exactly where
+# it. ``upload`` + ``call`` is ``dispatch``: the step's packed inputs made
+# ready for the device (the copy alone; 0.0 exactly where the device holds
+# them already), then the call of the jitted step, which transfers them.
+# ``rows``: requests in the decode step (0 where none ran); ``prefills``:
+# requests whose prefill, or a chunk of it, ran in this tick (0 exactly where
 # ``prefill`` is 0.0). Each column sums to its ``tick_phase_s`` entry.
 TIMELINE_COLUMNS = ("t_wall_ns", "t_start", "admit", "prefill", "prepare",
                     "upload", "call", "fetch", "record", "rows", "prefills")
 TIMELINE_CAPACITY = 32_768
 
 
+def _unpack(carry, num_slots: int, widths):
+    """(tokens, lengths, page table(s)) of a decode step out of its one
+    packed buffer, ``[tokens | lengths | table (| window table)]``, a
+    slot an entry or a row: views of a numpy buffer, static slices of a
+    traced one. ``widths``: the table's width, or ``{kind: width}`` for
+    a two-kind model (``ServingEngine._by_kind``)."""
+    n = num_slots
+    at = 2 * n
+
+    def take(width):
+        nonlocal at
+        at += n * width
+        return carry[at - n * width:at].reshape(n, width)
+
+    tables = ({kind: take(width) for kind, width in widths.items()}
+              if isinstance(widths, dict) else take(widths))
+    return carry[:n], carry[n:2 * n], tables
+
+
 class _RunState:
     """Accumulators for one serving run — the state ``run()`` kept in
     locals before the steppable extraction (``start_run`` /
     ``tick_once`` / ``finish_run``), so a control plane can interleave
-    N replica engines tick-by-tick in one host thread. Host-side only;
-    nothing here touches device memory."""
+    N replica engines tick-by-tick in one host thread. Host-side but for
+    ``carry``, the decode step's small inputs as the device holds them."""
 
     __slots__ = (
         "now", "tick_hook", "t0", "tok0", "done", "outputs",
@@ -210,7 +242,8 @@ class _RunState:
         "prefills", "chunks", "spec_drafted", "spec_accepted",
         "occ_slots", "occ_pages", "stalled", "tick", "t_last_decode",
         "max_gap", "step_time", "phase_s", "timeline", "table", "seq_lens",
-        "tokens",
+        "tokens", "packed", "held", "held_tokens", "held_lens", "carry",
+        "step_uploads", "uploaded",
         "keys_walked", "keys_reached", "window_table", "window_keys_walked",
         "window_keys_reached", "occ_window", "peak_pages", "recycled0",
         "experts_touched", "expert_skew", "rows_routed",
@@ -256,13 +289,23 @@ class _RunState:
         self.state_writes = self.state_peak_slots = 0
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.timeline: deque = deque(maxlen=TIMELINE_CAPACITY)
-        self.table = np.zeros((engine.num_slots, engine.table_width),
-                              np.int32)
-        self.window_table = (
-            np.zeros((engine.num_slots, engine.pool.ring), np.int32)
-            if engine.pool.window is not None else None)
-        self.seq_lens = np.zeros((engine.num_slots,), np.int32)
-        self.tokens = np.zeros((engine.num_slots,), np.int32)
+        # what a decode step takes from the host, in ONE buffer: tokens,
+        # lengths and page table(s) are views of ``packed``, filled by
+        # ``prepare`` every tick
+        self.packed = np.zeros((engine._carry_size,), np.int32)
+        self.tokens, self.seq_lens, table = engine._unpack_carry(self.packed)
+        self.table, self.window_table = (
+            (table, None) if engine.pool.window is None
+            else (table[GLOBAL], table[WINDOW]))
+        # the same as the DEVICE holds it (``carry``: the last step's own
+        # next inputs, or the buffer last sent; None before the run's
+        # first step) and the host's copy of that, advanced by the step's
+        # rule: a tick whose ``packed`` equals ``held`` sends nothing
+        self.carry = None
+        self.held = np.zeros_like(self.packed)
+        self.held_tokens, self.held_lens, _ = engine._unpack_carry(self.held)
+        # decode steps that took a transfer; this tick's own (0 or 1)
+        self.step_uploads = self.uploaded = 0
 
     def close_tick(self, t_wall_ns, t_start, admit, prefill, prepare, upload,
                    call, fetch, record, rows, prefills) -> None:
@@ -278,6 +321,14 @@ class _RunState:
         phase["record"] += record
         self.timeline.append((t_wall_ns, t_start, admit, prefill, prepare,
                               upload, call, fetch, record, rows, prefills))
+
+    def advance_held(self, nxt) -> None:
+        """The step's own rule for its next inputs, on the host's copy:
+        a live row's token is the one it just picked and its length one
+        more; a dead row keeps its zeros; the tables stay."""
+        live = self.held_lens > 0
+        self.held_tokens[:] = np.where(live, nxt, 0)
+        self.held_lens += live
 
 
 class ServingEngine:
@@ -458,6 +509,7 @@ class ServingEngine:
         self._m_shed = reg.counter("serving.shed_total")
         self._m_prefills = reg.counter("serving.prefills_total")
         self._m_steps = reg.counter("serving.decode_steps_total")
+        self._m_step_uploads = reg.counter("serving.step_uploads")
         self._m_ttft = reg.histogram("serving.ttft_seconds")
         self._m_tok_lat = reg.histogram("serving.decode_token_seconds")
         self._m_e2e = reg.histogram("serving.e2e_latency_seconds")
@@ -618,6 +670,10 @@ class ServingEngine:
         # the state bank, a row a slot a layer ({}: the model keeps none)
         self.state = init_state(model, num_slots)
         self._state_rows = state_walk_plan(num_slots)[0]
+        # a decode step's packed inputs: a token and a length a slot, then
+        # its row of every cache kind's page table
+        carry_widths = self._carry_widths = self._by_kind(lambda width: width)
+        self._carry_size = num_slots * (2 + self.table_width + ring)
         valid = getattr(config, "valid_vocab_size", None)
         mask_fn = vocab_mask_for(config)
         spec_k = speculative[0] if speculative else None
@@ -647,8 +703,8 @@ class ServingEngine:
                         k_pages, v_pages, cache, phys, pad, page_size,
                         length) + (write_state(state, cache["state"], slot),)
 
-            def _step(params, tokens, k_pages, v_pages, table, seq_lens,
-                      state={}):
+            def step_body(params, tokens, k_pages, v_pages, table, seq_lens,
+                          state):
                 # a fourth result: the blocks' counters, and a fifth: the
                 # state bank ({} where a model has none: no leaf, the
                 # same program)
@@ -689,7 +745,6 @@ class ServingEngine:
             self._prefill = jax.jit(_prefill)
             self._write = jax.jit(
                 _write, donate_argnums=(0, 1, 6) if model.state else (0, 1))
-            self._step = jax.jit(_step, donate_argnums=(2, 3, 6))
             self._chunk = jax.jit(_chunk, donate_argnums=(2, 3))
             self._copy = jax.jit(_copy, donate_argnums=(0, 1))
             self._draft = jax.jit(_draft, donate_argnums=(2, 3))
@@ -767,11 +822,17 @@ class ServingEngine:
                 in_specs=(pspec, pspec, cspec, P(), P()),
                 out_specs=(pspec, pspec), check_vma=False,
             ), donate_argnums=(0, 1))
-            self._step = jax.jit(shard_map(
+            sharded_step = shard_map(
                 _step_body, mesh=mesh,
                 in_specs=(param_specs, P(), pspec, pspec, P(), P()),
                 out_specs=(P(), pspec, pspec, {}, {}), check_vma=False,
-            ), donate_argnums=(2, 3))
+            )
+
+            def step_body(params, tokens, k_pages, v_pages, table, seq_lens,
+                          state):
+                return sharded_step(params, tokens, k_pages, v_pages, table,
+                                    seq_lens)
+
             self._chunk = jax.jit(shard_map(
                 _chunk_body, mesh=mesh,
                 in_specs=(param_specs, P(), pspec, pspec, P(), P(), P()),
@@ -799,6 +860,37 @@ class ServingEngine:
             self.k_pages = jax.device_put(self.k_pages, sharding)
             self.v_pages = jax.device_put(self.v_pages, sharding)
             self._pspec = pspec
+
+        # The decode step takes its small inputs PACKED, one int32 buffer
+        # (``_RunState.packed``), and returns the NEXT step's beside its
+        # picks, computed where it is needed and left there: a tick in
+        # which nothing else changed hands the device nothing.
+        def _step(params, carry, k_pages, v_pages, state={}):
+            tokens, seq_lens, table = _unpack(carry, num_slots, carry_widths)
+            nxt, k_pages, v_pages, counters, state = step_body(
+                params, tokens, k_pages, v_pages, table, seq_lens, state)
+            # a sixth result, the next step's inputs: a live row's token
+            # is this pick and its length one more, a dead row keeps its
+            # zeros, the tables stay (``_RunState.advance_held`` is the
+            # host's copy of this rule)
+            live = seq_lens > 0
+            head = jnp.concatenate(
+                [jnp.where(live, nxt.astype(carry.dtype), 0), seq_lens + live])
+            carry = jax.lax.dynamic_update_slice(carry, head, (0,))
+            return nxt, k_pages, v_pages, counters, state, carry
+
+        self._step = jax.jit(_step, donate_argnums=(1, 2, 3, 4))
+        # A buffer from the host goes to the CALL as the host array it
+        # is: the jitted call's own argument path transfers it for a
+        # fraction of what ``jax.device_put`` or ``jnp.asarray`` cost the
+        # host (PERF.md §6, PR 43). Under a mesh it is first placed on
+        # every device, where the step leaves its next inputs: a host
+        # array there meets a second compiled program.
+        if mesh is None:
+            self._place = lambda buf: buf
+        else:
+            everywhere = NamedSharding(mesh, P())
+            self._place = lambda buf: jax.device_put(buf, everywhere)
         # live memory ledger (telemetry/memledger.py) — attached LAST:
         # bytes-per-page is measured from the live pool arrays above
         self.memledger = None
@@ -820,21 +912,16 @@ class ServingEngine:
         pool. Shape-only: nothing executes, no pages are touched."""
         from pipegoose_tpu.telemetry.doctor import diagnose, set_doctor_gauges
 
-        i32 = jnp.int32
-        tokens = jax.ShapeDtypeStruct((self.num_slots,), i32)
-        table = self._by_kind(lambda width: jax.ShapeDtypeStruct(
-            (self.num_slots, width), i32))
-        seq_lens = jax.ShapeDtypeStruct((self.num_slots,), i32)
+        carry = jax.ShapeDtypeStruct((self._carry_size,), jnp.int32)
         intended = None
         if self.mesh is not None:
-            intended = (self.param_specs, P(), self._pspec, self._pspec,
-                        P(), P())
+            intended = (self.param_specs, P(), self._pspec, self._pspec)
         report = diagnose(
-            self._step, self.params, tokens, self.k_pages, self.v_pages,
-            table, seq_lens, *self._state_arg(),
+            self._step, self.params, carry, self.k_pages, self.v_pages,
+            *self._state_arg(),
             intended=intended,
-            labels=("params", "tokens", "k_pages", "v_pages", "table",
-                    "seq_lens") + ("state",) * len(self._state_arg()),
+            labels=("params", "carry", "k_pages", "v_pages")
+            + ("state",) * len(self._state_arg()),
             mesh=self.mesh, large_bytes=large_bytes,
         )
         if self.attn_kernel == "paged":
@@ -906,25 +993,23 @@ class ServingEngine:
 
         if self._run is not None:
             raise RuntimeError("profile() cannot run during a serving run")
-        i32 = jnp.int32
-        tokens = jnp.zeros((self.num_slots,), i32)
-        table = self._by_kind(
-            lambda width: jnp.zeros((self.num_slots, width), i32))
-        seq_lens = jnp.zeros((self.num_slots,), i32)
+        # every row dead: zero lengths, tables of the NULL page
+        carry = self._place(np.zeros((self._carry_size,), np.int32))
         final: dict = {}
 
         def update(out, cur):
-            # out = (next_tokens, k_pages, v_pages, counters, state); the
-            # pages and the state bank were donated — thread (and finally
-            # adopt) the new buffers
+            # out = (next_tokens, k_pages, v_pages, counters, state, the
+            # next step's inputs); the inputs, the pages and the state
+            # bank were donated — thread (and finally adopt) the new
+            # buffers
             final["k"], final["v"], final["state"] = out[1], out[2], out[4]
-            return (cur[0], cur[1], out[1], out[2], cur[4], cur[5]) + (
+            return (cur[0], out[5], out[1], out[2]) + (
                 (out[4],) if self.state else ())
 
         try:
             profile = profile_step(
-                self._step, self.params, tokens, self.k_pages, self.v_pages,
-                table, seq_lens, *self._state_arg(),
+                self._step, self.params, carry, self.k_pages, self.v_pages,
+                *self._state_arg(),
                 steps=steps, warmup=warmup, update_args=update,
                 mesh=self.mesh, trace_dir=trace_dir,
                 registry=registry or self.registry,
@@ -1084,6 +1169,11 @@ class ServingEngine:
         if self.pool.window is None:
             return make(self.table_width)
         return {GLOBAL: make(self.table_width), WINDOW: make(self.pool.ring)}
+
+    def _unpack_carry(self, carry):
+        """(tokens, lengths, page table(s)) out of a decode step's packed
+        buffer (``_unpack``) at this engine's sizes."""
+        return _unpack(carry, self.num_slots, self._carry_widths)
 
     def _phys_rows(self, req: Request):
         """A request's page-table rows for the page write, by kind."""
@@ -1611,14 +1701,16 @@ class ServingEngine:
         return self._run is not None
 
     def last_tick(self) -> Optional[dict]:
-        """The live run's newest tick by column (``TIMELINE_COLUMNS``):
-        what a driver reads after a ``tick_once`` for that tick's
-        phases. None with no run in progress or before its first
-        tick."""
+        """The live run's newest tick by column (``TIMELINE_COLUMNS``),
+        and ``step_uploads``, 1 where its decode step took its inputs
+        from the host: what a driver reads after a ``tick_once`` for
+        that tick's phases. None with no run in progress or before its
+        first tick."""
         rs = self._run
         if rs is None or not rs.timeline:
             return None
-        return dict(zip(TIMELINE_COLUMNS, rs.timeline[-1]))
+        return dict(zip(TIMELINE_COLUMNS, rs.timeline[-1]),
+                    step_uploads=rs.uploaded)
 
     def tick_once(self) -> bool:
         """One scheduler iteration: admit, shed, advance prefills, one
@@ -1649,6 +1741,7 @@ class ServingEngine:
         t_wall_ns = time.time_ns()
         t_start = t_mark = now()
         rs.tick += 1
+        rs.uploaded = 0
         with span("serving.admit", registry=reg):
             if rs.tick_hook is not None:
                 rs.tick_hook(self, rs.tick)
@@ -1751,35 +1844,41 @@ class ServingEngine:
                 # cache-ledger interference — see Scheduler.ensure_pages);
                 # only still-decoding survivors join the step
                 active = [r for r in active if r.status is Status.DECODE]
-                rs.table.fill(0)
-                rs.seq_lens.fill(0)
-                rs.tokens.fill(0)
+                rs.packed.fill(0)       # tokens, lengths, the table(s)
                 for req in active:
                     rs.table[req.slot, :len(req.pages)] = req.pages
                     rs.seq_lens[req.slot] = req.cached_len
                     rs.tokens[req.slot] = req.generated[-1]
-                table = rs.table
                 if rs.window_table is not None:
-                    rs.window_table.fill(0)
                     for req in active:
                         rs.window_table[req.slot, :len(req.window_pages)] = \
                             req.window_pages
-                    table = {GLOBAL: rs.table, WINDOW: rs.window_table}
+                # the device holds the last step's own next inputs; they
+                # are this step's unless a row was admitted, ended, was
+                # retracted or took a page, or rows moved outside the
+                # step (a speculative cycle, a chunk, a transfer)
+                rs.uploaded = int(rs.carry is None
+                                  or not np.array_equal(rs.packed, rs.held))
             first = self._note_program("step", 0)
-            t_step = now()
+            t_step = t_call = now()
             with span("serving.decode_step", registry=reg):
-                # the step's host arrays handed to the device, then the
-                # call: the two halves of ``dispatch``, told apart
+                # what changed made ready for the device, then the call,
+                # which transfers it: the two halves of ``dispatch``
                 with span("upload", registry=reg):
-                    tokens = jnp.asarray(rs.tokens)
-                    table = jax.tree_util.tree_map(jnp.asarray, table)
-                    seq_lens = jnp.asarray(rs.seq_lens)
-                t_call = now()
+                    if rs.uploaded:
+                        # ONE buffer, and a copy of it: on the CPU the
+                        # device may share the host's buffer, which the
+                        # next ``prepare`` refills
+                        rs.carry = self._place(rs.packed.copy())
+                        np.copyto(rs.held, rs.packed)
+                        rs.step_uploads += 1
+                        self._m_step_uploads.inc()
+                        t_call = now()
                 with span("dispatch", registry=reg):
                     (nxt, self.k_pages, self.v_pages, counters,
-                     self.state) = self._step(
-                        self.params, tokens, self.k_pages, self.v_pages,
-                        table, seq_lens, *self._state_arg(),
+                     self.state, rs.carry) = self._step(
+                        self.params, rs.carry, self.k_pages, self.v_pages,
+                        *self._state_arg(),
                     )
                 t_disp = now()
                 # the host waiting on the device: what it waits for is
@@ -1792,6 +1891,7 @@ class ServingEngine:
                     counters = {k: np.asarray(v)
                                 for k, v in counters.items()}
             t = now()
+            rs.advance_held(nxt)
             if first:
                 self._first_call_s["step", 0] = t - t_step
             emitted = len(active)
@@ -1982,6 +2082,9 @@ class ServingEngine:
         metrics = {
             "wall_time_s": round(wall, 6),
             "decode_steps": rs.steps,
+            # of them, the steps that took their inputs from the host (the
+            # others ran on what the step before left on the device)
+            "step_uploads": rs.step_uploads,
             # summed decode-step wall time: generated / this = the
             # decode-POOL rate (prefill stalls excluded) — DisaggEngine's
             # "prefill off the critical path" meter

@@ -47,8 +47,9 @@ def test_one_arithmetic_for_host_and_device():
 def test_key_share_is_what_the_steps_were_sent(setup, monkeypatch):
     """A scripted run of mixed lengths on a clock of fixed quanta: the
     share ``finish_run()`` reports is the walk's arithmetic over the
-    ``seq_lens`` each decode step was handed, the gauge mirrors it, and
-    the tokens are ``generate()``'s."""
+    ``seq_lens`` each decode step ran on (sent by the host, or carried on
+    the device from the step before), the gauge mirrors it, and the
+    tokens are ``generate()``'s."""
     monkeypatch.setattr(kv_pool, "WALK_KEYS", WALK)
     cfg, params, prompts = setup
     reg = MetricsRegistry(enabled=True)
@@ -57,14 +58,13 @@ def test_key_share_is_what_the_steps_were_sent(setup, monkeypatch):
     sent = []
     step = eng._step
 
-    def spy(p, tokens, kp, vp, table, seq_lens):
-        # a COPY: on the CPU ``jnp.asarray`` of an aligned numpy buffer
-        # shares it, and ``np.asarray`` of that is the engine's own
-        # ``seq_lens``, which the next tick refills (whether numpy's
-        # allocation is aligned varies run by run: half the runs read
-        # the NEXT step's lengths, 0.2604 for 0.25)
-        sent.append(np.array(seq_lens))
-        return step(p, tokens, kp, vp, table, seq_lens)
+    def spy(p, carry, kp, vp):
+        # the lengths the step RAN on, out of its packed inputs, whether
+        # the host sent them or the step before left them on the device.
+        # A COPY, taken before the call: the step is handed them donated,
+        # and on the CPU a device array may share a host buffer
+        sent.append(np.array(eng._unpack_carry(np.asarray(carry))[1]))
+        return step(p, carry, kp, vp)
 
     eng._step = spy
     clock = itertools.count()

@@ -609,7 +609,12 @@ def test_timeline_has_a_row_a_tick_and_sums_to_the_phase_clock(setup, kwargs):
     tl = m["tick_timeline"]
     assert tuple(tl["columns"]) == TIMELINE_COLUMNS
     assert tl["dropped"] == 0 and len(tl["rows"]) == m["ticks"] == len(seen)
-    assert [dict(zip(tl["columns"], row)) for row in tl["rows"]] == seen
+    # ``last_tick()`` is the tick's row by column, and whether its step
+    # took its inputs from the host: exactly the ticks whose ``upload``
+    # is not 0.0
+    assert [dict(zip(tl["columns"], row), step_uploads=int(up > 0.0))
+            for row, up in zip(tl["rows"], _column(tl, "upload"))] == seen
+    assert sum(s["step_uploads"] for s in seen) == m["step_uploads"]
     # every column sums to its phase; upload + call is dispatch
     phases = m["tick_phase_s"]
     for name in ("admit", "prefill", "prepare", "fetch", "record"):
@@ -665,6 +670,7 @@ def test_an_idle_tick_is_admit_and_record(setup):
     assert [row[k] for k in ("prefill", "prepare", "upload", "call", "fetch",
                              "rows", "prefills")] == [0.0] * 5 + [0, 0]
     assert row["admit"] > 0.0 and row["record"] > 0.0
+    assert row.pop("step_uploads") == 0
     assert m["tick_timeline"]["rows"] == [list(row.values())]
 
 
@@ -683,7 +689,8 @@ def test_timeline_ring_keeps_the_newest_and_counts_the_rest(setup,
     seen, m = _stepped_run(eng, _requests(prompts, MIXED))
     tl = m["tick_timeline"]
     assert len(tl["rows"]) == 5 and tl["dropped"] == m["ticks"] - 5 > 0
-    assert tl["rows"] == [list(row.values()) for row in seen[-5:]]
+    assert tl["rows"] == [list(row.values())[:len(tl["columns"])]
+                          for row in seen[-5:]]
     # the sums are kept apart from the ring: nothing of them is dropped
     assert m["decode_steps"] == whole["decode_steps"]
     assert sum(_column(tl, "fetch")) < m["tick_phase_s"]["fetch"]
@@ -718,3 +725,421 @@ def test_timeline_costs_a_tick_little_beside_an_empty_loop(setup):
         ratios.append((t1 - t0) / (time.perf_counter() - t1))
     assert sorted(ratios)[len(ratios) // 2] < 120
     assert len(rs.timeline) == rs.timeline.maxlen   # the ring went round
+
+
+# -- the decode step's inputs stay on the device (PR 44) ----------------------
+#
+# The step leaves its own next inputs on the device (a live row's pick and
+# its length plus one, the page tables as they were); a tick sends the one
+# packed buffer only where what ``prepare`` built differs from the host's
+# copy of what the device holds. The wrong outcome is a STALE step, so the
+# oracle is the tokens: those of the same engine made to send every tick,
+# and those of the model's own greedy forward.
+
+CARRY_PS, CARRY_CONTEXT = 8, 64
+# (prompt length, new tokens): more requests than the three slots, so rows
+# are admitted and end while others decode; a page of 8 leaves most ticks
+# of three rows with nothing new
+CARRY_MIX = [(25, 20), (9, 12), (17, 9), (2, 10), (12, 25), (5, 6)]
+
+
+def _carry_bloom():
+    cfg = bloom.BloomConfig(vocab_size=96, hidden_size=64, n_layer=2,
+                            n_head=4)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+
+    def check(prompt, generated):
+        np.testing.assert_array_equal(
+            generated, _reference(params, cfg, prompt, len(generated)))
+
+    return cfg, params, check
+
+
+def _forward_check(forward, params):
+    """``generated`` is what the model's own full forward puts first
+    after every prefix (the sequence right-padded, which a causal model
+    does not see)."""
+    best = jax.jit(lambda p, t: forward(p, t).argmax(-1))
+
+    def check(prompt, generated):
+        tokens = np.zeros((1, CARRY_CONTEXT), np.int32)
+        n = len(prompt) + len(generated)
+        tokens[0, :n] = np.concatenate([prompt, generated])
+        got = np.asarray(best(params, jnp.asarray(tokens)))[0]
+        np.testing.assert_array_equal(generated, got[len(prompt) - 1:n - 1])
+
+    return check
+
+
+def _carry_laguna():
+    from pipegoose_tpu.models import laguna
+
+    cfg = laguna.LagunaConfig(
+        vocab_size=96, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=5, num_key_value_heads=2, head_dim=16,
+        num_experts=16, num_experts_per_tok=4, moe_intermediate_size=32,
+        shared_expert_intermediate_size=32, sliding_window=8,
+        layer_types=(laguna.FULL,) + (laguna.SLIDING,) * 3 + (laguna.FULL,),
+        num_attention_heads_per_layer=(4, 6, 6, 6, 4), experts_held=(0, 8),
+        initializer_range=0.1)
+    params = laguna.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params, _forward_check(
+        lambda p, t: laguna.forward(p, t, cfg), params)
+
+
+def _carry_falcon():
+    from pipegoose_tpu.models import falcon_h1
+
+    cfg = falcon_h1.FalconH1Config(
+        vocab_size=96, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16,
+        mamba_n_groups=2, mamba_d_state=8, mamba_chunk_size=8,
+        lm_head_multiplier=0.5, embedding_multiplier=3.0,
+        initializer_range=0.3)
+    params = falcon_h1.init_params(cfg, jax.random.PRNGKey(0))
+    return cfg, params, _forward_check(
+        lambda p, t: falcon_h1.forward(p, t, cfg), params)
+
+
+CARRY_FAMILIES = {"one-table": _carry_bloom, "two-cache-kinds": _carry_laguna,
+                  "state-bank": _carry_falcon}
+
+
+@pytest.fixture(scope="module", params=sorted(CARRY_FAMILIES))
+def carry_family(request):
+    return CARRY_FAMILIES[request.param]()
+
+
+@pytest.fixture(scope="module")
+def carry_bloom():
+    return _carry_bloom()
+
+
+def _carry_requests():
+    rng = np.random.RandomState(7)
+    return [Request(prompt=rng.randint(1, 96, (s,)), max_new_tokens=n)
+            for s, n in CARRY_MIX]
+
+
+def _carry_engine(cfg, params, **kw):
+    kw = {"num_slots": 3, "num_pages": 48, "page_size": CARRY_PS,
+          "max_context": CARRY_CONTEXT, **kw}
+    return ServingEngine(params, cfg, **kw)
+
+
+class _StepSpy:
+    """Of every ``_step`` call of a run: the rows it ran on (slot -> the
+    request and its pages by kind) and whether its packed inputs came
+    from the host or were the very array the step before returned."""
+
+    def __init__(self, eng):
+        from pipegoose_tpu.serving.scheduler import Status
+
+        self.steps = []            # (rows, handed)
+        self._carry = None
+        step = eng._step
+
+        def spy(params, carry, *rest):
+            rows = {r.slot: (r.uid, tuple(r.pages), tuple(r.window_pages))
+                    for r in eng.sched.active() if r.status is Status.DECODE}
+            self.steps.append((rows, carry is not self._carry))
+            out = step(params, carry, *rest)
+            self._carry = out[-1]
+            return out
+
+        eng._step = spy
+
+    @property
+    def handed(self):
+        return sum(h for _, h in self.steps)
+
+
+def _carry_script(eng, requests, *, always_send=False, submit_at=None,
+                  late=(), retract_at=None):
+    """Drive the steppable run by a script: ``late`` requests submitted
+    at tick ``submit_at`` (an admission mid-run); at tick ``retract_at``
+    a neighbour's lazy growth retracts a decoding row inside ``prepare``
+    (what ``Scheduler._alloc`` does where eviction cannot cover a
+    reserved page: ``preempt`` of another active request), which is
+    re-admitted and re-prefilled. ``always_send`` forgets the device's
+    copy before every tick, so every step takes its inputs from the
+    host. Returns (outputs in submit order, metrics, ``last_tick()`` of
+    the ticks that decoded)."""
+    from pipegoose_tpu.serving.scheduler import Status
+
+    def hook(e, tick):
+        if always_send:
+            e._run.carry = None
+        if tick == submit_at:
+            for r in late:
+                e.submit_request(r)
+
+    ensure, retracted = eng.sched.ensure_page, []
+
+    def ensure_page(req):
+        if eng._run.tick == retract_at and not retracted:
+            victim = next(r for r in eng.sched.active()
+                          if r.status is Status.DECODE and r is not req)
+            retracted.append(victim)
+            eng.sched.preempt(victim)
+        ensure(req)
+
+    eng.sched.ensure_page = ensure_page
+    try:
+        eng.start_run(requests, tick_hook=hook)
+        ticks = []
+        while not eng.sched.all_done():
+            eng.tick_once()
+            tick = eng.last_tick()
+            if tick["rows"]:
+                ticks.append(tick)
+        outs, metrics = eng.finish_run()
+    finally:
+        del eng.sched.ensure_page
+    assert (retract_at is None) == (not retracted)
+    return sorted(outs, key=lambda o: o.uid), metrics, ticks
+
+
+def _assert_sent_where_something_changed(spy, metrics, ticks):
+    """A step takes its inputs from the host exactly where its rows are
+    not those of the step before (nobody admitted, ended or retracted,
+    no page taken: it runs on what that step left on the device);
+    ``step_uploads`` and the timeline's ``upload`` say the same."""
+    assert len(spy.steps) == len(ticks) > 0
+    before = None
+    for (rows, handed), tick in zip(spy.steps, ticks):
+        assert handed == (rows != before)
+        assert tick["step_uploads"] == handed
+        assert (tick["upload"] > 0.0) == handed
+        before = rows
+    assert metrics["step_uploads"] == spy.handed
+
+
+def test_the_carry_serves_what_sending_every_tick_serves(carry_family):
+    """Admitted mid-run, ended, grown over a page boundary, retracted by
+    a neighbour's lazy growth and re-admitted, aborted and started
+    again: token for token the always-send engine's, and the model's own
+    greedy forward's."""
+    from pipegoose_tpu.serving.scheduler import Status
+
+    cfg, params, check = carry_family
+    script = {"submit_at": 4, "retract_at": 9}
+
+    def run(eng, **kw):
+        reqs = _carry_requests()
+        return _carry_script(eng, reqs[:2], late=reqs[2:], **script, **kw)
+
+    eng = _carry_engine(cfg, params)
+    spy = _StepSpy(eng)
+    outs, metrics, ticks = run(eng)
+    assert len(outs) == len(CARRY_MIX)
+    for out in outs:
+        check(out.prompt, out.generated)
+    _assert_sent_where_something_changed(spy, metrics, ticks)
+    # what the script implies: the run's first step is sent; the next
+    # two are not (no admission, and 25 + 3 and 9 + 3 tokens lie in the
+    # pages of 8 that 25 + 1 and 9 + 1 do); tick 4 admits a late request
+    # into the free slot and its step is sent; the one after is not
+    assert [t["step_uploads"] for t in ticks[:5]] == [1, 0, 0, 1, 0]
+    assert [t["prefills"] for t in ticks[:5]] == [2, 0, 0, 1, 0]
+    assert all(t["step_uploads"] for t in ticks if t["prefills"])
+    assert metrics["prefills"] == len(CARRY_MIX) + 1    # the re-admission
+    # the mechanism engaged: most ticks of three rows take no page
+    plain = metrics["decode_steps"]
+    assert len(CARRY_MIX) < metrics["step_uploads"] < plain // 2
+    # every column still sums to its phase; upload is 0.0 where nothing
+    # was sent
+    tl = metrics["tick_timeline"]
+    for name in ("admit", "prefill", "prepare", "fetch", "record"):
+        assert sum(_column(tl, name)) == pytest.approx(
+            metrics["tick_phase_s"][name], abs=1e-6)
+    assert sum(_column(tl, "upload")) + sum(_column(tl, "call")) == \
+        pytest.approx(metrics["tick_phase_s"]["dispatch"], abs=1e-6)
+    assert sum(1 for u in _column(tl, "upload") if u > 0.0) == \
+        metrics["step_uploads"]
+
+    # the same script, every step sent
+    every = _carry_engine(cfg, params)
+    want, sent, _ = run(every, always_send=True)
+    assert sent["step_uploads"] == sent["decode_steps"] == plain
+    assert [list(o.generated) for o in outs] == \
+        [list(o.generated) for o in want]
+
+    # aborted and started again: a fresh run holds nothing, its first
+    # step is sent, and the tokens are the first run's
+    eng.start_run(_carry_requests()[:3])
+    for _ in range(4):
+        eng.tick_once()
+    assert eng._run.carry is not None
+    for req in list(eng.sched.active()):
+        eng.sched.preempt(req)
+        eng.sched.withdraw(req)
+    eng.abort_run()
+    assert eng.pool.used_count == 0 and not eng.run_in_progress
+    spy.steps.clear()
+    again, metrics2, ticks2 = run(eng)
+    _assert_sent_where_something_changed(spy, metrics2, ticks2)
+    assert ticks2[0]["step_uploads"] == 1
+    assert metrics2["step_uploads"] == metrics["step_uploads"]
+    assert [list(o.generated) for o in again] == \
+        [list(o.generated) for o in outs]
+
+
+def test_the_host_copy_is_the_engines_own(carry_bloom):
+    """On the CPU a device array may share the host buffer it was made
+    from: what is sent and the host's copy of what the device holds are
+    copies of ``prepare``'s buffer, never views of it; the copy is what
+    the device holds after a step."""
+    cfg, params, _ = carry_bloom
+    eng = _carry_engine(cfg, params)
+    eng.start_run(_carry_requests()[:2])
+    eng.tick_once()
+    rs = eng._run
+    assert rs.tokens.base is rs.packed and rs.table.base is not None
+    assert not np.shares_memory(rs.held, rs.packed)
+    held = rs.held.copy()
+    rs.packed.fill(-1)                      # what the next prepare does
+    np.testing.assert_array_equal(rs.held, held)
+    np.testing.assert_array_equal(np.asarray(rs.carry), rs.held)
+    eng.tick_once()                         # a step on the device's own
+    assert eng.last_tick()["step_uploads"] == 0
+    np.testing.assert_array_equal(np.asarray(rs.carry), rs.held)
+    eng.abort_run()
+
+
+def test_step_uploads_is_counted_on_the_registry(carry_bloom):
+    from pipegoose_tpu.telemetry import MetricsRegistry
+
+    cfg, params, _ = carry_bloom
+    reg = MetricsRegistry(enabled=True)
+    eng = _carry_engine(cfg, params, registry=reg)
+    _, metrics = eng.run(_carry_requests())
+    counted = reg.snapshot()["counters"]
+    assert counted["serving.step_uploads"] == metrics["step_uploads"] > 0
+    assert counted["serving.decode_steps_total"] == metrics["decode_steps"]
+
+
+CARRY_MODES = [
+    pytest.param({"speculative": (1, 2)}, id="speculative"),
+    pytest.param({"prefill_chunk": 8}, id="prefill_chunk"),
+    pytest.param({"prefix_cache": True}, id="prefix_cache"),
+]
+
+
+def _mode_requests(kwargs):
+    requests = _carry_requests()
+    if "prefix_cache" in kwargs:
+        # a shared prefix of three pages: admissions that share pages
+        for r in requests[1:]:
+            r.prompt = np.concatenate([requests[0].prompt[:24], r.prompt])
+            r.max_new_tokens = min(r.max_new_tokens, 10)
+    return requests
+
+
+@pytest.mark.parametrize("kwargs", CARRY_MODES)
+def test_a_mode_that_moves_rows_outside_the_step_is_never_stale(
+        carry_bloom, kwargs):
+    """A speculative cycle, a chunk, a shared prefix: whatever moved a
+    row between two plain steps shows as a difference between what the
+    host prepared and what the device holds, and is sent."""
+    cfg, params, check = carry_bloom
+    eng = _carry_engine(cfg, params, **kwargs)
+    spy = _StepSpy(eng)
+    outs, metrics, ticks = _carry_script(eng, _mode_requests(kwargs))
+    for out in outs:
+        check(out.prompt, out.generated)
+    want, _, _ = _carry_script(_carry_engine(cfg, params, **kwargs),
+                               _mode_requests(kwargs), always_send=True)
+    assert [list(o.generated) for o in outs] == \
+        [list(o.generated) for o in want]
+    assert metrics["step_uploads"] == spy.handed
+    before = None
+    for rows, handed in spy.steps:
+        # never a step on stale inputs: what differs from the step
+        # before is sent
+        assert handed or rows == before
+        before = rows
+    if "speculative" in kwargs:
+        # every cycle advanced its rows behind the device's copy: no
+        # plain step that follows one may run on it
+        assert metrics["speculative"]["draft_tokens"] > 0
+        assert spy.handed == len(spy.steps)
+    else:
+        assert 0 < spy.handed < len(spy.steps)
+
+
+class _Lowerings:
+    """Counts what JAX lowers, by function (``benchmark/compile_watch.py``
+    counts the same event)."""
+
+    EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
+    def __init__(self):
+        self.names = []
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._on)
+
+    def _on(self, event, duration, **kw):
+        if event == self.EVENT:
+            self.names.append(kw.get("fun_name"))
+
+    def count(self, fn):
+        return sum(1 for n in self.names if n == f"jit({fn})")
+
+
+def _warm_up_and_window(eng, check):
+    """The benchmark's shape: a warm-up of one request a prompt bucket,
+    then a window under a script."""
+    rng = np.random.RandomState(3)
+    eng.run([Request(prompt=rng.randint(1, 96, (b,)), max_new_tokens=2)
+             for b in (8, 16, 24, 32)])
+    reqs = _carry_requests()
+    outs, metrics, _ = _carry_script(eng, reqs[:4], late=reqs[4:],
+                                     submit_at=4)
+    for out in outs:
+        check(out.prompt, out.generated)
+    # both kinds of step ran: on inputs from the host, and on the
+    # device's own
+    assert 0 < metrics["step_uploads"] < metrics["decode_steps"]
+
+
+def test_the_step_is_lowered_once_whoever_hands_it_its_inputs(carry_bloom):
+    """The plain path's program count is the parent's: the step that
+    takes its inputs from the host and the step that takes the step
+    before's are ONE program."""
+    cfg, params, check = carry_bloom
+    eng = _carry_engine(cfg, params)
+    with _Lowerings() as seen:
+        _warm_up_and_window(eng, check)
+    assert seen.count("_step") == 1
+    assert seen.count("_prefill") == seen.count("_write") == 4
+
+
+def test_the_step_is_lowered_once_under_a_mesh(carry_bloom, devices):
+    """tp=2: the tokens are generate()'s, a quiet tick sends nothing,
+    and the buffer placed on every device meets the program that the
+    step's own next inputs meet."""
+    cfg, params, check = carry_bloom
+    ctx = ParallelContext(tensor_parallel_size=2, data_parallel_size=4)
+    try:
+        eng = _carry_engine(cfg, params, mesh=ctx.mesh,
+                            param_specs=bloom.tp_specs(params))
+        with _Lowerings() as seen:
+            _warm_up_and_window(eng, check)
+        assert seen.count("_step") == 1
+        spy = _StepSpy(eng)
+        outs, metrics, ticks = _carry_script(eng, _carry_requests())
+        _assert_sent_where_something_changed(spy, metrics, ticks)
+        for out in outs:
+            check(out.prompt, out.generated)
+        eng.doctor()
+        eng.profile(steps=1, warmup=1)
+        assert eng.pool.used_count == 0
+    finally:
+        ctx.destroy()

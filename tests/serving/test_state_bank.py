@@ -239,20 +239,25 @@ def test_a_model_without_state_builds_the_programs_it_built(family):
                         max_context=32)
     assert eng.state == {} and not eng.model.state
     assert all(g.mix is None for g in eng.model.groups)
-    i32 = jnp.int32
-    table = eng._by_kind(lambda w: jnp.zeros((2, w), i32))
-    args = (params, jnp.zeros((2,), i32), eng.k_pages, eng.v_pages, table,
-            jnp.zeros((2,), i32))
+    args = (params, jnp.zeros((eng._carry_size,), jnp.int32), eng.k_pages,
+            eng.v_pages)
     built = eng._step.lower(*args).as_text()
 
-    def _step(params, tokens, k_pages, v_pages, table, seq_lens):
-        # what the engine jitted before a bank existed
+    def _step(params, carry, k_pages, v_pages):
+        # what the engine jits where no bank exists: the step over its
+        # packed inputs, and the next step's beside its results
+        tokens, seq_lens, table = eng._unpack_carry(carry)
         logits, k_pages, v_pages, counters = kv_pool.paged_decode_step(
             params, tokens, k_pages, v_pages, table, seq_lens, eng.model,
             with_counters=True)
-        return logits.argmax(-1), k_pages, v_pages, counters
+        nxt = logits.argmax(-1)
+        live = seq_lens > 0
+        head = jnp.concatenate(
+            [jnp.where(live, nxt.astype(jnp.int32), 0), seq_lens + live])
+        carry = jax.lax.dynamic_update_slice(carry, head, (0,))
+        return nxt, k_pages, v_pages, counters, {}, carry   # {}: no leaf
 
-    plain = jax.jit(_step, donate_argnums=(2, 3)).lower(*args).as_text()
+    plain = jax.jit(_step, donate_argnums=(1, 2, 3)).lower(*args).as_text()
     assert built == plain
     main = next(x for x in built.splitlines() if "public @main(" in x)
     assert main.count("%arg") == len(jax.tree_util.tree_leaves(args))

@@ -204,9 +204,11 @@ def test_key_share_is_counted_by_kind(model, monkeypatch):
     sent = []
     step = eng._step
 
-    def spy(p, tokens, kp, vp, table, seq_lens):
-        sent.append(np.array(seq_lens))          # a copy: the engine's
-        return step(p, tokens, kp, vp, table, seq_lens)   # own buffer
+    def spy(p, carry, kp, vp):
+        # the lengths the step ran on, out of its packed inputs (sent or
+        # carried on the device); a copy, before the call donates them
+        sent.append(np.array(eng._unpack_carry(np.asarray(carry))[1]))
+        return step(p, carry, kp, vp)
 
     eng._step = spy
     clock = itertools.count()
