@@ -10,6 +10,7 @@ from pipegoose_tpu.nn.expert_parallel.loss import ExpertLoss
 from pipegoose_tpu.nn.expert_parallel.routers import (
     RouterOutput,
     SigmoidTopKRouter,
+    SoftmaxTopKRouter,
     SwitchNoisePolicy,
     Top1Router,
     Top2Router,
@@ -27,6 +28,7 @@ __all__ = [
     "ExpertLoss",
     "RouterOutput",
     "SigmoidTopKRouter",
+    "SoftmaxTopKRouter",
     "SwitchNoisePolicy",
     "Top1Router",
     "Top2Router",

@@ -11,12 +11,15 @@ Two output forms, both with static shapes (the reference returns a
 dynamic dispatching order consumed by index_select loops,
 experts.py:99-102, which cannot be jit-compiled):
 
-- ``SigmoidTopKRouter`` returns ``(T, k)`` expert ids and combine
+- ``SigmoidTopKRouter`` and ``SoftmaxTopKRouter`` (the score function
+  is the difference; the second counts a model's zero-compute experts
+  among its outputs) return ``(T, k)`` expert ids and combine
   weights (``TopKRouting``) and nothing else: no capacity, no dropped
   token. ``experts.grouped_experts`` sorts the picks by expert and runs
   a grouped matrix product over the groups, so memory and work grow
   with ``T * k`` rows. This is the form the expert models of the
-  benchmark run (models/glm4_moe_lite.py).
+  benchmark run (models/glm4_moe_lite.py, laguna.py,
+  longcat_flash.py).
 - ``TopKRouter`` returns dense one-hot dispatch/combine tensors of
   shape ``(T, E, C)`` (``RouterOutput``; the Mesh-TensorFlow/GShard
   formulation), consumed by two einsums around an ``all_to_all`` in
@@ -50,6 +53,18 @@ class TopKRouting(NamedTuple):
     scores: jax.Array  # (T, E) float32 router scores (counters, tests)
 
 
+def _pick(router, scores, bias) -> TopKRouting:
+    """The ``top_k`` largest of ``scores + bias`` with their weights
+    from the scores alone: what the two score functions below share."""
+    choice = scores + jax.lax.stop_gradient(bias.astype(jnp.float32))
+    _, experts = jax.lax.top_k(choice, router.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if router.normalize:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return TopKRouting(experts.astype(jnp.int32),
+                       weights * router.scaling, scores)
+
+
 @dataclasses.dataclass(frozen=True)
 class SigmoidTopKRouter:
     """Sigmoid scores, selection on score + bias, weights from the
@@ -73,15 +88,33 @@ class SigmoidTopKRouter:
         scores = jax.nn.sigmoid(jnp.dot(
             x, params["gate"]["kernel"], preferred_element_type=jnp.float32
         ))
-        choice = scores + jax.lax.stop_gradient(
-            params["bias"].astype(jnp.float32)
-        )
-        _, experts = jax.lax.top_k(choice, self.top_k)
-        weights = jnp.take_along_axis(scores, experts, axis=-1)
-        if self.normalize:
-            weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
-        return TopKRouting(experts.astype(jnp.int32),
-                           weights * self.scaling, scores)
+        return _pick(self, scores, params["bias"])
+
+
+@dataclasses.dataclass(frozen=True)
+class SoftmaxTopKRouter:
+    """Softmax scores over ALL outputs, selection on score + bias,
+    weights from the scores alone (HF ``longcat_flash``'s
+    ``LongcatFlashTopkRouter``): ``z = softmax(x W_g)`` in float32 over
+    ``num_experts`` outputs, which count the zero-compute experts a
+    model routes to beside its real ones; the ``top_k`` picks are the
+    largest of ``z + b``; their weights are ``z[chosen]`` times
+    ``scaling``, renormalised over the chosen only where ``normalize``
+    (LongCat-Flash: not). The bias moves the SELECTION only, as
+    :class:`SigmoidTopKRouter`'s. Same :class:`TopKRouting` out."""
+
+    num_experts: int
+    top_k: int
+    scaling: float = 1.0
+    normalize: bool = False
+
+    def __call__(self, params: dict, x: jax.Array) -> TopKRouting:
+        """``params``: ``{"gate": {"kernel": (H, E)}, "bias": (E,)}``;
+        ``x``: (T, H) flat tokens."""
+        scores = jax.nn.softmax(jnp.dot(
+            x, params["gate"]["kernel"], preferred_element_type=jnp.float32
+        ), axis=-1)
+        return _pick(self, scores, params["bias"])
 
 
 @dataclasses.dataclass(frozen=True)

@@ -29,10 +29,22 @@ the bank in a decode step, and its ``prefill`` the state after the last
 real token of a bucketed prompt, which the engine puts in the slot at
 admission.
 
+What a cached row IS belongs to the description too. By default it is
+``n_kv_head x head_dim`` lanes of keys in one bank and as many of values
+in a second. A model with LATENT attention names a :class:`LatentRow`
+instead: ONE row a token in ONE bank, which every query head reads, the
+row's first lanes being the value as well; no second bank exists
+(``kv_pool.init_pages``, ``_attend_latent``).
+
+A layer may attend MORE THAN ONCE (``LayerGroup.more``): each attention
+has a row of its own a token, so a layer of ``a`` attentions fills ``a``
+bank layers, attention ``j`` of layer ``l`` bank layer ``a * l + j``.
+
 BLOOM is the first instance (:func:`bloom_model`), Laguna the second
 (``models/laguna.py:paged_model``: two kinds), Falcon-H1 the third
 (``models/falcon_h1.py:paged_model``: global pages and a state in every
-block). A config object that has a ``paged_model(tp_axis)`` method
+block), LongCat-Flash the fourth (``models/longcat_flash.py:paged_model``:
+a latent row, two attentions a block). A config object that has a ``paged_model(tp_axis)`` method
 describes itself; any other is taken for a BLOOM (:func:`describe`).
 """
 from __future__ import annotations
@@ -42,6 +54,25 @@ from typing import Any, Callable, Optional, Tuple
 
 GLOBAL, WINDOW = "global", "window"
 KINDS = (GLOBAL, WINDOW)
+LANE_TILE = 128               # lanes of the chip's vector registers
+
+
+@dataclass(frozen=True)
+class LatentRow:
+    """A cached row that is neither a key nor a value but what both are
+    made from: one a token, read by every query head."""
+    lanes: int                # lanes a row holds
+    value_lanes: int          # its first lanes, which are the value too
+    q_heads: int              # query heads that read the one row
+    scale: float              # on the scores: the model's own, not lanes ** -.5
+
+    @property
+    def stored(self) -> int:
+        """Lanes the bank keeps a row in: whole lane tiles, zeros behind
+        the row. A row of 4.5 tiles (576) has the compiler put the PAGES
+        in the lanes and re-lay out the pool around every program
+        (PERF.md, PRs 27 and 45)."""
+        return -(-self.lanes // LANE_TILE) * LANE_TILE
 
 
 @dataclass(frozen=True)
@@ -51,10 +82,13 @@ class LayerGroup:
     stacked: bool             # params carry a leading (n,) axis
     params: Callable          # params -> the group's subtree
     # (blk, h (B, C, hidden), pos (B, C)) -> (q (B, C, H, hd),
-    #  k, v (B, C, KV, hd), saved): all before attention
+    #  k, v (B, C, KV, hd), saved): all before attention. Over a latent
+    # row: q (B, C, H, lanes) as it meets the row, k (B, C, 1, lanes)
+    # the row itself, v None
     qkv: Callable
     # (blk, h, ctx (B, C, H * hd), saved, live (B, C) bool | None)
-    #  -> (h, counters | None): all after it
+    #  -> (h, counters | None): all after it. Over a latent row ctx is
+    # (B, C, H * value_lanes): the probabilities over the rows' values
     finish: Callable
     # () -> (H,) ALiBi slopes of this shard's heads, called inside the
     # program; None: no position bias on the scores (rotary models)
@@ -65,6 +99,19 @@ class LayerGroup:
     #  -> (saved, bank), the bank's rows of ``layer`` read and overwritten
     # (``kv_pool.update_state_rows``); None: the layers keep none
     mix: Optional[Callable] = None
+    # the layer's further attentions after its first, ``((qkv, finish),
+    # ..)`` with the signatures above, each with its own row a token in
+    # the bank. What one attention's ``finish`` returns as ``h`` is the
+    # next one's and stays inside the layer, so it may hold more than
+    # the hidden state (a result that lands later in the block); the
+    # last returns the hidden state alone, and the layer's counters
+    # (an array under the model's one name, or ``{name: array}``). Such
+    # a layer is traced in line: its group is not stacked.
+    more: Tuple[Tuple[Callable, Callable], ...] = ()
+
+    @property
+    def attends(self) -> int:
+        return 1 + len(self.more)
 
 
 @dataclass(frozen=True)
@@ -80,7 +127,9 @@ class PagedModel:
     # (params, ids (1, S), mask (1, S)) -> (logits (1, V_local), cache)
     # with cache {"k", "v"} (L, 1, S, KV, hd) for a one-kind model and
     # {kind: {"k", "v"}} for a two-kind one; a model with ``state`` adds
-    # "state": {name: (L, 1, ..)} after the prompt's last REAL token
+    # "state": {name: (L, 1, ..)} after the prompt's last REAL token; a
+    # latent model's cache is {"rows": (L, 1, S, lanes)}, a layer an
+    # attention
     prefill: Callable
     left_pad: bool = True     # the side a bucketed prompt is padded on
     window: Optional[int] = None          # keys a window layer keeps
@@ -90,6 +139,23 @@ class PagedModel:
     # what a sequence leaves in a layer beside its keys and values, a
     # slot: ((name, shape, dtype), ..); (): nothing
     state: Tuple[tuple, ...] = ()
+    # the cached row where it is not keys and values of ``n_kv_head x
+    # head_dim`` lanes in two banks (then ``n_kv_head`` is 1 and
+    # ``head_dim`` the row's lanes)
+    latent: Optional[LatentRow] = None
+
+    @property
+    def banks(self) -> int:
+        """Banks the pool holds: keys and values, or the one of a
+        latent row."""
+        return 1 if self.latent is not None else 2
+
+    @property
+    def row_lanes(self) -> int:
+        """Lanes a bank keeps a cached row in, all shards."""
+        if self.latent is not None:
+            return self.latent.stored
+        return self.n_kv_head * self.head_dim
 
     @property
     def kinds(self) -> Tuple[str, ...]:
@@ -97,7 +163,8 @@ class PagedModel:
                      if any(g.kind == k for g in self.groups))
 
     def layers_of(self, kind: str) -> int:
-        return sum(g.n for g in self.groups if g.kind == kind)
+        """Layers of a kind's bank: a row a token an ATTENTION."""
+        return sum(g.n * g.attends for g in self.groups if g.kind == kind)
 
     @property
     def n_layer(self) -> int:

@@ -246,7 +246,7 @@ class _RunState:
         "step_uploads", "uploaded",
         "keys_walked", "keys_reached", "window_table", "window_keys_walked",
         "window_keys_reached", "occ_window", "peak_pages", "recycled0",
-        "experts_touched", "expert_skew", "rows_routed",
+        "experts_touched", "expert_skew", "rows_routed", "zero_pick_share",
         "state_rows_updated", "state_rows_live", "state_writes",
         "state_peak_slots",
     )
@@ -282,6 +282,9 @@ class _RunState:
         self.experts_touched: List[int] = []
         self.expert_skew = 0.0
         self.rows_routed = 0
+        # picks on zero-compute experts over all picks, summed step by
+        # step (None: the model routes to none)
+        self.zero_pick_share: Optional[float] = None
         # the state bank (a model that has one): rows the decode steps'
         # walks read and wrote, rows alive in them, prefill results put
         # in a slot, the most slots holding a request in a step
@@ -439,13 +442,17 @@ class ServingEngine:
         # a cache that is more than global pages
         more = ([f"{len(model.kinds)} cache kinds {model.kinds}"]
                 if len(model.kinds) > 1 else []) + (
-            ["a state a slot beside its pages"] if model.state else [])
+            ["a state a slot beside its pages"] if model.state else []) + (
+            ["a latent row a token in one bank"]
+            if model.latent is not None else [])
         if more:
-            # built for global pages alone, none half-carried over: a
-            # prefix page, a draft, a chunk or a transfer would each need
-            # the window layers' ring, or the state as it stood there (a
-            # shared prefix's, a refused draft's, a chunk's), beside the
-            # global layers' pages
+            # built for global pages of keys and values alone, none
+            # half-carried over: a prefix page, a draft, a chunk or a
+            # transfer would each need the window layers' ring, or the
+            # state as it stood there (a shared prefix's, a refused
+            # draft's, a chunk's), beside the global layers' pages; over
+            # a latent row each of their programs would need its form
+            # without the values' bank
             asked = {
                 "prefix_cache": prefix_cache, "speculative": speculative,
                 "prefill_chunk": prefill_chunk, "kv_dtype": kv_dtype,
@@ -536,6 +543,7 @@ class ServingEngine:
         }
         self._m_recycled = reg.counter("serving.window_pages_recycled_total")
         self._m_experts = reg.gauge("serving.experts_touched_share")
+        self._m_zero_picks = reg.gauge("serving.zero_pick_share")
         self._m_state_slots = reg.gauge("serving.state_slots_in_use")
         self._m_state_writes = reg.counter("serving.state_writes_total")
         self._m_prefill_tok = reg.counter("serving.prefill_tokens_total")
@@ -689,6 +697,13 @@ class ServingEngine:
                     return write_prompt_pages(
                         k_pages, v_pages, cache, phys, pad, page_size
                     )
+            elif model.latent is not None:
+                def _write(pages, cache, phys, pad, length):
+                    # the one bank of a latent row: no values' bank in
+                    # the program
+                    return write_prompt_pages(
+                        pages, None, cache, phys, pad, page_size,
+                        length)[:1]
             elif not model.state:
                 def _write(k_pages, v_pages, cache, phys, pad, length):
                     return write_prompt_pages(
@@ -744,7 +759,8 @@ class ServingEngine:
 
             self._prefill = jax.jit(_prefill)
             self._write = jax.jit(
-                _write, donate_argnums=(0, 1, 6) if model.state else (0, 1))
+                _write, donate_argnums=(0, 1, 6) if model.state
+                else (0,) if model.latent is not None else (0, 1))
             self._chunk = jax.jit(_chunk, donate_argnums=(2, 3))
             self._copy = jax.jit(_copy, donate_argnums=(0, 1))
             self._draft = jax.jit(_draft, donate_argnums=(2, 3))
@@ -879,7 +895,19 @@ class ServingEngine:
             carry = jax.lax.dynamic_update_slice(carry, head, (0,))
             return nxt, k_pages, v_pages, counters, state, carry
 
-        self._step = jax.jit(_step, donate_argnums=(1, 2, 3, 4))
+        donate = (1, 2, 3, 4)
+        if model.latent is not None:
+            both = _step
+
+            def _step(params, carry, pages):
+                # over a latent row the pool is ONE bank: the program
+                # takes, donates and returns no bank of values
+                nxt, pages, _, counters, state, carry = both(
+                    params, carry, pages, None)
+                return nxt, pages, counters, state, carry
+
+            donate = (1, 2)
+        self._step = jax.jit(_step, donate_argnums=donate)
         # A buffer from the host goes to the CALL as the host array it
         # is: the jitted call's own argument path transfers it for a
         # fraction of what ``jax.device_put`` or ``jnp.asarray`` cost the
@@ -916,11 +944,11 @@ class ServingEngine:
         intended = None
         if self.mesh is not None:
             intended = (self.param_specs, P(), self._pspec, self._pspec)
+        pool = self._pool()
         report = diagnose(
-            self._step, self.params, carry, self.k_pages, self.v_pages,
-            *self._state_arg(),
+            self._step, self.params, carry, *pool, *self._state_arg(),
             intended=intended,
-            labels=("params", "carry", "k_pages", "v_pages")
+            labels=("params", "carry", "k_pages", "v_pages")[:2 + len(pool)]
             + ("state",) * len(self._state_arg()),
             mesh=self.mesh, large_bytes=large_bytes,
         )
@@ -997,18 +1025,20 @@ class ServingEngine:
         carry = self._place(np.zeros((self._carry_size,), np.int32))
         final: dict = {}
 
+        n = len(self._pool())
+
         def update(out, cur):
-            # out = (next_tokens, k_pages, v_pages, counters, state, the
+            # out = (next_tokens, the pool's banks, counters, state, the
             # next step's inputs); the inputs, the pages and the state
             # bank were donated — thread (and finally adopt) the new
             # buffers
-            final["k"], final["v"], final["state"] = out[1], out[2], out[4]
-            return (cur[0], out[5], out[1], out[2]) + (
-                (out[4],) if self.state else ())
+            final["pool"], final["state"] = out[1:1 + n], out[n + 2]
+            return (cur[0], out[n + 3]) + tuple(out[1:1 + n]) + (
+                (out[n + 2],) if self.state else ())
 
         try:
             profile = profile_step(
-                self._step, self.params, carry, self.k_pages, self.v_pages,
+                self._step, self.params, carry, *self._pool(),
                 *self._state_arg(),
                 steps=steps, warmup=warmup, update_args=update,
                 mesh=self.mesh, trace_dir=trace_dir,
@@ -1020,7 +1050,7 @@ class ServingEngine:
             # parsing/export raises, or the engine's next decode step
             # would touch deleted arrays
             if final:
-                self.k_pages, self.v_pages = final["k"], final["v"]
+                self._adopt(final["pool"])
                 self.state = final["state"]
         self.last_step_profile = profile
         return profile
@@ -1048,12 +1078,13 @@ class ServingEngine:
         kv_total = int(sum(kv_by.values()))
         model = self.model
         num_pages = self.pool.num_pages
-        row = (self.page_size * model.n_kv_head * model.head_dim
+        # a page of one bank layer, and the banks, by the description
+        row = (self.page_size * model.row_lanes
                * int(np.dtype(model.dtype).itemsize))
         by_kind = {
             kind: {"layers": model.layers_of(kind),
                    "num_pages": self.pool.of(kind).num_pages,
-                   "fp_bytes": 2 * model.layers_of(kind)
+                   "fp_bytes": model.banks * model.layers_of(kind)
                    * self.pool.of(kind).num_pages * row}
             for kind in model.kinds}
         fp_total = sum(k["fp_bytes"] for k in by_kind.values())
@@ -1158,6 +1189,18 @@ class ServingEngine:
         for a model without (its program takes no such argument)."""
         return (self.state,) if self.state else ()
 
+    def _pool(self) -> tuple:
+        """The pool's banks as a program takes them: keys and values, or
+        the one bank of a latent row."""
+        if self.v_pages is None:
+            return (self.k_pages,)
+        return self.k_pages, self.v_pages
+
+    def _adopt(self, banks) -> None:
+        """The banks a program returned, in :meth:`_pool`'s order."""
+        self.k_pages, self.v_pages = (
+            banks if len(banks) == 2 else (banks[0], None))
+
     def _state_bytes(self) -> int:
         return sum(int(x.size) * int(np.dtype(x.dtype).itemsize)
                    for x in self.state.values())
@@ -1190,7 +1233,9 @@ class ServingEngine:
     def _note_counters(self, rs, counters: dict) -> None:
         """One plain decode step's counter channel: ``rows_per_expert``
         (sparse layers, held experts), the rows each held expert was
-        sent."""
+        sent; where the model routes to zero-compute experts too,
+        ``zero_picks`` and ``picks`` (a sparse layer each): the live
+        rows' picks that cost nothing, and all of them."""
         rows = counters.get("rows_per_expert")
         if rows is None:
             return
@@ -1203,6 +1248,11 @@ class ServingEngine:
         rs.expert_skew += float(
             (rows.max(axis=1) * held / np.maximum(total, 1)).sum())
         self._m_experts.set(touched / (layers * held))
+        zero = counters.get("zero_picks")
+        if zero is not None:
+            share = float(zero.sum()) / max(int(counters["picks"].sum()), 1)
+            rs.zero_pick_share = (rs.zero_pick_share or 0.0) + share
+            self._m_zero_picks.set(share)
 
     def _ledger_tick(self, rs) -> None:
         """Per-tick ledger hook (conservation check + forecast +
@@ -1320,18 +1370,19 @@ class ServingEngine:
             t_write = now()
             # dispatched and never fetched: on the device the write runs
             # after this span has closed, in the next fetch's wait
+            pool = self._pool()
             written = self._write(
-                self.k_pages, self.v_pages, cache, self._phys_rows(req),
+                *pool, cache, self._phys_rows(req),
                 jnp.asarray(pad, jnp.int32),
                 *(() if left else (jnp.asarray(s, jnp.int32),)),
                 *((self.state, jnp.asarray(req.slot, jnp.int32))
                   if self.state else ()),
             )
-            self.k_pages, self.v_pages = written[:2]
+            self._adopt(written[:len(pool)])
             if self.state:
                 # the slot starts from this prefill's state, whatever
                 # its last request left in the row
-                self.state = written[2]
+                self.state = written[len(pool)]
                 self._run.state_writes += 1
                 self._m_state_writes.inc()
             t_fetch = now()
@@ -1875,11 +1926,11 @@ class ServingEngine:
                         self._m_step_uploads.inc()
                         t_call = now()
                 with span("dispatch", registry=reg):
-                    (nxt, self.k_pages, self.v_pages, counters,
-                     self.state, rs.carry) = self._step(
-                        self.params, rs.carry, self.k_pages, self.v_pages,
+                    nxt, *pool, counters, self.state, rs.carry = self._step(
+                        self.params, rs.carry, *self._pool(),
                         *self._state_arg(),
                     )
+                    self._adopt(pool)
                 t_disp = now()
                 # the host waiting on the device: what it waits for is
                 # this step and whatever was queued before it (a page
@@ -2166,6 +2217,11 @@ class ServingEngine:
                 "rows_routed": rs.rows_routed,
                 "held_a_step": self._experts_held,
             }
+            if rs.zero_pick_share is not None:
+                # picks that fell on zero-compute experts, mean over the
+                # steps as ``touched_share`` is
+                metrics["experts"]["zero_pick_share"] = round(
+                    rs.zero_pick_share / n, 6)
         if self.state:
             metrics["state"] = {
                 "slots": self.num_slots,

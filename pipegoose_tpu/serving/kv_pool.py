@@ -362,20 +362,30 @@ def init_pages(config, num_pages: int, page_size: int, tp: int = 1,
 
     A model with two cache kinds gets a bank a kind, ``{"global": (L_g,
     num_pages, ..), "window": (L_w, window_pages, ..)}``: the layers of
-    a kind stacked in their order in the model."""
+    a kind stacked in their order in the model.
+
+    A model with a latent row (``PagedModel.latent``) gets ONE bank,
+    ``(L, num_pages, page_size, lanes in whole lane tiles)``, a layer an
+    attention, and ``None`` where the values' bank would be: the row is
+    its own value, and no program of such a model takes a second bank."""
     model = describe(config)
     nh, hd = model.n_kv_head // tp, model.head_dim
     kv_dtype = check_kv_dtype(kv_dtype)
+    if model.latent is not None and (kv_dtype, tp) != (None, 1):
+        raise ValueError("a latent row is kept whole and in the model's "
+                         "dtype: one shard, no int8 bank")
 
     def bank(layers, pages):
-        shape = (layers, pages, page_size, nh * hd)
+        shape = (layers, pages, page_size, model.row_lanes // tp)
         if kv_dtype is None:
             return jnp.zeros(shape, model.dtype)
         return {"q": jnp.zeros(shape, jnp.int8),
                 "scale": jnp.zeros(shape[:-1] + (nh,), jnp.float32)}
 
     if model.kinds == (GLOBAL,):
-        return bank(model.n_layer, num_pages), bank(model.n_layer, num_pages)
+        layers = model.layers_of(GLOBAL)
+        return bank(layers, num_pages), (
+            None if model.latent is not None else bank(layers, num_pages))
     sizes = {GLOBAL: num_pages, WINDOW: window_pages}
 
     def banks():
@@ -418,6 +428,11 @@ def _write_rows(pages, idx, val):
         return {"q": pages["q"].at[idx].set(_rows(q)),
                 "scale": pages["scale"].at[idx].set(s)}
     return pages.at[idx].set(_rows(val).astype(pages.dtype))
+
+
+def _to_lanes(x, lanes: int):
+    """``x`` (.., n) with zeros behind it up to ``lanes``."""
+    return jnp.pad(x, ((0, 0),) * (x.ndim - 1) + ((0, lanes - x.shape[-1]),))
 
 
 def _values(pages):
@@ -532,6 +547,9 @@ def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
     kind's row is its ring: position p lands in entry ``(p // page_size)
     % ring``, and only the pages the ring still holds at the prompt's
     end are written (the earlier ones would be overwritten anyway).
+
+    A latent model's cache is ``{"rows": (L, 1, S_pad, lanes)}`` and its
+    ``v_pages`` None: the one bank is written, ``(bank, None)`` returned.
     """
     if isinstance(cache, dict) and GLOBAL in cache:
         out = {k: _write_prompt(k_pages[k], v_pages[k], cache[k],
@@ -546,7 +564,11 @@ def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
 
 def _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
                   length=None, ring=False):
-    k_seq, v_seq = cache["k"][:, 0], cache["v"][:, 0]  # (L, S_pad, nh, hd)
+    if v_pages is None:
+        # a latent row is kept in whole lane tiles: zeros behind it
+        k_seq = _to_lanes(cache["rows"][:, 0, :, None], k_pages.shape[-1])
+    else:
+        k_seq, v_seq = cache["k"][:, 0], cache["v"][:, 0]  # (L, S_pad, nh, hd)
     s_pad = k_seq.shape[1]
     pos = jnp.arange(s_pad)
     logical = pos - pad
@@ -563,6 +585,8 @@ def _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
     dest_off = jnp.where(valid, lclip % page_size, 0)
     layers = jnp.arange(_values(k_pages).shape[0])[:, None]
     idx = (layers, dest_page[None], dest_off[None])
+    if v_pages is None:
+        return _write_rows(k_pages, idx, k_seq), None
     return _write_rows(k_pages, idx, k_seq), _write_rows(v_pages, idx, v_seq)
 
 
@@ -758,6 +782,61 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
     return ctx.astype(out_dtype)
 
 
+def _attend_latent(q, pages, layer, page_table, pos, qmask, out_dtype, row):
+    """Softmax attention of ``q`` (B, C, H, lanes) at global positions
+    ``pos`` (B, C) over layer ``layer`` of a LATENT bank (``row``:
+    ``blocks.LatentRow``), read through ``page_table`` (B, W) as stored:
+    every head's query meets the same rows, so a chunk of them is
+    gathered ONCE and used for both products on the matrix unit, the
+    scores over all of its (stored) lanes, ``(B, C*H, lanes) x (B, K,
+    lanes)``, and the context over its first ``value_lanes``, ``(B, C*H,
+    K) x (B, K, value_lanes)``. No key and no value a head exists at any point.
+    Float32 scores and accumulation, the probabilities rounded to the
+    bank's dtype, the walk as far as the furthest live query and the
+    mask as :func:`_attend_rows` has them. Returns (B, C, H *
+    value_lanes) in ``out_dtype``, pad queries zero."""
+    b, c, nh, _ = q.shape
+    ps, lanes = page_size_of(pages), pages.shape[-1]
+    n, vl = c * nh, row.value_lanes
+    width = page_table.shape[1]
+    n_pages, n_chunks = walk_plan(ps, width)
+    chunk_keys = n_pages * ps
+    table = jnp.pad(page_table, ((0, 0), (0, n_pages * n_chunks - width)),
+                    constant_values=NULL_PAGE)
+    # the bank keeps a row in whole lane tiles, zeros behind it: zeros
+    # behind the query too, which add nothing to a score
+    q2 = _to_lanes(q.reshape(b, n, -1), lanes).astype(pages.dtype)
+    q_pos = jnp.repeat(pos, nh, axis=1)[:, :, None]          # (B, C*H, 1)
+    live = pos if qmask is None else jnp.where(qmask, pos, 0)
+    trips = jnp.minimum(walked_chunks(jnp.max(live), chunk_keys), n_chunks)
+
+    def chunk(i, carry):
+        m, denom, acc = carry
+        ids = lax.dynamic_slice_in_dim(table, i * n_pages, n_pages, axis=1)
+        rows = pages[layer, ids].reshape(b, chunk_keys, lanes)
+        keep = i * chunk_keys + jnp.arange(chunk_keys) <= q_pos
+        s = jnp.einsum("bnr,bkr->bnk", q2, rows,
+                       preferred_element_type=jnp.float32) * row.scale
+        s = s + jnp.where(keep, 0.0, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        denom = denom * alpha + p.sum(-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bnk,bkr->bnr", p.astype(pages.dtype), rows[..., :vl],
+            preferred_element_type=jnp.float32)
+        return m_new, denom, acc
+
+    _, denom, acc = lax.fori_loop(0, trips, chunk, (
+        jnp.full((b, n), NEG_INF, jnp.float32),
+        jnp.zeros((b, n), jnp.float32),
+        jnp.zeros((b, n, vl), jnp.float32)))
+    ctx = (acc / denom[..., None]).reshape(b, c, nh * vl)
+    if qmask is not None:
+        ctx = ctx * qmask[:, :, None].astype(ctx.dtype)
+    return ctx.astype(out_dtype)
+
+
 def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                    dest_page, dest_off, qmask, config, tp_axis, attn_impl,
                    n_layers=None, live=None, state=None):
@@ -781,9 +860,13 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     (:func:`init_state`; ``{}`` or None for a model without): it rides
     the same carry, and a group's ``mix`` reads and overwrites its rows
     of the layer between ``qkv`` and ``finish`` (one query a row, row
-    ``i`` slot ``i``: a decode step). Returns (hidden, k_pages, v_pages,
-    counters, state): what the blocks' ``finish`` brought out, stacked
-    over the layers that bring any, ``{}`` for a model with none."""
+    ``i`` slot ``i``: a decode step). A layer that attends more than
+    once (``LayerGroup.more``) writes and reads a bank layer an
+    attention, ``l``, ``l + 1``, ..; over a latent row ``v_pages`` is
+    None, the one bank is written once an attention and read by
+    :func:`_attend_latent`. Returns (hidden, k_pages, v_pages, counters,
+    state): what the blocks' ``finish`` brought out, stacked over the
+    layers that bring any, ``{}`` for a model with none."""
     check_attn_impl(attn_impl)
     model = describe(config, tp_axis)
     b, c = tokens.shape
@@ -794,8 +877,10 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                          "one query a row, row i slot i")
     kp, vp = by_kind(k_pages), by_kind(v_pages)
     tables, dest = by_kind(page_table), by_kind(dest_page)
-    if attn_impl == "paged" and model.kinds != (GLOBAL,):
-        raise ValueError("the paged kernel reads one cache kind")
+    if attn_impl == "paged" and (model.kinds != (GLOBAL,)
+                                 or model.latent is not None):
+        raise ValueError("the paged kernel reads one cache kind of keys "
+                         "and values")
 
     x = model.embed(params, tokens)
     seen = dict.fromkeys(model.kinds, 0)      # layers of a kind so far
@@ -806,39 +891,58 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     brought = []
 
     for grp in model.groups:
-        take = min(grp.n, left)
+        a = grp.attends                       # bank layers a layer fills
+        take = min(grp.n, left // a)
         if take <= 0:
             break
-        left -= take
+        left -= take * a
         kind, base = grp.kind, seen[grp.kind]
-        seen[kind] += grp.n
+        seen[kind] += grp.n * a
         window = model.window if kind == WINDOW else None
         slopes = grp.slopes() if grp.slopes is not None else None
         blocks = grp.params(params)
         num_pages = _values(kp[kind]).shape[1]
+        halves = ((grp.qkv, grp.finish),) + grp.more
 
         def layer(l, h, kpk, vpk, st, blk):
-            q, k, v, saved = grp.qkv(blk, h, pos)
-            kpk = _write_rows(kpk, (l, dest[kind], dest_off), k)
-            vpk = _write_rows(vpk, (l, dest[kind], dest_off), v)
-            if attn_impl == "paged":
-                # the kernel takes one bank of pages: every layer's, the
-                # layer folded into the page id
-                ctx = paged_attention(q, _one_bank(kpk), _one_bank(vpk),
-                                      tables[kind] + l * num_pages,
-                                      pos[:, 0], slopes=slopes)
-                if qmask is not None:
-                    ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
-                ctx = ctx.astype(h.dtype).reshape(b, c, -1)
-            else:
-                ctx = _attend_rows(q, kpk, vpk, l, tables[kind], pos, qmask,
-                                   slopes, h.dtype, window)
-            if grp.mix is not None:
-                saved, st = grp.mix(blk, saved, st, l, live[:, 0])
-            h, out = grp.finish(blk, h, ctx, saved, live)
+            """Layer whose first attention is bank layer ``l``."""
+            for j, (qkv, finish) in enumerate(halves):
+                lj = l + j if j else l        # (no ``+ 0`` in a program)
+                q, k, v, saved = qkv(blk, h, pos)
+                if model.latent is not None:
+                    # the row in the bank's whole lane tiles
+                    k = _to_lanes(k, kpk.shape[-1])
+                kpk = _write_rows(kpk, (lj, dest[kind], dest_off), k)
+                if vpk is not None:
+                    vpk = _write_rows(vpk, (lj, dest[kind], dest_off), v)
+                # between a layer's attentions ``h`` may hold more than
+                # the hidden state, which comes first
+                dtype = jax.tree_util.tree_leaves(h)[0].dtype
+                if model.latent is not None:
+                    ctx = _attend_latent(q, kpk, lj, tables[kind], pos,
+                                         qmask, dtype, model.latent)
+                elif attn_impl == "paged":
+                    # the kernel takes one bank of pages: every layer's,
+                    # the layer folded into the page id
+                    ctx = paged_attention(q, _one_bank(kpk), _one_bank(vpk),
+                                          tables[kind] + lj * num_pages,
+                                          pos[:, 0], slopes=slopes)
+                    if qmask is not None:
+                        ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
+                    ctx = ctx.astype(dtype).reshape(b, c, -1)
+                else:
+                    ctx = _attend_rows(q, kpk, vpk, lj, tables[kind], pos,
+                                       qmask, slopes, dtype, window)
+                if grp.mix is not None:
+                    saved, st = grp.mix(blk, saved, st, l, live[:, 0])
+                h, out = finish(blk, h, ctx, saved, live)
             return h, kpk, vpk, st, out
 
         if grp.stacked:
+            if a != 1:
+                raise ValueError("a layer that attends more than once is "
+                                 "traced in line: its group is not stacked")
+
             def body(l, carry):
                 h, kpk, vpk, st = carry
                 # l counts the kind's layers, the stack the group's own
@@ -857,8 +961,13 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
             if out is not None:
                 brought.append(out)
     x = model.final(params, x)
-    counters = ({model.counters: jnp.stack(brought)}
-                if brought and model.counters else {})
+    counters = {}
+    if brought and model.counters:
+        # a layer brings an array, under the model's one name, or a dict
+        # of named ones: stacked over the layers either way
+        counters = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *brought)
+        if not isinstance(counters, dict):
+            counters = {model.counters: counters}
     return x, _like(k_pages, kp), _like(v_pages, vp), counters, state
 
 
@@ -907,6 +1016,9 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     ``"paged"`` walks it a page a grid step in one fused Pallas pass
     (ops/paged_attention.py) — same mask/bias semantics, int8 pages
     dequantized in-register.
+
+    Over a latent row (``PagedModel.latent``) ``k_pages`` is the one
+    bank and ``v_pages`` None, there as in the result.
 
     ``state``: the state bank of a model whose layers keep one
     (:func:`init_state`), row ``i`` slot ``i`` of the batch: a row with

@@ -645,3 +645,77 @@ def test_state_bank_is_updated_in_place(one_chip, program):
         assert trip in text
         assert re.search(r"f32\[%d,%d,%d,%d,%d\]\S* dynamic-update-slice\("
                          % bank["ssm"].shape, text)
+
+
+# The latent bank (PERF.md, PR 45): a model with latent attention keeps
+# ONE row a token an attention (LongCat-Flash: 512 + 64 lanes, stored in
+# five lane tiles) which all of its query heads read in the absorbed
+# form; there is no bank of values. Like the pool's layout, that holds in
+# the compiled program or not at all: at 576 stored lanes (4.5 tiles) the
+# compiler put the pages in the lanes again and copied the pool around
+# every step.
+
+# 8 heads at the published head widths; 80 table entries, five chunks of
+# 256 keys; 320 pages: a bank layer's plane has no weight's element
+# count, nor (at 3 slots) have a chunk's keys or values a head
+LATENT_L, LATENT_HEADS, LATENT_CONTEXT, LATENT_SLOTS = 2, 8, 1280, 3
+
+
+def test_latent_step_reads_one_bank_and_forms_no_keys_or_values(one_chip):
+    """The compiled decode step of a latent model aliases its ONE bank
+    (and its carry), holds no temporary of a bank layer's plane, copies
+    or re-lays out none, walks each attention's chunks in a loop of its
+    own, gathers a chunk's rows at their stored width, and has no array
+    shaped as keys (.., heads, 192) or values (.., heads, 128) a head at
+    a chunk's size."""
+    from pipegoose_tpu.models import longcat_flash
+
+    nh = LATENT_HEADS
+    cfg = longcat_flash.LongcatFlashConfig(
+        vocab_size=512, hidden_size=256, ffn_hidden_size=512,
+        expert_ffn_hidden_size=128, num_layers=LATENT_L,
+        num_attention_heads=nh, kv_lora_rank=512, q_lora_rank=256,
+        qk_rope_head_dim=64, qk_nope_head_dim=128, v_head_dim=128,
+        n_routed_experts=8, zero_expert_num=4, moe_topk=3,
+        experts_held=(0, 2), dtype=jnp.bfloat16)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(sds, jax.eval_shape(
+        lambda k: longcat_flash.init_params(cfg, k), jax.random.PRNGKey(0)))
+    eng = ServingEngine(params, cfg, num_slots=LATENT_SLOTS,
+                        num_pages=POOL_PAGES, page_size=PS,
+                        max_context=LATENT_CONTEXT)
+    assert eng.v_pages is None and eng.model.latent.lanes == 576
+    bank = sds(eng.k_pages)
+    # two attentions a block: a bank layer each, a row in five lane tiles
+    assert bank.shape == (2 * LATENT_L, POOL_PAGES, PS, 640)
+    carry = jax.ShapeDtypeStruct((eng._carry_size,), jnp.int32,
+                                 sharding=one_chip)
+    compiled = eng._step.lower(params, carry, bank).compile()
+    bank_bytes = bank.size * bank.dtype.itemsize
+    ma = compiled.memory_analysis()
+    carried = -(-4 * eng._carry_size // 512) * 512
+    assert ma.alias_size_in_bytes == bank_bytes + carried
+    plane = bank.size // (2 * LATENT_L)          # elements of a bank layer
+    assert ma.temp_size_in_bytes < plane * 2, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    chunk = LATENT_SLOTS * kv_pool.WALK_KEYS
+    moved, heads = [], []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]\S* ([\w\-]+)\(", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        elements = math.prod(dims)
+        if elements % plane == 0 and m.group(3) in ("copy", "transpose"):
+            moved.append(m.group(0))
+        # keys or values a head over a chunk's positions
+        if elements in (chunk * nh * 192, chunk * nh * 128) and (
+                dims[-2:] in ([nh, 192], [nh, 128])
+                or dims[-1] in (192, 128)):
+            heads.append(m.group(0))
+    assert not moved, moved
+    assert not heads, heads[:3]
+    # a chunk's rows are gathered at the width they are stored in ...
+    assert re.search(r"bf16\[(\d+,)*640\]\S* (fusion|gather)\(", text)
+    # ... in a walk of its own an attention (the blocks are traced in line)
+    assert text.count(" while(") == 2 * LATENT_L
