@@ -11,11 +11,19 @@ Kernel structure (canonical TPU flash attention):
   dimension is sequential ("arbitrary") so the (m, l, acc) scratch
   carries across kv steps for a fixed (bh, q) program. Also emits the
   per-row logsumexp for the backward.
-- backward: two kernels recomputing the probabilities from the saved
-  logsumexp (no (S,S) materialization):
+- backward: ONE kernel, ``flash_bwd``, recomputing the probabilities
+  from the saved logsumexp (no (S,S) materialization): grid (bh, nk,
+  nq), both sequential; a step forms its (BQ, BK) tile of P and dS once
+  and feeds dV += P^T dO and dK += dS^T Q (a key block's float32
+  scratch, q innermost) and dQ[q block] += dS K (a float32 accumulator
+  that holds the whole sequence of one (batch, head) and leaves VMEM
+  once a head); five matmuls a tile. Where that accumulator does not
+  fit the VMEM budget (``_working_set_bytes("bwd", ..)``: sequences of
+  ~64k and more at width 128) the same sums run as two kernels, each
+  forming the tile for itself, seven matmuls:
   dq:  grid (bh, nq, nk), kv sequential, accumulates dS @ K;
   dkv: grid (bh, nk, nq), q sequential, accumulates dS^T @ Q and P^T @ dO;
-  with delta = rowsum(dO * O) computed in plain XLA.
+  delta = rowsum(dO * O) is computed in plain XLA either way.
 - blocks: ``_pick_blocks(seq, head width, itemsize, kind, vmem limit)``
   gives each kernel the largest (block_q, block_k) whose working set
   fits three quarters of the scoped VMEM it asks for, half the device's
@@ -101,11 +109,20 @@ _STEP_TILES = {
     "dq": (3, 2, 1, 0, 4, 1),
     # q dO | k v dK dV | - | dK dV | p dp ds select | p ds, each transposed
     "dkv": (2, 4, 0, 2, 4, 4),
+    # dK/dV's, and the (BQ, hd) product that goes into dQ's accumulator
+    "bwd": (2, 4, 1, 2, 4, 4),
+}
+# What a kernel holds of the WHOLE sequence of one (batch, head),
+# whatever its blocks: float32 (seq, hd) scratch, and pipelined (seq,
+# hd) results in the operands' dtype.
+_SEQ_TILES = {
+    # dQ's accumulator | dQ
+    "bwd": (1, 1),
 }
 
 
 def _vmem_limit_bytes() -> int:
-    """Scoped VMEM the three non-ring kernels ask of the compiler: half
+    """Scoped VMEM the non-ring kernels ask of the compiler: half
     of what the device's core has (64 MiB of a v5e's 128), and the
     compiler's default where that is no more or the device is not a TPU
     Pallas knows (interpret mode, a lowering with no device)."""
@@ -119,11 +136,13 @@ def _vmem_limit_bytes() -> int:
 
 
 def _working_set_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
-                       itemsize: int) -> int:
-    """VMEM one grid step of kernel ``kind`` ("fwd", "dq", "dkv") holds,
-    by its own arithmetic: ``_STEP_TILES``' pipelined tiles twice (double
-    buffering), the scratch, the score tiles, and the per-query and
-    per-key float32 rows (two each side, pipelined). An upper bound: a
+                       itemsize: int, seq: int = 0) -> int:
+    """VMEM one grid step of kernel ``kind`` ("fwd", "dq", "dkv", "bwd")
+    holds, by its own arithmetic: ``_STEP_TILES``' pipelined tiles twice
+    (double buffering), the scratch, the score tiles, the per-query and
+    per-key float32 rows (two each side, pipelined), and what
+    ``_SEQ_TILES`` has the kind keep of a whole sequence of ``seq``
+    positions, which no choice of blocks makes smaller. An upper bound: a
     v5e's compiler took each kernel with 1.1-1.5x less at head widths of
     256 and more, where a budget binds, and with 2-4x less at 64
     (PERF.md, PR 30); tests/ops/test_chip_compile.py holds it to that."""
@@ -132,7 +151,19 @@ def _working_set_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
     tiles = (2 * (q_io * block_q + k_io * block_k) * itemsize
              + (q_f32 * block_q + k_f32 * block_k) * 4) * lanes
     rows = 2 * 2 * 8 * (block_q + block_k) * 4    # (1, n): 8 sublanes
-    return tiles + rows + block_q * block_k * (4 * s_f32 + itemsize * s_narrow)
+    seq_f32, seq_io = _SEQ_TILES.get(kind, (0, 0))
+    whole = seq * lanes * (4 * seq_f32 + 2 * itemsize * seq_io)
+    return (tiles + rows + whole
+            + block_q * block_k * (4 * s_f32 + itemsize * s_narrow))
+
+
+def _fits(kind: str, block_q: int, block_k: int, head_dim: int,
+          itemsize: int, seq: int, vmem_limit_bytes: int) -> bool:
+    """Whether kernel ``kind``'s working set fits three quarters of the
+    limit (the rest is the compiler's: its temporaries, semaphores,
+    alignment)."""
+    return (_working_set_bytes(kind, block_q, block_k, head_dim, itemsize, seq)
+            <= vmem_limit_bytes * 3 // 4)
 
 
 def _pick_blocks(seq: int, head_dim: int, itemsize: int, kind: str,
@@ -140,10 +171,8 @@ def _pick_blocks(seq: int, head_dim: int, itemsize: int, kind: str,
     """``(block_q, block_k)`` for kernel ``kind`` from the operands'
     shape and the scoped VMEM the kernel will ask for: the largest
     power-of-two blocks (at most ``_MAX_BLOCK`` a side) that divide
-    ``seq`` and whose working set, by ``_working_set_bytes``, fits three
-    quarters of the limit (the rest is the compiler's: its temporaries,
-    semaphores, alignment); tiny and odd ``seq`` fall back as
-    ``_pick_block`` does.
+    ``seq`` and whose working set, by ``_working_set_bytes``, fits
+    (``_fits``); tiny and odd ``seq`` fall back as ``_pick_block`` does.
 
     A grid step costs ~0.35 us whatever it computes, and what bounds a
     step's body is per-score work outside the matrix unit, so the step
@@ -153,8 +182,8 @@ def _pick_blocks(seq: int, head_dim: int, itemsize: int, kind: str,
     halved, the query side on a tie; which side is better kept has not
     been measured (no trained shape binds a v5e's budget)."""
     block_q = block_k = _pick_block(seq, _MAX_BLOCK)
-    while (_working_set_bytes(kind, block_q, block_k, head_dim, itemsize)
-           > vmem_limit_bytes * 3 // 4):
+    while not _fits(kind, block_q, block_k, head_dim, itemsize, seq,
+                    vmem_limit_bytes):
         if block_q >= block_k and block_q % 16 == 0:
             block_q //= 2
         elif block_k % 16 == 0:
@@ -221,6 +250,39 @@ def _q_block(j, i, block_q, block_k, causal, window, nq):
         last = (j * block_k + block_k + window - 2) // block_q
         i = jnp.minimum(i, jnp.minimum(last, nq - 1))
     return i
+
+
+def _q_inner_in_specs(bh, hd, g, block_q, block_k, causal, window, nq):
+    """Operand specs of a (bh, nk, nq) grid, the query block innermost
+    (``flash_dkv``, ``flash_bwd``): slopes in SMEM, q, k, v, dO, the lse
+    and delta rows, the per-key rows. Query block clamped by
+    ``_q_block``, kv head ``b // g`` (GQA)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def q_map(b, j, i):
+        return (b, _q_block(j, i, block_q, block_k, causal, window, nq), 0)
+
+    def q_row_map(b, j, i):
+        return (b, 0, _q_block(j, i, block_q, block_k, causal, window, nq))
+
+    def kv_map(b, j, i):
+        return (b // g, j, 0)
+
+    def kv_row_map(b, j, i):
+        return (b // g, 0, j)
+
+    return [
+        pl.BlockSpec((bh,), lambda b, j, i: (0,), memory_space=pltpu.SMEM),
+        pl.BlockSpec((1, block_q, hd), q_map),
+        pl.BlockSpec((1, block_k, hd), kv_map),
+        pl.BlockSpec((1, block_k, hd), kv_map),
+        pl.BlockSpec((1, block_q, hd), q_map),
+        pl.BlockSpec((1, 1, block_q), q_row_map),
+        pl.BlockSpec((1, 1, block_q), q_row_map),
+        pl.BlockSpec((1, 1, block_k), kv_row_map),
+        pl.BlockSpec((1, 1, block_k), kv_row_map),
+    ]
 
 
 def _scores(q, k, slope, kpos_ref, kneg_ref, scale, q_start, k_start,
@@ -462,29 +524,14 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
             dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
 
-    def q_map(b, j, i):
-        return (b, _q_block(j, i, block_q, block_k, causal, window, nq), 0)
-
-    def q_row_map(b, j, i):
-        return (b, 0, _q_block(j, i, block_q, block_k, causal, window, nq))
-
     grid = (bh, nk, nq)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((bh,), lambda b, j, i: (0,), memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, block_q, hd), q_map),
-                pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // g, j, 0)),
-                pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b // g, j, 0)),
-                pl.BlockSpec((1, block_q, hd), q_map),
-                pl.BlockSpec((1, 1, block_q), q_row_map),
-                pl.BlockSpec((1, 1, block_q), q_row_map),
-                pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // g, 0, j)),
-                pl.BlockSpec((1, 1, block_k), lambda b, j, i: (b // g, 0, j)),
-            ],
+            in_specs=_q_inner_in_specs(bh, hd, g, block_q, block_k, causal,
+                                       window, nq),
             out_specs=[
                 pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
                 pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
@@ -504,6 +551,109 @@ def _flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
         ),
         interpret=interpret,
         name="flash_dkv",
+    )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
+      kpos[:, None, :], kneg[:, None, :])
+
+
+def _flash_bwd_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
+                      scale, causal, block_q, block_k, interpret, g=1, window=None):
+    """dQ, dK and dV from ONE pass over the score tiles: ``flash_dkv``'s
+    grid and index maps, and ``flash_dq``'s sum beside its two. Every
+    sum runs in the order the two kernels give it (dQ over key blocks
+    ascending, dK/dV over query blocks ascending)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, s, hd = q.shape
+    # dK/dV are PER QUERY HEAD, as ``_flash_dkv_pallas`` returns them
+    nq, nk = s // block_q, s // block_k
+
+    def kernel(slope_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               kpos_ref, kneg_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc):
+        kj = pl.program_id(1)
+        qi = pl.program_id(2)
+        slope = slope_ref[pl.program_id(0)]
+
+        @pl.when((kj == 0) & (qi == 0))
+        def _init_head():
+            dq_sc[:] = jnp.zeros_like(dq_sc)
+
+        @pl.when(qi == 0)
+        def _init():
+            dk_sc[:] = jnp.zeros_like(dk_sc)
+            dv_sc[:] = jnp.zeros_like(dv_sc)
+
+        q_start = qi * block_q
+        k_start = kj * block_k
+
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
+        def _compute():
+            qb = q_ref[0]
+            kb = k_ref[0]
+            dob = do_ref[0]
+            s_blk = _scores(qb, kb, slope, kpos_ref, kneg_ref,
+                            scale, q_start, k_start, causal, window)
+            p = jnp.exp(s_blk - lse_ref[0, 0][:, None])  # (BQ, BK)
+            dv_sc[:] += jax.lax.dot_general(
+                p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # P^T @ dO -> (BK, hd)
+            dp = jax.lax.dot_general(
+                dob, v_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            ds = (p * (dp - delta_ref[0, 0][:, None])).astype(qb.dtype)
+            dk_sc[:] += scale * jax.lax.dot_general(
+                ds, qb, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # dS^T @ Q -> (BK, hd)
+            rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+            dq_sc[rows, :] += scale * jax.lax.dot_general(
+                ds, kb, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # dS @ K -> this query block's rows of (seq, hd)
+
+        @pl.when(qi == nq - 1)
+        def _finish():
+            dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _finish_head():
+            dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+
+    grid = (bh, nk, nq)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=grid,
+            in_specs=_q_inner_in_specs(bh, hd, g, block_q, block_k, causal,
+                                       window, nq),
+            out_specs=[
+                # the whole sequence of a head: it leaves VMEM once a head
+                pl.BlockSpec((1, s, hd), lambda b, j, i: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
+                pl.BlockSpec((1, block_k, hd), lambda b, j, i: (b, j, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((s, hd), jnp.float32),
+                pltpu.VMEM((block_k, hd), jnp.float32),
+                pltpu.VMEM((block_k, hd), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((bh, s, hd), k.dtype),
+            jax.ShapeDtypeStruct((bh, s, hd), v.dtype),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
+        ),
+        interpret=interpret,
+        name="flash_bwd",
     )(slopes, q, k, v, do, lse[:, None, :], delta[:, None, :],
       kpos[:, None, :], kneg[:, None, :])
 
@@ -913,20 +1063,23 @@ def _flash_bwd(scale, causal, interpret, g, window, res, ct):
     q, k, v, slopes, kpos, kneg, out, lse = res
     interpret = _resolve_interpret(interpret)
     delta = (ct.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)  # (bh, s)
-    dq = _flash_dq_pallas(
-        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal,
-        *_blocks(q, "dq"), interpret, g, window,
-    )
-    dk, dv = _flash_dkv_pallas(
-        q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal,
-        *_blocks(q, "dkv"), interpret, g, window,
-    )
+    operands = (q, k, v, ct, lse, delta, slopes, kpos, kneg, scale, causal)
+    seq, hd = q.shape[1], q.shape[2]
+    blocks = _blocks(q, "bwd")
+    if _fits("bwd", *blocks, hd, q.dtype.itemsize, seq, _vmem_limit_bytes()):
+        dq, dk, dv = _flash_bwd_pallas(*operands, *blocks, interpret, g, window)
+    else:
+        # the whole-sequence accumulator of dQ does not fit beside the
+        # smallest blocks: the same sums, the score tiles formed twice
+        dq = _flash_dq_pallas(*operands, *_blocks(q, "dq"), interpret, g,
+                              window)
+        dk, dv = _flash_dkv_pallas(*operands, *_blocks(q, "dkv"), interpret,
+                                   g, window)
     if g > 1:
         # per-query-head contributions -> shared kv heads (rows ordered
         # so g consecutive query heads share one kv row)
-        s, hd = k.shape[1], k.shape[2]
-        dk = dk.reshape(-1, g, s, hd).sum(1).astype(k.dtype)
-        dv = dv.reshape(-1, g, s, hd).sum(1).astype(v.dtype)
+        dk = dk.reshape(-1, g, seq, hd).sum(1).astype(k.dtype)
+        dv = dv.reshape(-1, g, seq, hd).sum(1).astype(v.dtype)
     return dq, dk, dv, jnp.zeros_like(slopes), jnp.zeros_like(kpos), jnp.zeros_like(kneg)
 
 

@@ -271,15 +271,19 @@ def test_gqa_grouped_kv_matches_repeated():
         )
 
 
-# -- the three kernels at explicit blocks, every kind of block ---------------
+# -- the kernels at explicit blocks, every kind of block ---------------------
 #
 # One program of each case meets a skipped block (whose index map is
 # clamped to a kept one), a block wholly inside the rule and a block the
 # diagonal or the window's edge crosses, with block_q != block_k both
 # ways; the last cases take the blocks ``_pick_blocks`` gives a v5e for
-# their shape.
+# their shape. The backward runs both ways: ``flash_bwd`` (one pass over
+# the score tiles) and the ``flash_dq`` + ``flash_dkv`` pair it falls
+# back to, which sum in the same order: the same bits at the same blocks.
 
 from pipegoose_tpu.ops import flash_attention as fa  # noqa: E402
+
+KINDS = ("fwd", "dq", "dkv", "bwd")
 
 # what ``_vmem_limit_bytes`` gives on a v5e (half of 128 MiB), and here,
 # where no TPU is attached (the compiler's default)
@@ -327,6 +331,9 @@ KERNEL_CASES = {
     "bf16_128x64_hd256": (512, 256, 1, True, None, True, jnp.bfloat16, (128, 64)),
     "picked_blocks_hd64": (2048, 64, 1, True, None, False, jnp.float32, None),
     "picked_blocks_hd128_window": (2048, 128, 1, True, 700, True, jnp.float32, None),
+    "bf16_256x128_hd128_padded": (512, 128, 1, True, None, True, jnp.bfloat16, (256, 128)),
+    "gqa2_noncausal_64x128_hd128": (256, 128, 2, False, None, True, jnp.float32, (64, 128)),
+    "one_block_hd256": (128, 256, 1, True, None, False, jnp.float32, (128, 128)),
 }
 
 
@@ -349,10 +356,9 @@ def test_kernels_at_explicit_blocks_match_the_dense_reference(case):
     scale = hd ** -0.5
     rule = (scale, causal)
 
-    kinds = ("fwd", "dq", "dkv")
     bq_bk = {kind: blocks or fa._pick_blocks(s, hd, q.dtype.itemsize, kind,
                                              V5E_LIMIT)
-             for kind in kinds}
+             for kind in KINDS}
     out, lse = fa._flash_fwd_pallas(q, k, v, slopes, kpos, kneg, *rule,
                                     *bq_bk["fwd"], True, g, window)
     delta = (do.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
@@ -360,8 +366,16 @@ def test_kernels_at_explicit_blocks_match_the_dense_reference(case):
                              *rule, *bq_bk["dq"], True, g, window)
     dk, dv = fa._flash_dkv_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
                                   *rule, *bq_bk["dkv"], True, g, window)
-    dk, dv = (x.astype(jnp.float32).reshape(nkv, g, s, hd).sum(1)
-              for x in (dk, dv))
+    one = fa._flash_bwd_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
+                               *rule, *bq_bk["bwd"], True, g, window)
+    if bq_bk["bwd"] == bq_bk["dq"] == bq_bk["dkv"]:
+        # one order of every sum: not close, the same
+        for a, b, name in zip(one, (dq, dk, dv), ("dq", "dk", "dv")):
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                err_msg=f"{case}: flash_bwd's {name} against the pair's")
+    dk, dv, dk1, dv1 = (x.astype(jnp.float32).reshape(nkv, g, s, hd).sum(1)
+                        for x in (dk, dv, one[1], one[2]))
 
     ref, vjp = jax.vjp(
         lambda q, k, v: _dense(q, k, v, slopes, scale, causal, window,
@@ -385,12 +399,12 @@ def test_kernels_at_explicit_blocks_match_the_dense_reference(case):
     close(dq, rq, grad_tol, "dq")
     close(dk, rk, grad_tol, "dk")
     close(dv, rv, grad_tol, "dv")
+    close(one[0], rq, grad_tol, "flash_bwd dq")
+    close(dk1, rk, grad_tol, "flash_bwd dk")
+    close(dv1, rv, grad_tol, "flash_bwd dv")
 
 
 # -- the block function alone ------------------------------------------------
-
-KINDS = ("fwd", "dq", "dkv")
-
 
 def test_vmem_limit_is_the_compilers_default_where_no_tpu_is_attached():
     assert fa._vmem_limit_bytes() == DEFAULT_LIMIT
@@ -406,15 +420,16 @@ def test_pick_blocks_divide_the_sequence_inside_the_vmem_budget(
             bq, bk = fa._pick_blocks(seq, width, itemsize, kind, limit)
             assert seq % bq == 0 and seq % bk == 0, (seq, bq, bk)
             assert bq <= 1024 and bk <= 1024
-            used = fa._working_set_bytes(kind, bq, bk, width, itemsize)
+            used = fa._working_set_bytes(kind, bq, bk, width, itemsize, seq)
             if min(bq, bk) > 8:     # (8, 8) is the floor, fit or not
                 assert used <= limit * 3 // 4, (seq, itemsize, bq, bk, used)
             # the largest that fits: the side halved last, twice as
             # large again, would pass the budget
             wider = (2 * bq, bk) if bq < bk else (bq, 2 * bk)
             if max(wider) <= 1024 and seq % max(wider) == 0:
-                assert fa._working_set_bytes(kind, *wider, width, itemsize) \
-                    > limit * 3 // 4, (seq, itemsize, bq, bk)
+                assert fa._working_set_bytes(kind, *wider, width, itemsize,
+                                             seq) > limit * 3 // 4, \
+                    (seq, itemsize, bq, bk)
 
 
 @pytest.mark.parametrize("seq,blocks", [(64, (64, 64)), (96, (32, 32)),
@@ -436,7 +451,111 @@ def test_pick_blocks_at_the_cells_shapes(seq, width):
     for kind in KINDS:
         assert fa._pick_blocks(seq, width, 2, kind, V5E_LIMIT) == (1024, 1024)
         bq, bk = fa._pick_blocks(seq, width, 2, kind, DEFAULT_LIMIT)
-        assert bq >= 256 and bq * bk > 128 * 512, (kind, bq, bk)
+        # more scores a step than the 128 x 512 the kernels had; as many
+        # for ``flash_bwd`` at width 256 x 4,096 positions, where dQ's
+        # accumulator and result take 8 of the default limit's 12 MiB,
+        # whatever the blocks, and leave 256 x 256
+        least = 128 * 512 + (kind != "bwd")
+        assert bq >= 256 and bq * bk >= least, (kind, bq, bk)
+    # on a v5e the one-kernel backward runs at all three (``_flash_bwd``)
+    assert fa._working_set_bytes("bwd", 1024, 1024, width, 2, seq) \
+        <= V5E_LIMIT * 3 // 4
+
+
+# -- which backward runs: a fact of the shape and the device's VMEM ---------
+
+# name: (seq, width, dtype, limit, the kernels of the backward)
+BACKWARD_PATHS = {
+    # the three train cells on a v5e
+    "cell_560m": (2048, 64, jnp.bfloat16, V5E_LIMIT, ["flash_bwd"]),
+    "cell_1b7": (2048, 128, jnp.bfloat16, V5E_LIMIT, ["flash_bwd"]),
+    "cell_glm": (4096, 256, jnp.bfloat16, V5E_LIMIT, ["flash_bwd"]),
+    "f32_w512": (2048, 512, jnp.float32, V5E_LIMIT, ["flash_bwd"]),
+    "32k_w128": (32768, 128, jnp.bfloat16, V5E_LIMIT, ["flash_bwd"]),
+    # dQ's float32 accumulator and its result: 8 bytes a lane a position
+    # at bf16, 64 MiB at 65,536 x 128 against a budget of 48
+    "64k_w128": (65536, 128, jnp.bfloat16, V5E_LIMIT,
+                 ["flash_dkv", "flash_dq"]),
+    "32k_w256": (32768, 256, jnp.bfloat16, V5E_LIMIT,
+                 ["flash_dkv", "flash_dq"]),
+    # where no TPU is attached (interpret mode) the budget is 12 MiB
+    "cell_glm_default": (4096, 256, jnp.bfloat16, DEFAULT_LIMIT,
+                         ["flash_bwd"]),
+    "16k_w128_default": (16384, 128, jnp.bfloat16, DEFAULT_LIMIT,
+                         ["flash_dkv", "flash_dq"]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BACKWARD_PATHS))
+def test_the_backward_is_one_kernel_where_its_accumulator_fits(
+        monkeypatch, case):
+    """``_flash_bwd`` takes ``flash_bwd`` where ``_working_set_bytes``
+    says dQ's whole-sequence accumulator fits beside the blocks, and the
+    pair where it does not: read off the gradient's jaxpr by kernel
+    name, nothing runs."""
+    seq, width, dtype, limit, want = BACKWARD_PATHS[case]
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: limit)
+    x = jax.ShapeDtypeStruct((1, seq, 2, width), dtype)
+    grad = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, None, interpret=True)
+        .astype(jnp.float32).sum(), argnums=(0, 1, 2)))(x, x, x)
+    ran = sorted(name for name in ("flash_bwd", "flash_dq", "flash_dkv")
+                 if kernel_calls(grad, name))
+    assert ran == want and kernel_calls(grad, "flash_fwd") == 1
+    assert all(kernel_calls(grad, name) == 1 for name in ran)
+
+
+@pytest.mark.parametrize("g,window", [(1, None), (2, 48)])
+def test_a_shape_that_falls_back_to_the_pair_has_the_same_gradients(
+        monkeypatch, g, window):
+    """A VMEM limit too small for dQ's accumulator (192 positions x 128
+    lanes x 12 bytes = 288 KiB against three quarters of 256): the pair
+    runs, at smaller blocks, and its gradients are the one kernel's to
+    float32 rounding and the dense reference's."""
+    b, s, nkv, hd = 1, 192, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(46), 4)
+    q, ct = (jax.random.normal(kk, (b, s, nkv * g, hd)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, s, nkv, hd)) for kk in ks[2:])
+    slopes = jnp.asarray(alibi_slopes(nkv * g))
+    mask = jnp.asarray(np.arange(s) < s - 21, jnp.int32)[None]
+    ct = ct * mask[:, :, None, None]
+
+    def grads():
+        fn = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, slopes, attention_mask=mask, window=window,
+            interpret=True)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(fn, q, k, v)[1](ct))(
+            q, k, v)
+        ran = {name: kernel_calls(jaxpr, name)
+               for name in ("flash_bwd", "flash_dq", "flash_dkv")}
+        return jax.vjp(fn, q, k, v)[1](ct), ran
+
+    one, ran = grads()
+    assert ran == {"flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: 256 * 2**10)
+    pair, ran = grads()
+    assert ran == {"flash_bwd": 0, "flash_dq": 1, "flash_dkv": 1}
+    for a, b_, name in zip(one, pair, ("dq", "dk", "dv")):
+        assert np.isfinite(np.asarray(b_)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=1e-4,
+                                   atol=4e-7 * s, err_msg=name)
+    if window is None:
+        kpos, kneg = fa.mask_to_kv_bias(mask)
+        heads = nkv * g
+
+        def flat(x):
+            return x.transpose(0, 2, 1, 3).reshape(-1, s, hd)
+
+        def dense(q, k, v):
+            out = _xla_reference(
+                flat(q), flat(k), flat(v), slopes, hd ** -0.5, True,
+                jnp.repeat(kpos, heads, axis=0), jnp.repeat(kneg, heads, axis=0))
+            return out.reshape(b, heads, s, hd).transpose(0, 2, 1, 3)
+
+        want = jax.vjp(dense, q, k, v)[1](ct)
+        for a, b_, name in zip(pair, want, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                       rtol=1e-4, atol=4e-7 * s, err_msg=name)
 
 
 # -- what a block's checkpoint keeps of the kernel -------------------------
@@ -489,8 +608,8 @@ def test_a_checkpointed_block_keeps_the_kernels_residuals(case):
     assert {n: kernel_calls(j, "flash_fwd") for n, j in jaxprs.items()} == {
         "plain": 2, "bare": 4, "kept": 2}
     for name, j in jaxprs.items():
-        assert (kernel_calls(j, "flash_dq"),
-                kernel_calls(j, "flash_dkv")) == (2, 2), name
+        assert (kernel_calls(j, "flash_bwd"), kernel_calls(j, "flash_dq"),
+                kernel_calls(j, "flash_dkv")) == (2, 0, 0), name
     want = grads["plain"](ws, x)
     for name in ("kept", "bare"):
         got = grads[name](ws, x)

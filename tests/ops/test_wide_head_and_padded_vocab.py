@@ -1,14 +1,17 @@
 """The shared kernels at what GLM-4.7-Flash asks of them, in interpret
-mode: the three flash kernels at head width 256 against the plain XLA
+mode: the flash kernels at head width 256 against the plain XLA
 attention, and the fused cross entropy over a vocabulary that is not a
 multiple of its block (padded rows masked by ``valid_size``), called
 twice on one weight as the main head and the MTP module call it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from pipegoose_tpu.ops import flash_attention as fa
 from pipegoose_tpu.ops.flash_attention import _xla_reference, flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
+from pipegoose_tpu.testing import kernel_calls
 
 
 def _flat(x):
@@ -23,16 +26,31 @@ def _plain(q, k, v, scale):
     return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
 
 
-def test_flash_kernels_at_head_width_256_match_plain_attention():
+@pytest.mark.parametrize("backward", ["flash_bwd", "pair"])
+def test_flash_kernels_at_head_width_256_match_plain_attention(
+        monkeypatch, backward):
+    """Forward and gradients at width 256, the backward both ways: the
+    one kernel, and (a VMEM limit too small for dQ's whole-sequence
+    accumulator: 256 x 256 x 12 bytes against three quarters of 512 KiB)
+    the ``flash_dq`` + ``flash_dkv`` pair it falls back to."""
     b, s, nh, hd = 1, 256, 2, 256
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     q, k, v = (jax.random.normal(kk, (b, s, nh, hd)) for kk in ks[:3])
     ct = jax.random.normal(ks[3], (b, s, nh, hd))
     scale = hd ** -0.5
+    if backward == "pair":
+        monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: 512 * 2**10)
 
     def flash(q, k, v):
         return flash_attention(q, k, v, scale=scale, interpret=True)
 
+    ran = jax.make_jaxpr(lambda q, k, v: jax.vjp(flash, q, k, v)[1](ct))(
+        q, k, v)
+    assert {name: kernel_calls(ran, name)
+            for name in ("flash_bwd", "flash_dq", "flash_dkv")} == (
+        {"flash_bwd": 1, "flash_dq": 0, "flash_dkv": 0}
+        if backward == "flash_bwd" else
+        {"flash_bwd": 0, "flash_dq": 1, "flash_dkv": 1})
     out, vjp = jax.vjp(flash, q, k, v)
     want, want_vjp = jax.vjp(lambda q, k, v: _plain(q, k, v, scale), q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
