@@ -40,11 +40,26 @@ A layer may attend MORE THAN ONCE (``LayerGroup.more``): each attention
 has a row of its own a token, so a layer of ``a`` attentions fills ``a``
 bank layers, attention ``j`` of layer ``l`` bank layer ``a * l + j``.
 
+ONE attention may read TWO caches under one softmax
+(``LayerGroup.summaries``, a :class:`Summaries`): the ring of its
+window's keys and values, and beside it, in ``global`` pages, one POOLED
+key and value a ``chunk`` of positions, made from the ring's own rows
+(the ``pool`` hook) in the step that completes the chunk. Which exact
+keys a query keeps is the model's window rule (``PagedModel.
+window_rule``): ``"sliding"``, the last ``window`` positions, or
+``"block"``, the positions since the last multiple of ``window``; which
+summaries it sees follows from it (:func:`summaries_seen`: the chunks
+that lie wholly behind what the rule keeps exact). A row of such a
+model's ``global`` bank stands for ``chunk`` positions
+(``PagedModel.stride``), and its pages are counted so.
+
 BLOOM is the first instance (:func:`bloom_model`), Laguna the second
 (``models/laguna.py:paged_model``: two kinds), Falcon-H1 the third
 (``models/falcon_h1.py:paged_model``: global pages and a state in every
 block), LongCat-Flash the fourth (``models/longcat_flash.py:paged_model``:
-a latent row, two attentions a block). A config object that has a ``paged_model(tp_axis)`` method
+a latent row, two attentions a block), EvaByte the fifth
+(``models/evabyte.py:paged_model``: a block window's ring and a summary
+a chunk under one softmax). A config object that has a ``paged_model(tp_axis)`` method
 describes itself; any other is taken for a BLOOM (:func:`describe`).
 """
 from __future__ import annotations
@@ -54,6 +69,8 @@ from typing import Any, Callable, Optional, Tuple
 
 GLOBAL, WINDOW = "global", "window"
 KINDS = (GLOBAL, WINDOW)
+SLIDING, BLOCK = "sliding", "block"
+WINDOW_RULES = (SLIDING, BLOCK)
 LANE_TILE = 128               # lanes of the chip's vector registers
 
 
@@ -73,6 +90,18 @@ class LatentRow:
         in the lanes and re-lay out the pool around every program
         (PERF.md, PRs 27 and 45)."""
         return -(-self.lanes // LANE_TILE) * LANE_TILE
+
+
+@dataclass(frozen=True)
+class Summaries:
+    """What a window layer's attention keeps of the positions its window
+    has left: one pooled key and one pooled value a ``chunk`` of them, a
+    row a chunk in the ``global`` bank, read in the same softmax as the
+    ring's exact keys."""
+    chunk: int                # positions a summary stands for
+    # (blk, k (.., chunk, KV, hd), v (.., chunk, KV, hd)) -> (k~, v~)
+    # (.., KV, hd): the summary of one chunk from its own rows alone
+    pool: Callable
 
 
 @dataclass(frozen=True)
@@ -108,6 +137,10 @@ class LayerGroup:
     # (an array under the model's one name, or ``{name: array}``). Such
     # a layer is traced in line: its group is not stacked.
     more: Tuple[Tuple[Callable, Callable], ...] = ()
+    # a ``window`` group whose one attention also reads a summary a
+    # chunk of what left its window (a row a chunk in the ``global``
+    # bank); None: the ring alone
+    summaries: Optional[Summaries] = None
 
     @property
     def attends(self) -> int:
@@ -133,6 +166,9 @@ class PagedModel:
     prefill: Callable
     left_pad: bool = True     # the side a bucketed prompt is padded on
     window: Optional[int] = None          # keys a window layer keeps
+    # which: the last ``window`` positions ("sliding") or those since
+    # the last multiple of ``window`` ("block")
+    window_rule: str = SLIDING
     # the name of what the groups' ``finish`` brings out of a decode
     # step (stacked over the layers that bring any); None: nothing
     counters: Optional[str] = None
@@ -157,24 +193,92 @@ class PagedModel:
             return self.latent.stored
         return self.n_kv_head * self.head_dim
 
+    def __post_init__(self):
+        if self.window_rule not in WINDOW_RULES:
+            raise ValueError(f"window_rule must be one of {WINDOW_RULES}, "
+                             f"got {self.window_rule!r}")
+        for g in self.groups:
+            if g.summaries is not None and (
+                    g.kind != WINDOW or g.more or self.latent is not None):
+                raise ValueError(
+                    "summaries stand beside a window layer's ring of keys "
+                    "and values, one attention a layer")
+        self.stride                       # the groups agree on it
+
+    def _fills(self, g: LayerGroup, kind: str) -> bool:
+        """Whether group ``g`` keeps rows in ``kind``'s bank."""
+        return g.kind == kind or (kind == GLOBAL and g.summaries is not None)
+
     @property
     def kinds(self) -> Tuple[str, ...]:
         return tuple(k for k in KINDS
-                     if any(g.kind == k for g in self.groups))
+                     if any(self._fills(g, k) for g in self.groups))
 
     def layers_of(self, kind: str) -> int:
-        """Layers of a kind's bank: a row a token an ATTENTION."""
-        return sum(g.n * g.attends for g in self.groups if g.kind == kind)
+        """Layers of a kind's bank: a row a token (a summary a chunk) an
+        ATTENTION."""
+        return sum(g.n * g.attends for g in self.groups
+                   if self._fills(g, kind))
+
+    @property
+    def summaries(self) -> Optional[Summaries]:
+        """The summaries its window layers keep (the first such
+        group's: they agree on the chunk); None: none keeps any."""
+        return next((g.summaries for g in self.groups
+                     if g.summaries is not None), None)
+
+    @property
+    def stride(self) -> int:
+        """Positions a row of the ``global`` bank stands for: 1 where it
+        is a position's keys and values, the chunk where it is a
+        summary. One bank, one stride."""
+        strides = {1 if g.summaries is None else g.summaries.chunk
+                   for g in self.groups if self._fills(g, GLOBAL)}
+        if len(strides) > 1:
+            raise ValueError(
+                f"the global bank holds a row every {sorted(strides)} "
+                f"positions: global layers beside summaries of another "
+                f"stride are not built")
+        return strides.pop() if strides else 1
 
     @property
     def n_layer(self) -> int:
         return sum(g.n for g in self.groups)
 
 
-def ring_pages(window: int, page_size: int) -> int:
-    """Pages in a window layer's ring: the window's keys can straddle
-    one page more than they fill."""
-    return -(-window // page_size) + 1
+def window_start(pos, window: int, rule: str = SLIDING):
+    """The first position a query at ``pos`` keeps exact under ``rule``
+    (may be negative under "sliding": every position so far). Pure
+    arithmetic, on a traced value and on the host's int alike."""
+    if rule == BLOCK:
+        return pos // window * window
+    return pos - window + 1
+
+
+def summaries_seen(pos, window: int, chunk: int, rule: str = SLIDING):
+    """Summaries a query at ``pos`` sees: the chunks that lie wholly
+    before :func:`window_start`, which are the first so many rows of the
+    summary bank. Under "block" every chunk of every CLOSED window."""
+    start = window_start(pos, window, rule)
+    return (start > 0) * start // chunk
+
+
+def ring_pages(window: int, page_size: int, rule: str = SLIDING) -> int:
+    """Pages in a window layer's ring: a sliding window's keys can
+    straddle one page more than they fill. A block window is a whole
+    number of pages: it starts on a page and never holds more than its
+    own, so entry ``r`` of its ring is the window's page ``r`` (positions
+    ``r * page_size`` on from the window's start) and nothing else, and
+    a read walks it only as far as the furthest row stands INTO its
+    window."""
+    pages = -(-window // page_size)
+    if rule != BLOCK:
+        return pages + 1
+    if window % page_size:
+        raise ValueError(
+            f"a block window is a whole number of pages: {window} "
+            f"positions are not, at {page_size} a page")
+    return pages
 
 
 def describe(config, tp_axis=None) -> PagedModel:

@@ -63,8 +63,14 @@ prefill's result is put in the slot's row by the program that writes the
 prompt's pages, so a slot's leftover state is never read; every decode
 step reads and overwrites the rows of the live slots in place; a
 preempted request re-prefills prompt plus generated tokens, so no state
-is ever saved. The opt-in modes below are built for a cache that is
-global pages and nothing more, and refuse either kind of model by name.
+is ever saved. A model whose window layers' ONE attention also reads a
+summary a chunk of what left the window (``blocks.Summaries``:
+models/evabyte.py) is a two-kind model whose ``global`` rows stand for a
+chunk of positions each: its global pages are counted a row a chunk
+(``PagePool.stride``), both tables reach every such layer, and the
+decode step writes a summary in the step that completes its chunk. The
+opt-in modes below are built for a cache that is global pages of a row a
+position and nothing more, and refuse each such model by name.
 
 Everything device-side is compiled with STATIC shapes: the decode step
 is one program for the (num_slots, page-table-width) layout regardless
@@ -128,8 +134,15 @@ from pipegoose_tpu.models._decode import (
     greedy_token,
     vocab_mask_for,
 )
-from pipegoose_tpu.serving.blocks import GLOBAL, WINDOW, describe, ring_pages
+from pipegoose_tpu.serving.blocks import (
+    GLOBAL,
+    WINDOW,
+    describe,
+    ring_pages,
+    summaries_seen,
+)
 from pipegoose_tpu.serving.kv_pool import (
+    SUMMARY_COUNTERS,
     PagePool,
     check_attn_impl,
     check_kv_dtype,
@@ -138,9 +151,11 @@ from pipegoose_tpu.serving.kv_pool import (
     init_state,
     paged_decode_step,
     paged_prefill_chunk,
+    ring_reach,
     state_walk_plan,
     walk_plan,
     walked_chunks,
+    walked_rows,
     walked_state_rows,
     write_prompt_pages,
     write_state,
@@ -248,7 +263,7 @@ class _RunState:
         "window_keys_reached", "occ_window", "peak_pages", "recycled0",
         "experts_touched", "expert_skew", "rows_routed", "zero_pick_share",
         "state_rows_updated", "state_rows_live", "state_writes",
-        "state_peak_slots",
+        "state_peak_slots", "summary_rows",
     )
 
     def __init__(self, engine: "ServingEngine", now, tick_hook):
@@ -290,6 +305,10 @@ class _RunState:
         # in a slot, the most slots holding a request in a step
         self.state_rows_updated = self.state_rows_live = 0
         self.state_writes = self.state_peak_slots = 0
+        # a ring and its summaries under one softmax (a model that has
+        # them): the decode steps' counters, summed, a layer a step
+        # (``kv_pool._summary_counters``'s names); None: none came
+        self.summary_rows: Optional[dict] = None
         self.phase_s = dict.fromkeys(TICK_PHASES, 0.0)
         self.timeline: deque = deque(maxlen=TIMELINE_CAPACITY)
         # what a decode step takes from the host, in ONE buffer: tokens,
@@ -544,6 +563,8 @@ class ServingEngine:
         self._m_recycled = reg.counter("serving.window_pages_recycled_total")
         self._m_experts = reg.gauge("serving.experts_touched_share")
         self._m_zero_picks = reg.gauge("serving.zero_pick_share")
+        self._m_rows_useful = reg.gauge("serving.eva_rows_useful_share")
+        self._m_summary_keys = reg.gauge("serving.eva_summary_key_share")
         self._m_state_slots = reg.gauge("serving.state_slots_in_use")
         self._m_state_writes = reg.counter("serving.state_writes_total")
         self._m_prefill_tok = reg.counter("serving.prefill_tokens_total")
@@ -556,7 +577,10 @@ class ServingEngine:
         self.config = config
         self.num_slots = num_slots
         self.page_size = page_size
-        self.table_width = max_context // page_size
+        # entries of a slot's global page table: a page holds
+        # ``page_size`` rows, a row ``stride`` positions (1, or a
+        # summary's chunk)
+        self.table_width = -(-max_context // (page_size * model.stride))
         self.mesh = mesh
         self.param_specs = param_specs
         self.tp_axis = tp_axis
@@ -582,10 +606,14 @@ class ServingEngine:
         self._reach_keys = self.table_width * page_size
         # a window layer keeps a ring of pages a slot, and the pool every
         # ring its slots can hold (+ the kind's NULL page)
-        ring = (ring_pages(model.window, page_size)
+        ring = (ring_pages(model.window, page_size, model.window_rule)
                 if WINDOW in model.kinds else 0)
         self._ring_keys = (walk_plan(page_size, ring)[1] * self._walk_keys
                            if ring else 0)
+        # positions a summary stands for, where the window layers keep
+        # summaries beside their ring (None: none does)
+        self._chunk_size = (model.summaries.chunk
+                            if model.summaries is not None else None)
         # sparse layers and held experts over them, as the decode
         # step's counters show them (0: the model brings none)
         self._sparse_layers = self._experts_held = 0
@@ -611,7 +639,7 @@ class ServingEngine:
             self.param_specs = param_specs
         self.pool = PagePool(num_pages, page_size,
                              window_pages=num_slots * ring + 1 if ring else 0,
-                             ring=ring)
+                             ring=ring, stride=model.stride)
         self._run_prefill_tokens = self._run_hit_tokens = 0  # set per run()
         self.prefix_cache = PrefixCache(self.pool) if prefix_cache else None
         # KV memory hierarchy (serving/kv_tier/): optional host-DRAM
@@ -708,7 +736,7 @@ class ServingEngine:
                 def _write(k_pages, v_pages, cache, phys, pad, length):
                     return write_prompt_pages(
                         k_pages, v_pages, cache, phys, pad, page_size,
-                        length)
+                        length, stride=model.stride)
             else:
                 def _write(k_pages, v_pages, cache, phys, pad, length,
                            state, slot):
@@ -1236,6 +1264,12 @@ class ServingEngine:
         sent; where the model routes to zero-compute experts too,
         ``zero_picks`` and ``picks`` (a sparse layer each): the live
         rows' picks that cost nothing, and all of them."""
+        if "summary_rows" in counters:
+            # a layer's two walks: what the softmax needed, what the
+            # walks gathered for it, the summaries written
+            got = rs.summary_rows = rs.summary_rows or {}
+            for name, n in zip(SUMMARY_COUNTERS, counters["summary_rows"]):
+                got[name] = got.get(name, 0) + int(n)
         rows = counters.get("rows_per_expert")
         if rows is None:
             return
@@ -1352,7 +1386,8 @@ class ServingEngine:
         t0 = now() if tr is not None else 0.0
         with span("serving.prefill", registry=self.registry):
             s = req.target_len
-            bucket = self.pool.pages_for(s) * self.page_size
+            # whole pages of POSITIONS (a global page may hold more)
+            bucket = self.pool.logical_pages(s) * self.page_size
             first = self._note_program("prefill", bucket)
             first_write = self._note_program("write", bucket)
             # the padding before the prompt (BLOOM) or behind it (a
@@ -1958,13 +1993,25 @@ class ServingEngine:
             rs.step_time += t - t_step
             if not use_spec:
                 # the program's own arithmetic on the lengths it was sent
-                walked = walked_chunks(
-                    int(rs.seq_lens.max()), self._walk_keys) * self._walk_keys
+                furthest = int(rs.seq_lens.max())
+                by_pos = walked_chunks(
+                    furthest, self._walk_keys) * self._walk_keys
+                # over summaries the global table is walked as far as
+                # the furthest row sees any
+                walked = by_pos if self._chunk_size is None else walked_rows(
+                    summaries_seen(furthest, self.model.window,
+                                   self._chunk_size, self.model.window_rule),
+                    self._walk_keys) * self._walk_keys
                 rs.keys_walked += min(walked, self._reach_keys)
                 rs.keys_reached += self._reach_keys
                 if self._ring_keys:
                     # a window layer's read walks its ring, no further
-                    rs.window_keys_walked += min(walked, self._ring_keys)
+                    # (a block window's only as far as a row stands in)
+                    reach = int(ring_reach(rs.seq_lens, self.model.window,
+                                           self.model.window_rule))
+                    rs.window_keys_walked += min(
+                        walked_chunks(reach, self._walk_keys)
+                        * self._walk_keys, self._ring_keys)
                     rs.window_keys_reached += self._ring_keys
                 if counters:
                     self._note_counters(rs, counters)
@@ -2222,6 +2269,21 @@ class ServingEngine:
                 # steps as ``touched_share`` is
                 metrics["experts"]["zero_pick_share"] = round(
                     rs.zero_pick_share / n, 6)
+        if rs.summary_rows is not None:
+            got = rs.summary_rows
+            needed = got["window_rows_needed"] + got["summary_rows_needed"]
+            gathered = (got["window_rows_gathered"]
+                        + got["summary_rows_gathered"])
+            # one attention over a ring and its summaries: rows the
+            # softmax needed over rows its two walks gathered, and the
+            # summaries' share of the keys it needed
+            metrics["eva"] = dict(
+                got,
+                rows_useful_share=round(needed / max(gathered, 1), 6),
+                summary_key_share=round(
+                    got["summary_rows_needed"] / max(needed, 1), 6))
+            self._m_rows_useful.set(metrics["eva"]["rows_useful_share"])
+            self._m_summary_keys.set(metrics["eva"]["summary_key_share"])
         if self.state:
             metrics["state"] = {
                 "slots": self.num_slots,

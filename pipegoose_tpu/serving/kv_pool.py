@@ -62,6 +62,15 @@ its slots a few at a time, only as far as the highest live slot
 (:func:`update_state_rows`, :func:`walked_state_rows`). A model without
 state carries an empty dict, which adds nothing to a program.
 
+A model whose window layers' ONE attention also reads a SUMMARY a chunk
+of what left its window (``blocks.Summaries``, models/evabyte.py) keeps
+the ring in the ``window`` kind's banks and the summaries, a row a chunk
+of positions, in the ``global`` kind's (``PagePool.stride``): a decode
+step writes the ring's row, pools the ring's page and writes ONE summary
+row in the step that completes its chunk (:func:`_write_summary`),
+and reads both under one softmax, the ring's walk handing its (m, denom,
+acc) to the summaries' (:func:`_attend_summarised`, :func:`_walk`).
+
 Under TP every function sees the LOCAL head subset (call inside
 shard_map with the pool's rows sharded over the tensor axis), and the
 engine pairs the local logits with ``global_greedy_pick``.
@@ -86,11 +95,15 @@ from jax import lax
 from pipegoose_tpu.models.bloom import NEG_INF
 from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.serving.blocks import (
+    BLOCK,
     GLOBAL,
     KINDS,
+    SLIDING,
     WINDOW,
     describe,
     ring_pages,
+    summaries_seen,
+    window_start,
 )
 
 NULL_PAGE = 0
@@ -108,6 +121,13 @@ WALK_KEYS = 256
 # writes (:func:`state_walk_plan`): at Falcon-H1-34B's 4.2 MB a slot a
 # block, 34 MB in flight
 STATE_ROWS = 8
+
+# what a decode step over a ring and its summaries counts of ONE layer's
+# two walks, in the order of its ``summary_rows`` counter
+# (:func:`_summary_counters`)
+SUMMARY_COUNTERS = ("rows_live", "window_rows_needed", "window_rows_gathered",
+                    "summary_rows_needed", "summary_rows_gathered",
+                    "summaries_written")
 
 
 def check_attn_impl(attn_impl: str) -> str:
@@ -180,10 +200,12 @@ class PagePool:
 
     def __init__(self, num_pages: int, page_size: int,
                  history_limit: int = 1024, window_pages: int = 0,
-                 ring: int = 0):
+                 ring: int = 0, stride: int = 1):
         """``window_pages`` > 0 adds the ``"window"`` kind: a second
         free list over pages of the window layers' banks (its own NULL
-        page 0), of which a sequence holds at most ``ring``."""
+        page 0), of which a sequence holds at most ``ring``. ``stride``:
+        positions a row of a ``global`` page stands for
+        (``blocks.PagedModel.stride``: 1, or a summary's chunk)."""
         if num_pages < 2:
             raise ValueError("need >= 2 pages (page 0 is the null page)")
         if page_size < 1:
@@ -191,8 +213,11 @@ class PagePool:
         if history_limit < 1:
             raise ValueError(
                 f"history_limit must be >= 1, got {history_limit}")
+        if stride < 1:
+            raise ValueError(f"stride must be positive, got {stride}")
         self.num_pages = num_pages
         self.page_size = page_size
+        self.stride = stride
         self._free: List[int] = list(range(num_pages - 1, 0, -1))
         self._ref: Dict[int, int] = {}   # page -> refcount (allocated only)
         self.history: Deque[Tuple[str, Tuple[int, ...], int]] = deque(
@@ -259,9 +284,16 @@ class PagePool:
 
     def pages_for(self, n_tokens: int, kind: str = GLOBAL) -> int:
         """Pages of ``kind`` that hold a sequence of ``n_tokens``: all
-        of them for ``global``, the ring at most for ``window``."""
-        n = -(-n_tokens // self.page_size)
-        return n if kind == GLOBAL else min(n, self.ring)
+        of them for ``global`` (a row every ``stride`` positions), the
+        ring at most for ``window``."""
+        if kind == GLOBAL:
+            return self.logical_pages(-(-n_tokens // self.stride))
+        return min(self.logical_pages(n_tokens), self.ring)
+
+    def logical_pages(self, n_tokens: int) -> int:
+        """Pages ``n_tokens`` rows fill. A row a position: what a ring
+        has held, and the bucket a prompt is forwarded in."""
+        return -(-n_tokens // self.page_size)
 
     def fragmentation(self) -> float:
         """1 - (largest contiguous free run / free pages): 0.0 when the
@@ -530,7 +562,7 @@ def update_state_rows(state: dict, layer, live, fn, xs):
 
 
 def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
-                      length=None):
+                      length=None, stride: int = 1):
     """Scatter a prefill's contiguous cache into the pool, in place (the
     L x S_pad rows addressed by layer, page and offset).
 
@@ -550,12 +582,20 @@ def write_prompt_pages(k_pages, v_pages, cache, phys_pages, pad, page_size,
 
     A latent model's cache is ``{"rows": (L, 1, S_pad, lanes)}`` and its
     ``v_pages`` None: the one bank is written, ``(bank, None)`` returned.
+
+    ``stride`` > 1 (``blocks.PagedModel.stride``): row ``c`` of the
+    ``global`` kind's cache is the summary of positions ``c * stride``
+    on, and the first ``length // stride`` of them are written (a chunk
+    the prompt ends in has none yet). A kind's cache may carry
+    ``"start"``, the position of its first row, where the prefill hands
+    over only what the ring must hold.
     """
     if isinstance(cache, dict) and GLOBAL in cache:
-        out = {k: _write_prompt(k_pages[k], v_pages[k], cache[k],
-                                phys_pages[k], pad, page_size, length,
-                                ring=k == WINDOW)
-               for k in cache}
+        out = {k: _write_prompt(
+            k_pages[k], v_pages[k], cache[k], phys_pages[k], pad, page_size,
+            length // stride if k == GLOBAL and stride > 1 else length,
+            ring=k == WINDOW)
+            for k in cache}
         return ({k: o[0] for k, o in out.items()},
                 {k: o[1] for k, o in out.items()})
     return _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
@@ -572,6 +612,8 @@ def _write_prompt(k_pages, v_pages, cache, phys_pages, pad, page_size,
     s_pad = k_seq.shape[1]
     pos = jnp.arange(s_pad)
     logical = pos - pad
+    if isinstance(cache, dict) and "start" in cache:
+        logical = logical + cache["start"]
     valid = logical >= 0
     if length is not None:
         valid = valid & (logical < length)
@@ -650,8 +692,177 @@ def walked_chunks(max_pos, chunk_keys: int):
     return max_pos // chunk_keys + 1
 
 
+def ring_reach(pos, window: int, rule: str):
+    """The position whose :func:`walked_chunks` a ring's walk makes for
+    rows at ``pos`` (any shape): the furthest row's offset INTO its
+    window under the block rule (the ring holds the window's pages in
+    order, ``blocks.ring_pages``), its position under the sliding one
+    (the walk is then the whole ring once a row is past it). On a traced
+    array and on the host's alike."""
+    if rule == BLOCK:
+        pos = pos % window
+    return pos.max()
+
+
+def walked_rows(n_rows, chunk_keys: int):
+    """Trips a walk makes over the first ``n_rows`` rows of a table
+    (summaries: a query may see none, and then the walk makes none).
+    Arithmetic on a traced value and on the host's int alike."""
+    return (n_rows + chunk_keys - 1) // chunk_keys
+
+
+def _walk_query(q, k_pages, pos, slopes):
+    """What the walks of one read share: the block-diagonal query (row
+    ``c*nh + h`` holds q[c, h] in the lanes of its KV head ``h // g``
+    and zeros elsewhere) in the operands' dtype, which lanes a head
+    owns, the queries' positions and ALiBi's slopes in the scores'
+    layout, (B, C*nh, 1)."""
+    b, c, nh, hd = q.shape
+    width = _values(k_pages).shape[-1]
+    kv = width // hd
+    g = nh // kv
+    operand = q.dtype if _is_quantized(k_pages) else k_pages.dtype
+    # own[h, r]: lane r of a row belongs to head h's KV head
+    own = jnp.arange(width)[None, :] // hd == jnp.arange(nh)[:, None] // g
+    lanes = _rows(q) if g == 1 else jnp.tile(q, (1, 1, 1, kv))
+    q_bd = jnp.where(own, lanes[:, :, None, :] if g == 1 else lanes, 0)
+    q_bd = q_bd.reshape(b, c * nh, width).astype(operand)
+    q_pos = jnp.repeat(pos, nh, axis=1)[:, :, None]          # (B, C*nh, 1)
+    slope = (None if slopes is None
+             else jnp.tile(slopes, c)[None, :, None])        # (1, C*nh, 1)
+    return q_bd, own, q_pos, slope
+
+
+def _walk_table(page_table, page_size: int):
+    """(the table as a walk takes it, the walk's chunks): a last chunk
+    that overhangs the table reads NULL pages, whose key positions lie
+    past every query's."""
+    width = page_table.shape[1]
+    pages, n_chunks = walk_plan(page_size, width)
+    return jnp.pad(page_table, ((0, 0), (0, pages * n_chunks - width)),
+                   constant_values=NULL_PAGE), n_chunks
+
+
+def _walk(carry, q_bd, k_pages, v_pages, layer, table, trips, mask,
+          heads, slope=None, guard=False):
+    """The online softmax ``carry`` = (m, denom, acc) of the queries
+    ``q_bd`` (:func:`_walk_query`) taken over the first ``trips`` chunks
+    of ``table`` (B, W: :func:`_walk_table`'s) in layer ``layer``, and
+    handed on UNDIVIDED: a read over two tables (a ring, then summaries)
+    divides once, after both. ``carry`` None: no key seen yet. ``trips``
+    counts chunks of :func:`walk_plan`, at most the table's; ``mask(i)
+    -> (keep, key_pos)`` says which of chunk ``i``'s key columns a query
+    keeps, (B | 1, C*nh | 1, K), and where they stand (``slope`` reads
+    it). ``heads`` = (C,
+    nh, hd), the queries' layout. ``guard``: a chunk may hold no key of
+    a row at all while its ``m`` is still at the floor, so masked
+    columns are zeroed and not left to the exponent."""
+    b, n, width = q_bd.shape
+    c, nh, hd = heads
+    ps = page_size_of(k_pages)
+    pages = walk_plan(ps, table.shape[1])[0]
+    chunk_keys = pages * ps
+    quantized = _is_quantized(k_pages)
+    operand = q_bd.dtype
+
+    def rows_of(bank, ids):
+        return _values(bank)[layer, ids].reshape(
+            b, chunk_keys, width).astype(operand)
+
+    def scales_of(bank, ids):
+        """(B, K, nh) -> the scores' layout (B, C*nh, K)."""
+        s = bank["scale"][layer, ids].reshape(b, chunk_keys, nh)
+        return jnp.tile(jnp.swapaxes(s, 1, 2), (1, c, 1))
+
+    def chunk(i, carry):
+        m, denom, acc = carry
+        ids = lax.dynamic_slice_in_dim(table, i * pages, pages, axis=1)
+        keep, key_pos = mask(i)
+        s = jnp.einsum("bnr,bkr->bnk", q_bd, rows_of(k_pages, ids),
+                       preferred_element_type=jnp.float32)
+        if quantized:
+            s = s * scales_of(k_pages, ids)
+        s = s * (hd ** -0.5)
+        if slope is not None:
+            s = s + slope * key_pos.astype(jnp.float32)
+        s = s + jnp.where(keep, 0.0, NEG_INF)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        if guard:
+            p = jnp.where(keep, p, 0.0)
+        alpha = jnp.exp(m - m_new)
+        denom = denom * alpha + p.sum(-1)
+        if quantized:
+            p = p * scales_of(v_pages, ids)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bnk,bkr->bnr", p.astype(operand), rows_of(v_pages, ids),
+            preferred_element_type=jnp.float32)
+        return m_new, denom, acc
+
+    if carry is None:
+        carry = (jnp.full((b, n), NEG_INF, jnp.float32),
+                 jnp.zeros((b, n), jnp.float32),
+                 jnp.zeros((b, n, width), jnp.float32))
+    return lax.fori_loop(0, trips, chunk, carry)
+
+
+def _walk_context(carry, own, heads, qmask, out_dtype):
+    """The ONE division of a read: (m, denom, acc) -> the context (B, C,
+    nh*hd) in ``out_dtype``, every head keeping its KV head's lanes, pad
+    queries zero."""
+    _, denom, acc = carry
+    c, nh, hd = heads
+    b, width = acc.shape[0], acc.shape[-1]
+    kv = width // hd
+    g = nh // kv
+    # a query is its own key (written before the read) and a dead slot
+    # reads key 0 of the NULL page, so denom >= 1
+    ctx = (acc / denom[..., None]).reshape(b, c, nh, width)
+    if g == 1:
+        ctx = jnp.sum(jnp.where(own, ctx, 0.0), axis=2)      # (B, C, nh*hd)
+    else:
+        # head h keeps the hd lanes of KV head h // g
+        mine = jnp.arange(kv)[None, :] == jnp.arange(nh)[:, None] // g
+        ctx = jnp.sum(jnp.where(mine[:, :, None],
+                                ctx.reshape(b, c, nh, kv, hd), 0.0), axis=3)
+        ctx = ctx.reshape(b, c, nh * hd)
+    if qmask is not None:
+        # pad-query context is ZERO in every attention path
+        ctx = ctx * qmask[:, :, None].astype(ctx.dtype)
+    return ctx.astype(out_dtype)
+
+
+def _ring_mask(pos, q_pos, ring, ps, window, rule):
+    """``mask`` of :func:`_walk` over a window layer's RING: entry ``r``
+    holds the newest logical page ``j <= pos // page_size`` with ``j %
+    ring == r``, so a key's position is read off the query's own. Kept:
+    the keys from ``blocks.window_start`` of the rule to the query."""
+    if rule == BLOCK and ring * ps != window:
+        raise ValueError(f"a block window's ring is its own pages in "
+                         f"order: {ring} pages of {ps} are not a window "
+                         f"of {window}")
+    b = pos.shape[0]
+    pages = walk_plan(ps, ring)[0]
+    cur = pos[:, :1] // ps                                   # (B, 1)
+
+    def mask(i):
+        entry = i * pages + jnp.arange(pages)                # (pages,)
+        page = cur - (cur - entry[None, :]) % ring           # (B, pages)
+        key_pos = (page[:, :, None] * ps
+                   + jnp.arange(ps)).reshape(b, 1, pages * ps)
+        held = jnp.repeat((entry < ring)[None, :] & (page >= 0), ps,
+                          axis=1)[:, None, :]
+        keep = held & (key_pos <= q_pos)
+        if rule == SLIDING:
+            return keep & (key_pos > q_pos - window), key_pos
+        return keep & (key_pos >= window_start(q_pos, window, rule)), key_pos
+
+    return mask
+
+
 def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
-                 out_dtype, window: Optional[int] = None):
+                 out_dtype, window: Optional[int] = None,
+                 rule: str = SLIDING):
     """Softmax attention of ``q`` (B, C, nh, hd) at global positions
     ``pos`` (B, C) over layer ``layer`` of the pool, read through
     ``page_table`` (B, W) AS STORED: a gathered row keeps its
@@ -672,114 +883,86 @@ def _attend_rows(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
     the key position, ``None`` for none.
 
     The keys are visited in chunks of whole pages (:func:`walk_plan`)
-    under an online softmax, and only as far as the furthest live query:
-    :func:`walked_chunks` of the largest position among the queries
-    ``qmask`` keeps. Chunks beyond are not gathered; inside the walk the
-    bias masks what ``_key_bias`` masks (columns past a query's own
-    position: unwritten offsets, stale tails, NULL-page garbage).
+    under an online softmax (:func:`_walk`), and only as far as the
+    furthest live query: :func:`walked_chunks` of the largest position
+    among the queries ``qmask`` keeps. Chunks beyond are not gathered;
+    inside the walk the bias masks what ``_key_bias`` masks (columns
+    past a query's own position: unwritten offsets, stale tails,
+    NULL-page garbage).
 
     ``window``: the layer keeps the keys with ``0 <= q_pos - k_pos <
-    window`` and ``page_table`` is a RING (``blocks.ring_pages``):
-    entry ``r`` holds the newest logical page ``j <= pos // page_size``
-    with ``j % ring == r``, so a key's position is read off the query's
-    own; the walk is the ring's few chunks however long the sequence
-    (one query a row: the ring holds one page more than the window).
+    window`` (``rule`` "sliding") or those from the last multiple of
+    ``window`` on ("block"), and ``page_table`` is a RING
+    (``blocks.ring_pages``, :func:`_ring_mask`); the walk is the ring's
+    few chunks however long the sequence (one query a row), and under
+    the block rule only as far as the furthest row stands into its
+    window (:func:`ring_reach`).
     Returns (B, C, nh*hd) in ``out_dtype``, pad queries zero."""
     b, c, nh, hd = q.shape
     ps = page_size_of(k_pages)
-    width = _values(k_pages).shape[-1]
-    kv = width // hd
-    g = nh // kv
-    n = c * nh
     ring = page_table.shape[1]
     if window is not None and c != 1:
         raise ValueError("a window layer's ring is read a query a row")
-    pages, n_chunks = walk_plan(ps, ring)
-    chunk_keys = pages * ps
-    # a last chunk that overhangs the table reads NULL pages, whose key
-    # positions lie past every query's
-    table = jnp.pad(page_table,
-                    ((0, 0), (0, pages * n_chunks - ring)),
-                    constant_values=NULL_PAGE)
-    quantized = _is_quantized(k_pages)
-    operand = q.dtype if quantized else k_pages.dtype
-    # own[h, r]: lane r of a row belongs to head h's KV head
-    own = jnp.arange(width)[None, :] // hd == jnp.arange(nh)[:, None] // g
-    lanes = _rows(q) if g == 1 else jnp.tile(q, (1, 1, 1, kv))
-    q_bd = jnp.where(own, lanes[:, :, None, :] if g == 1 else lanes, 0)
-    q_bd = q_bd.reshape(b, n, width).astype(operand)
-    q_pos = jnp.repeat(pos, nh, axis=1)[:, :, None]          # (B, C*nh, 1)
-    slope = (None if slopes is None
-             else jnp.tile(slopes, c)[None, :, None])        # (1, C*nh, 1)
+    chunk_keys = walk_plan(ps, ring)[0] * ps
+    table, n_chunks = _walk_table(page_table, ps)
+    q_bd, own, q_pos, slope = _walk_query(q, k_pages, pos, slopes)
     live = pos if qmask is None else jnp.where(qmask, pos, 0)
-    trips = jnp.minimum(walked_chunks(jnp.max(live), chunk_keys), n_chunks)
-    cur = pos[:, :1] // ps                                   # (B, 1)
-
-    def rows_of(bank, ids):
-        return _values(bank)[layer, ids].reshape(
-            b, chunk_keys, width).astype(operand)
-
-    def scales_of(bank, ids):
-        """(B, K, nh) -> the scores' layout (B, C*nh, K)."""
-        s = bank["scale"][layer, ids].reshape(b, chunk_keys, nh)
-        return jnp.tile(jnp.swapaxes(s, 1, 2), (1, c, 1))
-
-    def chunk(i, carry):
-        m, denom, acc = carry
-        ids = lax.dynamic_slice_in_dim(table, i * pages, pages, axis=1)
-        if window is None:
+    reach = (jnp.max(live) if window is None
+             else ring_reach(live, window, rule))
+    trips = jnp.minimum(walked_chunks(reach, chunk_keys), n_chunks)
+    if window is None:
+        def mask(i):
             key_pos = i * chunk_keys + jnp.arange(chunk_keys)
-            keep = key_pos <= q_pos
-        else:
-            entry = i * pages + jnp.arange(pages)            # (pages,)
-            page = cur - (cur - entry[None, :]) % ring       # (B, pages)
-            key_pos = (page[:, :, None] * ps
-                       + jnp.arange(ps)).reshape(b, 1, chunk_keys)
-            held = jnp.repeat((entry < ring)[None, :] & (page >= 0), ps,
-                              axis=1)[:, None, :]
-            keep = held & (key_pos <= q_pos) & (key_pos > q_pos - window)
-        s = jnp.einsum("bnr,bkr->bnk", q_bd, rows_of(k_pages, ids),
-                       preferred_element_type=jnp.float32)
-        if quantized:
-            s = s * scales_of(k_pages, ids)
-        s = s * (hd ** -0.5)
-        if slope is not None:
-            s = s + slope * key_pos.astype(jnp.float32)
-        s = s + jnp.where(keep, 0.0, NEG_INF)
-        m_new = jnp.maximum(m, s.max(-1))
-        p = jnp.exp(s - m_new[..., None])
-        if window is not None:
-            # a chunk of the ring may hold no key of a row's window yet
-            # (m still at its floor): such keys weigh nothing
-            p = jnp.where(keep, p, 0.0)
-        alpha = jnp.exp(m - m_new)
-        denom = denom * alpha + p.sum(-1)
-        if quantized:
-            p = p * scales_of(v_pages, ids)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "bnk,bkr->bnr", p.astype(operand), rows_of(v_pages, ids),
-            preferred_element_type=jnp.float32)
-        return m_new, denom, acc
-
-    _, denom, acc = lax.fori_loop(0, trips, chunk, (
-        jnp.full((b, n), NEG_INF, jnp.float32),
-        jnp.zeros((b, n), jnp.float32),
-        jnp.zeros((b, n, width), jnp.float32)))
-    # a query is its own key (written before the read) and a dead slot
-    # reads key 0 of the NULL page, so denom >= 1
-    ctx = (acc / denom[..., None]).reshape(b, c, nh, width)
-    if g == 1:
-        ctx = jnp.sum(jnp.where(own, ctx, 0.0), axis=2)      # (B, C, nh*hd)
+            return key_pos <= q_pos, key_pos
     else:
-        # head h keeps the hd lanes of KV head h // g
-        mine = jnp.arange(kv)[None, :] == jnp.arange(nh)[:, None] // g
-        ctx = jnp.sum(jnp.where(mine[:, :, None],
-                                ctx.reshape(b, c, nh, kv, hd), 0.0), axis=3)
-        ctx = ctx.reshape(b, c, nh * hd)
-    if qmask is not None:
-        # pad-query context is ZERO in every attention path
-        ctx = ctx * qmask[:, :, None].astype(ctx.dtype)
-    return ctx.astype(out_dtype)
+        mask = _ring_mask(pos, q_pos, ring, ps, window, rule)
+    carry = _walk(None, q_bd, k_pages, v_pages, layer, table, trips, mask,
+                  (c, nh, hd), slope, guard=window is not None)
+    return _walk_context(carry, own, (c, nh, hd), qmask, out_dtype)
+
+
+def _attend_summarised(q, k_pages, v_pages, layers, tables, pos, out_dtype,
+                       window: int, rule: str, chunk: int):
+    """ONE softmax of ``q`` (B, 1, nh, hd) at positions ``pos`` (B, 1)
+    over two caches (``blocks.Summaries``), both read as stored: the
+    ring of the window's exact keys and values (``k_pages[WINDOW]``
+    through ``tables[WINDOW]``, under ``rule``), then the summaries of
+    what the window has left (``k_pages[GLOBAL]``, a row a ``chunk`` of
+    positions, the first ``blocks.summaries_seen`` of them), as far as
+    the furthest row sees any. The second walk takes the first's (m,
+    denom, acc); the division comes after both. ``layers`` =
+    {kind: the bank layer}. Returns (B, 1, nh*hd)."""
+    b, c, nh, hd = q.shape
+    if c != 1:
+        raise ValueError("a ring and its summaries are read a query a row")
+    heads = (c, nh, hd)
+    kw, kg = k_pages[WINDOW], k_pages[GLOBAL]
+    ps = page_size_of(kw)
+    ring = tables[WINDOW].shape[1]
+    q_bd, own, q_pos, _ = _walk_query(q, kw, pos, None)
+    with jax.named_scope("eva.read.window"):
+        table, n_chunks = _walk_table(tables[WINDOW], ps)
+        trips = walked_chunks(ring_reach(pos, window, rule),
+                              walk_plan(ps, ring)[0] * ps)
+        carry = _walk(
+            None, q_bd, kw, v_pages[WINDOW], layers[WINDOW], table,
+            jnp.minimum(trips, n_chunks),
+            _ring_mask(pos, q_pos, ring, ps, window, rule), heads,
+            guard=True)
+    seen = summaries_seen(q_pos, window, chunk, rule)        # (B, nh, 1)
+    chunk_keys = walk_plan(ps, tables[GLOBAL].shape[1])[0] * ps
+
+    def mask(i):
+        row = i * chunk_keys + jnp.arange(chunk_keys)
+        return row < seen, row
+
+    with jax.named_scope("eva.read.summary"):
+        table, n_chunks = _walk_table(tables[GLOBAL], ps)
+        carry = _walk(
+            carry, q_bd, kg, v_pages[GLOBAL], layers[GLOBAL], table,
+            jnp.minimum(walked_rows(jnp.max(seen), chunk_keys), n_chunks),
+            mask, heads, guard=True)
+    return _walk_context(carry, own, heads, None, out_dtype)
 
 
 def _attend_latent(q, pages, layer, page_table, pos, qmask, out_dtype, row):
@@ -853,8 +1036,11 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     A group of stacked layers is one ``fori_loop``; a group of one
     unstacked layer is traced in line (its shapes are its own). With
     two cache kinds ``k_pages``, ``v_pages``, ``page_table``,
-    ``dest_page`` are ``{kind: ..}`` and a layer's bank index counts
-    the layers of its kind before it. ``live`` (B, C) bool says which
+    ``dest_page``, ``dest_off`` are ``{kind: ..}`` and a layer's bank
+    index counts the layers of its kind before it. A layer whose ONE
+    attention keeps a ring and summaries (``blocks.Summaries``) writes
+    both kinds' banks (:func:`_write_summary`) and reads both under one
+    softmax (:func:`_attend_summarised`). ``live`` (B, C) bool says which
     positions are real, for a block that sends rows somewhere (the
     experts) or keeps a state a slot. ``state`` is the state bank
     (:func:`init_state`; ``{}`` or None for a model without): it rides
@@ -877,6 +1063,7 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                          "one query a row, row i slot i")
     kp, vp = by_kind(k_pages), by_kind(v_pages)
     tables, dest = by_kind(page_table), by_kind(dest_page)
+    offs = by_kind(dest_off)
     if attn_impl == "paged" and (model.kinds != (GLOBAL,)
                                  or model.latent is not None):
         raise ValueError("the paged kernel reads one cache kind of keys "
@@ -897,28 +1084,46 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
             break
         left -= take * a
         kind, base = grp.kind, seen[grp.kind]
-        seen[kind] += grp.n * a
+        sm = grp.summaries
+        # the banks the group's layers write: its kind's and, where its
+        # attention keeps summaries, the global one beside it, whose
+        # layer is the ring's moved by what came before the group
+        fills = (kind,) if sm is None else (kind, GLOBAL)
+        shift = 0 if sm is None else seen[GLOBAL] - base
+        for k in fills:
+            seen[k] += grp.n * a
         window = model.window if kind == WINDOW else None
         slopes = grp.slopes() if grp.slopes is not None else None
         blocks = grp.params(params)
         num_pages = _values(kp[kind]).shape[1]
         halves = ((grp.qkv, grp.finish),) + grp.more
 
-        def layer(l, h, kpk, vpk, st, blk):
-            """Layer whose first attention is bank layer ``l``."""
+        def layer(l, h, kps, vps, st, blk):
+            """Layer whose first attention is bank layer ``l``; ``kps``,
+            ``vps``: {kind: bank} of the kinds it writes."""
+            kps, vps = dict(kps), dict(vps)
             for j, (qkv, finish) in enumerate(halves):
                 lj = l + j if j else l        # (no ``+ 0`` in a program)
                 q, k, v, saved = qkv(blk, h, pos)
                 if model.latent is not None:
                     # the row in the bank's whole lane tiles
-                    k = _to_lanes(k, kpk.shape[-1])
-                kpk = _write_rows(kpk, (lj, dest[kind], dest_off), k)
-                if vpk is not None:
-                    vpk = _write_rows(vpk, (lj, dest[kind], dest_off), v)
+                    k = _to_lanes(k, kps[kind].shape[-1])
+                at = (lj, dest[kind], offs[kind])
+                kps[kind] = _write_rows(kps[kind], at, k)
+                if vps[kind] is not None:
+                    vps[kind] = _write_rows(vps[kind], at, v)
+                kpk, vpk = kps[kind], vps[kind]
                 # between a layer's attentions ``h`` may hold more than
                 # the hidden state, which comes first
                 dtype = jax.tree_util.tree_leaves(h)[0].dtype
-                if model.latent is not None:
+                if sm is not None:
+                    lay = {kind: lj, GLOBAL: lj + shift if shift else lj}
+                    kps[GLOBAL], vps[GLOBAL] = _write_summary(
+                        sm, blk, kps, vps, lay, dest, offs, model.head_dim)
+                    ctx = _attend_summarised(
+                        q, kps, vps, lay, tables, pos, dtype, window,
+                        model.window_rule, sm.chunk)
+                elif model.latent is not None:
                     ctx = _attend_latent(q, kpk, lj, tables[kind], pos,
                                          qmask, dtype, model.latent)
                 elif attn_impl == "paged":
@@ -932,34 +1137,36 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                     ctx = ctx.astype(dtype).reshape(b, c, -1)
                 else:
                     ctx = _attend_rows(q, kpk, vpk, lj, tables[kind], pos,
-                                       qmask, slopes, dtype, window)
+                                       qmask, slopes, dtype, window,
+                                       model.window_rule)
                 if grp.mix is not None:
                     saved, st = grp.mix(blk, saved, st, l, live[:, 0])
                 h, out = finish(blk, h, ctx, saved, live)
-            return h, kpk, vpk, st, out
+            return h, kps, vps, st, out
 
+        carry = (x, {k: kp[k] for k in fills}, {k: vp[k] for k in fills},
+                 state)
         if grp.stacked:
             if a != 1:
                 raise ValueError("a layer that attends more than once is "
                                  "traced in line: its group is not stacked")
 
             def body(l, carry):
-                h, kpk, vpk, st = carry
                 # l counts the kind's layers, the stack the group's own
                 own = l - base if base else l
                 blk = jax.tree_util.tree_map(
                     lambda a: lax.dynamic_index_in_dim(a, own, 0,
                                                        keepdims=False),
                     blocks)
-                return layer(l, h, kpk, vpk, st, blk)[:4]
+                return layer(l, *carry, blk)[:4]
 
-            x, kp[kind], vp[kind], state = lax.fori_loop(
-                base, base + take, body, (x, kp[kind], vp[kind], state))
+            x, kps, vps, state = lax.fori_loop(base, base + take, body, carry)
         else:
-            x, kp[kind], vp[kind], state, out = layer(
-                base, x, kp[kind], vp[kind], state, blocks)
+            x, kps, vps, state, out = layer(base, *carry, blocks)
             if out is not None:
                 brought.append(out)
+        kp.update(kps)
+        vp.update(vps)
     x = model.final(params, x)
     counters = {}
     if brought and model.counters:
@@ -968,7 +1175,72 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
         counters = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *brought)
         if not isinstance(counters, dict):
             counters = {model.counters: counters}
+    if model.summaries is not None and live is not None:
+        counters = dict(counters, summary_rows=_summary_counters(
+            model, pos[:, 0], live[:, 0], tables, page_size_of(kp[GLOBAL])))
     return x, _like(k_pages, kp), _like(v_pages, vp), counters, state
+
+
+def _write_summary(sm, blk, kps, vps, layers, dest, offs, head_dim: int):
+    """The summary a decode step's layer makes (``sm``:
+    ``blocks.Summaries``), after its key and value are in the ring: the
+    ring's page that the row's position lies in, pooled (``pool`` hook:
+    a summary is its own chunk's rows and nothing else), ONE row a slot
+    written to the summary bank at ``dest[GLOBAL]``, which is the NULL
+    page but in the step whose position completes the chunk
+    (:func:`paged_decode_step`). ``layers``: {kind: the bank layer}.
+    Returns the ``global`` kind's (k bank, v bank)."""
+    kw, vw = kps[WINDOW], vps[WINDOW]
+    if sm.chunk != page_size_of(kw):
+        raise ValueError(
+            f"a summary is pooled from ONE page of the ring: the chunk "
+            f"({sm.chunk}) has to be the page size ({page_size_of(kw)})")
+    if dest[WINDOW].shape[1] != 1:
+        raise ValueError("a ring and its summaries take a query a row: "
+                         "a decode step")
+    with jax.named_scope("eva.pool"):
+        page = dest[WINDOW][:, 0]
+        lw = layers[WINDOW]
+        k_sum, v_sum = sm.pool(blk, _heads(kw[lw, page], head_dim),
+                               _heads(vw[lw, page], head_dim))
+    with jax.named_scope("eva.summary_write"):
+        to = (layers[GLOBAL], dest[GLOBAL], offs[GLOBAL])
+        return (_write_rows(kps[GLOBAL], to, k_sum[:, None]),
+                _write_rows(vps[GLOBAL], to, v_sum[:, None]))
+
+
+def _summary_counters(model, pos, live, tables, page_size: int):
+    """What the two walks of ONE layer's summarised read needed and
+    gathered in a decode step, over the live rows ``live`` (B,) at
+    ``pos`` (B,): how many they are, the keys the softmax has (the
+    window's exact ones, the summaries seen), the rows the walks brought
+    in for them (every live row's table is walked as far as the furthest
+    row's), and the summaries this step wrote. ONE int32 vector in
+    ``SUMMARY_COUNTERS``' order: the host fetches it in one transfer."""
+    sm, window, rule = model.summaries, model.window, model.window_rule
+    seen = summaries_seen(pos, window, sm.chunk, rule)
+    start = window_start(pos, window, rule)
+    alive = live.sum()
+    reach = ring_reach(pos, window, rule)
+
+    def gathered(table, trips):
+        pages, n_chunks = walk_plan(page_size, table.shape[1])
+        keys = pages * page_size
+        return alive * jnp.minimum(trips(keys), n_chunks) * keys
+
+    counted = {
+        "rows_live": alive,
+        "window_rows_needed": jnp.where(
+            live, pos - (start > 0) * start + 1, 0).sum(),
+        "window_rows_gathered": gathered(
+            tables[WINDOW], lambda keys: walked_chunks(reach, keys)),
+        "summary_rows_needed": jnp.where(live, seen, 0).sum(),
+        "summary_rows_gathered": gathered(
+            tables[GLOBAL], lambda keys: walked_rows(jnp.max(seen), keys)),
+        "summaries_written": (live & ((pos + 1) % sm.chunk == 0)).sum(),
+    }
+    return jnp.stack([counted[name].astype(jnp.int32)
+                      for name in SUMMARY_COUNTERS])
 
 
 def _dest(page_table, page_idx, ring: bool):
@@ -1036,19 +1308,38 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     ps = page_size_of(by_kind(k_pages)[GLOBAL])
     page_idx = (seq_lens // ps)[:, None]
     off = seq_lens % ps
+    summarised = model.stride > 1
     phys = {k: _dest(t, page_idx, k == WINDOW)[:, 0]
-            for k, t in by_kind(page_table).items()}
+            for k, t in by_kind(page_table).items()
+            if not (summarised and k == GLOBAL)}
     if write_ok is not None:
+        if summarised:
+            raise ValueError("write_ok caps a draft, which is not built "
+                             "over a ring and its summaries")
         phys = {k: jnp.where(write_ok, p, NULL_PAGE) for k, p in phys.items()}
         off = jnp.where(write_ok, off, 0)
+    if summarised:
+        # a row of the global bank is a SUMMARY, a chunk of positions:
+        # the step whose position completes the chunk writes it, at the
+        # chunk's index; any other step's goes to the NULL page
+        row = seq_lens // model.stride
+        done = (seq_lens + 1) % model.stride == 0
+        phys[GLOBAL] = jnp.where(done, _dest(
+            page_table[GLOBAL], (row // ps)[:, None], False)[:, 0], NULL_PAGE)
+        row_off = jnp.where(done, row % ps, 0)
+    # (B,) -> the forward's (B, 1); the kinds write at one offset, a
+    # summary at its own
+    tokens, pos = tokens[:, None], seq_lens[:, None]
+    dest_page = {k: p[:, None] for k, p in phys.items()}
+    dest_off = dict.fromkeys(phys, off[:, None])
+    if summarised:
+        dest_off[GLOBAL] = row_off[:, None]
     x, k_pages, v_pages, counters, new_state = _paged_forward(
-        params, tokens[:, None], k_pages, v_pages, page_table,
-        seq_lens[:, None], _like(page_table, {k: p[:, None]
-                                              for k, p in phys.items()}),
-        off[:, None], None, model, tp_axis, attn_impl,
-        n_layers=draft_layers,
-        live=((seq_lens > 0)[:, None] if model.counters or model.state
-              else None),
+        params, tokens, k_pages, v_pages, page_table, pos,
+        _like(page_table, dest_page), _like(page_table, dest_off), None,
+        model, tp_axis, attn_impl, n_layers=draft_layers,
+        live=((seq_lens > 0)[:, None]
+              if model.counters or model.state or summarised else None),
         state=state)
     logits = model.logits(params, x)[:, 0]  # (B, V_local)
     out = (logits, k_pages, v_pages) + ((counters,) if with_counters else ())

@@ -763,7 +763,7 @@ class Scheduler:
                 f"ensure_pages on a {req.status.value} request "
                 f"(retracted mid-batch by a neighbor's lazy growth?)"
             )
-        while len(req.pages) * self.pool.page_size < n_tokens:
+        while len(req.pages) < self.pool.pages_for(n_tokens):
             req.pages += self._alloc(1, owner=req, tag=("req", req.uid))
             req.outstanding -= 1
             self._outstanding_total -= 1
@@ -781,7 +781,7 @@ class Scheduler:
         need = pool.pages_for(n_tokens, "window")
         if need > have:
             req.window_pages += pool.alloc(need - have, "window")
-        logical = pool.pages_for(n_tokens)
+        logical = pool.logical_pages(n_tokens)
         seen = req.window_logical
         if logical > seen:
             pool.recycled += max(logical, pool.ring) - max(seen, pool.ring)

@@ -735,3 +735,107 @@ def test_latent_step_reads_one_bank_and_forms_no_keys_or_values(one_chip):
     assert re.search(r"bf16\[(\d+,)*640\]\S* (fusion|gather)\(", text)
     # ... in a walk of its own an attention (the blocks are traced in line)
     assert text.count(" while(") == 2 * LATENT_L
+
+
+# ONE attention over two caches (PERF.md, PR 47): a window layer that
+# keeps a ring of exact keys and, in global pages, a pooled key and value
+# a chunk of every closed window (EvaByte). The step writes the ring's
+# row, pools the ring's page and writes ONE summary row, then walks ring
+# and summaries under one softmax; the prefill runs EVA over the bucket's
+# windows as rows and over the summaries as one more chunk of keys. Like
+# the pool's layout, all of it holds in the compiled program or not at
+# all.
+
+# 4 heads at the published head width and window; 8,192 positions are
+# four windows, 512 summaries: two chunks of the summaries' walk
+EVA_L, EVA_HEADS, EVA_WINDOW, EVA_CONTEXT, EVA_SLOTS = 2, 4, 2048, 8192, 3
+
+
+def _eva_engine(one_chip):
+    from pipegoose_tpu.models import evabyte
+
+    cfg = evabyte.EvaByteConfig(
+        vocab_size=320, hidden_size=EVA_HEADS * 128, intermediate_size=1024,
+        num_hidden_layers=EVA_L, num_attention_heads=EVA_HEADS,
+        num_key_value_heads=EVA_HEADS, window_size=EVA_WINDOW, chunk_size=PS,
+        use_flash=True, ffn_block_tokens=2048, dtype=jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: evabyte.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    return params, ServingEngine(
+        params, cfg, num_slots=EVA_SLOTS, num_pages=POOL_PAGES, page_size=PS,
+        max_context=EVA_CONTEXT)
+
+
+def test_summarised_step_updates_both_banks_in_place(one_chip):
+    """The compiled decode step of a model whose attention reads a ring
+    AND summaries aliases all four banks (keys and values of both kinds)
+    and its carry, holds no temporary of a bank layer's plane, copies or
+    re-lays out none, and walks the ring and the summaries in a loop
+    each inside the one layer loop."""
+    params, eng = _eva_engine(one_chip)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    ring = EVA_WINDOW // PS                 # a block window's own pages
+    kp = jax.tree_util.tree_map(sds, eng.k_pages)
+    vp = jax.tree_util.tree_map(sds, eng.v_pages)
+    assert {k: v.shape for k, v in kp.items()} == {
+        "global": (EVA_L, POOL_PAGES, PS, EVA_HEADS * 128),
+        "window": (EVA_L, EVA_SLOTS * ring + 1, PS, EVA_HEADS * 128)}
+    # a global entry a 256 positions, the ring, a token and a length
+    assert eng._carry_size == EVA_SLOTS * (2 + EVA_CONTEXT // 256 + ring)
+    carry = jax.ShapeDtypeStruct((eng._carry_size,), jnp.int32,
+                                 sharding=one_chip)
+    compiled = eng._step.lower(params, carry, kp, vp).compile()
+    bank_bytes = 2 * sum(x.size * x.dtype.itemsize for x in kp.values())
+    ma = compiled.memory_analysis()
+    carried = -(-4 * eng._carry_size // 512) * 512
+    assert ma.alias_size_in_bytes == bank_bytes + carried
+    planes = {x.size // EVA_L for x in kp.values()}
+    assert ma.temp_size_in_bytes < min(planes) * 2, ma.temp_size_in_bytes
+    text = compiled.as_text()
+    moved = []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]\S* (copy|transpose)\(",
+                         text):
+        elements = math.prod(int(d) for d in m.group(2).split(","))
+        if any(elements % plane == 0 for plane in planes):
+            moved.append(m.group(0))
+    assert not moved, moved
+    # the layer loop, and inside it the ring's walk and the summaries'
+    assert text.count(" while(") == 3
+    # a chunk's rows are gathered at the width they are stored in
+    assert re.search(r"bf16\[(\d+,)*%d\]\S* (fusion|gather)\("
+                     % (EVA_HEADS * 128), text)
+
+
+def test_eva_prefill_compiles_its_kernels_and_forms_no_square_of_scores(
+        one_chip, monkeypatch):
+    """The prefill of a 4,096-byte bucket (two windows) for the described
+    chip, the flash kernels compiled and not interpreted: the window
+    part and the summary part are a kernel each, and no array is (..,
+    4096, 4096) or has as many elements as heads x 4,096 x 4,096."""
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    params, eng = _eva_engine(one_chip)
+    s = 2 * EVA_WINDOW
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+    # the kernels ask the default device for its VMEM while traced (the
+    # engine's own buffers are made before: they are real arrays)
+    with jax.default_device(next(iter(one_chip.device_set))):
+        low = eng._prefill.lower(params, ids, ids)
+    lowered = low.as_text()
+    assert "flash_fwd" in lowered and "flash_ring_fwd" in lowered
+    text = low.compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    square = []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        if dims[-2:] == [s, s] or math.prod(dims) >= EVA_HEADS * s * s:
+            square.append(m.group(0))
+    assert not square, square[:3]
+    cache = jax.eval_shape(eng._prefill, params, ids, ids)[1]
+    # what the ring must hold, and a summary a chunk of the bucket
+    assert cache["window"]["k"].shape == (EVA_L, 1, EVA_WINDOW, EVA_HEADS, 128)
+    assert cache["global"]["k"].shape == (EVA_L, 1, s // PS, EVA_HEADS, 128)

@@ -475,7 +475,7 @@ READ_LENGTHS = {
 
 
 def _oracle_read(q, k_pages, v_pages, layer, page_table, pos, qmask, slopes,
-                 out_dtype, window=None):
+                 out_dtype, window=None, rule="sliding"):
     """``_attend_rows``'s signature over the reconstructed view."""
     from pipegoose_tpu.serving import kv_pool
 
