@@ -23,7 +23,7 @@ Kernel structure (canonical TPU flash attention):
   forming the tile for itself, seven matmuls:
   dq:  grid (bh, nq, nk), kv sequential, accumulates dS @ K;
   dkv: grid (bh, nk, nq), q sequential, accumulates dS^T @ Q and P^T @ dO;
-  delta = rowsum(dO * O) is computed in plain XLA either way.
+  delta = rowsum(dO * O): plain XLA for these; the paired ``flash_bwd`` forms it itself.
 - blocks: ``_pick_blocks(seq, head width, itemsize, kind, vmem limit)``
   gives each kernel the largest (block_q, block_k) whose working set
   fits three quarters of the scoped VMEM it asks for, half the device's
@@ -45,6 +45,11 @@ Kernel structure (canonical TPU flash attention):
   are skipped with pl.when — ~2x fewer FLOPs for causal attention — and
   fetch nothing: their index maps are clamped to the last block the
   rule keeps, which is already in VMEM.
+- layout: operands are flattened ``(batch*heads, seq, head_dim)``, a
+  head a tile, EXCEPT where two heads of 64 fill one 128-lane tile:
+  there ``flash_fwd`` and ``flash_bwd`` (same names) read and write
+  ``(batch, seq, heads * 64)``, a pair of heads a grid row, each head's
+  arithmetic unchanged ("two heads a 128-lane tile", below).
 
 The three ``flash_ring_*`` kernels below were copied from these before
 PR 30 and keep the old step (128 x 512 blocks, float32 operands, every
@@ -111,6 +116,10 @@ _STEP_TILES = {
     "dkv": (2, 4, 0, 2, 4, 4),
     # dK/dV's, and the (BQ, hd) product that goes into dQ's accumulator
     "bwd": (2, 4, 1, 2, 4, 4),
+    # two heads a 128-lane tile (``hd`` = 128): m and l a head, and the
+    # forward's result beside dO, from which the kernel forms delta
+    "fwd_paired": (2, 2, 5, 0, 3, 1),
+    "bwd_paired": (3, 4, 1, 2, 4, 4),
 }
 # What a kernel holds of the WHOLE sequence of one (batch, head),
 # whatever its blocks: float32 (seq, hd) scratch, and pipelined (seq,
@@ -118,6 +127,7 @@ _STEP_TILES = {
 _SEQ_TILES = {
     # dQ's accumulator | dQ
     "bwd": (1, 1),
+    "bwd_paired": (1, 1),
 }
 
 
@@ -137,15 +147,21 @@ def _vmem_limit_bytes() -> int:
 
 def _working_set_bytes(kind: str, block_q: int, block_k: int, head_dim: int,
                        itemsize: int, seq: int = 0) -> int:
-    """VMEM one grid step of kernel ``kind`` ("fwd", "dq", "dkv", "bwd")
-    holds, by its own arithmetic: ``_STEP_TILES``' pipelined tiles twice
-    (double buffering), the scratch, the score tiles, the per-query and
-    per-key float32 rows (two each side, pipelined), and what
-    ``_SEQ_TILES`` has the kind keep of a whole sequence of ``seq``
-    positions, which no choice of blocks makes smaller. An upper bound: a
+    """VMEM one grid step of kernel ``kind`` ("fwd", "dq", "dkv", "bwd";
+    "fwd_paired", "bwd_paired" for the kernels that take two heads of 64
+    as ONE tile of ``head_dim`` = 128 lanes, a score tile formed for one
+    head at a time) holds, by its own arithmetic: ``_STEP_TILES``'
+    pipelined tiles twice (double buffering), the scratch, the score
+    tiles, the per-query and per-key float32 rows (two each side,
+    pipelined), and what ``_SEQ_TILES`` has the kind keep of a whole
+    sequence of ``seq`` positions, which no choice of blocks makes
+    smaller. An upper bound: a
     v5e's compiler took each kernel with 1.1-1.5x less at head widths of
     256 and more, where a budget binds, and with 2-4x less at 64
-    (PERF.md, PR 30); tests/ops/test_chip_compile.py holds it to that."""
+    (PERF.md, PR 30); tests/ops/test_chip_compile.py holds it to that.
+    A one-head tile of 64 lanes is counted, as it is stored and moved,
+    at the 128 it pads to: what the paired layout fills with a second
+    head."""
     q_io, k_io, q_f32, k_f32, s_f32, s_narrow = _STEP_TILES[kind]
     lanes = -(-head_dim // 128) * 128    # a tile's rows pad to 128 lanes
     tiles = (2 * (q_io * block_q + k_io * block_k) * itemsize
@@ -658,6 +674,281 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
       kpos[:, None, :], kneg[:, None, :])
 
 
+# -- two heads a 128-lane tile (head width 64) --------------------------------
+#
+# A Pallas operand is row-major with its last dimension in the lanes, so
+# a 64-wide head fills half of every (rows, 128) tile it is stored and
+# moved in: q, k, v, the result and the four gradients at twice their
+# bytes, behind transposes of heads over positions (PERF.md, PR 49).
+# Where two adjacent heads fill one tile the kernels below read q, k, v,
+# dO and write the result, dQ, dK, dV as ``(B, S, nh * 64)``, which is
+# the reshape of what a model hands over: nothing transposed, nothing
+# padded. A grid row is a PAIR of heads; each head's arithmetic is the
+# one-head kernels' term for term, a (BQ, BK) score tile formed for one
+# head at a time. A head reaches the matrix unit through a lane mask:
+# the contraction runs over all 128 lanes against an operand whose other
+# head's lanes are zero (the added terms are zeros, and a 64-deep
+# contraction already takes a 128-deep pass), as ``paged_attention``'s.
+
+_PAIRED_HEAD_DIM = 64
+_PAIR = 2
+_PAIR_LANES = _PAIR * _PAIRED_HEAD_DIM
+
+
+def _lanes_of(h):
+    """(1, 128) mask of the lanes that hold a tile's ``h``-th head."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _PAIR_LANES), 1)
+    return (lane >= h * _PAIRED_HEAD_DIM) & (lane < (h + 1) * _PAIRED_HEAD_DIM)
+
+
+def _own_lanes(x, h):
+    """Tile ``x`` (rows, 128) with the other head's lanes zeroed."""
+    return jnp.where(_lanes_of(h), x, 0)
+
+
+def _flash_fwd_paired_pallas(q, k, v, slopes, kpos, kneg, scale, causal,
+                             block_q, block_k, interpret, window=None):
+    """``_flash_fwd_pallas`` over ``(B, S, nh * 64)`` operands: grid
+    (B * nh / 2, nq, nk), slopes ``(B * nh,)``, per-key rows ``(B, S)``
+    (one a batch row); returns the result in the operands' layout and
+    the logsumexp ``(B * nh, S)``, a row a head."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, width = q.shape
+    pairs = width // _PAIR_LANES
+    nq, nk = s // block_q, s // block_k
+
+    def kernel(slope_ref, q_ref, k_ref, v_ref, kpos_ref, kneg_ref,
+               o_ref, lse_ref, m_sc, l_sc, acc_sc):
+        bp = pl.program_id(0)
+        qi = pl.program_id(1)
+        ki = pl.program_id(2)
+
+        @pl.when(ki == 0)
+        def _init():
+            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[:] = jnp.zeros_like(l_sc)
+            acc_sc[:] = jnp.zeros_like(acc_sc)
+
+        q_start = qi * block_q
+        k_start = ki * block_k
+
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
+        def _compute():
+            qb = q_ref[0]
+            kb = k_ref[0]
+            vb = v_ref[0]
+            for h in range(_PAIR):
+                s_blk = _scores(_own_lanes(qb, h), kb,
+                                slope_ref[_PAIR * bp + h], kpos_ref,
+                                kneg_ref, scale, q_start, k_start, causal,
+                                window)
+                m_prev = m_sc[h]  # (BQ, 1)
+                m_new = jnp.maximum(m_prev, s_blk.max(axis=1, keepdims=True))
+                p = jnp.exp(s_blk - m_new)
+                alpha = jnp.exp(m_prev - m_new)
+                l_sc[h] = l_sc[h] * alpha + p.sum(axis=1, keepdims=True)
+                # (BQ, 128): this head's lanes hold p v of this head
+                pv = jax.lax.dot_general(
+                    p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                acc = acc_sc[:]
+                acc_sc[:] = jnp.where(_lanes_of(h), acc * alpha + pv, acc)
+                m_sc[h] = m_new
+
+        @pl.when(ki == nk - 1)
+        def _finish():
+            ls = [jnp.maximum(l_sc[h], 1e-30) for h in range(_PAIR)]
+            l = jnp.where(_lanes_of(0), ls[0], ls[1])  # (BQ, 128)
+            o_ref[0] = (acc_sc[:] / l).astype(o_ref.dtype)
+            for h in range(_PAIR):
+                lse_ref[h, 0] = (m_sc[h] + jnp.log(ls[h]))[:, 0]
+
+    def q_map(bp, i, j):
+        return (bp // pairs, i, bp % pairs)
+
+    def kv_map(bp, i, j):
+        return (bp // pairs, _kv_block(i, j, block_q, block_k, causal, window),
+                bp % pairs)
+
+    def kv_row_map(bp, i, j):
+        return (bp // pairs, 0,
+                _kv_block(i, j, block_q, block_k, causal, window))
+
+    out, lse = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(b * pairs, nq, nk),
+            in_specs=[
+                pl.BlockSpec((b * pairs * _PAIR,), lambda bp, i, j: (0,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, block_q, _PAIR_LANES), q_map),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, _PAIR_LANES), q_map),
+                pl.BlockSpec((_PAIR, 1, block_q), lambda bp, i, j: (bp, 0, i)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((_PAIR, block_q, 1), jnp.float32),
+                pltpu.VMEM((_PAIR, block_q, 1), jnp.float32),
+                pltpu.VMEM((block_q, _PAIR_LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((b * pairs * _PAIR, 1, s), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
+        ),
+        interpret=interpret,
+        name="flash_fwd",
+    )(slopes, q, k, v, kpos[:, None, :], kneg[:, None, :])
+    return out, lse[:, 0, :]
+
+
+def _flash_bwd_paired_pallas(q, k, v, do, out, lse, slopes, kpos, kneg,
+                             scale, causal, block_q, block_k, interpret,
+                             window=None):
+    """``_flash_bwd_pallas`` over ``(B, S, nh * 64)`` operands and
+    results: grid (B * nh / 2, nk, nq); ``lse`` ``(B * nh, S)``, two
+    rows a grid row; dQ's whole-sequence float32 accumulator holds the
+    pair's 128 lanes. ``delta = rowsum(dO * O)`` is formed HERE, from the
+    forward's result ``out``, a head's 64 lanes of the tile at a time
+    (float32 products and sum, as the one-head path has them from XLA):
+    a sum over half a tile's lanes is what XLA re-lays the whole plane
+    out for, sequence-minor, twice a layer (PERF.md, PR 49)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, width = q.shape
+    pairs = width // _PAIR_LANES
+    nq, nk = s // block_q, s // block_k
+
+    def kernel(slope_ref, q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
+               kpos_ref, kneg_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc):
+        bp = pl.program_id(0)
+        kj = pl.program_id(1)
+        qi = pl.program_id(2)
+
+        @pl.when((kj == 0) & (qi == 0))
+        def _init_pair():
+            dq_sc[:] = jnp.zeros_like(dq_sc)
+
+        @pl.when(qi == 0)
+        def _init():
+            dk_sc[:] = jnp.zeros_like(dk_sc)
+            dv_sc[:] = jnp.zeros_like(dv_sc)
+
+        q_start = qi * block_q
+        k_start = kj * block_k
+
+        @pl.when(_keep_block(q_start, k_start, block_q, block_k, causal,
+                             window))
+        def _compute():
+            kb = k_ref[0]
+            vb = v_ref[0]
+            rows = pl.ds(pl.multiple_of(q_start, block_q), block_q)
+            do_o = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+            for h in range(_PAIR):
+                # every product below is zero outside this head's lanes
+                qb = _own_lanes(q_ref[0], h)
+                dob = _own_lanes(do_ref[0], h)
+                s_blk = _scores(qb, kb, slope_ref[_PAIR * bp + h], kpos_ref,
+                                kneg_ref, scale, q_start, k_start, causal,
+                                window)
+                p = jnp.exp(s_blk - lse_ref[h, 0][:, None])  # (BQ, BK)
+                dv_sc[:] += jax.lax.dot_general(
+                    p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # P^T @ dO -> (BK, 128)
+                dp = jax.lax.dot_general(
+                    dob, vb, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                delta = _own_lanes(do_o, h).sum(axis=1, keepdims=True)  # (BQ, 1)
+                ds = (p * (dp - delta)).astype(qb.dtype)
+                dk_sc[:] += scale * jax.lax.dot_general(
+                    ds, qb, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # dS^T @ Q -> (BK, 128)
+                dq_sc[rows, :] += scale * jax.lax.dot_general(
+                    ds, _own_lanes(kb, h), (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # dS @ K -> this query block's rows of (seq, 128)
+
+        @pl.when(qi == nq - 1)
+        def _finish():
+            dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
+            dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+
+        @pl.when((kj == nk - 1) & (qi == nq - 1))
+        def _finish_pair():
+            dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+
+    def q_map(bp, j, i):
+        return (bp // pairs, _q_block(j, i, block_q, block_k, causal, window, nq),
+                bp % pairs)
+
+    def q_row_map(bp, j, i):
+        return (bp, 0, _q_block(j, i, block_q, block_k, causal, window, nq))
+
+    def kv_map(bp, j, i):
+        return (bp // pairs, j, bp % pairs)
+
+    def kv_row_map(bp, j, i):
+        return (bp // pairs, 0, j)
+
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0,
+            grid=(b * pairs, nk, nq),
+            in_specs=[
+                pl.BlockSpec((b * pairs * _PAIR,), lambda bp, j, i: (0,),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, block_q, _PAIR_LANES), q_map),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+                pl.BlockSpec((1, block_q, _PAIR_LANES), q_map),
+                pl.BlockSpec((1, block_q, _PAIR_LANES), q_map),
+                pl.BlockSpec((_PAIR, 1, block_q), q_row_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+                pl.BlockSpec((1, 1, block_k), kv_row_map),
+            ],
+            out_specs=[
+                # the whole sequence of a pair: it leaves VMEM once a pair
+                pl.BlockSpec((1, s, _PAIR_LANES),
+                             lambda bp, j, i: (bp // pairs, 0, bp % pairs)),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+                pl.BlockSpec((1, block_k, _PAIR_LANES), kv_map),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((s, _PAIR_LANES), jnp.float32),
+                pltpu.VMEM((block_k, _PAIR_LANES), jnp.float32),
+                pltpu.VMEM((block_k, _PAIR_LANES), jnp.float32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in (q, k, v)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(),
+        ),
+        interpret=interpret,
+        name="flash_bwd",
+    )(slopes, q, k, v, do, out, lse[:, None, :], kpos[:, None, :],
+      kneg[:, None, :])
+
+
 def _flash_chunk_pallas(q, k, v, slopes, qpos, kpos, kneg, m0, l0, acc0,
                         scale, block_q, block_k, interpret, g=1):
     """Stateful flash chunk for ring attention: consume the incoming
@@ -1028,10 +1319,28 @@ def _resolve_interpret(interpret):
     return interpret
 
 
-def _blocks(q, kind):
-    """``_pick_blocks`` for flattened ``q`` (bh, seq, hd) on this device."""
+def _blocks(q, kind, paired=False):
+    """``_pick_blocks`` on this device for ``q`` as its kernels take it:
+    flattened ``(bh, seq, hd)``, a head a tile ``hd`` wide, or, where
+    ``paired``, ``(B, seq, nh * 64)``, two heads a 128-lane tile."""
+    if paired:
+        return _pick_blocks(q.shape[1], _PAIR_LANES, q.dtype.itemsize,
+                            kind + "_paired", _vmem_limit_bytes())
     return _pick_blocks(q.shape[1], q.shape[2], q.dtype.itemsize, kind,
                         _vmem_limit_bytes())
+
+
+def _pairs_heads(seq, nh, hd, g, itemsize) -> bool:
+    """Whether a call takes the paired layout: two heads fill one
+    128-lane tile (head width 64, an even number of heads, as many key
+    heads as query heads) and the one-kernel backward fits at the paired
+    blocks. Decided ONCE a call, from its shapes and the device, for the
+    forward, the saved residuals and the backward alike."""
+    if hd != _PAIRED_HEAD_DIM or g != 1 or nh % _PAIR:
+        return False
+    limit = _vmem_limit_bytes()
+    blocks = _pick_blocks(seq, _PAIR_LANES, itemsize, "bwd_paired", limit)
+    return _fits("bwd_paired", *blocks, _PAIR_LANES, itemsize, seq, limit)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10))
@@ -1086,6 +1395,39 @@ def _flash_bwd(scale, causal, interpret, g, window, res, ct):
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
+def _flash_paired(q, k, v, slopes, kpos, kneg, scale, causal, interpret,
+                  window=None):
+    """``_flash`` in the paired layout: q, k, v and the result ``(B, S,
+    nh * 64)``, slopes ``(B * nh,)``, per-key rows ``(B, S)``."""
+    return _flash_paired_fwd(q, k, v, slopes, kpos, kneg, scale, causal,
+                             interpret, window)[0]
+
+
+def _flash_paired_fwd(q, k, v, slopes, kpos, kneg, scale, causal, interpret,
+                      window=None):
+    out, lse = _flash_fwd_paired_pallas(
+        q, k, v, slopes, kpos, kneg, scale, causal,
+        *_blocks(q, "fwd", paired=True), _resolve_interpret(interpret), window,
+    )
+    # named INSIDE the rule, as ``_flash_fwd`` names them
+    out, lse = (checkpoint_name(x, name)
+                for x, name in zip((out, lse), RESIDUAL_NAMES))
+    return out, (q, k, v, slopes, kpos, kneg, out, lse)
+
+
+def _flash_paired_bwd(scale, causal, interpret, window, res, ct):
+    q, k, v, slopes, kpos, kneg, out, lse = res
+    dq, dk, dv = _flash_bwd_paired_pallas(
+        q, k, v, ct, out, lse, slopes, kpos, kneg, scale, causal,
+        *_blocks(q, "bwd", paired=True), _resolve_interpret(interpret), window,
+    )
+    return dq, dk, dv, jnp.zeros_like(slopes), jnp.zeros_like(kpos), jnp.zeros_like(kneg)
+
+
+_flash_paired.defvjp(_flash_paired_fwd, _flash_paired_bwd)
+
+
 def flash_attention(
     q: jax.Array,  # (B, S, nh, hd)
     k: jax.Array,  # (B, S, nh | nkv, hd) — fewer kv heads = native GQA
@@ -1109,6 +1451,22 @@ def flash_attention(
     nkv``, query head h sharing kv head h // g like HF), the kernels
     read the shared K/V directly via grouped index maps — K/V are never
     repeated in HBM, so KV read traffic shrinks by g.
+
+    Layout: one of two, chosen ONCE a call from its shapes and the
+    device (``_pairs_heads``) and held for the forward, the saved
+    residuals and the backward. Where two heads fill one 128-lane tile
+    (``hd == 64``, an even ``nh``, ``nkv == nh``, and the one-kernel
+    backward fits VMEM at the paired blocks) the kernels take q, k, v,
+    dO and return the result, dQ, dK, dV as ``(B, S, nh * hd)``, the
+    reshape of what the caller holds: nothing is transposed and no tile
+    is half padding (bloom-560m). Every other call (widths of 128 and
+    more, GQA, an odd head count) takes heads over positions,
+    ``(B * nh, S, hd)``, a head a tile, through the kernels as they
+    were. Counters ``flash.calls`` and ``flash.paired_calls``
+    (``telemetry.get_registry()``) count the calls of each once PER
+    TRACE (a checkpointed or scanned block is traced more than once a
+    compile, and counts each time): which layout a program took, not
+    how many kernels it holds.
     """
     b, s, nh, hd = q.shape
     nkv = k.shape[2]
@@ -1131,6 +1489,23 @@ def flash_attention(
         kv_neg = jnp.zeros((b, s), jnp.float32)
 
     slopes = jnp.broadcast_to(alibi_slopes[None], (b, nh)).reshape(b * nh)
+    window = int(window) if window is not None else None
+
+    from pipegoose_tpu.telemetry.registry import get_registry
+
+    # counted per TRACE of this call: the layout is a property of the
+    # traced program, not of a step
+    registry = get_registry()
+    registry.counter("flash.calls").inc(of_trace=True)
+    if _pairs_heads(s, nh, hd, g, q.dtype.itemsize):
+        registry.counter("flash.paired_calls").inc(of_trace=True)
+        out = _flash_paired(
+            *(x.reshape(b, s, nh * hd) for x in (q, k, v)),
+            slopes.astype(jnp.float32), kv_pos.astype(jnp.float32),
+            kv_neg.astype(jnp.float32), float(scale), causal, interpret,
+            window,
+        )
+        return out.reshape(b, s, nh, hd)
 
     def flat(x):
         h = x.shape[2]
@@ -1144,6 +1519,6 @@ def flash_attention(
     out = _flash(
         flat(q), flat(k), flat(v), slopes.astype(jnp.float32),
         flat_bs(kv_pos, nkv), flat_bs(kv_neg, nkv), float(scale), causal,
-        interpret, g, int(window) if window is not None else None,
+        interpret, g, window,
     )
     return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)

@@ -71,10 +71,16 @@ class Counter:
         self._lock = threading.Lock()
         self._registry = registry if registry is not None else _STANDALONE
 
-    def inc(self, amount: float = 1.0) -> None:
+    def inc(self, amount: float = 1.0, *, of_trace: bool = False) -> None:
+        """``of_trace``: the increment records a fact of the TRACE
+        itself (which path the traced program takes), so it is kept
+        under a jit trace, where any other increment is dropped. It
+        counts once PER TRACE of the calling code, not once a compile:
+        a body that ``jax.checkpoint`` or ``lax.scan`` traces again
+        counts again."""
         if not self._registry._enabled:
             return
-        if _tracing(amount):
+        if isinstance(amount, jax.core.Tracer) or (_tracing() and not of_trace):
             return
         if amount < 0:
             raise ValueError(f"counter {self.name}: negative increment {amount}")

@@ -161,10 +161,19 @@ CASES = {
     "flash_fwd_bwd_cell_560m": lambda: _flash(True, CELL_SHAPES["cell_560m"]),
     "flash_fwd_bwd_cell_1b7_tp2": lambda: _flash(True, CELL_SHAPES["cell_1b7_tp2"]),
     # the callers no cell runs, whose index maps clamp by their own rule:
-    # a sliding window (mixtral), no rule at all (albert, ulysses)
+    # a sliding window (mixtral), no rule at all (albert, ulysses). At
+    # 16 heads x 64 two heads share a 128-lane tile (the paired kernels,
+    # as in every case above but the 1b7 cell's); at 128 a head a tile
     "flash_fwd_bwd_window": lambda: _flash(True, (8, 2048, 16, 64), window=700),
     "flash_fwd_bwd_noncausal": lambda: _flash(True, (8, 2048, 16, 64),
                                               causal=False),
+    "flash_fwd_bwd_window_hd128": lambda: _flash(True, (8, 2048, 8, 128),
+                                                 window=700),
+    "flash_fwd_bwd_noncausal_hd128": lambda: _flash(True, (8, 2048, 8, 128),
+                                                    causal=False),
+    # width 64 where the heads do not pair (an odd count): a head a
+    # half-filled tile, the kernels as they were
+    "flash_fwd_bwd_odd_heads_hd64": lambda: _flash(True, (8, 2048, 15, 64)),
     # float32 at width 512: the working set passes a v5e's budget at
     # 1,024 x 1,024 and the backward takes 512 x 512
     "flash_fwd_bwd_f32_w512": lambda: _flash(True, (1, 2048, 16, 512),
@@ -201,6 +210,9 @@ KERNELS = {
     "flash_fwd_bwd_cell_1b7_tp2": ["flash_fwd", "flash_bwd"],
     "flash_fwd_bwd_window": ["flash_fwd", "flash_bwd"],
     "flash_fwd_bwd_noncausal": ["flash_fwd", "flash_bwd"],
+    "flash_fwd_bwd_window_hd128": ["flash_fwd", "flash_bwd"],
+    "flash_fwd_bwd_noncausal_hd128": ["flash_fwd", "flash_bwd"],
+    "flash_fwd_bwd_odd_heads_hd64": ["flash_fwd", "flash_bwd"],
     "flash_fwd_bwd_f32_w512": ["flash_fwd", "flash_bwd"],
     "ring_chunk": ["flash_ring_fwd"],
     "ring_chunk_bwd": ["flash_ring_fwd", "flash_ring_dq", "flash_ring_dkv"],
@@ -244,17 +256,41 @@ def test_kernel_compiles_for_v5e(one_chip, as_default_device, case):
             (name, called)
 
 
+def _flash_calls(text):
+    """The compiled program's Mosaic calls: ``[(instruction name, result
+    shapes, operand shapes, the line)]``."""
+    calls = []
+    for ln in text.splitlines():
+        if " custom-call(" in ln and "tpu_custom_call" in ln:
+            head, _, rest = ln.strip().partition(" custom-call(")
+            name, _, results = head.partition(" = ")
+            operands = rest.split("), custom_call_target")[0]
+            shape = re.compile(r"\b(?:bf16|f32|s32)\[[\d,]*\]")
+            calls.append((name, shape.findall(results),
+                          shape.findall(operands), ln.strip()))
+    return calls
+
+
 @pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
 def test_flash_results_keep_the_shapes_the_roofline_reader_tells_them_by(
         one_chip, as_default_device, cell):
     """``flash_attn_roofline.train`` tells flash kernels apart by their
     results (first ``bf16[rows*heads, seq, head_dim]``; a float32 row
     statistic second = forward, a second tensor = dK/dV, alone = dQ).
-    Compiled at the cell's shape the backward is ONE kernel,
-    ``flash_bwd``, whose results are dQ, dK and dV: the accepted reader
-    takes it for a dK/dV call (four matmuls where it runs five, so its
-    share reads low: PERF.md section 7), the forward for the forward,
-    and loses no call; nothing reads as dQ."""
+    Compiled at a cell's shape the program holds exactly ``flash_fwd``
+    and ``flash_bwd`` (dQ, dK and dV from one kernel).
+
+    ``cell_1b7_tp2`` (a head a 128-lane tile): the accepted reader takes
+    ``flash_bwd`` for a dK/dV call (four matmuls where it runs five, so
+    its share reads low: PERF.md section 7), the forward for the
+    forward, and loses no call; nothing reads as dQ.
+
+    ``cell_560m`` (two heads a tile since PR 49): the kernels take and
+    return the model's own ``bf16[8,2048,1024]``, nothing of that size is
+    transposed or copied between the parameters and the calls, and the
+    reader finds NONE of them: ``flash_attn_roofline.train`` reads
+    ``None`` in that cell (the silence, written down; ``mfu_pct.train``
+    and ``flash_bwd_roofline.train``, by name, go on reading)."""
     import os
 
     from benchmark import harness
@@ -263,18 +299,110 @@ def test_flash_results_keep_the_shapes_the_roofline_reader_tells_them_by(
         os.path.dirname(harness.__file__), "layer_metrics",
         "flash_attn_roofline.train.py"))
     rows, seq, heads, hd = CELL_SHAPES[cell]
-    fn, shapes = _flash(True, CELL_SHAPES[cell])
+    if cell == "cell_560m":
+        # the operands as the model holds them: (rows, seq, heads * hd)
+        plane = (rows, seq, heads * hd)
+
+        def loss(q, k, v, sl):
+            q, k, v = (x.reshape(rows, seq, heads, hd) for x in (q, k, v))
+            return flash_attention(q, k, v, alibi_slopes=sl, interpret=False) \
+                .reshape(plane).astype(jnp.float32).sum()
+
+        fn = jax.grad(loss, argnums=(0, 1, 2))
+        shapes = [(plane, jnp.bfloat16)] * 3 + [((heads,), jnp.float32)]
+    else:
+        fn, shapes = _flash(True, CELL_SHAPES[cell])
     text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
         .compile().as_text()
+    calls = _flash_calls(text)
     kinds = {}
-    for ln in text.splitlines():
-        if " custom-call(" in ln and "tpu_custom_call" in ln:
-            kind = reader.classify(ln.strip(), (rows * heads, seq, hd))
-            kinds.setdefault(kind, []).append(ln.split(" = ")[0].strip())
-    assert sorted(kinds) == ["dkv", "fwd"], kinds
-    assert len(kinds["fwd"]) == 1 and "flash_fwd" in kinds["fwd"][0], kinds
-    assert len(kinds["dkv"]) == 1 and "flash_bwd" in kinds["dkv"][0], kinds
+    for name, _, _, ln in calls:
+        kind = reader.classify(ln, (rows * heads, seq, hd))
+        kinds.setdefault(kind, []).append(name)
     assert "flash_dq" not in text and "flash_dkv" not in text
+    if cell != "cell_560m":
+        assert sorted(kinds) == ["dkv", "fwd"], kinds
+        assert len(kinds["fwd"]) == 1 and "flash_fwd" in kinds["fwd"][0], kinds
+        assert len(kinds["dkv"]) == 1 and "flash_bwd" in kinds["dkv"][0], kinds
+        return
+    assert list(kinds) == [None], kinds
+    assert sorted("flash_fwd" in name for name, *_ in calls) == [False, True]
+    assert sum("flash_bwd" in name for name, *_ in calls) == 1
+    tensor = "bf16[%d,%d,%d]" % plane
+    for name, results, operands, _ in calls:
+        tensors = [s for s in results + operands if s.startswith("bf16")]
+        assert tensors and set(tensors) == {tensor}, (name, tensors)
+    # the reshape of what the model hands over: no array of the plane's
+    # size is moved on the way in or out, and none is 64 wide
+    moved = [ln.strip()[:160] for ln in text.splitlines()
+             if re.search(r" (copy|transpose)\(", ln)
+             and re.search(r"= \w+\[([\d,]*)\]", ln)
+             and math.prod(int(d) for d in re.search(
+                 r"= \w+\[([\d,]*)\]", ln).group(1).split(",") if d)
+             >= math.prod(plane)]
+    assert not moved, moved
+    assert "bf16[%d,%d,%d]" % (rows * heads, seq, hd) not in text
+    assert ",%d,%d]" % (heads, hd) not in text
+
+
+def test_remat_block_at_the_560m_shape_holds_no_plane_heads_over_positions(
+        one_chip, as_default_device, monkeypatch):
+    """The gradient of ONE checkpointed bloom-560m block (8 rows x 2,048
+    positions x 16 heads of 64), compiled for the described v5e: what the
+    train step's layer loop runs twice a layer. With a head a half-filled
+    tile the compiler held q, k, v and the kernels' results heads over
+    positions, ``bf16[8,16,2048,64]`` and ``bf16[128,2048,64]``, padded to
+    twice their bytes, and copied a plane nine times on the way in and
+    out (PERF.md, PR 49, step 0). Now: one ``flash_fwd`` (its result and
+    lse saved, not run again) and one ``flash_bwd``, every tensor they
+    touch the model's own ``bf16[8,2048,1024]`` row-major; NO array of a
+    plane's size has the positions before a 64-wide last dimension, and
+    the program's temporaries fall from 0.548 to 0.375 GB.
+
+    What this PR leaves (ROADMAP A8 b): BLOOM's interleaved projection is
+    still taken apart as ``(8, 2048, 16, 3, 64)``, sequence-minor, at a
+    plane's copy a use. What stays sequence-minor (``{1,2,0}``) among the
+    hidden-1024 arrays around the norms and the 1,024-wide matmuls is the
+    compiler's own choice, the parent's and the change's alike."""
+    from functools import partial
+
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    rows, seq, heads, hd = CELL_SHAPES["cell_560m"]
+    hidden = heads * hd
+    cfg = bloom.BloomConfig(vocab_size=1024, hidden_size=hidden, n_layer=1,
+                            n_head=heads, remat=True, use_flash=True,
+                            dtype=jnp.bfloat16)
+    params = jax.eval_shape(lambda: bloom.init_params(
+        cfg, jax.random.PRNGKey(0)))
+    blk = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype, sharding=one_chip),
+        params["blocks"])
+    x = jax.ShapeDtypeStruct((rows, seq, hidden), jnp.bfloat16,
+                             sharding=one_chip)
+    mask = jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=one_chip)
+    block = bloom._remat_wrap(
+        partial(bloom._block, config=cfg, tp_axis=None), cfg)
+
+    def loss(blk, x, mask):
+        return block(blk, x, bloom.attention_bias(mask, cfg)) \
+            .astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        blk, x, mask).compile()
+    text = compiled.as_text()
+    calls = _flash_calls(text)
+    assert sorted(("flash_fwd" in n, "flash_bwd" in n) for n, *_ in calls) \
+        == [(False, True), (True, False)], [n for n, *_ in calls]
+    plane = "bf16[%d,%d,%d]" % (rows, seq, hidden)
+    for name, results, operands, ln in calls:
+        tensors = [s for s in results + operands if s.startswith("bf16")]
+        assert set(tensors) == {plane}, (name, tensors)
+        assert plane + "{2,1,0" in ln
+    for dims in re.findall(r"= \w+\[([\d,]+)\]\{", text):
+        dims = [int(d) for d in dims.split(",")]
+        if math.prod(dims) >= rows * seq * hidden:
+            assert dims[-2:] != [seq, hd], dims
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.40e9
 
 
 # width 256 at 4,096 positions is GLM-4.7-Flash's (its other kernels
@@ -312,11 +440,17 @@ WORKING_SETS = {
     "f32_w256": (2048, 256, jnp.float32, None),
     "f32_w512": (2048, 512, jnp.float32, None),
     "bf16_w64_128x512": (2048, 64, jnp.bfloat16, (128, 512)),
+    # two heads of 64 a 128-lane tile, counted at the tile's width; its
+    # kernels are the forward and the one-kernel backward
+    "bf16_paired_w64": (2048, 64, jnp.bfloat16, None),
 }
+PAIRED_SETS = {"bf16_paired_w64"}
 
 
-@pytest.mark.parametrize("kind", ["fwd", "dq", "dkv", "bwd"])
-@pytest.mark.parametrize("case", sorted(WORKING_SETS))
+@pytest.mark.parametrize("case,kind", [
+    (case, kind) for case in sorted(WORKING_SETS)
+    for kind in ["fwd", "dq", "dkv", "bwd"]
+    if case not in PAIRED_SETS or kind in ("fwd", "bwd")])
 def test_working_set_bounds_what_the_compiler_needs(one_chip, monkeypatch,
                                                     case, kind):
     """``_working_set_bytes`` against the compiler: given exactly the
@@ -325,13 +459,29 @@ def test_working_set_bounds_what_the_compiler_needs(one_chip, monkeypatch,
     operand is small enough for XLA to keep it in VMEM whole."""
     seq, hd, dtype, blocks = WORKING_SETS[case]
     itemsize = jnp.dtype(dtype).itemsize
-    bq, bk = blocks or fa._pick_blocks(seq, hd, itemsize, kind, V5E_LIMIT)
-    counted = fa._working_set_bytes(kind, bq, bk, hd, itemsize, seq)
+    paired = case in PAIRED_SETS
+    width, counted_as = (128, kind + "_paired") if paired else (hd, kind)
+    bq, bk = blocks or fa._pick_blocks(seq, width, itemsize, counted_as,
+                                       V5E_LIMIT)
+    counted = fa._working_set_bytes(counted_as, bq, bk, width, itemsize, seq)
     monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: counted)
     x, row, sl = ((16, seq, hd), dtype), ((16, seq), jnp.float32), \
         ((16,), jnp.float32)
+    if paired:
+        # one batch row of sixteen heads: the tensors as the model holds
+        # them, a per-key row a batch row, a float32 row a head
+        x, krow = ((1, seq, 16 * hd), dtype), ((1, seq), jnp.float32)
     rule = (hd ** -0.5, True, bq, bk, False)
-    if kind == "fwd":
+    if paired and kind == "fwd":
+        fn = lambda q, k, v, s, kp, kn: fa._flash_fwd_paired_pallas(  # noqa: E731
+            q, k, v, s, kp, kn, *rule)
+        shapes = [x, x, x, sl, krow, krow]
+    elif paired:
+        fn = lambda q, k, v, do, out, lse, s, kp, kn: (  # noqa: E731
+            fa._flash_bwd_paired_pallas(q, k, v, do, out, lse, s, kp, kn,
+                                        *rule))
+        shapes = [x, x, x, x, x, row, sl, krow, krow]
+    elif kind == "fwd":
         fn = lambda q, k, v, s, kp, kn: fa._flash_fwd_pallas(  # noqa: E731
             q, k, v, s, kp, kn, *rule)
         shapes = [x, x, x, sl, row, row]

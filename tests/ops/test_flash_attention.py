@@ -183,6 +183,57 @@ def test_bloom_with_flash_matches_plain():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("tp", [1, 2])
+def test_bloom_flash_at_head_width_64_matches_plain(tp):
+    """Heads of 64 (bloom-560m's width): the kernels take q, k and v as
+    ``(B, S, heads * 64)``, two heads a tile, through the model's
+    unchanged ``flash_attention(q, k, v, ...)`` call; loss and every
+    parameter's gradient equal the plain path's, on one device and with
+    the heads sharded over two."""
+    import dataclasses
+
+    from jax.flatten_util import ravel_pytree
+    from jax.sharding import PartitionSpec as P
+
+    from pipegoose_tpu.distributed import ParallelContext
+    from pipegoose_tpu.distributed.compat import shard_map
+    from pipegoose_tpu.models import bloom
+
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=256, n_layer=2, n_head=4)
+    cfg_f = dataclasses.replace(cfg, use_flash=True)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 32)))
+    mask = np.ones((2, 32), np.int32)
+    mask[0, 20:] = 0
+    mask = jnp.asarray(mask)
+    ref_loss, ref_g = jax.value_and_grad(bloom.loss_fn)(params, ids, mask, ids, cfg)
+
+    def step(axis):
+        return jax.value_and_grad(lambda p, i, m: bloom.loss_fn(
+            p, i, m, i, cfg_f, tp_axis=axis))
+
+    jaxpr = jax.make_jaxpr(step(None))(params, ids, mask)
+    for name, calls in _kernel_operands(jaxpr).items():
+        assert all(c[1:4] == [(2, 32, 256)] * 3 for c in calls), (name, calls)
+    if tp == 1:
+        out_loss, out_g = step(None)(params, ids, mask)
+    else:
+        ctx = ParallelContext(tensor_parallel_size=2, data_parallel_size=4)
+        try:
+            specs = bloom.tp_specs(params)
+            out_loss, out_g = shard_map(
+                step("tensor"), mesh=ctx.mesh, in_specs=(specs, P(), P()),
+                out_specs=(P(), specs), check_vma=False)(params, ids, mask)
+        finally:
+            ctx.destroy()
+    np.testing.assert_allclose(float(out_loss), float(ref_loss), rtol=2e-4)
+    flat_r, _ = ravel_pytree(ref_g)
+    flat_o, _ = ravel_pytree(out_g)
+    assert np.isfinite(np.asarray(flat_o)).all()
+    np.testing.assert_allclose(np.asarray(flat_o), np.asarray(flat_r),
+                               rtol=5e-3, atol=1e-4)
+
+
 @pytest.mark.parametrize("family", ["llama", "mixtral"])
 def test_rope_family_flash_matches_plain(family):
     """use_flash=True for the RoPE families (zero ALiBi slopes, padding
@@ -410,8 +461,12 @@ def test_vmem_limit_is_the_compilers_default_where_no_tpu_is_attached():
     assert fa._vmem_limit_bytes() == DEFAULT_LIMIT
 
 
+# the kernels that take two heads of 64 as one 128-lane tile
+PAIRED_KINDS = ("fwd_paired", "bwd_paired")
+
+
 @pytest.mark.parametrize("limit", [DEFAULT_LIMIT, 32 * 2**20, V5E_LIMIT])
-@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("kind", KINDS + PAIRED_KINDS)
 @pytest.mark.parametrize("width", [64, 96, 128, 256, 512, 1024, 2048])
 def test_pick_blocks_divide_the_sequence_inside_the_vmem_budget(
         kind, width, limit):
@@ -460,6 +515,11 @@ def test_pick_blocks_at_the_cells_shapes(seq, width):
     # on a v5e the one-kernel backward runs at all three (``_flash_bwd``)
     assert fa._working_set_bytes("bwd", 1024, 1024, width, 2, seq) \
         <= V5E_LIMIT * 3 // 4
+    if width == 64:
+        # two heads a tile: the paired kernels, at the same blocks
+        for kind in PAIRED_KINDS:
+            assert fa._pick_blocks(seq, 128, 2, kind, V5E_LIMIT) == (1024, 1024)
+        assert fa._pairs_heads(seq, 16, width, 1, 2) is True
 
 
 # -- which backward runs: a fact of the shape and the device's VMEM ---------
@@ -562,6 +622,7 @@ def test_a_shape_that_falls_back_to_the_pair_has_the_same_gradients(
 
 # name: (heads, kv heads, head width, window)
 REMAT_CASES = {
+    # the first and the third take the paired layout (PR 49)
     "mha_hd64": (4, 4, 64, None),
     "gqa2": (4, 2, 64, None),
     "window": (4, 4, 64, 48),
@@ -610,6 +671,16 @@ def test_a_checkpointed_block_keeps_the_kernels_residuals(case):
     for name, j in jaxprs.items():
         assert (kernel_calls(j, "flash_bwd"), kernel_calls(j, "flash_dq"),
                 kernel_calls(j, "flash_dkv")) == (2, 0, 0), name
+    # what the policy keeps of one block: the kernel's two residuals,
+    # the result in the layout the call took (two heads of 64 a
+    # 128-lane tile where they pair, a head a row otherwise)
+    paired = hd == 64 and nh == nkv and nh % 2 == 0
+    kept = saved_residuals(
+        lambda w, x: jax.checkpoint(block, policy=fa.remat_policy())(w, x).sum(),
+        jax.tree_util.tree_map(lambda a: a[0], ws), x)
+    assert (f"f32[{b * nh},{s}]", "named 'flash_lse'") in kept
+    out = f"f32[{b},{s},{nh * hd}]" if paired else f"f32[{b * nh},{s},{hd}]"
+    assert out in {shape for shape, _ in kept}, kept
     want = grads["plain"](ws, x)
     for name in ("kept", "bare"):
         got = grads[name](ws, x)
@@ -639,9 +710,228 @@ def test_remat_policy_keeps_what_the_callers_policy_keeps():
     alone = kept(fa.remat_policy())
     assert ("f32[2,64]", "named 'flash_lse'") in alone
     # ``out`` goes on into the block, so jax reports the rounding it
-    # puts on a saved value that is also used, not the name
-    assert {shape for shape, _ in alone} == {"f32[2,64]", "f32[2,64,64]"}
+    # puts on a saved value that is also used, not the name; two heads
+    # of 64 are one 128-lane tile, kept as the model holds it
+    assert {shape for shape, _ in alone} == {"f32[2,64]", "f32[1,64,128]"}
     both = kept(fa.remat_policy(
         jax.checkpoint_policies.save_only_these_names("mine")))
     assert alone < both
     assert {shape for shape, _ in both - alone} == {"f32[1,64,2,64]"}
+
+
+# -- two heads a 128-lane tile (head width 64) -------------------------------
+#
+# Where two heads fill one tile ``flash_attention`` hands the kernels
+# ``(B, S, nh * 64)`` arrays, a pair of heads a grid row (PERF.md, PR
+# 49). The cases go through the public call at explicit small blocks, so
+# one program meets skipped, whole and crossed blocks; the dispatch is a
+# fact of the call's shapes, read off the jaxpr.
+
+def _flat(x):
+    b, s, h, hd = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * h, s, hd)
+
+
+def _todays_path(q, k, v, slopes, kpos, kneg, causal=True, window=None):
+    """``flash_attention`` as it ran before the paired layout, for every
+    shape: heads over positions, ``_flash``, and back."""
+    b, s, nh, hd = q.shape
+    nkv = k.shape[2]
+    sl = jnp.broadcast_to(slopes[None], (b, nh)).reshape(b * nh)
+
+    def rows(x):
+        return jnp.broadcast_to(x.astype(jnp.float32)[:, None, :],
+                                (b, nkv, s)).reshape(b * nkv, s)
+
+    out = fa._flash(_flat(q), _flat(k), _flat(v), sl.astype(jnp.float32),
+                    rows(kpos), rows(kneg), float(hd ** -0.5), causal, True,
+                    nh // nkv, window)
+    return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
+
+
+def _kernel_operands(jaxpr):
+    """Operand shapes of the jaxpr's flash kernels, by kernel name."""
+    found = {}
+
+    def walk(j):
+        for eqn in getattr(j, "jaxpr", j).eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.setdefault(eqn.params["name"], []).append(
+                    [v.aval.shape for v in eqn.invars])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr)
+    return found
+
+
+# name: (rows, seq, heads, causal, alibi, left padding, window, dtype, blocks)
+PAIRED_CASES = {
+    "causal_alibi_2h_64x128": (2, 512, 2, True, True, False, None, jnp.float32, (64, 128)),
+    "causal_alibi_16h_128x64": (1, 256, 16, True, True, False, None, jnp.float32, (128, 64)),
+    "left_padded_2h_64x128": (2, 512, 2, True, True, True, None, jnp.float32, (64, 128)),
+    "left_padded_16h_128x64": (2, 256, 16, True, True, True, None, jnp.float32, (128, 64)),
+    "window_2h_64x128": (2, 512, 2, True, True, False, 160, jnp.float32, (64, 128)),
+    "window_4h_128x64_padded": (1, 512, 4, True, False, True, 160, jnp.float32, (128, 64)),
+    "noncausal_2h_64x128": (2, 256, 2, False, False, False, None, jnp.float32, (64, 128)),
+    "noncausal_window_4h_128x64": (1, 512, 4, False, False, True, 96, jnp.float32, (128, 64)),
+    "bf16_causal_alibi_2h_64x128": (2, 512, 2, True, True, False, None, jnp.bfloat16, (64, 128)),
+    "bf16_left_padded_16h_128x64": (1, 256, 16, True, True, True, None, jnp.bfloat16, (128, 64)),
+    "picked_blocks_4h": (1, 2048, 4, True, True, False, None, jnp.float32, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAIRED_CASES))
+def test_paired_heads_match_the_dense_reference(monkeypatch, case):
+    b, s, nh, causal, alibi, padded, window, dtype, blocks = PAIRED_CASES[case]
+    hd = 64
+    if blocks:
+        monkeypatch.setattr(fa, "_pick_blocks", lambda *a: blocks)
+    ks = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q, k, v, ct = (jax.random.normal(kk, (b, s, nh, hd)).astype(dtype)
+                   for kk in ks)
+    slopes = jnp.asarray(alibi_slopes(nh)) if alibi else jnp.zeros(nh)
+    valid = np.ones((b, s), np.float32)
+    if padded:
+        valid[0, :75] = 0.0  # left padding, not on a block's edge
+    kpos, kneg = fa.mask_to_kv_bias(jnp.asarray(valid))
+    # a model's loss masks the padded queries: their cotangent is zero
+    ct = ct * jnp.asarray(valid)[:, :, None, None].astype(dtype)
+
+    def fn(q, k, v):
+        return flash_attention(q, k, v, slopes, kv_pos=kpos, kv_neg=kneg,
+                               causal=causal, window=window, interpret=True)
+
+    ran = _kernel_operands(jax.make_jaxpr(
+        lambda q, k, v: jax.vjp(fn, q, k, v)[1](ct))(q, k, v))
+    assert sorted(ran) == ["flash_bwd", "flash_fwd"], ran
+    for name, calls in ran.items():
+        assert len(calls) == 1
+        # slopes, then the tensors as the model holds them; no
+        # (rows * heads, seq, 64) operand anywhere
+        assert calls[0][1:4] == [(b, s, nh * hd)] * 3, (name, calls[0])
+        assert (b * nh, s, hd) not in calls[0]
+
+    def dense(q, k, v):
+        out = _dense(_flat(q), _flat(k), _flat(v), jnp.tile(slopes, b),
+                     hd ** -0.5, causal, window, jnp.repeat(kpos, nh, axis=0),
+                     jnp.repeat(kneg, nh, axis=0), 1)
+        return out.reshape(b, nh, s, hd).transpose(0, 2, 1, 3)
+
+    out, vjp = jax.vjp(fn, q, k, v)
+    ref, ref_vjp = jax.vjp(dense, q, k, v)
+    if dtype == jnp.float32:
+        fwd_tol = dict(rtol=2e-5, atol=2e-8 * s)
+        grad_tol = dict(rtol=1e-4, atol=4e-7 * s)
+    else:
+        fwd_tol = grad_tol = dict(rtol=3e-2, atol=3e-2)
+    rows = np.asarray(valid, bool)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[rows],
+                               np.asarray(ref, np.float32)[rows], **fwd_tol)
+    grads = vjp(ct)
+    for got, want, name in zip(grads, ref_vjp(ct), ("dq", "dk", "dv")):
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all(), name
+        np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                                   err_msg=f"{case}: {name}", **grad_tol)
+    if dtype == jnp.float32 and blocks:
+        # per head the one-head kernels' arithmetic at the same blocks:
+        # the same sums in the same order
+        old, old_vjp = jax.vjp(lambda q, k, v: _todays_path(
+            q, k, v, slopes, kpos, kneg, causal, window), q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(old),
+                                   rtol=1e-6, atol=1e-6)
+        for got, want in zip(grads, old_vjp(ct)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-5, atol=1e-5)
+
+
+# name: (heads, kv heads, head width): an odd head count, GQA at width
+# 64, width 128, and width 32 (four heads would fill a tile: not paired)
+UNPAIRED_CASES = {
+    "odd_heads_hd64": (3, 3, 64),
+    "gqa2_hd64": (4, 2, 64),
+    "hd128": (2, 2, 128),
+    "hd32": (4, 4, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNPAIRED_CASES))
+def test_a_shape_that_does_not_pair_takes_todays_path(case):
+    """Such a call lowers to the text of the path as it was (heads over
+    positions, ``_flash``, and back) and has its gradients."""
+    nh, nkv, hd = UNPAIRED_CASES[case]
+    b, s = 2, 128
+    ks = jax.random.split(jax.random.PRNGKey(49), 4)
+    q, ct = (jax.random.normal(kk, (b, s, nh, hd)) for kk in ks[:2])
+    k, v = (jax.random.normal(kk, (b, s, nkv, hd)) for kk in ks[2:])
+    slopes = jnp.asarray(alibi_slopes(nh))
+    mask = jnp.asarray(np.arange(s) >= 21, jnp.int32)[None].repeat(b, 0)
+    kpos, kneg = fa.mask_to_kv_bias(mask)
+
+    def new(q, k, v, slopes, kpos, kneg):
+        return flash_attention(q, k, v, slopes, kv_pos=kpos, kv_neg=kneg,
+                               interpret=True)
+
+    args = (q, k, v, slopes, kpos, kneg)
+
+    def grads(fn):
+        return lambda *a: jax.vjp(lambda q, k, v: fn(q, k, v, *a[3:6]),
+                                  *a[:3])[1](a[6])
+
+    def lowered(fn):
+        text = jax.jit(grads(fn)).lower(*args, ct).as_text()
+        # locations aside
+        return [ln.split(" loc(")[0] for ln in text.splitlines()
+                if not ln.startswith("#loc")]
+
+    assert lowered(new) == lowered(_todays_path)
+    for got, want in zip(grads(new)(*args, ct), grads(_todays_path)(*args, ct)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_a_paired_call_that_cannot_keep_dqs_accumulator_takes_todays_path(
+        monkeypatch):
+    """The choice holds for forward and backward alike: where the
+    one-kernel backward does not fit at the paired blocks, the forward
+    is today's too."""
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: 256 * 2**10)
+    x = jax.ShapeDtypeStruct((1, 192, 2, 64), jnp.float32)
+    ran = _kernel_operands(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: flash_attention(q, k, v, None, interpret=True).sum(),
+        argnums=(0, 1, 2)))(x, x, x))
+    assert sorted(ran) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    assert ran["flash_fwd"][0][1] == (2, 192, 64)
+
+
+def test_flash_calls_are_counted_when_traced():
+    """``flash.calls`` and ``flash.paired_calls`` count TRACED calls: the
+    layout is a property of the compiled program, so running a compiled
+    step again counts nothing."""
+    from pipegoose_tpu.telemetry.registry import get_registry
+
+    reg = get_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        def counts():
+            return (reg.counter("flash.calls").value,
+                    reg.counter("flash.paired_calls").value)
+
+        start = counts()
+        paired = jax.jit(lambda q: flash_attention(q, q, q, interpret=True))
+        q = jnp.ones((1, 64, 2, 64))
+        paired(q)
+        assert counts() == (start[0] + 1, start[1] + 1)
+        paired(q)  # compiled: nothing is traced
+        assert counts() == (start[0] + 1, start[1] + 1)
+        jax.make_jaxpr(lambda q: flash_attention(q, q, q, interpret=True))(
+            jnp.ones((1, 64, 3, 64)))  # an odd head count
+        assert counts() == (start[0] + 2, start[1] + 1)
+        # a gradient traces the call once: one layout for both passes
+        jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, q, q, interpret=True).sum()))(q)
+        assert counts() == (start[0] + 3, start[1] + 2)
+    finally:
+        if not was:
+            reg.disable()

@@ -160,6 +160,30 @@ def test_tracer_and_trace_time_mutation_noop(reg):
     assert h.count == 0
 
 
+def test_an_increment_of_the_trace_counts_once_a_trace(reg):
+    """``inc(of_trace=True)`` is kept under a jit trace (a fact of the
+    traced program: once per trace, nothing on later executions of the
+    compiled program); a tracer amount and a disabled registry still
+    record nothing."""
+    c = reg.counter("jit.paths")
+
+    @jax.jit
+    def f(x):
+        c.inc(of_trace=True)
+        c.inc(x[0], of_trace=True)   # a tracer is never an amount
+        return x * 2
+
+    for _ in range(3):
+        f(jnp.arange(4.0))
+    assert c.value == 1.0
+    f(jnp.arange(8.0))               # another shape: another compile
+    assert c.value == 2.0
+    reg.disable()
+    jax.jit(lambda x: (c.inc(of_trace=True), x)[1])(jnp.arange(2.0))
+    assert c.value == 2.0
+    reg.enable()
+
+
 def test_snapshot_and_prometheus_render(reg):
     reg.counter("a.total", help="things").inc(3)
     reg.gauge("b.depth").set(2.0)
