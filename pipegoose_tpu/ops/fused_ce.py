@@ -8,9 +8,14 @@ kernel computes the loss STRAIGHT from (hidden, embedding) with an
 online log-sum-exp over vocab tiles — the full logits tensor never
 exists in HBM, forward or backward:
 
-- forward: grid (token_blocks, vocab_blocks), vocab sequential; per
-  token-block scratch carries the online (max, sumexp, target-logit)
-  triple; emits per-token local ``lse`` and ``target_logit``.
+- forward: ONE kernel (``fused_ce_fwd``), grid (token super-block,
+  vocabulary tile), vocabulary sequential; the super-block's hidden
+  states and its per-token online (max, sumexp, target-logit) triple
+  stay in VMEM while the vocabulary is walked, a grid step takes the
+  super-block a token tile at a time against one weight tile; emits
+  per-token local ``lse`` and ``target_logit``. Its tiles come from the
+  operands' shapes and the device's VMEM (``_pick_fwd_plan``), not from
+  a caller.
 - backward: dlogits = softmax - onehot is rematerialized tile-by-tile
   from the saved GLOBAL lse (Megatron's analytic CE backward, reference
   loss.py:71-89, without ever holding more than one (BT, BV) tile), in
@@ -54,6 +59,15 @@ def _resolve_interpret(interpret):
     return interpret
 
 
+def _token_block(tokens: int, cap: int) -> int:
+    """Token tile: the least power of two (>= 8) that holds ``tokens``,
+    at most ``cap`` (tokens are padded up to it)."""
+    block = 8
+    while block < min(tokens, cap):
+        block *= 2
+    return min(block, cap)
+
+
 def _pick_block(n: int, target: int):
     """Largest halving of ``target`` (>= 8) dividing ``n``. Returns
     ``(block, exact)`` — ``exact=False`` means NO such divisor exists
@@ -68,86 +82,223 @@ def _pick_block(n: int, target: int):
     return n, False
 
 
-def _fwd_pallas(h, w, targets, offset, valid, block_t, block_v, interpret,
-                vh):
+# tokens a matmul tile of the forward: the (tile, vocabulary tile) float32
+# logits and what is formed from them are the kernel's temporaries, so
+# the tile stays small and the vocabulary tile takes the VMEM
+_FWD_BLOCK_T = 256
+# tokens the forward aims to hold while the vocabulary is walked: at 256
+# the head's reads take as long as the matmul at a v5e's peaks (the
+# kernel on both rooflines at once), at 1,024 a quarter of it. Alone on
+# the chip the reads hid under the matmul even at 256 (PERF.md, PR 50):
+# the mark buys margin, the time comes from the vocabulary tile
+_FWD_RESIDENT_TOKENS = 1024
+# vocabulary rows a tile at most, and what the picker takes as "enough"
+# when it weighs a larger tile against more resident tokens: the cost of
+# the per-token columns falls as 1 / tile and is within 1% of its floor
+# past ~2,048 (560m shape: 55.0 ms at 1,024 rows, 49.2 at 2,560, 48.8
+# at 3,584)
+_FWD_MAX_BLOCK_V = 4096
+_FWD_ENOUGH_BLOCK_V = 2048
+_LANES = 128
+
+
+def _fwd_vocab_tiles(v_loc: int):
+    """Vocabulary tiles the forward may take, ascending: the divisors of
+    the shard's rows that are whole lane tiles of the logits (multiples
+    of 128) up to ``_FWD_MAX_BLOCK_V``; where there is none, the
+    multiples of 8 (a sublane tile of the weight). Empty where 8 does
+    not divide the shard: no tile a compiled kernel can take."""
+    for unit in (_LANES, 8):
+        tiles = [d for d in range(unit, min(v_loc, _FWD_MAX_BLOCK_V) + 1, unit)
+                 if v_loc % d == 0]
+        if tiles:
+            return tiles
+    return []
+
+
+def _fwd_working_set_bytes(super_t: int, block_t: int, block_v: int,
+                           hidden: int, itemsize: int) -> int:
+    """VMEM the forward kernel holds, by its own arithmetic: the resident
+    tokens' ``h`` and the weight tile, both pipelined (twice); a token
+    tile's and the weight tile's float32 casts; the (BT, BV) float32
+    tiles of logits, ``p`` and the two selects; the per-token columns
+    (running max, sum, target logit, target id: a (N, 1) column takes a
+    lane tile a sublane) and the three pipelined (1, N) rows (targets
+    in, ``lse`` and the target logit out: 8 sublanes each). An upper
+    bound, held to the chip's compiler by tests/ops/test_chip_compile.py."""
+    return (2 * super_t * hidden * itemsize + 2 * block_v * hidden * itemsize
+            + (block_t + block_v) * hidden * 4
+            + 4 * block_t * block_v * 4
+            + 4 * super_t * _LANES * 4 + 3 * 2 * 8 * super_t * 4)
+
+
+def _pick_fwd_plan(tokens: int, v_loc: int, hidden: int, itemsize: int,
+                   vmem_limit_bytes: int):
+    """``(block_t, token tiles resident, super-blocks, block_v)`` of the
+    forward from the operands' shape and the scoped VMEM the kernel will
+    ask for; where ``_fwd_vocab_tiles`` has no tile for the shard, the
+    whole of it is one (the interpreter's alone).
+
+    Two things are paid beside the matmul. The head is read once a
+    super-block, so its bytes fall with the RESIDENT tokens (at 256 as
+    long as the matmul, at 1,024 a quarter of it; on a v5e they hide
+    under the matmul either way). The running max, sum and target logit
+    are (N, 1) columns, one lane of 128 in use, touched once a (token,
+    vocabulary tile), and a grid step's fixed cost is paid once a
+    (super-block, vocabulary tile): both fall with the VOCABULARY tile,
+    and they were the whole ~0.8 us a step the caller's 256 x 512 tile
+    lost (PERF.md, PR 50). The token tile inside a step stays
+    ``_FWD_BLOCK_T``: it sizes the float32 temporaries and buys nothing
+    larger. So, from
+    one token tile and the smallest vocabulary tile, whichever of the two
+    still fits three quarters of the limit (``_fwd_working_set_bytes``)
+    grows: the resident tokens by doubling up to ``_MAX_SUPER_TOKENS``,
+    the vocabulary tile along ``_fwd_vocab_tiles``; where both fit, the
+    one further below its mark (``_FWD_RESIDENT_TOKENS``,
+    ``_FWD_ENOUGH_BLOCK_V``). Then the tiles are evened out over the
+    super-blocks, so that the tokens are padded by less than a tile
+    each."""
+    block_t = _token_block(tokens, _FWD_BLOCK_T)
+    nt = -(-tokens // block_t)
+    tiles = _fwd_vocab_tiles(v_loc)
+    if not tiles:  # the whole shard as one tile: the interpreter's
+        return block_t, 1, nt, v_loc
+    budget = vmem_limit_bytes * 3 // 4
+
+    def fits(ni, bv):
+        return _fwd_working_set_bytes(ni * block_t, block_t, bv, hidden,
+                                      itemsize) <= budget
+
+    ni, k = 1, 0
+    while True:
+        more_t = (ni < nt and 2 * ni * block_t <= _MAX_SUPER_TOKENS
+                  and fits(2 * ni, tiles[k]))
+        more_v = k + 1 < len(tiles) and fits(ni, tiles[k + 1])
+        if not (more_t or more_v):
+            break
+        if more_t and (not more_v or ni * block_t * _FWD_ENOUGH_BLOCK_V
+                       < tiles[k] * _FWD_RESIDENT_TOKENS):
+            ni *= 2
+        else:
+            k += 1
+    n_super = -(-nt // ni)
+    return block_t, -(-nt // n_super), n_super, tiles[k]
+
+
+def _fwd_pallas(h, w, targets, offset, valid, interpret, vh):
+    """Per-token ``(lse, target logit)`` of the LOCAL shard. Grid (token
+    super-block, vocabulary tile), vocabulary sequential: the
+    super-block's ``h`` and its per-token columns (running max, sum,
+    target logit) stay in VMEM while the vocabulary is walked, so the
+    head is read once a super-block; inside a grid step a loop takes the
+    super-block a token tile at a time against the one weight tile, so
+    the float32 logits never pass (block_t, block_v). The plan is the
+    kernel's own (``_pick_fwd_plan``)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    from pipegoose_tpu.telemetry.registry import get_registry
+
     t_tot, hd = h.shape
     v_loc = w.shape[0] if vh else w.shape[1]
-    nt, nv = t_tot // block_t, v_loc // block_v
+    limit = flash_attention._vmem_limit_bytes()
+    block_t, ni, n_super, block_v = _pick_fwd_plan(
+        t_tot, v_loc, hd, h.dtype.itemsize, limit)
+    super_t, nv = ni * block_t, v_loc // block_v
+    held = _fwd_working_set_bytes(super_t, block_t, block_v, hd,
+                                  h.dtype.itemsize)
+    # counted per TRACE: the plan is a property of the traced program
+    registry = get_registry()
+    registry.counter("fused_ce.fwd_calls").inc(of_trace=True)
+    registry.gauge("fused_ce.fwd_grid_steps").set(n_super * nv, of_trace=True)
+    registry.gauge("fused_ce.fwd_head_walks").set(n_super, of_trace=True)
+    registry.gauge("fused_ce.fwd_vmem_bytes").set(held, of_trace=True)
+    pad = n_super * super_t - t_tot
+    if pad:  # their lse and target logit are cut off below
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        targets = jnp.pad(targets, (0, pad))
 
     def kernel(off_ref, h_ref, w_ref, t_ref, lse_ref, tl_ref,
-               m_sc, l_sc, t_sc):
+               m_sc, l_sc, t_sc, id_sc):
         vi = pl.program_id(1)
 
         @pl.when(vi == 0)
-        def _init():
-            m_sc[:] = jnp.full_like(m_sc, NEG_INF)
-            l_sc[:] = jnp.zeros_like(l_sc)
-            t_sc[:] = jnp.zeros_like(t_sc)
+        def _enter_super_block():
+            m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+            l_sc[...] = jnp.zeros_like(l_sc)
+            t_sc[...] = jnp.zeros_like(t_sc)
+            id_sc[...] = t_ref[0][:, None]  # targets as a column, once
 
-        hb = h_ref[...].astype(jnp.float32)  # (BT, H)
-        wb = w_ref[...].astype(jnp.float32)  # (BV, H) | (H, BV)
-        logits = jax.lax.dot_general(
-            hb, wb, (((1,), (1,) if vh else (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # (BT, BV)
-        col = off_ref[0] + vi * block_v + jax.lax.broadcasted_iota(
-            jnp.int32, (block_t, block_v), 1
-        )
-        if valid is not None:
-            logits = jnp.where(col < valid, logits, NEG_INF)
-        tb = t_ref[0]  # (BT,) int32
-        hit = tb[:, None] == col
-        t_sc[:, 0] += jnp.where(hit, logits, 0.0).sum(axis=1)
+        # a tile's columns are local: the global column of lane c is
+        # first + c, so col < valid is c < valid - first and the target
+        # sits at lane target - first
+        first = off_ref[0] + vi * block_v
+        lane = jax.lax.broadcasted_iota(jnp.int32, (block_t, block_v), 1)
 
-        m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, logits.max(axis=1))
-        p = jnp.exp(logits - m_new[:, None])
-        l_sc[:, 0] = l_sc[:, 0] * jnp.exp(m_prev - m_new) + p.sum(axis=1)
-        m_sc[:, 0] = m_new
+        def token_tile(i, carry):
+            rows = pl.ds(pl.multiple_of(i * block_t, block_t), block_t)
+            hb = h_ref[rows, :].astype(jnp.float32)  # (BT, H)
+            wb = w_ref[...].astype(jnp.float32)      # (BV, H) | (H, BV)
+            logits = jax.lax.dot_general(
+                hb, wb, (((1,), (1,) if vh else (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (BT, BV)
+            if valid is not None:
+                logits = jnp.where(lane < valid - first, logits, NEG_INF)
+            hit = lane == id_sc[rows, :] - first
+            t_sc[rows, :] += jnp.where(hit, logits, 0.0).sum(
+                axis=1, keepdims=True)
+            m_prev = m_sc[rows, :]
+            m_new = jnp.maximum(m_prev, logits.max(axis=1, keepdims=True))
+            p = jnp.exp(logits - m_new)
+            l_sc[rows, :] = (l_sc[rows, :] * jnp.exp(m_prev - m_new)
+                             + p.sum(axis=1, keepdims=True))
+            m_sc[rows, :] = m_new
+            return carry
+
+        jax.lax.fori_loop(0, ni, token_tile, 0)
 
         @pl.when(vi == nv - 1)
-        def _finish():
-            lse_ref[0] = m_sc[:, 0] + jnp.log(jnp.maximum(l_sc[:, 0], 1e-30))
-            tl_ref[0] = t_sc[:, 0]
+        def _leave_super_block():
+            lse = m_sc[...] + jnp.log(jnp.maximum(l_sc[...], 1e-30))
+            lse_ref[0] = lse[:, 0]
+            tl_ref[0] = t_sc[...][:, 0]
 
+    row = pl.BlockSpec((1, super_t), lambda s, j: (0, s))
+    column = pltpu.VMEM((super_t, 1), jnp.float32)
     lse, tl = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=0,
-            grid=(nt, nv),
+            grid=(n_super, nv),
             in_specs=[
-                pl.BlockSpec((1,), lambda i, j: (0,),
+                pl.BlockSpec((1,), lambda s, j: (0,),
                              memory_space=pltpu.SMEM),
-                pl.BlockSpec((block_t, hd), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_v, hd), lambda i, j: (j, 0))
+                pl.BlockSpec((super_t, hd), lambda s, j: (s, 0)),
+                pl.BlockSpec((block_v, hd), lambda s, j: (j, 0))
                 if vh else
-                pl.BlockSpec((hd, block_v), lambda i, j: (0, j)),
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
+                pl.BlockSpec((hd, block_v), lambda s, j: (0, j)),
+                row,
             ],
-            out_specs=[
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
-                pl.BlockSpec((1, block_t), lambda i, j: (0, i)),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((block_t, 1), jnp.float32),
-                pltpu.VMEM((block_t, 1), jnp.float32),
-                pltpu.VMEM((block_t, 1), jnp.float32),
-            ],
+            out_specs=[row, row],
+            scratch_shapes=[column, column, column,
+                            pltpu.VMEM((super_t, 1), jnp.int32)],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((1, t_tot), jnp.float32),
-            jax.ShapeDtypeStruct((1, t_tot), jnp.float32),
+            jax.ShapeDtypeStruct((1, n_super * super_t), jnp.float32),
+            jax.ShapeDtypeStruct((1, n_super * super_t), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
+            # where even one token tile passes the limit (a device
+            # Pallas does not know: the compiler's default), the kernel
+            # asks for what that one tile needs
+            vmem_limit_bytes=max(limit, held),
         ),
         interpret=interpret,
         name="fused_ce_fwd",
     )(offset, h, w, targets[None, :])
-    return lse[0], tl[0]
+    return lse[0, :t_tot], tl[0, :t_tot]
 
 
 def _dlogits_tile(hb, wb, tb, lse_b, g_b, off, vi, block_t, block_v, valid,
@@ -401,9 +552,8 @@ def _combine(lse_l, tl_l, axis_name):
 def _fused_ce_fwd(h, w, targets, token_w, axis_name, valid_size, block_t,
                   block_v, interpret, vh):
     offset = _shard_offset(axis_name, w.shape[0] if vh else w.shape[1])
-    lse_l, tl_l = _fwd_pallas(
-        h, w, targets, offset, valid_size, block_t, block_v, interpret, vh
-    )
+    lse_l, tl_l = _fwd_pallas(h, w, targets, offset, valid_size, interpret,
+                              vh)
     lse, tl = _combine(lse_l, tl_l, axis_name)
     loss_sum = ((lse - tl) * token_w).sum()
     return (loss_sum, token_w.sum()), (h, w, targets, token_w, lse)
@@ -448,7 +598,9 @@ def fused_ce_sums(
     Same contract as chunked_ce_sums' return (callers divide), same TP
     and padded-vocab semantics as vocab_parallel_cross_entropy — but no
     logits buffer and no chunk recompute. Pads T up to the token block
-    (weight-0 pad tokens).
+    (weight-0 pad tokens). ``block_t`` and ``block_v`` are the
+    BACKWARD's tile (its super-block is its own, ``_pick_super_block``);
+    the forward plans every tile of its own (``_pick_fwd_plan``).
 
     ``weight_layout``: "vh" = (V_local, H) (bloom's tied embedding),
     "hv" = (H, V_local) (llama/mixtral's untied column-parallel head) —
@@ -460,16 +612,14 @@ def fused_ce_sums(
     t = hidden.shape[0]
     # token blocks stay powers of two (pad T up); vocab blocks must
     # divide V_local (pad_vocab guarantees power-of-two-friendly shards)
-    pow2 = 8
-    while pow2 < min(t, block_t):
-        pow2 *= 2
-    block_t = min(pow2, block_t)
+    block_t = _token_block(t, block_t)
     v_loc = weight.shape[0] if vh else weight.shape[1]
     requested_v = block_v
     block_v, exact_v = _pick_block(v_loc, block_v)
     interpret = _resolve_interpret(interpret)
-    if not exact_v and not interpret:
-        # _pick_block's fallback is the WHOLE vocab dim as one tile.
+    if not (exact_v and _fwd_vocab_tiles(v_loc)) and not interpret:
+        # _pick_block's fallback (and the forward's, where not even 8
+        # divides the shard) is the WHOLE vocab dim as one tile.
         # Whether V_local is larger than the requested block (a
         # (V_local, H) fp32 tile cannot fit VMEM) or merely smaller but
         # not 8-aligned (Mosaic rejects the ragged tile), the compiled

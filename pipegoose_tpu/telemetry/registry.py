@@ -104,10 +104,13 @@ class Gauge:
         self._lock = threading.Lock()
         self._registry = registry if registry is not None else _STANDALONE
 
-    def set(self, value: float) -> None:
+    def set(self, value: float, *, of_trace: bool = False) -> None:
+        """``of_trace``: as ``Counter.inc``'s: the value is a fact of the
+        TRACE itself (a plan the traced program took), kept under a jit
+        trace, where any other write is dropped."""
         if not self._registry._enabled:
             return
-        if _tracing(value):
+        if isinstance(value, jax.core.Tracer) or (_tracing() and not of_trace):
             return
         with self._lock:
             self._value = float(value)

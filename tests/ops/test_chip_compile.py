@@ -552,6 +552,58 @@ def test_backward_working_set_bounds_what_the_compiler_needs(
     assert "fused_ce_bwd" in text
 
 
+@pytest.mark.parametrize("cell", sorted(CE_CELLS))
+def test_fused_ce_forward_is_one_kernel_at_the_cells_shapes(
+        one_chip, as_default_device, cell):
+    """The head's loss at a train cell's own shape, whatever tile the
+    caller names for the backward: ONE instruction named
+    ``fused_ce_fwd`` (the name ``fused_ce_roofline.train`` finds it by),
+    at a plan of the kernel's own that fits the scoped VMEM it asks for,
+    holds at least 1,024 tokens while the vocabulary is walked and takes
+    under 8,000 grid steps; and the program holds no array of
+    (tokens, rows) entries, the logits the kernel exists to spare."""
+    t, v, hidden, valid, block_v = CE_CELLS[cell]
+    fn, shapes = _fused_ce(False, t, v, hidden, valid, block_v)
+    compiled = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile()
+    text = compiled.as_text()
+    called = [ln.split(" = ")[0] for ln in text.splitlines()
+              if " custom-call(" in ln and "tpu_custom_call" in ln]
+    assert len(called) == 1 and "fused_ce_fwd" in called[0], called
+    limit = fa._vmem_limit_bytes()
+    bt, ni, n_super, bv = fused_ce._pick_fwd_plan(t, v, hidden, 2, limit)
+    assert v % bv == 0 and ni * n_super * bt == t
+    assert ni * bt >= 1024 and n_super * (v // bv) <= 8000
+    assert fused_ce._fwd_working_set_bytes(
+        ni * bt, bt, bv, hidden, 2) <= limit * 3 // 4
+    assert not re.search(rf"\[{t},{v}\]|\[{v},{t}\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < t * v
+
+
+@pytest.mark.parametrize("cell", sorted(CE_CELLS))
+def test_forward_working_set_bounds_what_the_compiler_needs(
+        one_chip, monkeypatch, cell):
+    """``_fwd_working_set_bytes`` against the compiler: given exactly
+    the bytes the arithmetic counts as its scoped-VMEM limit, the chip's
+    compiler takes the kernel at the plan a v5e's limit gives."""
+    t, v, hidden, valid, _ = CE_CELLS[cell]
+    plan = fused_ce._pick_fwd_plan(t, v, hidden, 2, V5E_LIMIT)
+    bt, ni, _, bv = plan
+    counted = fused_ce._fwd_working_set_bytes(ni * bt, bt, bv, hidden, 2)
+    # the plan made for a v5e, no quarter to spare
+    monkeypatch.setattr(fused_ce, "_pick_fwd_plan", lambda *a: plan)
+    monkeypatch.setattr(fa, "_vmem_limit_bytes", lambda: counted)
+
+    def fn(h, w, tgt, off):
+        return fused_ce._fwd_pallas(h, w, tgt, off, valid, False, True)
+
+    shapes = [((t, hidden), jnp.bfloat16), ((v, hidden), jnp.bfloat16),
+              ((t,), jnp.int32), ((1,), jnp.int32)]
+    text = jax.jit(fn).lower(*_shapes(shapes, sharding=one_chip)) \
+        .compile().as_text()
+    assert "fused_ce_fwd" in text
+
+
 def test_train_step_holds_one_backward_kernel_and_none_of_the_old(
         monkeypatch):
     """A model's loss and its gradient, lowered for the TPU without one:

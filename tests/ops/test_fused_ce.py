@@ -91,26 +91,12 @@ def _dense_loss(h, w_vh, targets, token_w, valid):
     return tot / cnt
 
 
-@pytest.mark.parametrize("tensor", [1, 2])
-@pytest.mark.parametrize("layout", ["vh", "hv"])
-@pytest.mark.parametrize("case", sorted(BWD_CASES))
-def test_fused_backward_matches_the_dense_head(monkeypatch, devices, case,
-                                               layout, tensor):
-    """``dh`` and ``dw`` of ``fused_ce_bwd`` equal the dense head's, in
-    both weight layouts, alone and over a tensor axis of 2 (the shard's
-    column offset, ``dh`` summed over the axis), with zero-weight tokens
-    in every case; the carried cases visit each ``dw`` tile more than
-    once, so a read-modify-write that is missed or lands late shows."""
-    t, v, block_t, block_v, max_super, valid, visits = BWD_CASES[case]
-    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", max_super)
-    padded = -(-t // block_t) * block_t
-    assert fused_ce._pick_super_block(
-        padded, block_t, block_v, H, 4, 16 * 2**20)[1] == visits
-    rng = np.random.RandomState(3)
-    h = jnp.asarray(rng.randn(t, H), jnp.float32) * 0.3
-    w = jnp.asarray(rng.randn(v, H), jnp.float32) * 0.3
-    targets = jnp.asarray(rng.randint(0, valid or v, (t,)))
-    token_w = jnp.asarray((rng.rand(t) < 0.8).astype(np.float32))
+def _assert_loss_and_grads_match_the_dense_head(
+        devices, h, w, targets, token_w, valid, layout, tensor, block_t,
+        block_v):
+    """Loss, ``dh`` and ``dw`` of ``fused_ce_sums`` (interpreter) against
+    the dense head's, in the given weight layout, alone or over a tensor
+    axis of ``tensor`` devices."""
     rl, (rdh, rdw) = jax.value_and_grad(_dense_loss, argnums=(0, 1))(
         h, w, targets, token_w, valid)
     axis = "tensor" if tensor > 1 else None
@@ -136,6 +122,31 @@ def test_fused_backward_matches_the_dense_head(monkeypatch, devices, case,
     np.testing.assert_allclose(
         np.asarray(fdw if layout == "vh" else fdw.T), np.asarray(rdw),
         rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("tensor", [1, 2])
+@pytest.mark.parametrize("layout", ["vh", "hv"])
+@pytest.mark.parametrize("case", sorted(BWD_CASES))
+def test_fused_backward_matches_the_dense_head(monkeypatch, devices, case,
+                                               layout, tensor):
+    """``dh`` and ``dw`` of ``fused_ce_bwd`` equal the dense head's, in
+    both weight layouts, alone and over a tensor axis of 2 (the shard's
+    column offset, ``dh`` summed over the axis), with zero-weight tokens
+    in every case; the carried cases visit each ``dw`` tile more than
+    once, so a read-modify-write that is missed or lands late shows."""
+    t, v, block_t, block_v, max_super, valid, visits = BWD_CASES[case]
+    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", max_super)
+    padded = -(-t // block_t) * block_t
+    assert fused_ce._pick_super_block(
+        padded, block_t, block_v, H, 4, 16 * 2**20)[1] == visits
+    rng = np.random.RandomState(3)
+    h = jnp.asarray(rng.randn(t, H), jnp.float32) * 0.3
+    w = jnp.asarray(rng.randn(v, H), jnp.float32) * 0.3
+    targets = jnp.asarray(rng.randint(0, valid or v, (t,)))
+    token_w = jnp.asarray((rng.rand(t) < 0.8).astype(np.float32))
+    _assert_loss_and_grads_match_the_dense_head(
+        devices, h, w, targets, token_w, valid, layout, tensor, block_t,
+        block_v)
 
 
 @pytest.mark.parametrize("layout", ["vh", "hv"])
@@ -209,6 +220,206 @@ def test_super_block_fits_the_limit_it_is_given(shape, mib):
     if ni * 256 < fused_ce._MAX_SUPER_TOKENS and held <= limit * 3 // 4:
         assert fused_ce._bwd_working_set_bytes(
             2 * ni * 256, 256, bv, hd, 2) > limit * 3 // 4
+
+
+# the forward's plan at the head one chip of each train cell sees,
+# (tokens, local rows, hidden, itemsize, limit MiB) -> (block_t, token
+# tiles resident, super-blocks, block_v): on a v5e's 64 MiB, the
+# compiler's default 16 MiB, 96 MiB; then small and odd shapes: tokens
+# under a tile, a tile count no power of two divides, a shard only 8
+# divides (its whole 296 rows are one tile), the shifted 16,376 tokens
+FWD_PLANS = [
+    ((16384, 250880, 1024, 2, 64), (256, 8, 8, 2560)),
+    ((16384, 125440, 2048, 2, 64), (256, 4, 16, 1792)),
+    ((16384, 19456, 2048, 2, 64), (256, 8, 8, 1024)),
+    ((16384, 250880, 1024, 2, 16), (256, 2, 32, 640)),
+    ((16384, 125440, 2048, 2, 16), (256, 1, 64, 256)),
+    ((16384, 19456, 2048, 2, 16), (256, 1, 64, 256)),
+    ((16384, 250880, 1024, 2, 96), (256, 16, 4, 3584)),
+    ((16384, 125440, 2048, 2, 96), (256, 4, 16, 2560)),
+    ((16384, 19456, 2048, 2, 96), (256, 8, 8, 2432)),
+    ((24, 128, 32, 4, 16), (32, 1, 1, 128)),
+    ((37, 296, 32, 4, 16), (64, 1, 1, 296)),
+    ((61 * 256, 250880, 1024, 2, 64), (256, 8, 8, 2560)),
+    ((8 * 2047, 250880, 1024, 2, 64), (256, 8, 8, 2560)),
+]
+
+
+@pytest.mark.parametrize("shape, want", FWD_PLANS)
+def test_forward_plan_comes_from_the_shapes_and_the_vmem(shape, want):
+    t, v, hd, itemsize, mib = shape
+    bt, ni, n_super, bv = fused_ce._pick_fwd_plan(t, v, hd, itemsize,
+                                                  mib * 2**20)
+    assert (bt, ni, n_super, bv) == want
+    # a tile divides the shard; every token is in a super-block, padded
+    # by less than a tile each
+    assert v % bv == 0
+    assert 0 <= ni * n_super - -(-t // bt) < n_super
+
+
+@pytest.mark.parametrize("mib", [16, 64, 96])
+@pytest.mark.parametrize("shape", [(16384, 250880, 1024),
+                                   (16384, 125440, 2048),
+                                   (16384, 19456, 2048)])
+def test_forward_plan_fits_the_limit_it_is_given(shape, mib):
+    """The plan's working set never passes three quarters of the limit
+    unless it is ONE token tile at the smallest vocabulary tile (where
+    the kernel asks for that tile's bytes instead), and neither more
+    resident tokens nor the next vocabulary tile would still fit. On a
+    v5e's limit and above: at least 1,024 tokens resident (at most 16
+    walks of the head) and under 8,000 grid steps a call."""
+    t, v, hd = shape
+    limit = mib * 2**20
+    bt, ni, n_super, bv = fused_ce._pick_fwd_plan(t, v, hd, 2, limit)
+    tiles = fused_ce._fwd_vocab_tiles(v)
+    assert bv in tiles and ni * n_super * bt >= t
+
+    def held(ni, bv):
+        return fused_ce._fwd_working_set_bytes(ni * bt, bt, bv, hd, 2)
+
+    assert held(ni, bv) <= limit * 3 // 4 or (ni == 1 and bv == tiles[0])
+    if 2 * ni * bt <= fused_ce._MAX_SUPER_TOKENS:
+        assert held(2 * ni, bv) > limit * 3 // 4
+    if bv != tiles[-1]:
+        assert held(ni, tiles[tiles.index(bv) + 1]) > limit * 3 // 4
+    if mib >= 64:
+        assert ni * bt >= 1024 and n_super <= 16
+        assert n_super * (v // bv) <= 8000
+
+
+@pytest.mark.parametrize("rows, want", [
+    (250880, [128, 256, 512, 640, 896, 1024, 1280, 1792, 2560, 3584]),
+    (125440, [128, 256, 512, 640, 896, 1280, 1792, 2560, 3584]),
+    (19456, [128, 256, 512, 1024, 2432]),
+    (296, [8, 296]),      # 8 x 37: no lane multiple divides it
+    (1001, []),           # nothing a compiled kernel can take
+])
+def test_forward_vocabulary_tiles_divide_the_shard(rows, want):
+    assert fused_ce._fwd_vocab_tiles(rows) == want
+
+
+# the forward at a plan that is NOT the caller's tile (block_t 16,
+# block_v 64 go to the backward): token tiles of 8, two a super-block,
+# three super-blocks (40 tokens padded to 48), four vocabulary tiles of
+# 128 a shard; targets on both sides of every tile border, the last
+# valid column and column 0; valid_size ON a tile border of the last
+# shard (384 + 512 * (tensor - 1)) and inside a tile (400)
+@pytest.mark.parametrize("tensor", [1, 2])
+@pytest.mark.parametrize("layout", ["vh", "hv"])
+@pytest.mark.parametrize("valid_in_last_shard", [384, 400, None])
+def test_forward_at_its_own_plan_matches_the_dense_head(
+        monkeypatch, devices, layout, tensor, valid_in_last_shard):
+    monkeypatch.setattr(fused_ce, "_FWD_BLOCK_T", 8)
+    monkeypatch.setattr(fused_ce, "_FWD_MAX_BLOCK_V", 128)
+    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", 16)
+    t, shard = 40, 512
+    v = shard * tensor
+    valid = (None if valid_in_last_shard is None
+             else v - shard + valid_in_last_shard)
+    assert fused_ce._pick_fwd_plan(t, shard, H, 4, 16 * 2**20) == (
+        8, 2, 3, 128)
+    rng = np.random.RandomState(11)
+    h = jnp.asarray(rng.randn(t, H), jnp.float32) * 0.3
+    w = jnp.asarray(rng.randn(v, H), jnp.float32) * 0.3
+    last = (valid or v) - 1
+    edges = [0, 127, 128, 255, 256, 383, 384, last - 1, last]
+    if tensor > 1:
+        edges += [shard - 1, shard, shard + 127, shard + 128]
+    targets = rng.randint(0, valid or v, (t,))
+    targets[:len(edges)] = edges
+    targets = jnp.asarray(targets)
+    token_w = jnp.asarray((rng.rand(t) < 0.8).astype(np.float32))
+    _assert_loss_and_grads_match_the_dense_head(
+        devices, h, w, targets, token_w, valid, layout, tensor, 16, 64)
+
+
+def test_forward_per_token_results_match_the_dense_head(monkeypatch):
+    """``lse`` and the target logit of every token, not their weighted
+    sum: several super-blocks and vocabulary tiles, ragged tokens (the
+    padded rows are cut off), a masked tail of the vocabulary."""
+    monkeypatch.setattr(fused_ce, "_FWD_BLOCK_T", 8)
+    monkeypatch.setattr(fused_ce, "_FWD_MAX_BLOCK_V", 128)
+    monkeypatch.setattr(fused_ce, "_MAX_SUPER_TOKENS", 16)
+    t, v, valid = 37, 384, 300
+    rng = np.random.RandomState(12)
+    h = jnp.asarray(rng.randn(t, H), jnp.float32) * 0.3
+    w = jnp.asarray(rng.randn(v, H), jnp.float32) * 0.3
+    targets = jnp.asarray(rng.randint(0, valid, (t,)), jnp.int32)
+    logits = jnp.where(jnp.arange(v) < valid, h @ w.T, -jnp.inf)
+    for vh in (True, False):
+        lse, tl = fused_ce._fwd_pallas(
+            h, w if vh else w.T, targets, jnp.zeros((1,), jnp.int32), valid,
+            True, vh)
+        assert lse.shape == tl.shape == (t,)
+        np.testing.assert_allclose(
+            np.asarray(lse), np.asarray(jax.nn.logsumexp(logits, axis=1)),
+            rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(tl),
+            np.asarray(jnp.take_along_axis(logits, targets[:, None], 1)[:, 0]),
+            rtol=1e-5, atol=1e-5)
+
+
+def test_traced_bloom_loss_records_the_forward_plan_once_a_trace():
+    """``fused_ce.fwd_calls`` counts TRACED head passes and the gauges
+    hold the plan that trace took: grid steps, walks of the head and the
+    counted VMEM (``telemetry.get_registry()``); a compiled step run
+    again records nothing."""
+    import dataclasses
+
+    from pipegoose_tpu.models import bloom
+    from pipegoose_tpu.telemetry.registry import get_registry
+
+    cfg = dataclasses.replace(
+        bloom.BloomConfig(vocab_size=256, hidden_size=64, n_layer=1,
+                          n_head=4), fused_ce=True)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    ids = jnp.asarray(np.random.RandomState(5).randint(0, 256, (2, 24)))
+    reg = get_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        calls = reg.counter("fused_ce.fwd_calls")
+        start = calls.value
+        step = jax.jit(jax.grad(
+            lambda p: bloom.loss_fn(p, ids, None, ids, cfg)))
+        step(params)
+        assert calls.value == start + 1
+        bt, ni, n_super, bv = fused_ce._pick_fwd_plan(
+            2 * 23, 256, 64, 4, 16 * 2**20)
+        assert reg.gauge("fused_ce.fwd_head_walks").value == n_super
+        assert reg.gauge("fused_ce.fwd_grid_steps").value \
+            == n_super * (256 // bv)
+        assert reg.gauge("fused_ce.fwd_vmem_bytes").value \
+            == fused_ce._fwd_working_set_bytes(ni * bt, bt, bv, 64, 4)
+        step(params)  # compiled: nothing is traced
+        assert calls.value == start + 1
+    finally:
+        if not was:
+            reg.disable()
+
+
+def test_bench_script_rehearses_off_the_chip(capsys):
+    """``scripts/bench_fused_ce.py`` off the TPU: no time is reported,
+    both kernels' plans are printed and the interpreter's forward is
+    held against a dense head at the shape asked for."""
+    import importlib.util
+    import json
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "scripts",
+                        "bench_fused_ce.py")
+    spec = importlib.util.spec_from_file_location("bench_fused_ce", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    assert bench.main(["--tokens", "40", "--rows", "384", "--hidden", "32",
+                       "--valid", "300", "--dtype", "float32"]) == 0
+    line = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert line["device"]["platform"] == "cpu" and "fwd" not in line
+    assert line["timed"].startswith("not measured")
+    assert line["plan"]["fwd"]["block_v"] == 384
+    assert line["plan"]["bwd"]["block_v"] == 128
+    assert max(line["interpreter_against_dense"].values()) < 1e-4
 
 
 def test_fused_valid_size_masks_padded_slots(data):

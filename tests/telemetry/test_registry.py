@@ -184,6 +184,30 @@ def test_an_increment_of_the_trace_counts_once_a_trace(reg):
     reg.enable()
 
 
+def test_a_gauge_of_the_trace_holds_what_the_last_trace_set(reg):
+    """``set(value, of_trace=True)`` is kept under a jit trace (a plan
+    the traced program took); a tracer value, a plain ``set`` under a
+    trace and a disabled registry still record nothing."""
+    g = reg.gauge("jit.plan_steps")
+
+    @jax.jit
+    def f(x):
+        g.set(x.shape[0], of_trace=True)
+        g.set(x[0], of_trace=True)   # a tracer is never a value
+        g.set(-1.0)                  # not asked for by name: dropped
+        return x * 2
+
+    f(jnp.arange(4.0))
+    assert g.value == 4.0
+    f(jnp.arange(8.0))               # another shape: another trace
+    f(jnp.arange(4.0))               # compiled: nothing is traced
+    assert g.value == 8.0
+    reg.disable()
+    jax.jit(lambda x: (g.set(2.0, of_trace=True), x)[1])(jnp.arange(2.0))
+    assert g.value == 8.0
+    reg.enable()
+
+
 def test_snapshot_and_prometheus_render(reg):
     reg.counter("a.total", help="things").inc(3)
     reg.gauge("b.depth").set(2.0)
