@@ -54,8 +54,7 @@ SIZE = dict(
     # quantized-weight engines hold the fp and the quantized tree at
     # once; their short run gets a smaller pool so both fit the chip
     quant_requests=4, quant_new=8, quant_pages=1024,
-    kernel=dict(b=8, s=1024, nh=16, hd=64, h=1024, v=250880, pages=4096,
-                ps=16, width=64),
+    kernel=dict(b=8, s=1024, nh=16, hd=64, h=1024, v=250880),
 )
 
 
@@ -159,14 +158,9 @@ def phase_kernels(rep, seed):
     )
     from pipegoose_tpu.ops import flash_attention as fa
     from pipegoose_tpu.ops.fused_ce import fused_ce_sums
-    from pipegoose_tpu.ops.paged_attention import (
-        paged_attention,
-        paged_attention_reference,
-    )
     from pipegoose_tpu.quant import QuantSpec
     from pipegoose_tpu.quant.matmul import _matmul_xla, quantized_matmul
     from pipegoose_tpu.quant.weights import _quantize_kernel
-    from pipegoose_tpu.serving.kv_pool import quantize_kv
 
     k = SIZE["kernel"]
     b, s, nh, hd, h, v = k["b"], k["s"], k["nh"], k["hd"], k["h"], k["v"]
@@ -258,35 +252,6 @@ def phase_kernels(rep, seed):
                lambda x, qw, sc: _matmul_xla(x.astype(jnp.float32), qw, sc,
                                              mode == "int4"),
                (x, leaf["q"], leaf["scale"]), 2e-2)
-
-    # paged attention at decode geometry, fp and int8 pools, vs the
-    # gather-then-attend reference
-    ps, width, pages = k["ps"], k["width"], k["pages"]
-    # banks in the pool's layout: a position's heads in one row
-    kp = jax.random.normal(next(keys), (pages, ps, nh * hd), bf)
-    vp = jax.random.normal(next(keys), (pages, ps, nh * hd), bf)
-    rng = np.random.RandomState(seed)
-    table = jnp.asarray(rng.permutation(np.arange(1, pages))[:b * width]
-                        .reshape(b, width), jnp.int32)
-    start = jnp.asarray(rng.randint(ps, ps * width - 1, (b,)), jnp.int32)
-    # table entries beyond a row's live prefix are NULL, as in the engine
-    live = (jnp.arange(width)[None, :] * ps) <= start[:, None]
-    table = jnp.where(live, table, 0)
-    q1 = jax.random.normal(next(keys), (b, 1, nh, hd), bf)
-
-    def quant_bank(p):
-        qv, sc = quantize_kv(p.astype(jnp.float32).reshape(pages, ps, nh, hd))
-        return {"q": qv.reshape(p.shape), "scale": sc}
-
-    for name, kbank, vbank in (("paged_fp", kp, vp),
-                               ("paged_int8", quant_bank(kp), quant_bank(vp))):
-        record(name,
-               lambda q, kb, vb: paged_attention(
-                   q, kb, vb, table, start, slopes=slopes,
-                   interpret=False),
-               lambda q, kb, vb: paged_attention_reference(
-                   q, kb, vb, table, start, slopes=slopes),
-               (q1, kbank, vbank), 2e-2)
 
     rep.phase("kernels", checks, kernels=info)
 
@@ -451,7 +416,7 @@ def phase_serve(rep, seed):
                         "prompt_lens": [len(p) for p, _ in reqs],
                         **SIZE["serve"]}
 
-    # gather path vs per-request generate()
+    # the engine vs per-request generate()
     base, drained, wall, _ = run_engine(params, cfg, reqs)
     oracle = [reference_tokens(params, cfg, p, n)
               for p, n in reqs[:SIZE["n_oracle"]]]
@@ -461,21 +426,6 @@ def phase_serve(rep, seed):
     checks["gather.all_tokens_emitted"] = sum(map(len, base)) == n_gen
     info["gather"] = {"wall_s_with_compiles": wall, "oracle_requests":
                       len(oracle), "identical": n_same, "first_diff": first}
-
-    # the paged-attention kernel over fp and int8 pools vs the gather path
-    for name, kw in (("paged_fp", {}), ("paged_int8", {"kv_dtype": "int8"})):
-        ref = base
-        if kw:
-            ref, d0, _, _ = run_engine(params, cfg, reqs, **kw)
-            checks["gather_int8.pool_drained"] = d0
-        toks, drained, wall, text = run_engine(params, cfg, reqs,
-                                               attn_kernel="paged", **kw)
-        ok, n_same, first = same_tokens(toks, ref)
-        checks[f"{name}.identical_to_gather"] = ok
-        checks[f"{name}.pool_drained"] = drained
-        checks[f"{name}.step_has_custom_call"] = "tpu_custom_call" in text
-        info[name] = {"wall_s_with_compiles": wall, "identical": n_same,
-                      "of": len(reqs), "first_diff": first}
 
     # quantized weights: the engine's dequant-fused matmuls vs
     # generate() over the same quantized tree

@@ -12,7 +12,7 @@ fatter step. The doctor (pipegoose_tpu/telemetry/doctor.py) diffs the
 compiled program against the intended specs, names the offending
 module path, and shows the inserted gather; the fixed spec then
 compiles back to ZERO resharding-gather bytes and passes the same
-guards that run in CI (scripts/mesh_doctor.py, scripts/ci_fast.sh).
+guards that run in CI (scripts/mesh_doctor.py, tests/test_cli_gates.py).
 
     python examples/mesh_doctor_demo.py --fake-devices 8 --tp 2 --dp 4
 """

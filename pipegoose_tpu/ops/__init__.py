@@ -1,19 +1,4 @@
 """Hand-written Pallas TPU kernels (flash attention, fused
-cross-entropy, fused paged attention) with ``interpret=`` CPU
-fallbacks. Heavy modules stay import-on-demand
-(``from pipegoose_tpu.ops import flash_attention as fa``); the paged
-decode kernel's public surface is re-exported here because serving
-code and scripts reach for it by name."""
-from pipegoose_tpu.ops.paged_attention import (
-    check_paged_tile,
-    paged_attention,
-    paged_attention_reference,
-    paged_tile_geometry,
-)
-
-__all__ = [
-    "check_paged_tile",
-    "paged_attention",
-    "paged_attention_reference",
-    "paged_tile_geometry",
-]
+cross-entropy) with ``interpret=`` CPU fallbacks. The modules are
+import-on-demand (``from pipegoose_tpu.ops import flash_attention as
+fa``)."""

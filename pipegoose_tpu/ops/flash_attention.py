@@ -688,7 +688,7 @@ def _flash_bwd_pallas(q, k, v, do, lse, delta, slopes, kpos, kneg,
 # head at a time. A head reaches the matrix unit through a lane mask:
 # the contraction runs over all 128 lanes against an operand whose other
 # head's lanes are zero (the added terms are zeros, and a 64-deep
-# contraction already takes a 128-deep pass), as ``paged_attention``'s.
+# contraction already takes a 128-deep pass).
 
 _PAIRED_HEAD_DIM = 64
 _PAIR = 2

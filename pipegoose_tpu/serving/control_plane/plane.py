@@ -99,7 +99,6 @@ class ControlPlane:
                  autoscaler: Optional[Autoscaler] = None,
                  registry: Optional[MetricsRegistry] = None,
                  stall_patience: int = 200,
-                 affinity_slack_tokens: int = 192,
                  recorder: Optional[Any] = None,
                  suspect_after_ticks: int = 5,
                  failed_after_ticks: int = 20,
@@ -186,8 +185,7 @@ class ControlPlane:
         self.goodput = goodput or None
         self._tick = 0   # last tick seen by run() — lifecycle calls
         #                  outside the loop (rejoin/drain) stamp it
-        self.router = Router(policy, registry=self.registry,
-                             affinity_slack_tokens=affinity_slack_tokens)
+        self.router = Router(policy, registry=self.registry)
         self.ledger = ledger if ledger is not None else TenantLedger()
         self.autoscaler = autoscaler
         self.stall_patience = stall_patience

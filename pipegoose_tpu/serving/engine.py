@@ -144,7 +144,6 @@ from pipegoose_tpu.serving.blocks import (
 from pipegoose_tpu.serving.kv_pool import (
     SUMMARY_COUNTERS,
     PagePool,
-    check_attn_impl,
     check_kv_dtype,
     copy_page,
     init_pages,
@@ -362,8 +361,15 @@ class ServingEngine:
     step compiles for). Pass ``mesh``/``param_specs`` for tensor
     parallelism (vocab/head-sharded params, same contract as
     ``generate_tp``). ``prefix_cache``/``prefill_chunk``/``speculative``
-    are the opt-in serving-perf modes (module docstring); all default
-    OFF, preserving the PR 1 engine bit-for-bit."""
+    are the opt-in serving-perf modes (module docstring), all default
+    OFF. Every paged program (decode step, draft, verify, chunk) reads
+    the pool one way, the walk (``kv_pool._attend_rows``): the page
+    table in chunks of whole pages, only as far as the longest live
+    sequence of the call, each chunk's rows as stored contracted on the
+    matrix unit against a block-diagonal query.
+    ``finish_run()["decode_key_share"]`` (gauge
+    ``serving.decode_key_share``) is the share of the table's key
+    columns the plain decode steps walked."""
 
     def __init__(self, params, config, *, num_slots: int = 4,
                  num_pages: int = 64, page_size: int = 16,
@@ -382,8 +388,7 @@ class ServingEngine:
                  host_tier=None,
                  host_tier_wire: Optional[str] = None,
                  cost_model=None,
-                 memledger=None,
-                 attn_kernel: str = "gather"):
+                 memledger=None):
         """``recorder``: optional ``telemetry.FlightRecorder`` — every
         decode step lands in its ring, and the no-decode-progress
         watchdog dumps a black box through it before raising.
@@ -432,20 +437,7 @@ class ServingEngine:
         (or ``True`` to construct one) — live byte-exact per-owner-
         class page accounting with leak audits and an exhaustion
         forecast. Default None keeps every pool event and tick at one
-        attribute read + branch (guard-tested < 5 µs).
-
-        ``attn_kernel`` ("gather" | "paged", default "gather"): decode/
-        chunk attention implementation. "gather" reads the pool's rows
-        as they are stored (kv_pool._attend_rows): the page table is
-        walked in chunks of whole pages, only as far as the longest
-        live sequence of the call, each chunk's rows contracted on the
-        matrix unit against a block-diagonal query, never split into
-        heads or widened. ``finish_run()["decode_key_share"]`` (gauge
-        ``serving.decode_key_share``) is the share of the table's key
-        columns the plain decode steps walked. "paged" routes every
-        paged program (decode step, speculative draft/verify, chunked
-        prefill) through the fused Pallas kernel
-        (ops/paged_attention.py), a grid step a page."""
+        attribute read + branch (guard-tested < 5 µs)."""
         t_build = time.perf_counter()
         if max_context % page_size:
             raise ValueError("max_context must be a multiple of page_size")
@@ -476,8 +468,7 @@ class ServingEngine:
                 "prefix_cache": prefix_cache, "speculative": speculative,
                 "prefill_chunk": prefill_chunk, "kv_dtype": kv_dtype,
                 "weight_dtype": weight_dtype, "host_tier": host_tier,
-                "prefill_only": prefill_only,
-                "attn_kernel": attn_kernel != "gather", "mesh": mesh,
+                "prefill_only": prefill_only, "mesh": mesh,
                 "memledger": memledger,
             }
             for mode, value in asked.items():
@@ -598,9 +589,7 @@ class ServingEngine:
             weight_dtype = None
         self.weight_dtype = weight_dtype
         self.kv_dtype = check_kv_dtype(kv_dtype)
-        check_attn_impl(attn_kernel)
-        self.attn_kernel = attn_kernel
-        # key columns a trip of the "gather" read's walk visits, and all
+        # key columns a trip of the decode read's walk visits, and all
         # a table reaches: the host counts what the decode program walks
         self._walk_keys = walk_plan(page_size, self.table_width)[0] * page_size
         self._reach_keys = self.table_width * page_size
@@ -754,8 +743,7 @@ class ServingEngine:
                 logits, k_pages, v_pages, counters, state = \
                     paged_decode_step(
                         params, tokens, k_pages, v_pages, table, seq_lens,
-                        model, attn_impl=attn_kernel, with_counters=True,
-                        state=state,
+                        model, with_counters=True, state=state,
                     )
                 return (greedy_token(logits, mask_fn), k_pages, v_pages,
                         counters, state)
@@ -763,8 +751,7 @@ class ServingEngine:
             def _chunk(params, ids, k_pages, v_pages, table, start, n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
                     params, ids, k_pages, v_pages, table, start, n_valid,
-                    config, attn_impl=attn_kernel,
-                )
+                    config)
                 return greedy_token(logits, mask_fn), k_pages, v_pages
 
             def _copy(k_pages, v_pages, src, dst):
@@ -774,15 +761,13 @@ class ServingEngine:
                 logits, k_pages, v_pages = paged_decode_step(
                     params, tokens, k_pages, v_pages, table, seq_lens,
                     config, write_ok=ok, draft_layers=spec_k,
-                    attn_impl=attn_kernel,
                 )
                 return greedy_token(logits, mask_fn), k_pages, v_pages
 
             def _verify(params, ids, k_pages, v_pages, table, start, n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
                     params, ids, k_pages, v_pages, table, start, n_valid,
-                    config, all_logits=True, attn_impl=attn_kernel,
-                )
+                    config, all_logits=True)
                 return greedy_token(logits, mask_fn), k_pages, v_pages
 
             self._prefill = jax.jit(_prefill)
@@ -817,8 +802,7 @@ class ServingEngine:
             def _step_body(params, tokens, k_pages, v_pages, table, seq_lens):
                 logits, k_pages, v_pages = paged_decode_step(
                     params, tokens, k_pages, v_pages, table, seq_lens,
-                    model, tp_axis, attn_impl=attn_kernel,
-                )
+                    model, tp_axis)
                 tok = global_greedy_pick(logits, tp_axis, valid)
                 return tok, k_pages, v_pages, {}, {}
 
@@ -826,8 +810,7 @@ class ServingEngine:
                             n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
                     params, ids, k_pages, v_pages, table, start, n_valid,
-                    config, tp_axis, attn_impl=attn_kernel,
-                )
+                    config, tp_axis)
                 tok = global_greedy_pick(logits, tp_axis, valid)
                 return tok, k_pages, v_pages
 
@@ -839,7 +822,6 @@ class ServingEngine:
                 logits, k_pages, v_pages = paged_decode_step(
                     params, tokens, k_pages, v_pages, table, seq_lens,
                     config, tp_axis, write_ok=ok, draft_layers=spec_k,
-                    attn_impl=attn_kernel,
                 )
                 tok = global_greedy_pick(logits, tp_axis, valid)
                 return tok, k_pages, v_pages
@@ -848,8 +830,7 @@ class ServingEngine:
                              n_valid):
                 logits, k_pages, v_pages = paged_prefill_chunk(
                     params, ids, k_pages, v_pages, table, start, n_valid,
-                    config, tp_axis, all_logits=True, attn_impl=attn_kernel,
-                )
+                    config, tp_axis, all_logits=True)
                 b, c, _ = logits.shape
                 tok = global_greedy_pick(
                     logits.reshape(b * c, -1), tp_axis, valid
@@ -980,25 +961,9 @@ class ServingEngine:
             + ("state",) * len(self._state_arg()),
             mesh=self.mesh, large_bytes=large_bytes,
         )
-        if self.attn_kernel == "paged":
-            report.extras = {"paged_tile": self._paged_tile(n_queries=1)}
         set_doctor_gauges(report, registry=registry or self.registry)
         self.last_doctor_report = report   # /debug/doctor serves this
         return report
-
-    def _paged_tile(self, n_queries: int) -> dict:
-        """Chosen Pallas paged-attention tile geometry for this engine's
-        pool — logged into the doctor report (``extras["paged_tile"]``)
-        so the CI artifact records which VMEM footprint the feasibility
-        guard approved."""
-        from pipegoose_tpu.ops.paged_attention import paged_tile_geometry
-
-        head_dim = self.config.hidden_size // self.config.n_head
-        tp = self.mesh.shape[self.tp_axis] if self.mesh is not None else 1
-        return paged_tile_geometry(
-            self.page_size, self.config.n_head // tp, head_dim, n_queries,
-            quantized=self.kv_dtype == "int8",
-        )
 
     def doctor_chunk(self, large_bytes: int = 1 << 20, registry=None):
         """Same report for the compiled CHUNKED-PREFILL program — the
@@ -1026,8 +991,6 @@ class ServingEngine:
                     "start", "n_valid"),
             mesh=self.mesh, large_bytes=large_bytes,
         )
-        if self.attn_kernel == "paged":
-            report.extras = {"paged_tile": self._paged_tile(n_queries=c)}
         set_doctor_gauges(report, registry=registry or self.registry)
         self.last_doctor_report = report
         return report
@@ -2222,13 +2185,11 @@ class ServingEngine:
                 },
             },
         }
-        if self.attn_kernel == "gather":
-            # key columns the plain decode steps walked over those their
-            # tables reach: how far the read's walk engaged
-            share = (rs.keys_walked / rs.keys_reached
-                     if rs.keys_reached else 0.0)
-            metrics["decode_key_share"] = round(share, 6)
-            self._m_key_share.set(share)
+        # key columns the plain decode steps walked over those their
+        # tables reach: how far the read's walk engaged
+        share = rs.keys_walked / rs.keys_reached if rs.keys_reached else 0.0
+        metrics["decode_key_share"] = round(share, 6)
+        self._m_key_share.set(share)
         metrics["pages_by_kind"] = {
             kind: {"capacity": self.pool.of(kind).capacity,
                    "peak_in_use": rs.peak_pages[kind]}
@@ -2245,7 +2206,7 @@ class ServingEngine:
             # a window layer's walk over its ring, and over what a global
             # layer's walk of the same steps visited
             metrics["decode_key_share_by_kind"] = {
-                GLOBAL: metrics.get("decode_key_share"),
+                GLOBAL: metrics["decode_key_share"],
                 WINDOW: round(rs.window_keys_walked
                               / max(rs.window_keys_reached, 1), 6)}
             metrics["window_key_share"] = round(

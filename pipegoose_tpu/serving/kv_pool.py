@@ -15,11 +15,11 @@ updates the pool IN PLACE: rows are addressed by (layer, page, offset)
 on the donated buffer, never on a slice of it. Values keep
 ``(.., nh, hd)`` at the edges (:func:`gather_pages`, the wire slabs).
 
-The attention READ (:func:`_attend_rows`, the default
-``attn_impl="gather"``) takes the rows as they are stored: a page
-table is walked in chunks of whole pages (:func:`walk_plan`), only as
-far as the furthest live query of the call (:func:`walked_chunks`, a
-trip count on the device), and each gathered chunk, still ``nh*hd``
+The attention READ (:func:`_attend_rows`) takes the rows as they are
+stored: a page table is walked in chunks of whole pages
+(:func:`walk_plan`), only as far as the furthest live query of the call
+(:func:`walked_chunks`, a trip count on the device), and each gathered
+chunk, still ``nh*hd``
 lanes of the pool's dtype, is contracted on the matrix unit against a
 block-diagonal query under an online softmax. No row is split into
 heads or widened in memory, and the pages past the longest live
@@ -93,7 +93,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from pipegoose_tpu.models.bloom import NEG_INF
-from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.serving.blocks import (
     BLOCK,
     GLOBAL,
@@ -110,9 +109,7 @@ NULL_PAGE = 0
 
 KV_DTYPES = (None, "fp", "int8")
 
-ATTN_IMPLS = ("gather", "paged")
-
-# key columns one trip of the "gather" read's walk visits, in whole
+# key columns one trip of the read's walk visits, in whole
 # pages (:func:`walk_plan`). Measured on the chip at 128 / 256 / 512
 # (PERF.md, PR 32).
 WALK_KEYS = 256
@@ -130,11 +127,6 @@ SUMMARY_COUNTERS = ("rows_live", "window_rows_needed", "window_rows_gathered",
                     "summaries_written")
 
 
-def check_attn_impl(attn_impl: str) -> str:
-    if attn_impl not in ATTN_IMPLS:
-        raise ValueError(f"attn_impl must be one of {ATTN_IMPLS}, got "
-                         f"{attn_impl!r}")
-    return attn_impl
 
 _KV_INT8_MAX = 127.0
 
@@ -472,12 +464,6 @@ def _values(pages):
     return pages["q"] if _is_quantized(pages) else pages
 
 
-def _one_bank(pages):
-    """(L, P, ..) -> (L*P, ..), free: layer l's page p is page l*P + p."""
-    return jax.tree_util.tree_map(
-        lambda a: a.reshape((-1,) + a.shape[2:]), pages)
-
-
 def init_state(config, num_slots: int) -> dict:
     """The state bank of ``config``'s model: ``{name: zeros (L,
     num_slots, ..)}`` after ``PagedModel.state``, a row a slot a layer;
@@ -646,9 +632,9 @@ def gather_pages(pages, page_table, head_dim: int):
     contiguous view (.., B, W * page_size, nh, hd), the rows split back
     into heads of ``head_dim``, an int8 bank dequantized per (position,
     head). The RECONSTRUCTION of what a table holds: the oracle of the
-    parity tests (with :func:`_key_bias` and ``_attn_core``) and of
-    ``ops/paged_attention.py``. No program reads the pool this way: the
-    decode read is :func:`_attend_rows`, over the rows as stored."""
+    parity tests (with :func:`_key_bias` and ``_attn_core``). No program
+    reads the pool this way: the decode read is :func:`_attend_rows`,
+    over the rows as stored."""
     if _is_quantized(pages):
         q = _heads(_gather(pages["q"], page_table), head_dim)
         return dequantize_kv(q, _gather(pages["scale"], page_table))
@@ -1021,7 +1007,7 @@ def _attend_latent(q, pages, layer, page_table, pos, qmask, out_dtype, row):
 
 
 def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
-                   dest_page, dest_off, qmask, config, tp_axis, attn_impl,
+                   dest_page, dest_off, qmask, config, tp_axis,
                    n_layers=None, live=None, state=None):
     """The forward both paged programs share: ``tokens`` (B, C) at
     global positions ``pos`` (B, C) through the model's blocks
@@ -1053,9 +1039,8 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     :func:`_attend_latent`. Returns (hidden, k_pages, v_pages, counters,
     state): what the blocks' ``finish`` brought out, stacked over the
     layers that bring any, ``{}`` for a model with none."""
-    check_attn_impl(attn_impl)
     model = describe(config, tp_axis)
-    b, c = tokens.shape
+    c = tokens.shape[1]
     state = state or {}
     if model.state and (not state or c != 1 or live is None):
         raise ValueError("a model with a state a slot runs the paged "
@@ -1064,10 +1049,6 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
     kp, vp = by_kind(k_pages), by_kind(v_pages)
     tables, dest = by_kind(page_table), by_kind(dest_page)
     offs = by_kind(dest_off)
-    if attn_impl == "paged" and (model.kinds != (GLOBAL,)
-                                 or model.latent is not None):
-        raise ValueError("the paged kernel reads one cache kind of keys "
-                         "and values")
 
     x = model.embed(params, tokens)
     seen = dict.fromkeys(model.kinds, 0)      # layers of a kind so far
@@ -1095,7 +1076,6 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
         window = model.window if kind == WINDOW else None
         slopes = grp.slopes() if grp.slopes is not None else None
         blocks = grp.params(params)
-        num_pages = _values(kp[kind]).shape[1]
         halves = ((grp.qkv, grp.finish),) + grp.more
 
         def layer(l, h, kps, vps, st, blk):
@@ -1126,15 +1106,6 @@ def _paged_forward(params, tokens, k_pages, v_pages, page_table, pos,
                 elif model.latent is not None:
                     ctx = _attend_latent(q, kpk, lj, tables[kind], pos,
                                          qmask, dtype, model.latent)
-                elif attn_impl == "paged":
-                    # the kernel takes one bank of pages: every layer's,
-                    # the layer folded into the page id
-                    ctx = paged_attention(q, _one_bank(kpk), _one_bank(vpk),
-                                          tables[kind] + lj * num_pages,
-                                          pos[:, 0], slopes=slopes)
-                    if qmask is not None:
-                        ctx = ctx * qmask[:, :, None, None].astype(ctx.dtype)
-                    ctx = ctx.astype(dtype).reshape(b, c, -1)
                 else:
                     ctx = _attend_rows(q, kpk, vpk, lj, tables[kind], pos,
                                        qmask, slopes, dtype, window,
@@ -1254,8 +1225,7 @@ def _dest(page_table, page_idx, ring: bool):
 def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
                       config, tp_axis=None, write_ok=None,
                       draft_layers: Optional[int] = None,
-                      attn_impl: str = "gather", with_counters: bool = False,
-                      state=None):
+                      with_counters: bool = False, state=None):
     """One decode step for every slot of the ragged active batch.
 
     ``tokens`` (B,) are the pending tokens (each slot's last emitted
@@ -1281,13 +1251,6 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     touched (the verification pass later overwrites them with
     byte-identical values, since layer i's k/v depend only on the token
     sequence and layers < i).
-
-    ``attn_impl``: ``"gather"`` (default) walks the page table in
-    chunks of whole pages as far as the longest ``seq_lens`` and
-    contracts the gathered rows as stored (:func:`_attend_rows`);
-    ``"paged"`` walks it a page a grid step in one fused Pallas pass
-    (ops/paged_attention.py) — same mask/bias semantics, int8 pages
-    dequantized in-register.
 
     Over a latent row (``PagedModel.latent``) ``k_pages`` is the one
     bank and ``v_pages`` None, there as in the result.
@@ -1337,7 +1300,7 @@ def paged_decode_step(params, tokens, k_pages, v_pages, page_table, seq_lens,
     x, k_pages, v_pages, counters, new_state = _paged_forward(
         params, tokens, k_pages, v_pages, page_table, pos,
         _like(page_table, dest_page), _like(page_table, dest_off), None,
-        model, tp_axis, attn_impl, n_layers=draft_layers,
+        model, tp_axis, n_layers=draft_layers,
         live=((seq_lens > 0)[:, None]
               if model.counters or model.state or summarised else None),
         state=state)
@@ -1409,8 +1372,7 @@ def copy_page(k_pages, v_pages, src, dst):
 
 
 def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
-                        n_valid, config, tp_axis=None, all_logits=False,
-                        attn_impl: str = "gather"):
+                        n_valid, config, tp_axis=None, all_logits=False):
     """Forward one CHUNK of C tokens per row straight through the pool.
 
     The prefill half of a chunked-prefill mixed step: ``tokens`` (B, C)
@@ -1430,11 +1392,6 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     prefill needs — or at EVERY chunk position, (B, C, V_local), with
     ``all_logits=True`` (self-speculative verification scores the whole
     draft bundle in one pass through this same paged path).
-
-    ``attn_impl="paged"`` reads through the fused Pallas page-table
-    walk in its ragged multi-token mode — the decode step's kernel, with
-    ``start`` as the per-row global query origin; pad queries beyond
-    ``n_valid`` are zeroed by the same qmask multiply.
     """
     model = describe(config, tp_axis)
     if model.kinds != (GLOBAL,):
@@ -1453,7 +1410,7 @@ def paged_prefill_chunk(params, tokens, k_pages, v_pages, page_table, start,
     dest_off = jnp.where(valid, pos % ps, 0)
     x, k_pages, v_pages, _, _ = _paged_forward(
         params, tokens, k_pages, v_pages, page_table, pos, dest_page,
-        dest_off, valid, model, tp_axis, attn_impl)
+        dest_off, valid, model, tp_axis)
     if all_logits:
         return model.logits(params, x), k_pages, v_pages        # (B, C, V)
     last = jnp.take_along_axis(x, (n_valid - 1)[:, None, None], axis=1)

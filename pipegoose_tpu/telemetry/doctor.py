@@ -308,19 +308,12 @@ class DoctorReport:
     # measures the same count from its own HLO parse). None on older
     # artifacts and backends without HLO text export.
     hlo_instructions: Optional[int] = None
-    # free-form program annotations the producer wants in the artifact
-    # (e.g. the serving engine's chosen paged-attention tile geometry).
-    # JSON-serializable values only. None on older artifacts.
-    extras: Optional[dict] = None
 
     def to_json(self) -> dict:
-        d = {"sharding": self.sharding.to_json(),
-             "memory": self.memory.to_json(),
-             "cost_flops": self.cost_flops,
-             "hlo_instructions": self.hlo_instructions}
-        if self.extras is not None:
-            d["extras"] = self.extras
-        return d
+        return {"sharding": self.sharding.to_json(),
+                "memory": self.memory.to_json(),
+                "cost_flops": self.cost_flops,
+                "hlo_instructions": self.hlo_instructions}
 
     @classmethod
     def from_json(cls, d: dict) -> "DoctorReport":
@@ -333,8 +326,7 @@ class DoctorReport:
                                else float(d["cost_flops"])),
                    hlo_instructions=(
                        None if d.get("hlo_instructions") is None
-                       else int(d["hlo_instructions"])),
-                   extras=d.get("extras"))
+                       else int(d["hlo_instructions"])))
 
     def format_table(self, max_rows: int = 32) -> str:
         return (self.sharding.format_table(max_rows=max_rows)

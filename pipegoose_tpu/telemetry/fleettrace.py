@@ -273,19 +273,6 @@ class FleetTracer:
         with self._lock:
             self.tracers[name] = tracer
 
-    def reset(self) -> None:
-        """Drop every stitched trace (active, completed ring, tail,
-        uid index) but keep replica registrations and the trace-id
-        sequence — reset between a compile warmup and the measured
-        replay so warmup traces never land in the reported
-        attribution."""
-        with self._lock:
-            self.active.clear()
-            self.completed.clear()
-            self.tail = TailSampler(self.tail.k)
-            self._uid_to_trace.clear()
-            self._awaiting_pass.clear()
-
     # -- plane hooks (ControlPlane drives these, in causal order) ----------
 
     def on_ingress(self, req: Any, t: float) -> int:

@@ -27,7 +27,7 @@ comment in the source is also exempt (visible, reviewable waiver).
     python scripts/lint_jit_safety.py              # lint, exit 1 on findings
     python scripts/lint_jit_safety.py --verbose    # also list allowed hits
 
-Wired into scripts/ci_fast.sh before the doctor gates.
+Tier-1 runs it on the tree (tests/test_lint_jit_safety.py).
 """
 from __future__ import annotations
 

@@ -67,11 +67,7 @@ def build_serving_reports(args, ctx, cfg, params, bloom):
     """Decode step AND the chunked-prefill program of the mixed step
     (prefix cache + chunking on): ISSUE 6 pins BOTH at zero
     partitioner-inserted resharding, so a PartitionSpec regression in
-    either half of the serving tick dies here at compile time. The
-    fused paged-attention variants (ISSUE 20, int8 pool — the kernel's
-    headline case) are pinned the same way: the Pallas call must lower
-    under the head-sharded mesh without the partitioner moving a page,
-    and their reports log the tile geometry the VMEM guard approved."""
+    either half of the serving tick dies here at compile time."""
     from pipegoose_tpu.serving import ServingEngine
 
     engine = ServingEngine(
@@ -79,18 +75,9 @@ def build_serving_reports(args, ctx, cfg, params, bloom):
         max_context=32, mesh=ctx.mesh, param_specs=bloom.tp_specs(params),
         prefix_cache=True, prefill_chunk=16,
     )
-    paged = ServingEngine(
-        params, cfg, num_slots=2, num_pages=16, page_size=8,
-        max_context=32, mesh=ctx.mesh, param_specs=bloom.tp_specs(params),
-        prefix_cache=True, prefill_chunk=16, kv_dtype="int8",
-        attn_kernel="paged",
-    )
     return {
         "decode_step": engine.doctor(large_bytes=args.large_bytes),
         "prefill_chunk": engine.doctor_chunk(large_bytes=args.large_bytes),
-        "decode_step_paged": paged.doctor(large_bytes=args.large_bytes),
-        "prefill_chunk_paged": paged.doctor_chunk(
-            large_bytes=args.large_bytes),
     }
 
 
@@ -113,7 +100,7 @@ def run_guards(name, report, args) -> int:
     return rc
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="compiled-program sharding & memory inspector")
     ap.add_argument("--tp", type=int, default=2)
@@ -140,7 +127,8 @@ def main() -> int:
     ap.add_argument("--expect-ppermute", action="store_true",
                     help="guard: fail (exit 2) unless the train step's "
                          "compiled schedule contains ppermute ring "
-                         "collectives (the overlap gate in ci_fast.sh)")
+                         "collectives (the overlap gate, "
+                         "tests/test_cli_gates.py)")
     ap.add_argument("--check", action="store_true",
                     help="run the regression guards; exit 2 on violation")
     ap.add_argument("--allow", action="append", default=[],
@@ -158,7 +146,7 @@ def main() -> int:
                     help="write the report(s) as JSON to this path")
     ap.add_argument("--quiet", action="store_true",
                     help="suppress the tables (guards/JSON only)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.fake_devices:
         from pipegoose_tpu.testing import force_cpu_devices

@@ -39,7 +39,7 @@ def _bool_set(s: str):
     return {"both": (False, True), "on": (True,), "off": (False,)}[s]
 
 
-def main() -> int:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         description="compile-time parallelism planner (static layout search)")
     ap.add_argument("--vocab", type=int, default=128)
@@ -112,7 +112,7 @@ def main() -> int:
                          "precision")
     ap.add_argument("--kv-dtype", default="fp", choices=("fp", "int8"),
                     help="serving-decode --check: configured KV page dtype")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     if args.fake_devices:
         from pipegoose_tpu.testing.fake_cluster import fake_cluster

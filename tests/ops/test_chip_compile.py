@@ -26,7 +26,6 @@ from pipegoose_tpu.ops import flash_attention as fa
 from pipegoose_tpu.ops import fused_ce
 from pipegoose_tpu.ops.flash_attention import flash_attention
 from pipegoose_tpu.ops.fused_ce import fused_ce_sums
-from pipegoose_tpu.ops.paged_attention import paged_attention
 from pipegoose_tpu.quant.matmul import quantized_matmul
 from pipegoose_tpu.serving import ServingEngine, kv_pool
 from pipegoose_tpu.serving.kv_pool import import_page_slab
@@ -34,7 +33,7 @@ from pipegoose_tpu.serving.kv_pool import import_page_slab
 # bloom-560m: hidden 1024, 16 heads x 64, padded vocab 250880; train
 # b8 x s1024, decode 8 slots over a 16-row-page pool.
 B, S, NH, HD, H, V = 8, 1024, 16, 64, 1024, 250880
-PS, W, PAGES = 16, 64, 4096
+PS = 16
 
 
 @pytest.fixture(scope="module")
@@ -138,23 +137,6 @@ def _quant_matmul(int4):
     return fn, [((B, k), jnp.bfloat16), q, scale]
 
 
-def _paged(quantized, ps=PS, c=1, nh=NH, hd=HD):
-    w = W * PS // ps
-    # a bank in the pool's layout: a position's heads in one row
-    if quantized:
-        bank = {"q": ((PAGES, ps, nh * hd), jnp.int8),
-                "scale": ((PAGES, ps, nh), jnp.float32)}
-    else:
-        bank = ((PAGES, ps, nh * hd), jnp.bfloat16)
-
-    def fn(q, kp, vp, pt, start, sl):
-        return paged_attention(q, kp, vp, pt, start, slopes=sl,
-                               interpret=False)
-
-    return fn, [((B, c, nh, hd), jnp.bfloat16), bank, bank,
-                ((B, w), jnp.int32), ((B,), jnp.int32), ((nh,), jnp.float32)]
-
-
 CASES = {
     "flash_fwd": lambda: _flash(False),
     "flash_fwd_bwd": lambda: _flash(True),
@@ -187,17 +169,6 @@ CASES = {
     "fused_ce_bwd_hv": lambda: _fused_ce(True, layout="hv"),
     "matmul_int8": lambda: _quant_matmul(False),
     "matmul_int4": lambda: _quant_matmul(True),
-    "paged_fp": lambda: _paged(False),
-    "paged_int8": lambda: _paged(True),
-    "paged_int8_ps32": lambda: _paged(True, ps=32),
-    "paged_fp_chunk": lambda: _paged(False, c=16),
-    # what one device of a tp=2 engine sees
-    "paged_int8_tp2_local": lambda: _paged(True, nh=NH // 2),
-    # head slabs narrower or wider than the 128 lanes they are cut at:
-    # an odd local head count at hd 64 (one head per 64-lane slab), and
-    # a head_dim that does not divide 128
-    "paged_fp_odd_heads": lambda: _paged(False, nh=3),
-    "paged_int8_hd96": lambda: _paged(True, nh=4, hd=96),
 }
 
 
@@ -251,7 +222,7 @@ def test_kernel_compiles_for_v5e(one_chip, as_default_device, case):
     # through (``%transpose_jvp_flash_bwd__.1``), so a reader searches
     called = [ln.split(" = ")[0] for ln in text.splitlines()
               if " custom-call(" in ln and "tpu_custom_call" in ln]
-    for name in KERNELS.get(case, ["paged_attention"]):
+    for name in KERNELS[case]:
         assert any(name in instruction for instruction in called), \
             (name, called)
 
@@ -630,8 +601,7 @@ def test_lowered_kernel_holds_its_name(case):
     text = jax.jit(fn).trace(*_shapes(shapes)).lower(
         lowering_platforms=("tpu",)).as_text()
     found = set(re.findall(r'kernel_name = "([^"]*)"', text))
-    assert found == set(NO_DEVICE_KERNELS.get(
-        case, KERNELS.get(case, ["paged_attention"])))
+    assert found == set(NO_DEVICE_KERNELS.get(case, KERNELS[case]))
 
 
 # -- the serving engine's pool programs, for their structure ------------------

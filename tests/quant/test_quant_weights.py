@@ -3,7 +3,7 @@ accuracy claim rests on, the pack/unpack nibble convention, target
 selection (block kernels only — the embedding doubles as the lm head
 and stays fp), the PartitionSpec derivation that keeps tp sharding
 unchanged, and the byte census the doctor satellite reports. These are
-the fast-tier bounds; the engine-level parity pins live in
+the bounds; the engine-level parity pins live in
 tests/serving/test_quantized.py."""
 import jax
 import jax.numpy as jnp

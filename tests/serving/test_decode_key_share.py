@@ -88,16 +88,11 @@ def test_key_share_is_what_the_steps_were_sent(setup, monkeypatch):
         pytest.approx(share)
 
 
-def test_key_share_of_an_idle_run_and_of_the_kernel(setup):
-    """No decode step: 0.0, like the occupancies. The Pallas kernel
-    walks a page a grid step, not this walk: the key is left out."""
+def test_key_share_of_an_idle_run(setup):
+    """No decode step: 0.0, like the occupancies."""
     cfg, params, prompts = setup
     kw = dict(num_slots=2, num_pages=32, page_size=PS, max_context=CONTEXT)
     eng = ServingEngine(params, cfg, **kw)
     _, metrics = eng.run([Request(prompt=prompts[0], max_new_tokens=1)])
     assert metrics["decode_steps"] == 0
     assert metrics["decode_key_share"] == 0.0
-    paged = ServingEngine(params, cfg, attn_kernel="paged", **kw)
-    _, metrics = paged.run([Request(prompt=prompts[0], max_new_tokens=3)])
-    assert metrics["decode_steps"] == 2
-    assert "decode_key_share" not in metrics

@@ -465,7 +465,6 @@ REFUSED = {
     "weight_dtype": {"weight_dtype": "int8"},
     "host_tier": {"host_tier": object(), "prefix_cache": False},
     "prefill_only": {"prefill_only": True, "prefill_chunk": 16},
-    "attn_kernel": {"attn_kernel": "paged"},
     "mesh": {"mesh": object()},
     "memledger": {"memledger": True},
 }
@@ -491,10 +490,6 @@ def test_the_paged_programs_refuse_what_the_engine_refuses(model):
         kv_pool.paged_decode_step(
             params, jnp.zeros((2,), i32), kp, vp, tables, jnp.zeros((2,), i32),
             cfg, write_ok=jnp.ones((2,), bool))
-    with pytest.raises(ValueError, match="paged kernel reads one cache kind"):
-        kv_pool.paged_decode_step(
-            params, jnp.zeros((2,), i32), kp, vp, tables, jnp.zeros((2,), i32),
-            cfg, attn_impl="paged")
     with pytest.raises(ValueError, match="a prefill chunk reads one cache "
                                          "kind"):
         kv_pool.paged_prefill_chunk(

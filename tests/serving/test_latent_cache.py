@@ -241,7 +241,6 @@ REFUSED = {
     "weight_dtype": {"weight_dtype": "int8"},
     "host_tier": {"host_tier": object(), "prefix_cache": False},
     "prefill_only": {"prefill_only": True, "prefill_chunk": 8},
-    "attn_kernel": {"attn_kernel": "paged"},
     "mesh": {"mesh": object()},
     "memledger": {"memledger": True},
 }
@@ -263,18 +262,10 @@ def test_the_paged_programs_refuse_what_the_engine_refuses(model):
         kv_pool.init_pages(desc, 16, PS, kv_dtype="int8")
     with pytest.raises(ValueError, match="one shard"):
         kv_pool.init_pages(desc, 16, PS, tp=2)
-    pages, _ = kv_pool.init_pages(desc, 16, PS)
-    i32 = jnp.int32
-    with pytest.raises(ValueError, match="paged kernel reads one cache kind "
-                                         "of keys and values"):
-        kv_pool.paged_decode_step(
-            params, jnp.zeros((2,), i32), pages, None,
-            jnp.zeros((2, 16), i32), jnp.zeros((2,), i32), cfg,
-            attn_impl="paged")
     with pytest.raises(ValueError, match="served on one device"):
         cfg.paged_model("tensor")
     assert _engine(cfg, params, kv_dtype="fp", weight_dtype="fp",
-                   attn_kernel="gather", prefix_cache=False).v_pages is None
+                   prefix_cache=False).v_pages is None
 
 
 def test_a_stacked_group_attends_once_a_layer(model):

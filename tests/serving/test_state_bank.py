@@ -174,7 +174,6 @@ REFUSED = {
     "weight_dtype": {"weight_dtype": "int8"},
     "host_tier": {"host_tier": object(), "prefix_cache": False},
     "prefill_only": {"prefill_only": True, "prefill_chunk": 8},
-    "attn_kernel": {"attn_kernel": "paged"},
     "mesh": {"mesh": object()},
     "memledger": {"memledger": True},
 }
@@ -205,7 +204,7 @@ def test_the_paged_programs_refuse_what_the_engine_refuses(model):
     with pytest.raises(ValueError, match="served on one device"):
         cfg.paged_model("tensor")
     assert _engine(cfg, params, kv_dtype="fp", weight_dtype="fp",
-                   attn_kernel="gather", prefix_cache=False).state
+                   prefix_cache=False).state
 
 
 # -- models without a state: the programs they built ---------------------------

@@ -263,7 +263,6 @@ REFUSED = {
     "weight_dtype": {"weight_dtype": "int8"},
     "host_tier": {"host_tier": object(), "prefix_cache": False},
     "prefill_only": {"prefill_only": True, "prefill_chunk": None},
-    "attn_kernel": {"attn_kernel": "paged"},
     "mesh": {"mesh": object()},
     "memledger": {"memledger": True},
 }
@@ -284,7 +283,7 @@ def test_a_model_with_two_cache_kinds_refuses_the_mode_by_name(model, mode):
 def test_the_defaults_spelled_out_are_not_refused(model):
     cfg, params, _ = model
     eng = _engine(cfg, params, kv_dtype="fp", weight_dtype="fp",
-                  attn_kernel="gather", prefix_cache=False)
+                  prefix_cache=False)
     assert eng.model.kinds == ("global", "window")
 
 

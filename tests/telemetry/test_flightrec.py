@@ -1,6 +1,6 @@
 """Flight recorder (telemetry/flightrec.py): ring bounds, structured
 triggers, atomic black-box dumps, and the recovery handshake — all
-host-side (no device work), so the whole file rides the fast tier."""
+host-side (no device work)."""
 import json
 import os
 import types

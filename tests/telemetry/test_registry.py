@@ -3,7 +3,6 @@ disabled-overhead contract that lets instrumentation live in library
 hot loops, and jit-trace safety (ISSUE 2 regression)."""
 import json
 import threading
-import time
 
 import jax
 import jax.numpy as jnp
@@ -102,33 +101,16 @@ def test_disabled_registry_records_nothing():
     assert c.value == 1.0
 
 
-def test_disabled_overhead_under_5us():
+def test_disabled_overhead_under_5us(empty_iterations):
     """The CI overhead guard (ISSUE 2): instrumentation stays ON in
     library code because a disabled counter inc / span entry costs next
-    to nothing. The bound is RELATIVE: 300 iterations of an empty loop
-    (5 µs where one takes 17 ns, as on the machine the bound was set on;
-    a span entry is ~130 there, quiet or loaded), each batch of calls
-    timed back to back with its batch of empty iterations, so whatever
-    slows the machine meets both."""
+    to nothing. The bound is RELATIVE (``conftest.empty_iterations``):
+    300 iterations of an empty loop; a span entry is ~130, quiet or
+    loaded."""
     from pipegoose_tpu.telemetry import span
 
     reg = MetricsRegistry(enabled=False)
     c = reg.counter("c")
-    n = 2000
-
-    def empty_iterations(fn):
-        """A call of ``fn`` in iterations of an empty loop: the median
-        over 15 batches of n calls against n iterations."""
-        ratios = []
-        for _ in range(15):
-            t0 = time.perf_counter()
-            for _ in range(n):
-                fn()
-            t1 = time.perf_counter()
-            for _ in range(n):
-                pass
-            ratios.append((t1 - t0) / (time.perf_counter() - t1))
-        return sorted(ratios)[len(ratios) // 2]
 
     def enter_span():
         with span("s", registry=reg):

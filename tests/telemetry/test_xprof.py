@@ -1,5 +1,5 @@
 """Measured step attribution (telemetry/xprof.py, ISSUE 14): trace
-parsing + schedule joining on synthetic events (fast tier), the real
+parsing + schedule joining on synthetic events, the real
 profiled shard_map program's per-axis buckets and sum-to-wall contract,
 and the host-clock fallback."""
 import json
@@ -31,7 +31,7 @@ def _ev(name, dur_us, module="jit_step", with_args=True):
     return e
 
 
-# -- parsing / attribution (pure host, fast tier) --------------------------
+# -- parsing / attribution (pure host) ------------------------------------
 
 
 def test_attribute_op_times_buckets_and_joins_schedule():

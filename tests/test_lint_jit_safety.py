@@ -1,7 +1,7 @@
 """Jit-safety lint (scripts/lint_jit_safety.py, ISSUE 7 satellite):
 rule detection on inline sources, allowlist/waiver semantics, and the
 gate itself — the shipped tree lints clean against the checked-in
-allowlist (the same invocation scripts/ci_fast.sh runs)."""
+allowlist."""
 import importlib.util
 import os
 import subprocess

@@ -248,6 +248,14 @@ def _run_with_chaos(seed, tmp_path, tag):
         ctx.destroy()
 
 
+def test_seeded_nonfinite_bomb_is_recovered_with_finite_losses(tmp_path):
+    """One seeded run end to end: the bomb fires once, is rolled back to
+    the last checkpoint once, and the run ends on finite losses."""
+    _, applied, restores, losses = _run_with_chaos(11, tmp_path, "a")
+    assert len(applied) == 1 and restores == 1
+    assert losses and all(np.isfinite(losses))
+
+
 def test_same_seed_same_injections_same_loss_trajectory(tmp_path):
     """The replayability contract end to end: two runs from one seed
     inject identically AND recover onto the identical loss trajectory —
