@@ -204,10 +204,9 @@ def _unsort_rows_bwd(order, g):
 _unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 
 
-def swiglu_grouped(
-    expert_params: dict, rows: jax.Array, sizes: jax.Array
-) -> jax.Array:
-    """down(silu(gate x) * up x) for rows sorted by expert: row block
+def _glu_grouped(act: Callable, expert_params: dict, rows: jax.Array,
+                 sizes: jax.Array) -> jax.Array:
+    """down(act(gate x) * up x) for rows sorted by expert: row block
     ``g`` (``sizes[g]`` rows) meets expert ``g``'s matrices. Kernels are
     stacked ``(E_held, in, out)``. Rows past ``sizes.sum()`` belong to
     no group and their result is unspecified."""
@@ -220,7 +219,23 @@ def swiglu_grouped(
 
     g = mm(rows, expert_params["gate"]["kernel"])
     u = mm(rows, expert_params["up"]["kernel"])
-    return mm(jax.nn.silu(g) * u, expert_params["down"]["kernel"])
+    return mm(act(g) * u, expert_params["down"]["kernel"])
+
+
+def swiglu_grouped(
+    expert_params: dict, rows: jax.Array, sizes: jax.Array
+) -> jax.Array:
+    """SwiGLU experts over rows sorted by expert: ``down(silu(gate x) *
+    up x)`` (:func:`_glu_grouped`)."""
+    return _glu_grouped(jax.nn.silu, expert_params, rows, sizes)
+
+
+def reglu_grouped(
+    expert_params: dict, rows: jax.Array, sizes: jax.Array
+) -> jax.Array:
+    """ReGLU experts over rows sorted by expert: ``down(relu(gate x) *
+    up x)``, the same three grouped products (:func:`_glu_grouped`)."""
+    return _glu_grouped(jax.nn.relu, expert_params, rows, sizes)
 
 
 def grouped_experts(

@@ -59,7 +59,10 @@ BLOOM is the first instance (:func:`bloom_model`), Laguna the second
 block), LongCat-Flash the fourth (``models/longcat_flash.py:paged_model``:
 a latent row, two attentions a block), EvaByte the fifth
 (``models/evabyte.py:paged_model``: a block window's ring and a summary
-a chunk under one softmax). A config object that has a ``paged_model(tp_axis)`` method
+a chunk under one softmax), SmallThinker the sixth
+(``models/smallthinker.py:paged_model``: Laguna's two kinds, a ring of
+257 pages; ``qkv`` makes the router's picks from the attention's input
+and hands them to ``finish`` as ``saved``). A config object that has a ``paged_model(tp_axis)`` method
 describes itself; any other is taken for a BLOOM (:func:`describe`).
 """
 from __future__ import annotations

@@ -136,6 +136,7 @@ from pipegoose_tpu.models._decode import (
 )
 from pipegoose_tpu.serving.blocks import (
     GLOBAL,
+    SLIDING,
     WINDOW,
     describe,
     ring_pages,
@@ -259,7 +260,9 @@ class _RunState:
         "tokens", "packed", "held", "held_tokens", "held_lens", "carry",
         "step_uploads", "uploaded",
         "keys_walked", "keys_reached", "window_table", "window_keys_walked",
-        "window_keys_reached", "occ_window", "peak_pages", "recycled0",
+        "window_keys_reached", "ring_rows", "ring_rows_wrapped",
+        "ring_keys_needed", "ring_keys_gathered", "occ_window", "peak_pages",
+        "recycled0",
         "experts_touched", "expert_skew", "rows_routed", "zero_pick_share",
         "state_rows_updated", "state_rows_live", "state_writes",
         "state_peak_slots", "summary_rows",
@@ -287,6 +290,12 @@ class _RunState:
         self.keys_walked = self.keys_reached = 0
         # the same for a window layer's ring, where the pool has one
         self.window_keys_walked = self.window_keys_reached = 0
+        # a SLIDING ring's rows, step by step: live rows and those at or
+        # past the window (their ring has wrapped); key columns the live
+        # rows' windows hold, and those the walk gathered for them (every
+        # row walks as far as the furthest)
+        self.ring_rows = self.ring_rows_wrapped = 0
+        self.ring_keys_needed = self.ring_keys_gathered = 0
         self.occ_window = 0.0
         self.peak_pages = dict.fromkeys(engine.pool.kinds, 0)
         self.recycled0 = engine.pool.recycled
@@ -369,7 +378,11 @@ class ServingEngine:
     matrix unit against a block-diagonal query.
     ``finish_run()["decode_key_share"]`` (gauge
     ``serving.decode_key_share``) is the share of the table's key
-    columns the plain decode steps walked."""
+    columns the plain decode steps walked; over a sliding ring
+    ``finish_run()["window"]`` gives the live rows whose ring has
+    wrapped (``wrapped_row_share``) and the key columns the live rows'
+    windows hold over those their steps' walks gathered
+    (``rows_useful_share``), counted by the same arithmetic."""
 
     def __init__(self, params, config, *, num_slots: int = 4,
                  num_pages: int = 64, page_size: int = 16,
@@ -552,6 +565,8 @@ class ServingEngine:
             WINDOW: reg.gauge("serving.pages_in_use.window"),
         }
         self._m_recycled = reg.counter("serving.window_pages_recycled_total")
+        self._m_ring_wrapped = reg.gauge("serving.ring_wrapped_share")
+        self._m_ring_useful = reg.gauge("serving.ring_rows_useful_share")
         self._m_experts = reg.gauge("serving.experts_touched_share")
         self._m_zero_picks = reg.gauge("serving.zero_pick_share")
         self._m_rows_useful = reg.gauge("serving.eva_rows_useful_share")
@@ -1972,10 +1987,19 @@ class ServingEngine:
                     # (a block window's only as far as a row stands in)
                     reach = int(ring_reach(rs.seq_lens, self.model.window,
                                            self.model.window_rule))
-                    rs.window_keys_walked += min(
+                    ring_walked = min(
                         walked_chunks(reach, self._walk_keys)
                         * self._walk_keys, self._ring_keys)
+                    rs.window_keys_walked += ring_walked
                     rs.window_keys_reached += self._ring_keys
+                    if self.model.window_rule == SLIDING:
+                        pos = rs.seq_lens[rs.seq_lens > 0]
+                        rs.ring_rows += pos.size
+                        rs.ring_rows_wrapped += int(
+                            (pos >= self.model.window).sum())
+                        rs.ring_keys_needed += int(np.minimum(
+                            pos + 1, self.model.window).sum())
+                        rs.ring_keys_gathered += pos.size * ring_walked
                 if counters:
                     self._note_counters(rs, counters)
                 if self.state:
@@ -2211,6 +2235,21 @@ class ServingEngine:
                               / max(rs.window_keys_reached, 1), 6)}
             metrics["window_key_share"] = round(
                 rs.window_keys_walked / max(rs.keys_walked, 1), 6)
+            if self.model.window_rule == SLIDING:
+                # both ring states in one step: the live rows whose ring
+                # has wrapped, and the key columns the live rows' windows
+                # hold over those the walk gathered for them (a row walks
+                # as far as the furthest row of its step)
+                metrics["window"] = {
+                    "wrapped_row_share": round(
+                        rs.ring_rows_wrapped / max(rs.ring_rows, 1), 6),
+                    "rows_useful_share": round(
+                        rs.ring_keys_needed
+                        / max(rs.ring_keys_gathered, 1), 6)}
+                self._m_ring_wrapped.set(
+                    metrics["window"]["wrapped_row_share"])
+                self._m_ring_useful.set(
+                    metrics["window"]["rows_useful_share"])
         if rs.experts_touched:
             n = len(rs.experts_touched)
             metrics["experts"] = {

@@ -89,6 +89,19 @@ def _flash(grad, shape=(B, S, NH, HD), dtype=jnp.bfloat16, **rule):
     return jax.grad(loss, argnums=(0, 1, 2)), qkv + [slopes]
 
 
+def _flash_gqa(q_shape, kv_heads, dtype=jnp.bfloat16, **rule):
+    """The forward a rotary model's prefill makes: no slopes, ``kv_heads``
+    KV heads under the queries' ``q_shape[2]``."""
+    b, s, _, hd = q_shape
+    kv = ((b, s, kv_heads, hd), dtype)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, alibi_slopes=None, interpret=False,
+                               scale=hd ** -0.5, **rule)
+
+    return fwd, [(q_shape, dtype), kv, kv]
+
+
 def _ring_chunk(grad=False):
     shapes = [((B, S, NH, HD), jnp.bfloat16)] * 3 + [((NH,), jnp.float32)]
 
@@ -160,6 +173,11 @@ CASES = {
     # 1,024 x 1,024 and the backward takes 512 x 512
     "flash_fwd_bwd_f32_w512": lambda: _flash(True, (1, 2048, 16, 512),
                                              jnp.float32),
+    # SmallThinker's 8,192-token prefill: 7 query heads a KV head, and a
+    # window layer's 4,096 keys
+    "flash_fwd_g7_think": lambda: _flash_gqa((1, 8192, 28, 128), 4),
+    "flash_fwd_g7_window_think": lambda: _flash_gqa((1, 8192, 28, 128), 4,
+                                                    window=4096),
     "ring_chunk": _ring_chunk,
     "ring_chunk_bwd": lambda: _ring_chunk(True),
     "fused_ce_fwd": lambda: _fused_ce(False),
@@ -185,6 +203,8 @@ KERNELS = {
     "flash_fwd_bwd_noncausal_hd128": ["flash_fwd", "flash_bwd"],
     "flash_fwd_bwd_odd_heads_hd64": ["flash_fwd", "flash_bwd"],
     "flash_fwd_bwd_f32_w512": ["flash_fwd", "flash_bwd"],
+    "flash_fwd_g7_think": ["flash_fwd"],
+    "flash_fwd_g7_window_think": ["flash_fwd"],
     "ring_chunk": ["flash_ring_fwd"],
     "ring_chunk_bwd": ["flash_ring_fwd", "flash_ring_dq", "flash_ring_dkv"],
     "fused_ce_fwd": ["fused_ce_fwd"],
@@ -1011,3 +1031,100 @@ def test_eva_prefill_compiles_its_kernels_and_forms_no_square_of_scores(
     # what the ring must hold, and a summary a chunk of the bucket
     assert cache["window"]["k"].shape == (EVA_L, 1, EVA_WINDOW, EVA_HEADS, 128)
     assert cache["global"]["k"].shape == (EVA_L, 1, s // PS, EVA_HEADS, 128)
+
+
+# -- a router before attention, both ring states in a step (PERF.md, PR 52) ----
+#
+# SmallThinker's decode step routes from the attention's input, walks a
+# ring of 257 pages on its window layers and every page on its global
+# ones; its 8,192-token prefill is flash attention at a group of 7 with
+# the 4,096-key window. As the pool's layout, all of it holds in the
+# compiled program or not at all.
+
+# the published heads (28 over 4 of 128), window and expert width; the
+# hidden size, the experts' count and the depth (one layer a kind) cut
+THINK_SLOTS, THINK_WINDOW, THINK_CONTEXT = 2, 4096, 8192
+
+
+def _think_engine(one_chip):
+    from pipegoose_tpu.models import smallthinker
+
+    cfg = smallthinker.SmallThinkerConfig(
+        vocab_size=512, hidden_size=512, num_hidden_layers=2,
+        moe_num_primary_experts=8, rope_layout=(0, 1),
+        sliding_window_layout=(0, 1), sliding_window_size=THINK_WINDOW,
+        use_flash=True, moe_block_tokens=2048, dtype=jnp.bfloat16)
+    params = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        jax.eval_shape(lambda k: smallthinker.init_params(cfg, k),
+                       jax.random.PRNGKey(0)))
+    return params, ServingEngine(
+        params, cfg, num_slots=THINK_SLOTS, num_pages=POOL_PAGES,
+        page_size=PS, max_context=THINK_CONTEXT)
+
+
+def test_think_step_updates_both_kinds_banks_in_place(one_chip):
+    """The compiled decode step aliases all four banks (keys and values
+    of both kinds) and its carry, holds no temporary of a bank layer's
+    plane, and copies or re-lays out none."""
+    params, eng = _think_engine(one_chip)
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    ring = THINK_WINDOW // PS + 1
+    kp = jax.tree_util.tree_map(sds, eng.k_pages)
+    vp = jax.tree_util.tree_map(sds, eng.v_pages)
+    assert {k: v.shape for k, v in kp.items()} == {
+        "global": (1, POOL_PAGES, PS, 4 * 128),
+        "window": (1, THINK_SLOTS * ring + 1, PS, 4 * 128)}
+    assert eng._carry_size == THINK_SLOTS * (2 + THINK_CONTEXT // PS + ring)
+    carry = jax.ShapeDtypeStruct((eng._carry_size,), jnp.int32,
+                                 sharding=one_chip)
+    compiled = eng._step.lower(params, carry, kp, vp).compile()
+    bank_bytes = 2 * sum(x.size * x.dtype.itemsize for x in kp.values())
+    ma = compiled.memory_analysis()
+    # 1,542 int32: over a tile, so whole tiles of 1,024 elements
+    carried = -(-eng._carry_size // 1024) * 4096
+    assert ma.alias_size_in_bytes == bank_bytes + carried
+    planes = {x.size for x in kp.values()}       # a layer a kind
+    assert ma.temp_size_in_bytes < min(planes) * 2, ma.temp_size_in_bytes
+    moved = []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]\S* (copy|transpose)\(",
+                         compiled.as_text()):
+        elements = math.prod(int(d) for d in m.group(2).split(","))
+        if any(elements % plane == 0 for plane in planes):
+            moved.append(m.group(0))
+    assert not moved, moved
+
+
+def test_think_prefill_compiles_flash_at_g7_and_forms_no_square_of_scores(
+        one_chip, monkeypatch):
+    """The prefill of an 8,192-token bucket for the described chip, the
+    flash kernel compiled by Mosaic and not interpreted, a call a layer
+    (the global one causal, the window one under its 4,096 keys): no
+    array is (.., 8192, 8192) or has as many elements as heads x 8,192 x
+    8,192."""
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    params, eng = _think_engine(one_chip)
+    s = THINK_CONTEXT
+    ids = jax.ShapeDtypeStruct((1, s), jnp.int32, sharding=one_chip)
+    with jax.default_device(next(iter(one_chip.device_set))):
+        low = eng._prefill.lower(params, ids, ids)
+    assert low.as_text().count('kernel_name = "flash_fwd"') == 2
+    text = low.compile().as_text()
+    called = [ln for ln in text.splitlines()
+              if " custom-call(" in ln and "tpu_custom_call" in ln
+              and "flash_fwd" in ln.split(" = ")[0]]
+    assert len(called) == 2
+    # the result the roofline reader tells a call by: heads, length, width
+    assert all("bf16[28,8192,128]" in ln.split(" = ")[1] for ln in called)
+    square = []
+    for m in re.finditer(r"= \(?(\w+)\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        if dims[-2:] == [s, s] or math.prod(dims) >= 28 * s * s:
+            square.append(m.group(0))
+    assert not square, square[:3]
+    cache = jax.eval_shape(eng._prefill, params, ids, ids)[1]
+    assert cache["global"]["k"].shape == cache["window"]["k"].shape \
+        == (1, 1, s, 4, 128)
