@@ -247,6 +247,72 @@ def _mlp(
     )
 
 
+def _columns_by_kind(params: dict, local_heads: int, hd: int) -> dict:
+    """The fused projection's parameters with their columns (every
+    leaf's LAST dimension: kernel, bias, a quantized ``q`` and its
+    ``scale``) permuted from HF's ``[head][q|k|v][hd]`` to ``[q|k|v]
+    [head][hd]``: one reshape-transpose-reshape a leaf, whose cotangent
+    is the inverse transpose of the same few MB."""
+
+    def by_kind(a: jax.Array) -> jax.Array:
+        lead = a.shape[:-1]
+        a = a.reshape(*lead, local_heads, 3, hd).swapaxes(-3, -2)
+        return a.reshape(*lead, 3 * local_heads * hd)
+
+    return jax.tree_util.tree_map(by_kind, params)
+
+
+def _project_qkv(
+    blk: dict,
+    x: jax.Array,
+    config: BloomConfig,
+    tp_axis: Optional[str],
+    overlap: bool = False,
+) -> tuple:
+    """q, k, v ``(B, S, local heads, head_dim)`` of the fused projection.
+
+    The stored projection keeps HF's column order ``[head][q|k|v]
+    [head_dim]``. Where a head is not whole 128-lane tiles
+    (``head_dim % 128``: bloom-560m's 64) a slice of the
+    ``(B, S, nh, 3, hd)`` view is part of a tile, and the chip's
+    compiler copies a ``(B, S, H)`` plane at every use of q, k or v;
+    there the projection's columns (6 MB a layer, not 100 MB of
+    activations) are regrouped by kind in the graph,
+    :func:`_columns_by_kind`, and q, k, v are three lane-aligned thirds
+    of its result. Each column is the same dot product either way. The
+    test is of the projection's layout alone: ``flash_attention``'s
+    ``_pairs_heads`` asks its own question (do two heads' blocks fit
+    VMEM) and neither calls the other, because ``(B, S, nh, hd)``
+    reshapes into either kernel layout for nothing."""
+    from pipegoose_tpu.telemetry.registry import get_registry
+
+    b = x.shape[0]
+    hd = config.head_dim
+    tp = jax.lax.axis_size(tp_axis) if tp_axis else 1
+    local_heads = _local_heads(config, tp)
+    # counted per TRACE, as flash.calls / flash.paired_calls are
+    registry = get_registry()
+    registry.counter("bloom.qkv_projections").inc(of_trace=True)
+    if hd % 128 == 0:
+        fused = column_parallel_linear(
+            blk["qkv"], x, tp_axis, overlap=overlap
+        )  # (B,S,3H/tp) — full-token either way
+        fused = fused.reshape(b, fused.shape[1], local_heads, 3, hd)
+        return fused[..., 0, :], fused[..., 1, :], fused[..., 2, :]
+    registry.counter("bloom.qkv_by_kind").inc(of_trace=True)
+    fused = column_parallel_linear(
+        _columns_by_kind(blk["qkv"], local_heads, hd), x, tp_axis,
+        overlap=overlap,
+    )  # (B,S,3H/tp) as [q|k|v][head][hd]
+    # the three slices' cotangents leave the chip's compiler as ONE bf16
+    # plane written a third at a time, no pad and no add
+    # (tests/ops/test_chip_compile.py holds it to that)
+    return tuple(
+        t.reshape(b, fused.shape[1], local_heads, hd)
+        for t in jnp.split(fused, 3, axis=-1)
+    )
+
+
 def _attention(
     blk: dict,
     x: jax.Array,
@@ -266,17 +332,9 @@ def _attention(
     every key anyway), the attention core runs full-sequence exactly as
     the monolithic path, and the output projection ring-reduce-scatters
     back to the token chunk."""
-    b = x.shape[0]
     hd = config.head_dim
-    tp = jax.lax.axis_size(tp_axis) if tp_axis else 1
-    local_heads = _local_heads(config, tp)
-
-    fused = column_parallel_linear(
-        blk["qkv"], x, tp_axis, overlap=overlap
-    )  # (B,S,3H/tp) — full-token either way
-    s = fused.shape[1]
-    fused = fused.reshape(b, s, local_heads, 3, hd)
-    q, k, v = fused[..., 0, :], fused[..., 1, :], fused[..., 2, :]
+    q, k, v = _project_qkv(blk, x, config, tp_axis, overlap)
+    b, s, local_heads = q.shape[:3]
 
     if config.use_flash:
         # fused kernel path: alibi from static slopes; causal + padding

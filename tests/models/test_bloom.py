@@ -206,3 +206,206 @@ def test_pad_for_tp_odd_vocab(devices):
         assert abs(out - ref) < 2e-4, (out, ref)
     finally:
         ctx.destroy()
+
+
+# -- the fused projection's columns, by kind under a 128-lane head ----------
+
+def _interleaved_qkv(blk, x, config, tp_axis, overlap=False):
+    """The split as it stood before the regroup (HF's ``[head][q|k|v]
+    [head_dim]`` columns taken apart as a five-dimensional view): the
+    reference every case below holds ``bloom._project_qkv`` to."""
+    from pipegoose_tpu.nn.tensor_parallel.layers import column_parallel_linear
+
+    tp = jax.lax.axis_size(tp_axis) if tp_axis else 1
+    fused = column_parallel_linear(blk["qkv"], x, tp_axis, overlap=overlap)
+    b, s = fused.shape[:2]
+    fused = fused.reshape(b, s, config.n_head // tp, 3, config.head_dim)
+    return fused[..., 0, :], fused[..., 1, :], fused[..., 2, :]
+
+
+def _attention_case(head_dim, use_flash, with_bias, n_head=2, seq=128):
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=n_head * head_dim,
+                            n_layer=1, n_head=n_head, use_flash=use_flash)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    blk = jax.tree_util.tree_map(lambda a: a[0], params["blocks"])["attn"]
+    # init_params gives zero biases: make the bias carry something
+    blk["qkv"]["bias"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(1), blk["qkv"]["bias"].shape)
+    if not with_bias:
+        del blk["qkv"]["bias"]
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, seq, cfg.hidden_size))
+    mask = jnp.ones((2, seq), jnp.int32).at[1, seq - 5:].set(0)
+    return cfg, blk, x, bloom.attention_bias(mask, cfg)
+
+
+@pytest.mark.parametrize("with_bias", [True, False], ids=["bias", "nobias"])
+@pytest.mark.parametrize("use_flash", [False, True], ids=["xla", "flash"])
+@pytest.mark.parametrize("head_dim", [32, 64, 128])
+def test_projection_by_kind_matches_the_interleaved_split(
+        monkeypatch, head_dim, use_flash, with_bias):
+    """Under a 128-lane head the projection's columns are regrouped by
+    kind in the graph; every output column is the same dot product, so
+    the attention's result equals the interleaved split's to the bit in
+    float32, and the gradients with respect to the STORED kernel and
+    bias (HF's order) and to ``x`` within round-off. At 128 the split
+    stays and the two are the same program."""
+    cfg, blk, x, bias = _attention_case(head_dim, use_flash, with_bias)
+
+    def attend(blk, x):
+        return bloom._attention(blk, x, bias, cfg, None)
+
+    def loss(blk, x):
+        return (attend(blk, x) * jnp.cos(jnp.arange(x.shape[-1]))).sum()
+
+    got, got_grads = attend(blk, x), jax.grad(loss, argnums=(0, 1))(blk, x)
+    monkeypatch.setattr(bloom, "_project_qkv", _interleaved_qkv)
+    want, want_grads = attend(blk, x), jax.grad(loss, argnums=(0, 1))(blk, x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert jax.tree_util.tree_structure(got_grads) \
+        == jax.tree_util.tree_structure(want_grads)
+    for g, w in zip(jax.tree_util.tree_leaves(got_grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-5)
+    # the stored tree is HF's whatever the path: (H, 3H) and (3H,)
+    assert got_grads[0]["qkv"]["kernel"].shape \
+        == (cfg.hidden_size, 3 * cfg.hidden_size)
+
+
+def test_at_a_whole_tile_head_the_lowered_attention_is_the_interleaved_split(
+        monkeypatch):
+    """``head_dim`` 128 (bloom-1b7): the condition is off and the lowered
+    text of ``_attention`` and of its gradient is the interleaved
+    split's, line for line."""
+    cfg, blk, x, bias = _attention_case(128, False, True)
+
+    def lowered():
+        fn = jax.grad(lambda blk, x: bloom._attention(
+            blk, x, bias, cfg, None).sum(), argnums=(0, 1))
+        return jax.jit(fn).lower(blk, x).as_text()
+
+    ours = lowered()
+    monkeypatch.setattr(bloom, "_project_qkv", _interleaved_qkv)
+    assert ours == lowered()
+    assert "x3x128x" in ours  # the five-dimensional view
+
+
+def test_quantized_projection_is_regrouped_with_its_scales():
+    """A quantized leaf's ``q`` and per-column ``scale`` carry the
+    columns in their last dimension as the kernel and the bias do:
+    ``_columns_by_kind`` permutes every leaf alike, int8 (a scale a
+    column) and int4 (a scale a group a column), and the regrouped
+    projection's columns are the interleaved one's, moved."""
+    from pipegoose_tpu.nn.tensor_parallel.layers import column_parallel_linear
+    from pipegoose_tpu.quant.weights import QuantSpec, quantize_params
+
+    nh, hd = 2, 64
+    kernel = jax.random.normal(jax.random.PRNGKey(0), (nh * hd, 3 * nh * hd))
+    bias = jax.random.normal(jax.random.PRNGKey(1), (3 * nh * hd,))
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 8, nh * hd))
+    for spec in (QuantSpec("int8"), QuantSpec("int4", 32)):
+        leaf = quantize_params(
+            {"blocks": {"attn": {"qkv": {"kernel": kernel, "bias": bias}}}},
+            spec)["blocks"]["attn"]["qkv"]
+        assert "q" in leaf and "kernel" not in leaf
+        regrouped = bloom._columns_by_kind(leaf, nh, hd)
+        assert jax.tree_util.tree_map(jnp.shape, regrouped) \
+            == jax.tree_util.tree_map(jnp.shape, leaf)
+        want = column_parallel_linear(leaf, x, None) \
+            .reshape(2, 8, nh, 3, hd).swapaxes(-3, -2).reshape(2, 8, -1)
+        got = column_parallel_linear(regrouped, x, None)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_tp2_shard_regroups_its_own_heads(devices, monkeypatch):
+    """Under ``tp_axis`` a shard holds whole heads and regroups its own
+    ``3 * local_heads * head_dim`` columns: the sharded attention equals
+    the unsharded one, and the interleaved split's under the same
+    mesh."""
+    cfg, blk, x, bias = _attention_case(64, False, True, n_head=4, seq=16)
+    ref = bloom._attention(blk, x, bias, cfg, None)
+    ctx = ParallelContext(tensor_parallel_size=2, data_parallel_size=4)
+    try:
+        specs = bloom.tp_specs({"blocks": {"attn": jax.tree_util.tree_map(
+            lambda a: a[None], blk)}})["blocks"]["attn"]
+        specs = jax.tree_util.tree_map(
+            lambda s: P(*s[1:]), specs, is_leaf=lambda s: isinstance(s, P))
+
+        def sharded():
+            return shard_map(
+                lambda blk, x: bloom._attention(blk, x, bias, cfg, "tensor"),
+                mesh=ctx.mesh, in_specs=(specs, P()), out_specs=P(),
+                check_vma=False)(blk, x)
+
+        got = sharded()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-5)
+        monkeypatch.setattr(bloom, "_project_qkv", _interleaved_qkv)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(sharded()),
+                                   rtol=1e-5, atol=1e-6)
+    finally:
+        ctx.destroy()
+
+
+@pytest.mark.parametrize("head_dim", [32, 64, 128])
+def test_stored_parameters_keep_hfs_layout(head_dim):
+    """What is stored, checkpointed and sharded is HF's ``[head][q|k|v]
+    [head_dim]`` at every width: the regroup lives in the graph. The
+    tree's shapes, its gradient's, and the HF state dict's fused weight
+    (the stored kernel transposed, untouched)."""
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=2 * head_dim,
+                            n_layer=2, n_head=2)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    h = cfg.hidden_size
+    assert params["blocks"]["attn"]["qkv"]["kernel"].shape == (2, h, 3 * h)
+    assert params["blocks"]["attn"]["qkv"]["bias"].shape == (2, 3 * h)
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 64, (2, 8)))
+    grads = jax.grad(bloom.loss_fn)(params, ids, None, ids, cfg)
+    assert jax.tree_util.tree_map(jnp.shape, grads) \
+        == jax.tree_util.tree_map(jnp.shape, params)
+    sd = bloom_params_to_hf_state_dict(params)
+    np.testing.assert_array_equal(
+        sd["transformer.h.1.self_attention.query_key_value.weight"],
+        np.asarray(params["blocks"]["attn"]["qkv"]["kernel"][1]).T)
+    # a head's q, k, v rows as HF reads them: [head][q|k|v][head_dim]
+    view = np.asarray(params["blocks"]["attn"]["qkv"]["kernel"][1]) \
+        .reshape(h, 2, 3, head_dim)
+    np.testing.assert_array_equal(
+        sd["transformer.h.1.self_attention.query_key_value.weight"]
+        .reshape(2, 3, head_dim, h)[1, 2], view[:, 1, 2, :].T)
+
+
+@pytest.mark.parametrize("head_dim,by_kind", [(64, True), (128, False)])
+def test_projections_by_kind_are_counted_when_traced(head_dim, by_kind):
+    """``bloom.qkv_projections`` counts every traced ``_attention``,
+    ``bloom.qkv_by_kind`` those that regrouped: equal under a 128-lane
+    head, 0 of some at a whole-tile head. Remat and the gradient trace a
+    block more than once, so the two are compared, not counted."""
+    from pipegoose_tpu.telemetry.registry import get_registry
+
+    cfg = bloom.BloomConfig(vocab_size=64, hidden_size=2 * head_dim,
+                            n_layer=2, n_head=2, remat=True)
+    params = bloom.init_params(cfg, jax.random.PRNGKey(0))
+    ids = jnp.zeros((1, 8), jnp.int32)
+    reg = get_registry()
+    was = reg.enabled
+    reg.enable()
+    try:
+        def counts():
+            return (reg.counter("bloom.qkv_projections").value,
+                    reg.counter("bloom.qkv_by_kind").value)
+
+        start = counts()
+        step = jax.jit(jax.grad(
+            lambda p: bloom.loss_fn(p, ids, None, ids, cfg)))
+        step(params)
+        traced = counts()
+        projections = traced[0] - start[0]
+        assert projections > 0
+        assert traced[1] - start[1] == (projections if by_kind else 0)
+        step(params)  # compiled: nothing is traced
+        assert counts() == traced
+    finally:
+        if not was:
+            reg.disable()

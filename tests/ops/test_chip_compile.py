@@ -336,29 +336,12 @@ def test_flash_results_keep_the_shapes_the_roofline_reader_tells_them_by(
     assert ",%d,%d]" % (heads, hd) not in text
 
 
-def test_remat_block_at_the_560m_shape_holds_no_plane_heads_over_positions(
-        one_chip, as_default_device, monkeypatch):
-    """The gradient of ONE checkpointed bloom-560m block (8 rows x 2,048
-    positions x 16 heads of 64), compiled for the described v5e: what the
-    train step's layer loop runs twice a layer. With a head a half-filled
-    tile the compiler held q, k, v and the kernels' results heads over
-    positions, ``bf16[8,16,2048,64]`` and ``bf16[128,2048,64]``, padded to
-    twice their bytes, and copied a plane nine times on the way in and
-    out (PERF.md, PR 49, step 0). Now: one ``flash_fwd`` (its result and
-    lse saved, not run again) and one ``flash_bwd``, every tensor they
-    touch the model's own ``bf16[8,2048,1024]`` row-major; NO array of a
-    plane's size has the positions before a 64-wide last dimension, and
-    the program's temporaries fall from 0.548 to 0.375 GB.
-
-    What this PR leaves (ROADMAP A8 b): BLOOM's interleaved projection is
-    still taken apart as ``(8, 2048, 16, 3, 64)``, sequence-minor, at a
-    plane's copy a use. What stays sequence-minor (``{1,2,0}``) among the
-    hidden-1024 arrays around the norms and the 1,024-wide matmuls is the
-    compiler's own choice, the parent's and the change's alike."""
+def _remat_block_gradient(one_chip, heads, hd):
+    """ONE checkpointed BLOOM block's gradient at 8 rows x 2,048
+    positions, traced for the described chip (shapes alone)."""
     from functools import partial
 
-    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
-    rows, seq, heads, hd = CELL_SHAPES["cell_560m"]
+    rows, seq = 8, 2048
     hidden = heads * hd
     cfg = bloom.BloomConfig(vocab_size=1024, hidden_size=hidden, n_layer=1,
                             n_head=heads, remat=True, use_flash=True,
@@ -378,8 +361,41 @@ def test_remat_block_at_the_560m_shape_holds_no_plane_heads_over_positions(
         return block(blk, x, bloom.attention_bias(mask, cfg)) \
             .astype(jnp.float32).sum()
 
-    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        blk, x, mask).compile()
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(blk, x, mask)
+
+
+def test_remat_block_at_the_560m_shape_holds_no_plane_heads_over_positions(
+        one_chip, as_default_device, monkeypatch):
+    """The gradient of ONE checkpointed bloom-560m block (8 rows x 2,048
+    positions x 16 heads of 64), compiled for the described v5e: what the
+    train step's layer loop runs twice a layer. With a head a half-filled
+    tile the compiler held q, k, v and the kernels' results heads over
+    positions, ``bf16[8,16,2048,64]`` and ``bf16[128,2048,64]``, padded to
+    twice their bytes, and copied a plane nine times on the way in and
+    out (PERF.md, PR 49, step 0). Now: one ``flash_fwd`` (its result and
+    lse saved, not run again) and one ``flash_bwd``, every tensor they
+    touch the model's own ``bf16[8,2048,1024]`` row-major; NO array of a
+    plane's size has the positions before a 64-wide last dimension.
+
+    Since PR 53 the projection's columns are regrouped by kind
+    (``bloom._project_qkv``): the interleaved ``(8, 2048, 16, 3, 64)``
+    view, which the compiler kept sequence-minor and copied a plane of at
+    every use (12 of the 14 plane-sized copies this test's parent held,
+    PR 49's count), is gone in every dtype; the projection
+    ``bf16[8,2048,3072]`` is row-major wherever it stands; the three
+    slices' gradient is assembled in place, a third at a time
+    (``dynamic-update-slice`` into ONE bf16 buffer: no ``pad``, no
+    float32 plane, with no ``custom_vjp`` to ask for it); and the
+    program's temporaries fall from 0.375 to 0.341 GB. TWO plane-sized
+    copies are left, the number PR 49's round read with the regroup:
+    ``flash_fwd``'s row-major result into the output projection's
+    sequence-minor operand and dO back (ROADMAP A8 c). What stays
+    sequence-minor (``{1,2,0}``) among the hidden-1024 arrays around the
+    norms and the 1,024-wide matmuls is the compiler's own choice."""
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    rows, seq, heads, hd = CELL_SHAPES["cell_560m"]
+    hidden = heads * hd
+    compiled = _remat_block_gradient(one_chip, heads, hd).compile()
     text = compiled.as_text()
     calls = _flash_calls(text)
     assert sorted(("flash_fwd" in n, "flash_bwd" in n) for n, *_ in calls) \
@@ -389,11 +405,36 @@ def test_remat_block_at_the_560m_shape_holds_no_plane_heads_over_positions(
         tensors = [s for s in results + operands if s.startswith("bf16")]
         assert set(tensors) == {plane}, (name, tensors)
         assert plane + "{2,1,0" in ln
-    for dims in re.findall(r"= \w+\[([\d,]+)\]\{", text):
+    moved = []
+    for dims, op in re.findall(r"= \w+\[([\d,]+)\]\{[^}]*\} ([\w-]+)\(", text):
         dims = [int(d) for d in dims.split(",")]
         if math.prod(dims) >= rows * seq * hidden:
             assert dims[-2:] != [seq, hd], dims
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.40e9
+            if op in ("copy", "transpose", "pad"):
+                moved.append((op, dims))
+    assert sorted(moved) == [("copy", [rows, seq, hidden])] * 2, moved
+    assert "[%d,%d,%d,3,%d]" % (rows, seq, heads, hd) not in text
+    fused = "bf16[%d,%d,%d]" % (rows, seq, 3 * hidden)
+    layouts = set(re.findall(re.escape(fused) + r"\{([\d,]+)", text))
+    assert layouts == {"2,1,0"}, layouts
+    assert re.search(re.escape(fused) + r"\{[^}]*\} dynamic-update-slice\(",
+                     text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.36e9
+
+
+def test_remat_block_at_a_whole_tile_head_keeps_the_interleaved_split(
+        one_chip, as_default_device, monkeypatch):
+    """The same block at 16 heads of 128 (bloom-1b7's width): a head is
+    a whole tile, ``head_dim % 128 == 0``, and the lowered gradient still
+    takes the projection apart as ``(8, 2048, 16, 3, 128)``; no column
+    of the weight is moved."""
+    monkeypatch.setattr(fa, "_resolve_interpret", lambda interpret: False)
+    text = _remat_block_gradient(one_chip, 16, 128).as_text()
+    assert "8x2048x16x3x128x" in text
+    assert "2048x16x3x128x" not in text.replace("8x2048x16x3x128x", "")
+    by_kind = _remat_block_gradient(one_chip, 16, 64).as_text()
+    assert "8x2048x16x3x64x" not in by_kind
+    assert "1024x16x3x64x" in by_kind  # the weight's columns, regrouped
 
 
 # width 256 at 4,096 positions is GLM-4.7-Flash's (its other kernels
